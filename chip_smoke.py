@@ -1,0 +1,516 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that demi_tpu's main path still
+starts on the chip.
+
+Drives sweep -> lift -> DPOR -> fuzz/minimize/replay once, through the
+``demi_tpu.cli`` verbs a user would call (and, for the lift, the public
+``demi_tpu.runner.lift_lane_to_host``), in ONE process: the process that
+holds the chip. Each phase checks its answer by the repo's own means (the
+host oracle is the plain reference), and any phase's failure is the
+script's failure: no phase is wrapped in an ``except``.
+
+    python chip_smoke.py                       # on a TPU, at SIZES["chip"]
+    python chip_smoke.py --size tiny --expect-platform cpu   # CPU tests
+
+It fails, before anything else, unless JAX's first device is the expected
+platform. The last line of standard output is one JSON object:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import shutil
+import sys
+import tempfile
+import time
+
+#: One table of sizes, one code path. "chip" is what the repo calls its
+#: accelerator size (bench.py's headline shape): 5-node raft, pool 96, 144
+#: steps, invariant checked on every delivery, 8,192 lanes per launch.
+#: "tiny" is the same path cut down for the CPU tests.
+SIZES = {
+    "chip": dict(
+        nodes=5, pool=96, steps=144, lanes=32768, chunk=8192, lifts=8,
+        dpor_batch=256, dpor_rounds=8,
+        min_nodes=3, min_events=12, min_steps=400, fuzz_executions=200,
+    ),
+    "tiny": dict(
+        nodes=3, pool=48, steps=64, lanes=64, chunk=32, lifts=2,
+        dpor_batch=16, dpor_rounds=2,
+        min_nodes=3, min_events=6, min_steps=96, fuzz_executions=200,
+    ),
+}
+
+_COMPILE_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+)
+
+
+class _Tee(io.TextIOBase):
+    """Standard output that also keeps what a verb printed, so its JSON
+    summary can be read back."""
+
+    def __init__(self, stream):
+        self.stream = stream
+        self.kept = io.StringIO()
+
+    def write(self, text):
+        self.kept.write(text)
+        return self.stream.write(text)
+
+    def flush(self):
+        self.stream.flush()
+
+
+class Smoke:
+    def __init__(self, size: dict, device: dict):
+        import jax
+
+        self.size = size
+        self.device = device
+        self.phases: dict = {}
+        self._compile = {"total": 0.0, "backend": 0.0}
+        self._cache = {"hits": 0, "misses": 0}
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration
+        )
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def close(self) -> None:
+        import jax
+
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
+
+    # -- measurement -------------------------------------------------------
+    def _on_duration(self, event: str, seconds: float, **_kw) -> None:
+        if event in _COMPILE_EVENTS:
+            self._compile["total"] += seconds
+        if event == _COMPILE_EVENTS[2]:
+            self._compile["backend"] += seconds
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            self._cache["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self._cache["misses"] += 1
+
+    def _peak_bytes(self):
+        import jax
+
+        peaks = [
+            (d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in jax.local_devices()
+        ]
+        peaks = [p for p in peaks if p is not None]
+        return max(peaks) if peaks else None
+
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """Time one phase: wall seconds, compile seconds apart from run
+        seconds (JAX's own trace/lower/compile duration events), the
+        persistent cache's hits and misses, and the peak device memory so
+        far. The record is kept only if the body did not raise."""
+        before = (dict(self._compile), dict(self._cache))
+        record: dict = {}
+        t0 = time.perf_counter()
+        yield record
+        wall = time.perf_counter() - t0
+        compile_s = self._compile["total"] - before[0]["total"]
+        record.update(
+            wall_s=round(wall, 3),
+            compile_s=round(compile_s, 3),
+            backend_compile_s=round(
+                self._compile["backend"] - before[0]["backend"], 3
+            ),
+            run_s=round(wall - compile_s, 3),
+            cache_hits=self._cache["hits"] - before[1]["hits"],
+            cache_misses=self._cache["misses"] - before[1]["misses"],
+            peak_bytes_in_use=self._peak_bytes(),
+        )
+        self.phases[name] = record
+        print(f"[smoke] {name}: {json.dumps(record)}", flush=True)
+
+    # -- verbs -------------------------------------------------------------
+    def verb(self, argv, want_rc=0):
+        """Run one ``demi_tpu`` verb in this process and return the JSON
+        summary it printed (None for verbs that print none)."""
+        from demi_tpu.cli import main
+
+        print(f"[smoke] $ demi_tpu {' '.join(argv)}", flush=True)
+        tee = _Tee(sys.stdout)
+        with contextlib.redirect_stdout(tee):
+            rc = main(list(argv))
+        if rc != want_rc:
+            raise RuntimeError(
+                f"demi_tpu {argv[0]} exited {rc}, expected {want_rc}"
+            )
+        summary = None
+        for line in tee.kept.getvalue().splitlines():
+            if line.startswith("{"):
+                summary = json.loads(line)
+        return summary
+
+    def check_device(self, summary: dict, lanes_per_launch=None) -> None:
+        """Hold a verb's summary to this process's device, and to every
+        local device when there are several."""
+        want = (
+            self.device["platform"], self.device["kind"],
+            self.device["count"],
+        )
+        got = (
+            summary["platform"], summary["device_kind"], summary["devices"]
+        )
+        check(got == want, f"summary names device {got}, smoke holds {want}")
+        if lanes_per_launch is not None and not (
+            lanes_per_launch % self.device["count"]
+        ):
+            sharding = summary["lane_sharding"]
+            check(
+                sharding["devices"] == self.device["count"],
+                f"lanes span {sharding['devices']} device(s), "
+                f"{self.device['count']} present",
+            )
+            check(
+                sharding["lanes_per_device"] * sharding["devices"]
+                >= lanes_per_launch,
+                f"sharding {sharding} does not hold {lanes_per_launch} lanes",
+            )
+
+
+def check(ok: bool, message: str) -> None:
+    if not ok:
+        raise AssertionError(f"chip_smoke: {message}")
+
+
+def _raft(nodes: int, bug=True) -> list:
+    return ["--app", "raft", "--nodes", str(nodes)] + (
+        ["--bug", "multivote"] if bug else []
+    )
+
+
+def _sweep_workload(z: dict) -> dict:
+    """The sweep verb's workload (its flag defaults spelled out), for the
+    shared builder the multi-process verbs use."""
+    return {
+        "app": "raft", "nodes": z["nodes"], "bug": "multivote", "seed": 0,
+        "num_events": 12, "max_messages": z["steps"], "pool": z["pool"],
+        "timer_weight": 0.2, "kill_weight": 0.05, "partition_weight": 0.0,
+    }
+
+
+def phase_native(smoke: Smoke) -> None:
+    """Build both native libraries from native/*.cpp, here, so a machine
+    without a compiler is seen and not guessed."""
+    from demi_tpu.native.build import build_library, native_source
+
+    with smoke.phase("native") as rec:
+        for src, stem in (
+            ("trace_analysis.cpp", "libdemi_analysis"),
+            ("record_codec.cpp", "libdemi_records"),
+        ):
+            so = build_library(native_source(src), stem, rebuild=True)
+            rec[stem] = "built" if so is not None else "no compiler"
+
+
+def phase_sweep(smoke: Smoke) -> dict:
+    """BASELINE config 1/5 shape: the fuzz sweep, in the default
+    (continuous) mode and as chunked whole-batch launches over the same
+    seeds. The two modes must agree lane for lane."""
+    z = smoke.size
+    base = ["sweep"] + _raft(z["nodes"]) + [
+        "--batch", str(z["lanes"]), "--chunk", str(z["chunk"]),
+        "--pool", str(z["pool"]), "--max-messages", str(z["steps"]),
+        "--strict-io",
+    ]
+    summaries = {}
+    for name, extra in (
+        ("sweep_continuous", []),
+        ("sweep_chunked", ["--sweep-mode", "chunked"]),
+        # The same kernels built a second time in the same process: what
+        # the persistent compile cache gives back.
+        ("sweep_chunked_warm", ["--sweep-mode", "chunked"]),
+    ):
+        with smoke.phase(name) as rec:
+            s = smoke.verb(base + extra)
+            smoke.check_device(s, lanes_per_launch=z["chunk"])
+            check(s["lanes"] == z["lanes"], f"{name}: {s['lanes']} lanes")
+            check(
+                s["overflow_lanes"] == 0,
+                f"{name}: {s['overflow_lanes']} overflow lanes (pool too small)",
+            )
+            check(s["violations"] > 0, f"{name}: no violation found")
+            check(s["unique_schedules"] > 0, f"{name}: no schedules counted")
+            rec.update(
+                lanes=s["lanes"], lanes_per_launch=z["chunk"],
+                unique_schedules=s["unique_schedules"],
+                violations=s["violations"], overflow_lanes=0,
+                lanes_digest=s["lanes_digest"],
+                lane_sharding=s.get("lane_sharding"),
+                verb_schedules_per_sec=s["schedules_per_sec"],
+            )
+        summaries[name] = s
+    first = summaries["sweep_continuous"]
+    for name, s in summaries.items():
+        for key in ("lanes_digest", "unique_schedules", "violations", "codes"):
+            check(
+                s[key] == first[key],
+                f"{name} disagrees with sweep_continuous on {key}: "
+                f"{s[key]} vs {first[key]}",
+            )
+    return first
+
+
+def _cache_configured() -> bool:
+    import jax
+
+    return bool(jax.config.jax_compilation_cache_dir)
+
+
+def check_warm_compile(smoke: Smoke) -> None:
+    """A cache that never hits must be visible: where a persistent cache
+    is configured, the second build of the same kernels loads them."""
+    cold = smoke.phases["sweep_chunked"]
+    warm = smoke.phases["sweep_chunked_warm"]
+    print(
+        f"[smoke] compile seconds cold {cold['backend_compile_s']} "
+        f"warm {warm['backend_compile_s']} (persistent cache "
+        f"{'on' if _cache_configured() else 'off'})",
+        flush=True,
+    )
+    if _cache_configured():
+        check(
+            warm["cache_hits"] > 0 and warm["cache_misses"] == 0,
+            f"persistent compile cache did not hit on the second build: {warm}",
+        )
+
+
+def phase_lift(smoke: Smoke, sweep_summary: dict) -> None:
+    """Violating lanes of that sweep, re-run traced on the device and
+    lifted through GuidedScheduler to the host oracle: each must
+    reproduce the same violation code. This is the check that the branch
+    the device takes computes what the host tier computes; a
+    GuideDivergence propagates."""
+    import jax
+    import numpy as np
+
+    from demi_tpu.apps.common import make_host_invariant
+    from demi_tpu.config import SchedulerConfig
+    from demi_tpu.device.encoding import lower_program, stack_programs
+    from demi_tpu.parallel.distributed import build_workload
+    from demi_tpu.runner import lift_lane_to_host
+
+    z = smoke.size
+    with smoke.phase("lift") as rec:
+        picked = sweep_summary["violating_seeds"][: z["lifts"]]
+        check(
+            len(picked) >= z["lifts"],
+            f"sweep named {len(picked)} violating lanes, need {z['lifts']}",
+        )
+        # The sweep verb's own workload builder and per-lane key scheme.
+        app, cfg, fuzzer = build_workload(_sweep_workload(z))
+        seeds = np.asarray([s for s, _ in picked], np.uint32)
+        progs = stack_programs([
+            lower_program(app, cfg, fuzzer.generate_fuzz_test(seed=int(s)))
+            for s in seeds
+        ])
+        keys = jax.vmap(
+            lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s)
+        )(seeds)
+        config = SchedulerConfig(invariant_check=make_host_invariant(app))
+        for lane, (seed, code) in enumerate(picked):
+            single, host = lift_lane_to_host(
+                app, cfg, progs, keys, lane, config
+            )
+            check(
+                int(single.violation) == code,
+                f"seed {seed}: traced re-run gave code "
+                f"{int(single.violation)}, sweep gave {code}",
+            )
+            check(
+                host.violation is not None and host.violation.code == code,
+                f"seed {seed}: host oracle gave {host.violation}, "
+                f"device gave code {code}",
+            )
+        rec.update(lanes_lifted=len(picked), host_agrees=True)
+
+
+def phase_dpor(smoke: Smoke) -> None:
+    """BASELINE config 2 shape. First the full round budget on the
+    correct protocol (a violating run stops at its first hit, so this is
+    the run that drives prescribed frontier rounds and the racing scan),
+    then the seeded bug, whose found interleaving the verb re-executes on
+    the host oracle."""
+    z = smoke.size
+    common = [
+        "--batch", str(z["dpor_batch"]), "--rounds", str(z["dpor_rounds"]),
+        "--pool", str(z["pool"]), "--max-messages", str(z["steps"]),
+        "--strict-io",
+    ]
+    with smoke.phase("dpor_rounds") as rec:
+        s = smoke.verb(["dpor"] + _raft(z["nodes"], bug=False) + common, 1)
+        smoke.check_device(s, lanes_per_launch=z["dpor_batch"])
+        check(not s["violation_found"], "correct raft reported a violation")
+        check(
+            s["interleavings"] == z["dpor_batch"] * z["dpor_rounds"],
+            f"ran {s['interleavings']} interleavings, budget was "
+            f"{z['dpor_batch']} x {z['dpor_rounds']}",
+        )
+        rec.update(
+            interleavings=s["interleavings"], racing_scan=s["racing_scan"],
+            lane_sharding=s.get("lane_sharding"),
+        )
+    with smoke.phase("dpor_find") as rec:
+        s = smoke.verb(["dpor"] + _raft(z["nodes"]) + common)
+        smoke.check_device(s, lanes_per_launch=z["dpor_batch"])
+        check(
+            s["violation_found"] and s["host_verified"],
+            "DPOR did not find a host-verified violation",
+        )
+        rec.update(
+            interleavings=s["interleavings"], deliveries=s["deliveries"],
+            host_verified=True, racing_scan=s["racing_scan"],
+        )
+
+
+def phase_minimize(smoke: Smoke, workdir: str) -> None:
+    """BASELINE config 3 shape: fuzz -> minimize (device-batched trials)
+    -> strict replay on the seeded 3-node raft bug."""
+    z = smoke.size
+    app = _raft(z["min_nodes"]) + [
+        "--num-events", str(z["min_events"]),
+        "--max-messages", str(z["min_steps"]),
+    ]
+    with smoke.phase("fuzz"):
+        smoke.verb(
+            ["fuzz"] + app + [
+                "--max-executions", str(z["fuzz_executions"]),
+                "-o", workdir, "--strict-io",
+            ]
+        )
+    with smoke.phase("minimize") as rec:
+        s = smoke.verb(["minimize"] + app + ["-e", workdir, "--strict-io"])
+        smoke.check_device(s)
+        if smoke.device["count"] > 1:
+            check(
+                s["lane_sharding"]["devices"] == smoke.device["count"],
+                f"minimize trials span {s['lane_sharding']}",
+            )
+        check(s["oracle"] == "device", "minimize did not use device trials")
+        check(s["mcs_verified"], "the MCS does not verify on the host oracle")
+        check(
+            0 < s["mcs_externals"] <= s["externals"],
+            f"MCS of {s['mcs_externals']} from {s['externals']} externals",
+        )
+        rec.update(
+            externals=s["externals"], mcs_externals=s["mcs_externals"],
+            deliveries=s["deliveries"],
+            minimized_deliveries=s["minimized_deliveries"],
+            replays=s["replays"], mcs_verified=True,
+            lane_sharding=s.get("lane_sharding"),
+        )
+    with smoke.phase("replay"):
+        # rc 0 == the strict replay reproduced the violation.
+        smoke.verb(["replay"] + app + ["-e", workdir])
+
+
+def phase_mesh_parity(smoke: Smoke) -> None:
+    """With several devices: the same seeds through the lane-sharded
+    kernel and through the one-device kernel give the same status,
+    violation and sched_hash for every lane."""
+    from demi_tpu.parallel.distributed import build_workload
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    z = smoke.size
+    with smoke.phase("mesh_parity") as rec:
+        app, cfg, fuzzer = build_workload(_sweep_workload(z))
+        gen = lambda s: fuzzer.generate_fuzz_test(seed=s)  # noqa: E731
+        seeds = range(z["chunk"])
+        one = SweepDriver(app, cfg, gen).run_chunk(seeds)
+        many = SweepDriver(app, cfg, gen, use_mesh=True).run_chunk(seeds)
+        check(
+            one.lane_sharding["devices"] == 1
+            and many.lane_sharding["devices"] == smoke.device["count"],
+            f"layouts {one.lane_sharding} / {many.lane_sharding}",
+        )
+        check(
+            one.lanes_digest == many.lanes_digest
+            and one.violations == many.violations,
+            "lane-sharded results differ from the one-device results",
+        )
+        rec.update(
+            lanes=z["chunk"], one_device=one.lane_sharding,
+            all_devices=many.lane_sharding,
+            lanes_digest=f"{many.lanes_digest:016x}",
+        )
+
+
+def run(size: dict, device: dict) -> dict:
+    smoke = Smoke(size, device)
+    workdir = tempfile.mkdtemp(prefix="demi_smoke_")
+    try:
+        phase_native(smoke)
+        sweep_summary = phase_sweep(smoke)
+        check_warm_compile(smoke)
+        phase_lift(smoke, sweep_summary)
+        phase_dpor(smoke)
+        phase_minimize(smoke, workdir)
+        if device["count"] > 1:
+            phase_mesh_parity(smoke)
+    finally:
+        shutil.rmtree(workdir, ignore_errors=True)
+        smoke.close()
+    return smoke.phases
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--size", choices=sorted(SIZES), default="chip")
+    parser.add_argument(
+        "--expect-platform", default="tpu", dest="expect_platform",
+        help="fail unless jax.devices()[0].platform is this (default tpu)",
+    )
+    args = parser.parse_args(argv)
+
+    # The device, before anything else.
+    import jax
+
+    devices = jax.devices()
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    print(
+        f"[smoke] device: platform={device['platform']} "
+        f"device_kind={device['kind']!r} count={device['count']}",
+        flush=True,
+    )
+    if device["platform"] != args.expect_platform:
+        print(
+            f"chip_smoke: expected a {args.expect_platform!r} device, JAX "
+            f"found {device['platform']!r}; nothing was run",
+            file=sys.stderr,
+        )
+        return 2
+    t0 = time.perf_counter()
+    phases = run(SIZES[args.size], device)
+    print(json.dumps({
+        "smoke": {
+            "size": args.size, "device": device,
+            "wall_s": round(time.perf_counter() - t0, 3), "phases": phases,
+        },
+        "claim": None,
+    }))
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -89,6 +89,26 @@ def _workload_discriminator(args) -> dict:
     return {"workload": f"{args.app}:{args.bug or 'none'}"}
 
 
+#: How many violating lanes the sweep summary names (``violating_seeds``).
+_VIOLATING_SEEDS_KEPT = 32
+
+
+def _device_fields() -> dict:
+    from .parallel.mesh import device_fields
+
+    return device_fields()
+
+
+def _sweep_driver(app, cfg, gen):
+    """The sweep verb's driver: lane-sharded over every local device
+    when this process has more than one (parallel/mesh.local_lane_mesh)."""
+    from .parallel.mesh import local_lane_mesh
+    from .parallel.sweep import SweepDriver
+
+    mesh = local_lane_mesh()
+    return SweepDriver(app, cfg, gen, mesh=mesh, use_mesh=mesh is not None)
+
+
 def _autotune_requested(args) -> bool:
     """``--autotune`` or ``DEMI_AUTOTUNE=1``. Process state is never
     mutated: the commands thread the answer explicitly to everything
@@ -432,8 +452,11 @@ def _dpor_checkpoint_run(args, app, cfg) -> int:
     # (below) so the checkpoint restores regardless of the new
     # environment's DEMI_SLEEP_SETS/DEMI_STATIC_PRUNE — same contract
     # as host_path.
+    from .parallel.mesh import local_lane_mesh
+
     dpor = DeviceDPOR(
         app, cfg, program, batch_size=args.batch,
+        mesh=local_lane_mesh(args.batch),
         static_independence=(
             bool(getattr(args, "static_prune", False))
             if ckpt is not None
@@ -582,7 +605,6 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
     its seed range) with the merged codes / dedup set / seed cursor
     checkpointed every N chunks; SIGTERM checkpoints at the next chunk
     boundary and ``demi_tpu resume`` continues at the next seed."""
-    from .parallel.sweep import SweepDriver
     from .persist import CheckpointStore, PreemptionGuard
 
     if _autotune_requested(args):
@@ -598,7 +620,7 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
         )
     store = CheckpointStore(args.checkpoint_dir)
     gen = lambda s: fuzzer.generate_fuzz_test(seed=args.seed + s)  # noqa: E731
-    driver = SweepDriver(app, cfg, gen)
+    driver = _sweep_driver(app, cfg, gen)
     chunk = min(args.batch, getattr(args, "chunk", None) or args.batch)
     state = {
         "seeds_done": 0, "chunks": 0, "violations": 0, "codes": {},
@@ -685,6 +707,7 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
         "overflow_lanes": state["overflow_lanes"],
         "resumed": resumed,
         "checkpoints": dict(store.stats),
+        **_device_fields(),
     }
     if driver.host_share is not None:
         summary["host_share"] = round(driver.host_share, 3)
@@ -1235,13 +1258,26 @@ def cmd_minimize(args) -> int:
         _obs_end(args, args.experiment)
         return 0
     # Device-batched trials are the default for DSL apps (the BASELINE
-    # north-star pipeline); --host falls back to the sequential STS oracle.
-    device_cfg = None
-    if args.peek and not args.host:
-        from .device.batch_oracle import default_device_config
+    # north-star pipeline); --host selects the sequential STS oracle.
+    # The checker is built here (not inside the gamut) so each level's
+    # candidate batch shards over every local device when this process
+    # has more than one, and so the summary can say how it was laid out.
+    checker = None
+    if not args.host:
+        from .device.batch_oracle import (
+            DeviceReplayChecker,
+            default_device_config,
+        )
+        from .parallel.mesh import local_lane_mesh
 
-        device_cfg = default_device_config(
-            app, trace, externals, replay_peek=args.peek
+        checker = DeviceReplayChecker(
+            app,
+            default_device_config(
+                app, trace, externals,
+                **({"replay_peek": args.peek} if args.peek else {}),
+            ),
+            config,
+            mesh=local_lane_mesh(),
         )
     with obs.span("cli.minimize", app=args.app):
         if getattr(args, "streaming", False):
@@ -1260,7 +1296,7 @@ def cmd_minimize(args) -> int:
             result = drain_stream(run_the_gamut_streaming(
                 config, fr, wildcards=not args.no_wildcards,
                 app=None if args.host else app,
-                device_cfg=device_cfg,
+                checker=checker,
                 checkpoint_dir=args.experiment, resume=args.resume,
                 stage_budget_seconds=args.stage_budget,
             ))
@@ -1280,7 +1316,7 @@ def cmd_minimize(args) -> int:
             result = run_the_gamut(
                 config, fr, wildcards=not args.no_wildcards,
                 app=None if args.host else app,
-                device_cfg=device_cfg,
+                checker=checker,
                 checkpoint_dir=args.experiment, resume=args.resume,
                 stage_budget_seconds=args.stage_budget,
             )
@@ -1293,6 +1329,26 @@ def cmd_minimize(args) -> int:
         stats=result.stats,
     )
     print(f"MCS + minimized trace saved to {args.experiment}")
+    # The MCS is re-checked on the host STS oracle against the ORIGINAL
+    # trace — the plain reference, independent of the device trials.
+    from .schedulers.replay import sts_oracle
+
+    verified = sts_oracle(config, trace).test(
+        list(result.mcs_externals), violation
+    )
+    summary = {
+        "externals": len(externals),
+        "mcs_externals": len(result.mcs_externals),
+        "deliveries": len(trace.deliveries()),
+        "minimized_deliveries": len(result.final_trace.deliveries()),
+        "replays": result.stats.total_replays,
+        "mcs_verified": verified is not None,
+        "oracle": "host" if args.host else "device",
+        **_device_fields(),
+    }
+    if checker is not None and checker.lane_sharding is not None:
+        summary["lane_sharding"] = checker.lane_sharding
+    print(json.dumps(summary))
     _obs_end(args, args.experiment)
     return 0
 
@@ -1359,6 +1415,7 @@ def cmd_sweep(args) -> int:
                 "pool": args.pool,
             },
         )
+        summary["rehearsal"] = True
         print(json.dumps(summary))
         _obs_end(args)
         return 0
@@ -1384,6 +1441,14 @@ def cmd_sweep(args) -> int:
         return _sweep_checkpoint_run(args, app, cfg, fuzzer)
     gen = lambda s: fuzzer.generate_fuzz_test(seed=args.seed + s)  # noqa: E731
     chunk = min(args.batch, getattr(args, "chunk", None) or args.batch)
+    violating: list = []
+
+    def note_violations(seeds, codes) -> None:
+        for seed, code in zip(seeds, codes):
+            if len(violating) >= _VIOLATING_SEEDS_KEPT:
+                return
+            violating.append([int(seed), int(code)])
+
     autotune_summary = None
     if _autotune_requested(args):
         # Closed loop: calibrate (variant, chunk) — cache hit skips the
@@ -1415,6 +1480,7 @@ def cmd_sweep(args) -> int:
         driver = SweepDriver(
             app, cfg, gen, variant=decision.params.get("variant")
         )
+        driver.violation_hook = note_violations
         controller = ExplorationController(fuzzer)
         # --sweep-mode continuous rides the lane-compacted continuous
         # driver with segment-boundary reward attribution (lanes tagged
@@ -1433,7 +1499,8 @@ def cmd_sweep(args) -> int:
             },
         }
     else:
-        driver = SweepDriver(app, cfg, gen)
+        driver = _sweep_driver(app, cfg, gen)
+        driver.violation_hook = note_violations
         # Default: lane-compacted continuous sweep (finished lanes are
         # harvested and refilled at segment boundaries). --sweep-mode
         # chunked launches fixed whole-batch kernels instead.
@@ -1444,11 +1511,22 @@ def cmd_sweep(args) -> int:
         "violations": result.violations,
         "codes": {str(c): n for c, n in result.codes.items()},
         "first_violating_seed": result.first_violating_seed,
+        # The first few violating lanes as [seed, code]: what a caller
+        # needs to re-run them traced and lift them to the host oracle
+        # (runner.lift_lane_to_host) without sweeping again.
+        "violating_seeds": violating,
         "overflow_lanes": result.overflow_lanes,
+        # Order-free digest of every lane's (seed, status, code,
+        # sched_hash): equal across modes, chunkings and device counts
+        # for the same seeds (parallel/sweep.lanes_digest).
+        "lanes_digest": f"{result.lanes_digest:016x}",
         # Wall-clock aggregate (per-chunk seconds overlap under async
         # dispatch; this one never double-counts).
         "schedules_per_sec": round(result.schedules_per_sec_wall, 1),
+        **_device_fields(),
     }
+    if result.lane_sharding is not None:
+        summary["lane_sharding"] = result.lane_sharding
     if result.occupancy is not None:
         summary["occupancy"] = round(result.occupancy, 3)
     if driver.host_share is not None:
@@ -1544,9 +1622,12 @@ def cmd_dpor(args) -> int:
         )
         if host_shard_decision.shards > 1:
             os.environ["DEMI_HOST_SHARDS"] = str(host_shard_decision.shards)
+    from .parallel.mesh import local_lane_mesh
+
     oracle = DeviceDPOROracle(
         app, cfg, config, batch_size=args.batch, max_rounds=args.rounds,
         autotune=autotune, double_buffer=double_buffer,
+        mesh=local_lane_mesh(args.batch),
         static_independence=(
             True if getattr(args, "static_prune", False) else None
         ),
@@ -1557,11 +1638,24 @@ def cmd_dpor(args) -> int:
     _profile_begin(args)
     with obs.span("cli.dpor", app=args.app):
         trace = oracle.test(program, None)
+    from .native import scan_backend
+
     summary = {
         "interleavings": oracle.last_interleavings,
         "violation_found": trace is not None,
         "deliveries": len(trace.deliveries()) if trace is not None else None,
+        # A found interleaving is returned only after GuidedScheduler
+        # re-executed it on the host oracle and the violation matched
+        # (DeviceDPOROracle.test), so "found" means host-verified.
+        "host_verified": trace is not None,
+        # Which implementation served the host-half racing scan: the C++
+        # library built from native/trace_analysis.cpp, or its NumPy twin
+        # (no compiler, or a degraded surface).
+        "racing_scan": scan_backend(),
+        **_device_fields(),
     }
+    if oracle.lane_sharding is not None:
+        summary["lane_sharding"] = oracle.lane_sharding
     _profile_end(args, summary, app, cfg)
     if oracle.host_share() is not None:
         # Host-vs-device wall split across the frontier rounds (also the
@@ -2376,9 +2470,12 @@ def main(argv: Optional[list] = None) -> int:
     )
     p.add_argument(
         "--processes", type=int, default=1,
-        help=">1: multi-process jax.distributed sweep (seed-space "
+        help=">1: CPU REHEARSAL of the multi-process jax.distributed "
+             "sweep: N processes on virtual CPU devices (seed-space "
              "partition per process, summaries aggregated over the "
-             "distributed runtime)",
+             "distributed runtime). It never uses a chip; the summary "
+             "says platform cpu. On a multi-chip host plain `sweep` "
+             "already shards over every local chip in one process",
     )
     checkpoint_flags(p, 5, "chunks")
     strict_io_flags(p)

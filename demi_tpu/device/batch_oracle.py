@@ -131,12 +131,9 @@ class DeviceReplayChecker:
             from ..parallel.mesh import shard_replay_kernel
 
             if impl == "pallas":
-                import sys
-
-                print(
-                    "DeviceReplayChecker: mesh sharding uses the XLA "
-                    "replay kernel; ignoring impl=pallas",
-                    file=sys.stderr,
+                raise ValueError(
+                    "DeviceReplayChecker: mesh sharding runs the XLA "
+                    "replay kernel; impl='pallas' has no sharded replay twin"
                 )
             self.kernel = _counted_kernel(
                 shard_replay_kernel(app, cfg, mesh), "replay-mesh"
@@ -152,6 +149,9 @@ class DeviceReplayChecker:
                 make_replay_kernel(app, cfg), "replay"
             )
         self.max_records = cfg.max_steps + cfg.max_external_ops
+        # Layout of the widest replay launch so far: devices its output
+        # spanned and lanes on each (mesh.lane_sharding_summary).
+        self.lane_sharding: Optional[dict] = None
         # Prefix-fork (device/fork.py, DEMI_PREFIX_FORK=1 / --prefix-fork):
         # a level's candidates are identical up to the first removed index,
         # so the shared prefix is replayed ONCE on a trunk lane and each
@@ -163,13 +163,11 @@ class DeviceReplayChecker:
         if prefix_fork_enabled(prefix_fork):
             from .fork import PrefixForker, make_replay_prefix_runner
 
-            if impl == "pallas" and mesh is None:
-                import sys
-
-                print(
+            if impl == "pallas":
+                raise ValueError(
                     "DeviceReplayChecker: prefix-fork trunk/fork lanes run "
-                    "on the XLA replay kernel (bit-identical verdicts)",
-                    file=sys.stderr,
+                    "on the XLA replay kernel; drop impl='pallas' or "
+                    "prefix_fork"
                 )
             if mesh is not None:
                 from ..parallel.mesh import shard_replay_kernel
@@ -427,6 +425,7 @@ class DeviceReplayChecker:
             rows.append(np.repeat(first[None], pad, axis=0))
         batch = np.concatenate(rows) if len(rows) > 1 else rows[0]
         res = self.kernel(batch, replay_keys(bucket))
+        self._note_sharding(res.violation)
         self.pipeline_stats["launches"] += 1
         self.pipeline_stats["lanes_launched"] += bucket
         pending.lanes_launched += bucket
@@ -516,6 +515,15 @@ class DeviceReplayChecker:
         # group padding) is simply dropped: speculation only ever rides
         # lanes that already exist — it never pays for its own launch.
 
+    def _note_sharding(self, violation_dev) -> None:
+        from ..parallel.mesh import lane_sharding_summary
+
+        seen = lane_sharding_summary(violation_dev)
+        width = seen["devices"] * seen["lanes_per_device"]
+        prev = self.lane_sharding
+        if prev is None or width > prev["devices"] * prev["lanes_per_device"]:
+            self.lane_sharding = seen
+
     def _pull_codes(self, violation_dev, bucket: int) -> np.ndarray:
         """The ONE blocking verdict pull of the synchronous paths:
         budget-ledgered (dispatch+harvest bracket the inline block) and
@@ -529,6 +537,7 @@ class DeviceReplayChecker:
         arr = np.asarray(violation_dev)
         if PROFILER.enabled:
             PROFILER.block("replay", bucket, time.perf_counter() - t0)
+        self._note_sharding(violation_dev)
         if self.launch_budget is not None:
             self.launch_budget.note_harvest("minimize", bucket)
         return arr

@@ -309,6 +309,11 @@ class ContinuousSweepDriver:
         # kernel driven with PRNGKey(seed). SweepDriver passes its
         # fold_in(base_key, seed) scheme for cross-mode parity.
         self.key_fn = key_fn or jax.random.PRNGKey
+        # Vectorized key derivation (a per-seed Python loop costs 10s of
+        # ms per refill round at big batches): key_fn must trace under
+        # jit(vmap) over uint32 seeds. A key_fn that cannot is a caller
+        # bug, and a device failure here is not answered by a host loop.
+        self._vkeys = jax.jit(jax.vmap(self.key_fn))
         # program_key(seed) -> hashable: callers whose generator is
         # periodic in seed (config-5 style sweeps) pass the period key so
         # refill skips re-lowering — at 1e5+ lanes host-side lowering
@@ -337,10 +342,8 @@ class ContinuousSweepDriver:
             self.segment = make_segment_kernel_pallas(
                 app, cfg, seg_steps, block_lanes=block_lanes, mesh=mesh
             )
-        elif impl == "xla":
-            self.segment = make_segment_kernel(app, cfg, seg_steps, mesh=mesh)
         else:
-            raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
+            self.segment = make_segment_kernel(app, cfg, seg_steps, mesh=mesh)
         self.mesh = mesh
         self.init = make_init_kernel(app, cfg, mesh=mesh)
         self.refill = make_refill_kernel(app, cfg, mesh=mesh)
@@ -359,6 +362,8 @@ class ContinuousSweepDriver:
         # much the host-side refill path costs.
         self.last_segment_seconds: float = 0.0
         self.last_harvest_seconds: float = 0.0
+        # Devices the last _run's lane state spanned, lanes on each.
+        self.last_lane_sharding: Optional[dict] = None
 
     def _record_round_stats(self, state, finished, vio) -> None:
         """Fold one harvest round's finished lanes into the registry
@@ -446,21 +451,6 @@ class ContinuousSweepDriver:
         live_lane_steps = 0
         total_lane_steps = 0
 
-        # Vectorized key derivation: the per-seed Python loop costs
-        # 10s of ms per refill round at big batches (a visible slice of
-        # harvest overhead at 1e5+ lanes). Falls back to the loop for
-        # key_fns that don't trace.
-        vkeys = getattr(self, "_vkeys", None)
-        if vkeys is None:
-            try:
-                vkeys = jax.jit(jax.vmap(self.key_fn))
-                vkeys(jnp.arange(2, dtype=jnp.uint32))  # traceability probe
-            except Exception:
-                vkeys = lambda seeds: jnp.stack(  # noqa: E731
-                    [self.key_fn(int(s)) for s in seeds]
-                )
-            self._vkeys = vkeys
-
         def keys_for(seeds):
             return self._vkeys(jnp.asarray(seeds, jnp.uint32))
 
@@ -480,6 +470,7 @@ class ContinuousSweepDriver:
 
         self.last_segment_seconds = 0.0
         self.last_harvest_seconds = 0.0
+        self.last_lane_sharding = None
         while done_count < total_lanes:
             total_lane_steps += b * self.seg_steps
             live_lane_steps += int(active.sum()) * self.seg_steps
@@ -494,6 +485,10 @@ class ContinuousSweepDriver:
             # device-segment time, the rest of the iteration is harvest.
             _status_sync = np.asarray(state.status)
             t_harvest = time.perf_counter()
+            if self.last_lane_sharding is None:
+                from ..parallel.mesh import lane_sharding_summary
+
+                self.last_lane_sharding = lane_sharding_summary(state.status)
             self.last_segment_seconds += t_harvest - t_seg
             steps_run = np.minimum(
                 steps_run + self.seg_steps, self.cfg.max_steps

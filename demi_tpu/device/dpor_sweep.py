@@ -452,6 +452,38 @@ def make_dpor_kernel(
     )
 
 
+def lane_keys(seeds):
+    """The DPOR lane key scheme: ``fold_in(PRNGKey(0), seed)`` per lane.
+    One definition for the in-process loop and the fleet worker, which
+    receives seeds — not keys — on the wire."""
+    return jax.vmap(
+        lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s)
+    )(np.asarray(seeds, np.uint32))
+
+
+def build_dpor_kernel(
+    app: DSLApp, cfg: DeviceConfig, mesh=None, start_state: bool = False,
+    sleep_cap: int = 0, commute_matrix=None,
+):
+    """``make_dpor_kernel`` or, under a mesh, its lane-sharded twin
+    (parallel/mesh.py) — one selector shared by ``DeviceDPOR`` and
+    ``DeviceDPOROracle`` so the two cannot build different kernels for
+    the same (mesh, sleep) choice."""
+    if mesh is None:
+        return make_dpor_kernel(
+            app, cfg, start_state=start_state, sleep_cap=sleep_cap,
+            commute_matrix=commute_matrix,
+        )
+    from ..parallel.mesh import shard_dpor_kernel, shard_dpor_sleep_kernel
+
+    if sleep_cap > 0:
+        return shard_dpor_sleep_kernel(
+            app, cfg, mesh, sleep_cap, commute_matrix=commute_matrix,
+            start_state=start_state,
+        )
+    return shard_dpor_kernel(app, cfg, mesh, start_state=start_state)
+
+
 # ---------------------------------------------------------------------------
 # Host-side racing analysis over parent-tracked records
 # ---------------------------------------------------------------------------
@@ -653,6 +685,7 @@ class DeviceDPOROracle:
         host_path: Optional[str] = None,
         static_independence=None,
         sleep_sets=None,
+        mesh=None,
     ):
         from ..minimization.pipeline import async_min_enabled
         from .fork import prefix_fork_enabled
@@ -660,6 +693,7 @@ class DeviceDPOROracle:
         self.app = app
         self.cfg = cfg
         self.config = config
+        self.mesh = mesh
         self.batch_size = batch_size
         self.max_rounds = max_rounds
         self.last_interleavings = 0
@@ -717,20 +751,21 @@ class DeviceDPOROracle:
         self.autotune = autotune
         self._async = async_min_enabled(async_min)
         self._double_buffer = double_buffer
-        # Shared kernels (pallas builds its own per-instance closures;
-        # mesh sharding isn't an oracle concern).
+        # Shared kernels (pallas builds its own per-instance closures):
+        # under a mesh every instance's rounds shard over the same
+        # lane-sharded twin.
         impl = os.environ.get("DEMI_DEVICE_IMPL", "xla")
         self._kernel = (
-            make_dpor_kernel(
-                app, cfg, sleep_cap=self._sleep_kernel_cap,
+            build_dpor_kernel(
+                app, cfg, mesh=mesh, sleep_cap=self._sleep_kernel_cap,
                 commute_matrix=self._sleep_matrix,
             )
             if impl != "pallas"
             else None
         )
         self._fork_kernel = (
-            make_dpor_kernel(
-                app, cfg, start_state=True,
+            build_dpor_kernel(
+                app, cfg, mesh=mesh, start_state=True,
                 sleep_cap=self._sleep_kernel_cap,
                 commute_matrix=self._sleep_matrix,
             )
@@ -822,6 +857,15 @@ class DeviceDPOROracle:
             ),
         }
 
+    @property
+    def lane_sharding(self) -> Optional[dict]:
+        """Devices a whole-batch round's output spanned and lanes on each
+        (None before any round ran) — what the CLI summary reports."""
+        for inst in self._instances.values():
+            if inst.lane_sharding is not None:
+                return inst.lane_sharding
+        return None
+
     def host_share(self) -> Optional[float]:
         """Host-vs-device wall-time split summed across the resumable
         instances (None before any round ran) — the CLI summary's
@@ -839,6 +883,7 @@ class DeviceDPOROracle:
 
             inst = DeviceDPOR(
                 self.app, self.cfg, externals, self.batch_size,
+                mesh=self.mesh,
                 prefix_fork=self.prefix_fork,
                 double_buffer=self._double_buffer,
                 kernel=self._kernel,
@@ -1208,35 +1253,19 @@ class DeviceDPOR:
             # §2.8: the batch axis covers EVERY batched workload, the
             # search kernels included). Rounds are padded to batch_size,
             # which must divide over the mesh axis.
-            from ..parallel.mesh import LANES, shard_dpor_kernel
+            from ..parallel.mesh import LANES
 
             if impl == "pallas":
-                import sys
-
-                print(
-                    "DeviceDPOR: mesh sharding uses the XLA DPOR kernel; "
-                    "ignoring impl=pallas",
-                    file=sys.stderr,
+                raise ValueError(
+                    "DeviceDPOR: mesh sharding runs the XLA DPOR kernel; "
+                    "impl='pallas' has no sharded DPOR twin"
                 )
-
             if batch_size % mesh.shape[LANES]:
                 raise ValueError(
                     f"batch_size {batch_size} must be a multiple of the "
                     f"mesh axis {mesh.shape[LANES]}"
                 )
-            if self.sleep is not None:
-                # Intra-slice fleet ring: the sleep-set twin shards its
-                # extra per-lane inputs (sleep rows, node ordinals) with
-                # the batch (parallel/mesh.py).
-                from ..parallel.mesh import shard_dpor_sleep_kernel
-
-                self.kernel = shard_dpor_sleep_kernel(
-                    app, cfg, mesh, self.sleep.cap,
-                    commute_matrix=self.sleep.matrix,
-                )
-            else:
-                self.kernel = shard_dpor_kernel(app, cfg, mesh)
-        elif impl == "pallas":
+        if impl == "pallas":
             from .pallas_explore import make_dpor_kernel_pallas
 
             self.kernel = make_dpor_kernel_pallas(
@@ -1244,19 +1273,17 @@ class DeviceDPOR:
             )
         elif kernel is not None:
             # A caller-shared kernel (DeviceDPOROracle keeps one per
-            # app/cfg): every fresh DeviceDPOR otherwise jits its own
+            # app/cfg/mesh): every fresh DeviceDPOR otherwise jits its own
             # closure, so a DDMin run probing many subsequences would
             # recompile the identical kernel per subsequence. With sleep
             # sets on the caller must share a SLEEP kernel (same
-            # sleep_cap/matrix) — the oracle does.
+            # sleep_cap/matrix), and with a mesh the SHARDED twin — the
+            # oracle does both.
             self.kernel = kernel
-        elif self.sleep is not None:
-            self.kernel = make_dpor_kernel(
-                app, cfg, sleep_cap=self.sleep.cap,
-                commute_matrix=self.sleep.matrix,
-            )
         else:
-            self.kernel = make_dpor_kernel(app, cfg)
+            self.kernel = build_dpor_kernel(
+                app, cfg, mesh=mesh, **self._sleep_kernel_args()
+            )
         self.prog = lower_program(app, cfg, list(program))
         self.batch_size = batch_size
         # Prefix-fork (device/fork.py, DEMI_PREFIX_FORK=1 / --prefix-fork):
@@ -1274,33 +1301,15 @@ class DeviceDPOR:
                 make_dpor_prefix_runner,
             )
 
-            if impl == "pallas" and mesh is None:
-                import sys
-
-                print(
+            if impl == "pallas":
+                raise ValueError(
                     "DeviceDPOR: prefix-fork trunk/fork lanes run on the "
-                    "XLA DPOR kernel (bit-identical semantics)",
-                    file=sys.stderr,
+                    "XLA DPOR kernel; drop impl='pallas' or prefix_fork"
                 )
-            if mesh is None:
-                self._fork_kernel = fork_kernel or make_dpor_kernel(
-                    app, cfg, start_state=True,
-                    sleep_cap=self.sleep.cap if self.sleep else 0,
-                    commute_matrix=self.sleep.matrix if self.sleep else None,
-                )
-            elif self.sleep is not None:
-                from ..parallel.mesh import shard_dpor_sleep_kernel
-
-                self._fork_kernel = shard_dpor_sleep_kernel(
-                    app, cfg, mesh, self.sleep.cap,
-                    commute_matrix=self.sleep.matrix, start_state=True,
-                )
-            else:
-                from ..parallel.mesh import shard_dpor_kernel
-
-                self._fork_kernel = shard_dpor_kernel(
-                    app, cfg, mesh, start_state=True
-                )
+            self._fork_kernel = fork_kernel or build_dpor_kernel(
+                app, cfg, mesh=mesh, start_state=True,
+                **self._sleep_kernel_args(),
+            )
             if fork_min_group is None:
                 # A trunk run is a SINGLE-lane O(prefix) execution and a
                 # fork group is an extra kernel launch: on CPU — where a
@@ -1359,6 +1368,9 @@ class DeviceDPOR:
                 ),
             )
         self._mesh = mesh
+        # Layout of the first whole-batch round harvested: devices its
+        # output spanned and lanes on each.
+        self.lane_sharding: Optional[dict] = None
         self._double_buffer = _resolve_double_buffer(double_buffer)
         # In-flight round economics (the signal calibrate_dpor_inflight
         # and bench config 8 read): speculative launches, and how many
@@ -1659,13 +1671,27 @@ class DeviceDPOR:
             return gen, pending
         return gen + pending, []
 
-    def _round_keys(self, n: int, base: int, batch: Optional[List[Tuple]] = None):
-        """Per-lane keys for one round. ``key_mode='position'`` (the
-        default): position in the cumulative interleaving count — every
-        round is padded to ``batch_size``, so ``base`` advances
+    def _sleep_kernel_args(self) -> dict:
+        """The (sleep_cap, commute_matrix) pair that fixes this
+        instance's kernel shape."""
+        if self.sleep is None:
+            return {"sleep_cap": 0, "commute_matrix": None}
+        return {
+            "sleep_cap": self.sleep.cap, "commute_matrix": self.sleep.matrix
+        }
+
+    def _round_seeds(
+        self, n: int, base: int, batch: Optional[List[Tuple]] = None
+    ) -> np.ndarray:
+        """Per-lane rng seeds (uint32) for one round — pure NumPy, so a
+        process that only plans rounds (the fleet coordinator) derives
+        them without initialising a JAX backend; ``lane_keys`` folds
+        them into keys where the kernel runs. ``key_mode='position'``
+        (the default): position in the cumulative interleaving count —
+        every round is padded to ``batch_size``, so ``base`` advances
         deterministically and a speculative round N+1 dispatched before
         round N's harvest derives the exact keys the synchronous loop
-        would. ``key_mode='content'`` (sleep-set mode): each lane's key
+        would. ``key_mode='content'`` (sleep-set mode): each lane's seed
         derives from its prescription's content digest, so a
         prescription explores the identical suffix no matter where
         pruning shifts it in the round order — the property the sleep
@@ -1673,19 +1699,18 @@ class DeviceDPOR:
         if self.key_mode == "content" and batch is not None:
             from ..native import prescription_digest
 
-            seeds = np.asarray(
+            return np.asarray(
                 [
                     int.from_bytes(prescription_digest(p)[:4], "little")
                     for p in batch
                 ],
                 np.uint32,
             )
-            return jax.vmap(
-                lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s)
-            )(seeds)
-        return jax.vmap(
-            lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s)
-        )(np.arange(base, base + n, dtype=np.uint32))
+        return np.arange(base, base + n, dtype=np.uint32)
+
+    def _round_keys(self, n: int, base: int, batch: Optional[List[Tuple]] = None):
+        """Per-lane keys for one round: ``lane_keys(_round_seeds(...))``."""
+        return lane_keys(self._round_seeds(n, base, batch=batch))
 
     def _dispatch_round(self, prescs: np.ndarray, keys, batch: List[Tuple]):
         """Launch one frontier round's lane work WITHOUT pulling results
@@ -1858,6 +1883,10 @@ class DeviceDPOR:
         if len(parts) == 1 and parts[0][0] is None:
             res = parts[0][1]
             jax.block_until_ready(res.violation)
+            if self.lane_sharding is None:
+                from ..parallel.mesh import lane_sharding_summary
+
+                self.lane_sharding = lane_sharding_summary(res.violation)
             if PROFILER.enabled:
                 PROFILER.block(
                     "dpor", batch_len, time.perf_counter() - t0

@@ -412,17 +412,18 @@ def make_explore_kernel_variant(
 
 
 def resolve_impl(impl: str, cfg: DeviceConfig, driver: str) -> str:
-    """Backend selection rule shared by the sweep drivers: round mode is
-    XLA-only (pallas_explore guard), and an env/arg-forced pallas must
-    degrade rather than abort — TPU bench windows are scarce."""
-    if impl == "pallas" and cfg.round_delivery:
-        import sys
-
-        print(
-            f"{driver}: round_delivery is XLA-only; using the XLA kernels",
-            file=sys.stderr,
+    """Backend validation shared by the sweep drivers. A backend the
+    caller asked for and cannot have is an error, never a substitution:
+    round mode is XLA-only (pallas_explore guard)."""
+    if impl not in ("xla", "pallas"):
+        raise ValueError(
+            f"{driver}: impl must be 'xla' or 'pallas', got {impl!r}"
         )
-        return "xla"
+    if impl == "pallas" and cfg.round_delivery:
+        raise ValueError(
+            f"{driver}: round_delivery is XLA-only; drop impl='pallas' "
+            "(or round mode)"
+        )
     return impl
 
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax._src import prng as _prng
 
 # The one-hot forms are the SAME semantics handlers use via the dsl
 # helpers — delegate so the subtle parts (bool-dtype reductions, the
@@ -53,14 +54,7 @@ def rng_split(key: jnp.ndarray, n: int = 2) -> jnp.ndarray:
     iota_2x32_shape instead of the opaque ``random_split`` primitive
     (unsupported by Mosaic). Bit-identical to jax.random.split for raw
     uint32 keys (verified in tests/test_pallas.py)."""
-    try:
-        from jax._src import prng as _prng
-
-        return _prng.threefry_split(key, (n,))
-    except (ImportError, AttributeError, TypeError):  # pragma: no cover - jax internals moved
-        import jax
-
-        return jax.random.split(key, n)
+    return _prng.threefry_split(key, (n,))
 
 
 def onehot(i, n: int) -> jnp.ndarray:

@@ -19,13 +19,18 @@ kernels (vmapped over the lane block), so the backends are bit-identical
 single-lane re-run (device/explore.py make_single_lane_trace_kernel)
 depends on when lifting a violating lane to the host oracle.
 
-On non-TPU backends the kernels run in Pallas interpret mode, which is
-how the parity suite validates them on the CPU mesh (tests/test_pallas.py).
+Under ``JAX_PLATFORMS=cpu`` (the test boot) the kernels run in Pallas
+interpret mode, which is how the parity suite validates them
+(tests/test_pallas.py). Everywhere else they go to the Mosaic compiler,
+whose refusal is the caller's error. As of PR 21 Mosaic (jax 0.9.0,
+libtpu 0.0.34) refuses all three kernels on the TPU v5e — see PERF.md,
+"Bring-up" — so ``--impl pallas`` raises there.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Optional, Sequence
 
 import jax
@@ -52,7 +57,12 @@ def _pad_to(x, b: int, axis: int = 0):
 
 def _check_pallas_cfg(cfg: DeviceConfig, interpret: Optional[bool]):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        # Interpret mode is for runs that FORCED the CPU (the test boot).
+        # It is never reached because a chip was expected and some other
+        # backend came up: there the Mosaic compile fails loudly.
+        interpret = (
+            os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+        )
     if not interpret and not cfg.use_onehot:
         # Scatter-mode kernels trace cumsum/searchsorted/scatter, none of
         # which have Mosaic lowerings — fail fast instead of deep inside

@@ -106,9 +106,10 @@ def run_worker(addr: str, worker_id: str) -> int:
         )
         return 5
 
-    from ..device.dpor_sweep import make_dpor_kernel
+    from ..device.dpor_sweep import build_dpor_kernel, lane_keys
     from ..device.encoding import lower_program
     from ..device.explore import broadcast_program
+    from ..parallel.mesh import device_fields, local_lane_mesh
 
     batch = int(cfg_msg["batch"])
     sleep = bool(cfg_msg.get("sleep"))
@@ -118,26 +119,10 @@ def run_worker(addr: str, worker_id: str) -> int:
         from ..analysis import StaticIndependence
 
         matrix = StaticIndependence.for_app(app).device_matrix()
-    n_dev = jax.local_device_count()
-    if n_dev > 1 and batch % n_dev == 0:
-        from ..parallel.mesh import (
-            make_mesh,
-            shard_dpor_kernel,
-            shard_dpor_sleep_kernel,
-        )
-
-        mesh = make_mesh()
-        kernel = (
-            shard_dpor_sleep_kernel(
-                app, cfg, mesh, sleep_cap, commute_matrix=matrix
-            )
-            if sleep
-            else shard_dpor_kernel(app, cfg, mesh)
-        )
-    else:
-        kernel = make_dpor_kernel(
-            app, cfg, sleep_cap=sleep_cap, commute_matrix=matrix
-        )
+    kernel = build_dpor_kernel(
+        app, cfg, mesh=local_lane_mesh(batch), sleep_cap=sleep_cap,
+        commute_matrix=matrix,
+    )
     prog = lower_program(app, cfg, list(program))
     progs = broadcast_program(prog, batch)
 
@@ -153,11 +138,7 @@ def run_worker(addr: str, worker_id: str) -> int:
     warm_prescs = np.zeros(
         (batch, cfg.max_steps, cfg.rec_width), np.int32
     )
-    warm_keys = np.asarray(
-        jax.vmap(lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s))(
-            np.arange(batch, dtype=np.uint32)
-        )
-    )
+    warm_keys = lane_keys(np.arange(batch, dtype=np.uint32))
     execute(
         warm_prescs, warm_keys,
         np.zeros((batch, sleep_cap, cfg.rec_width), np.int32)
@@ -165,10 +146,19 @@ def run_worker(addr: str, worker_id: str) -> int:
         np.zeros((batch,), np.int32) if sleep else None,
     )
 
+    # What this worker's runtime came up on, sent with its first poll:
+    # the coordinator never asks JAX itself.
+    first_poll: Dict[str, Any] = {
+        "device": {
+            **device_fields(),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+        }
+    }
     die_after = int(os.environ.get("DEMI_FLEET_DIE_AFTER", "0") or 0)
     served = 0
     while True:
-        msg = rpc({"op": "next", "worker": worker_id})
+        msg = rpc({"op": "next", "worker": worker_id, **first_poll})
+        first_poll = {}
         if msg is None or msg.get("op") == "shutdown":
             break
         if msg.get("op") == "wait":
@@ -185,7 +175,7 @@ def run_worker(addr: str, worker_id: str) -> int:
             # re-lease the round bit-identically.
             os._exit(17)
         prescs = unpack_array(msg["prescs"])
-        keys = unpack_array(msg["keys"])
+        keys = lane_keys(unpack_array(msg["seeds"]))
         sleeps = unpack_array(msg["sleeps"]) if "sleeps" in msg else None
         sfrom = unpack_array(msg["sfrom"]) if "sfrom" in msg else None
         # Child span under the propagated lease context: the stitched

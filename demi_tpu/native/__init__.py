@@ -6,6 +6,7 @@ from .analysis import (
     prescription_digests,
     racing_pair_scan,
     racing_prescriptions_batch,
+    scan_backend,
 )
 from .codec import (
     native_available,
@@ -28,4 +29,5 @@ __all__ = [
     "prescription_digests",
     "prescription_digest",
     "digest_keys",
+    "scan_backend",
 ]

@@ -22,7 +22,8 @@ Two tiers:
   explored-set membership check dedups on these instead of
   materializing a Python tuple per (mostly redundant) prescription.
 
-Build robustness: the compiler is ``$CXX`` when set, else the first of
+Build (native/build.py): the library is rebuilt whenever the source's
+content hash changes; the compiler is ``$CXX`` when set, else the first of
 g++ / clang++ / cc that links. When no native library can be built the
 NumPy fallback is used and a ONE-TIME log line + ``native.analysis_fallback``
 obs counter fire, so a silent native-miss perf regression shows up in
@@ -34,15 +35,13 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "trace_analysis.cpp")
-_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
-_SO = os.path.join(_BUILD_DIR, "libdemi_analysis.so")
+from .build import build_library, native_source
+
+_SRC = native_source("trace_analysis.cpp")
 
 _log = logging.getLogger("demi_tpu.native")
 
@@ -57,41 +56,6 @@ def _delivery_kinds():
     from ..device.core import REC_DELIVERY, REC_TIMER
 
     return (REC_DELIVERY, REC_TIMER)
-
-
-def _compiler_candidates():
-    """$CXX first when set, then the conventional fallback chain."""
-    env = os.environ.get("CXX", "").strip()
-    out = [env] if env else []
-    for cxx in ("g++", "clang++", "cc"):
-        if cxx not in out:
-            out.append(cxx)
-    return out
-
-
-def _compile(src: str, dst: str) -> bool:
-    """Try each candidate compiler until one produces ``dst``. ``-x c++``
-    + ``-lstdc++`` keep a bare ``cc`` driver viable for the C++ source."""
-    for cxx in _compiler_candidates():
-        tmp = f"{dst}.{os.getpid()}.tmp"
-        try:
-            subprocess.run(
-                [cxx, "-O2", "-shared", "-fPIC", "-x", "c++", src,
-                 "-o", tmp, "-lstdc++"],
-                check=True, capture_output=True, timeout=120,
-            )
-        except Exception:
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            continue
-        # Build to a per-pid temp path, then atomically replace:
-        # concurrent builders (parallel pytest) must never interleave
-        # writes into the loaded .so.
-        os.replace(tmp, dst)
-        return True
-    return False
 
 
 def note_fallback(reason: str) -> None:
@@ -125,19 +89,15 @@ def _load_native() -> Optional[ctypes.CDLL]:
     if _lib_tried:
         return _lib
     _lib_tried = True
+    if not os.path.exists(_SRC):
+        note_fallback("source missing")
+        return None
+    so = build_library(_SRC, "libdemi_analysis")
+    if so is None:
+        note_fallback("no working C++ compiler")
+        return None
     try:
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not os.path.exists(_SRC):
-                note_fallback("source missing")
-                return None
-            os.makedirs(_BUILD_DIR, exist_ok=True)
-            if not _compile(_SRC, _SO):
-                note_fallback("no working C++ compiler")
-                return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         lib.demi_racing_pairs.restype = ctypes.c_int64
         lib.demi_racing_pairs.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
@@ -175,7 +135,7 @@ def _load_native() -> Optional[ctypes.CDLL]:
             ctypes.c_void_p, ctypes.c_void_p,
         ]
         _lib = lib
-    except Exception as exc:  # stale .so without the batch symbol included
+    except (OSError, AttributeError) as exc:
         note_fallback(f"load failed: {type(exc).__name__}")
         _lib = None
     return _lib
@@ -183,6 +143,18 @@ def _load_native() -> Optional[ctypes.CDLL]:
 
 def analysis_native_available() -> bool:
     return _load_native() is not None
+
+
+def scan_backend() -> str:
+    """Which implementation serves the racing scan in this process:
+    ``"native"`` (the C++ library built from native/trace_analysis.cpp)
+    or ``"numpy"`` (no library could be built or loaded, or the launch
+    supervisor degraded the surface)."""
+    from ..persist.supervisor import SUPERVISOR
+
+    if _load_native() is None or SUPERVISOR.degraded("native.analysis"):
+        return "numpy"
+    return "native"
 
 
 def _py_racing_pairs(recs: np.ndarray) -> np.ndarray:

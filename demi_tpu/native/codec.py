@@ -14,20 +14,17 @@ Record-log file layout:
 from __future__ import annotations
 
 import ctypes
-import os
 import struct
-import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
 
+from .build import build_library, native_source
+
 _MAGIC = b"DEMIRECS"
 _VERSION = 1
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-_SRC = os.path.join(_REPO_ROOT, "native", "record_codec.cpp")
-_BUILD_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
-_SO = os.path.join(_BUILD_DIR, "libdemi_records.so")
+_SRC = native_source("record_codec.cpp")
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_tried = False
@@ -38,23 +35,11 @@ def _load_native() -> Optional[ctypes.CDLL]:
     if _lib_tried:
         return _lib
     _lib_tried = True
+    so = build_library(_SRC, "libdemi_records")
+    if so is None:
+        return None
     try:
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not os.path.exists(_SRC):
-                return None
-            os.makedirs(_BUILD_DIR, exist_ok=True)
-            # Per-pid temp + atomic replace: concurrent builders must not
-            # interleave writes into the loaded .so.
-            tmp = f"{_SO}.{os.getpid()}.tmp"
-            subprocess.run(
-                ["g++", "-O2", "-shared", "-fPIC", _SRC, "-o", tmp],
-                check=True, capture_output=True, timeout=120,
-            )
-            os.replace(tmp, _SO)
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         lib.demi_pack.restype = ctypes.c_int64
         lib.demi_pack.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_int64,
@@ -66,7 +51,7 @@ def _load_native() -> Optional[ctypes.CDLL]:
             ctypes.c_int64, ctypes.c_int64,
         ]
         _lib = lib
-    except Exception:
+    except (OSError, AttributeError):
         _lib = None
     return _lib
 

@@ -40,6 +40,42 @@ def make_mesh(devices: Optional[Sequence] = None, axis: str = LANES) -> Mesh:
     return Mesh(np.asarray(devices), (axis,))
 
 
+def local_lane_mesh(batch: Optional[int] = None) -> Optional[Mesh]:
+    """The mesh a one-process driver shards its lane batch over: every
+    local device when this process has more than one — and, for kernels
+    that need an even split, when ``batch`` divides by their count. None
+    selects the single-device kernels. The CLI verbs and the fleet worker
+    all choose here, from what the process can observe, with no flag."""
+    n = jax.local_device_count()
+    if n <= 1 or (batch is not None and batch % n):
+        return None
+    return make_mesh(jax.local_devices())
+
+
+def device_fields() -> dict:
+    """The devices this process's numbers come from, as JAX reports
+    them — carried by every sweep/dpor/minimize summary, every fleet
+    worker and every rehearsal rank, so a run that lost its chip does not
+    read as a slow run and a child can be held to its device."""
+    devices = jax.local_devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "devices": len(devices),
+    }
+
+
+def lane_sharding_summary(x) -> dict:
+    """How a kernel output's lane axis is laid out: the devices its
+    sharding spans and the lanes each holds (what a run on several chips
+    shows to prove they were used)."""
+    shards = x.addressable_shards
+    return {
+        "devices": len(x.sharding.device_set),
+        "lanes_per_device": int(shards[0].data.shape[0]),
+    }
+
+
 def sweep_sharding(mesh: Mesh, axis: str = LANES) -> Tuple[NamedSharding, NamedSharding]:
     """(batch-axis sharding, fully-replicated sharding) for a sweep."""
     return NamedSharding(mesh, P(axis)), NamedSharding(mesh, P())
@@ -163,12 +199,6 @@ def shard_explore_kernel_pallas(
     from ..device.explore import ExtProgram, LaneResult
     from ..device.pallas_explore import make_explore_kernel_pallas
 
-    # shard_map's import home moved across jax releases; prefer the
-    # stable top-level name, fall back to the experimental module.
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     kernel = make_explore_kernel_pallas(app, cfg, block_lanes=block_lanes)
     lane = P(axis)
     in_specs = (ExtProgram(op=lane, a=lane, b=lane, msg=lane), lane)
@@ -177,22 +207,13 @@ def shard_explore_kernel_pallas(
         trace_len=lane, sched_hash=lane,
     )
     # pallas_call's out_shape ShapeDtypeStructs carry no varying-mesh-
-    # axes annotation; skip the replication/vma check (lanes are fully
-    # independent, nothing is replicated). The kwarg name changed
-    # across jax releases (check_rep -> check_vma).
-    import inspect
-
-    params = inspect.signature(shard_map).parameters
-    check_kw = (
-        {"check_vma": False}
-        if "check_vma" in params
-        else {"check_rep": False} if "check_rep" in params else {}
-    )
+    # axes annotation; skip the vma check (lanes are fully independent,
+    # nothing is replicated).
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda progs, keys: kernel(progs, keys),
             mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **check_kw,
+            check_vma=False,
         )
     )
 

@@ -30,7 +30,12 @@ from ..device.core import ST_OVERFLOW, ST_VIOLATION, DeviceConfig
 from ..device.encoding import lower_program, stack_programs
 from ..device.explore import make_explore_kernel
 from ..external_events import ExternalEvent
-from .mesh import LANES, make_mesh, shard_explore_kernel
+from .mesh import (
+    LANES,
+    lane_sharding_summary,
+    make_mesh,
+    shard_explore_kernel,
+)
 
 
 @dataclass
@@ -51,6 +56,33 @@ class SweepChunkResult:
     # Deduped device-side schedule fingerprints (LaneResult.sched_hash)
     # for this chunk's real lanes: the honest "unique schedules" numerator.
     unique_hashes: Optional[np.ndarray] = None
+    # Order-free digest of this chunk's per-lane (seed, status, code,
+    # sched_hash) rows (``lanes_digest``).
+    lanes_digest: int = 0
+    # Devices the kernel output spanned and lanes on each
+    # (``mesh.lane_sharding_summary``).
+    lane_sharding: Optional[dict] = None
+
+
+def lanes_digest(seeds, statuses, codes, hashes) -> int:
+    """Order-free 64-bit digest of per-lane results: a wrapping sum of a
+    per-lane mix of (seed, status, violation code, sched_hash). Two sweeps
+    share it iff (modulo collisions) every seed produced the same three
+    values — whatever the harvest order, chunking, mode or number of
+    devices. This is how a 4-chip run is compared with a 1-chip run."""
+    m = np.uint64(0xFFFFFFFF)
+    x = ((np.asarray(seeds).astype(np.uint64) & m) << np.uint64(32)) | (
+        np.asarray(hashes).astype(np.uint64) & m
+    )
+    y = ((np.asarray(statuses).astype(np.uint64) & m) << np.uint64(32)) | (
+        np.asarray(codes).astype(np.uint64) & m
+    )
+    # splitmix64 finalizer over the two words.
+    z = x * np.uint64(0x9E3779B97F4A7C15) + y
+    z = (z ^ (z >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    z = (z ^ (z >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    z = z ^ (z >> np.uint64(31))
+    return int(z.sum(dtype=np.uint64))
 
 
 @dataclass
@@ -112,6 +144,17 @@ class SweepResult:
         return sum(c.overflow_lanes for c in self.chunks)
 
     @property
+    def lanes_digest(self) -> int:
+        return sum(c.lanes_digest for c in self.chunks) % (1 << 64)
+
+    @property
+    def lane_sharding(self) -> Optional[dict]:
+        for c in self.chunks:
+            if c.lane_sharding is not None:
+                return c.lane_sharding
+        return None
+
+    @property
     def unique_schedules(self) -> int:
         """Distinct delivered sequences across the whole sweep (union of
         per-chunk fingerprint sets)."""
@@ -137,10 +180,14 @@ class _HarvestAccumulator:
         self.codes: dict = {}
         self.first_seed: Optional[int] = None
         self.first_code: Optional[int] = None
+        self.digest = 0
         self._hash_parts: List[np.ndarray] = []
 
     def add(self, seeds, statuses, codes, hashes) -> None:
         self.lanes += len(seeds)
+        self.digest = (
+            self.digest + lanes_digest(seeds, statuses, codes, hashes)
+        ) % (1 << 64)
         self.overflow += int((statuses == ST_OVERFLOW).sum())
         self._hash_parts.append(
             np.asarray(hashes)[statuses != ST_OVERFLOW]
@@ -175,6 +222,7 @@ class _HarvestAccumulator:
             overflow_lanes=self.overflow,
             unique_hashes=self.unique_hashes(),
             first_violating_seed=self.first_seed,
+            lanes_digest=self.digest,
         )
 
 
@@ -383,12 +431,9 @@ class SweepDriver:
             )
 
             if self.impl == "pallas":
-                import sys
-
-                print(
+                raise ValueError(
                     "SweepDriver: prefix-fork trunk/fork lanes run on the "
-                    "XLA explore kernel (bit-identical results)",
-                    file=sys.stderr,
+                    "XLA explore kernel; drop impl='pallas' or prefix_fork"
                 )
             self._fork_kernel = (
                 shard_explore_kernel(app, self.cfg, self.mesh, start_state=True)
@@ -693,9 +738,8 @@ class SweepDriver:
             int(c): int(k) for c, k in zip(uniq.tolist(), cnt.tolist())
             if c != 0
         }
-        chunk_uniq = np.unique(
-            np.asarray(res.sched_hash)[:n_real][statuses != ST_OVERFLOW]
-        )
+        hashes = np.asarray(res.sched_hash)[:n_real]
+        chunk_uniq = np.unique(hashes[statuses != ST_OVERFLOW])
         if lane_stats is not None:
             from ..obs import lane_stats as _ls
 
@@ -737,6 +781,8 @@ class SweepDriver:
             # Overflowed lanes aborted mid-schedule: their truncated
             # fingerprints are not explored schedules, keep them out.
             unique_hashes=chunk_uniq,
+            lanes_digest=lanes_digest(real, statuses, violations, hashes),
+            lane_sharding=lane_sharding_summary(res.status),
         )
 
     def sweep(
@@ -870,6 +916,7 @@ class SweepDriver:
             if stop_on_violation and len(vio):
                 break
         chunk = acc.chunk(slice_index=0, seconds=time.perf_counter() - t0)
+        chunk.lane_sharding = drv.last_lane_sharding
         result = SweepResult(chunks=[chunk])
         result.occupancy = drv.last_occupancy
         # One chunk, harvested synchronously: its seconds ARE wall time.

@@ -131,9 +131,11 @@ class LaunchSupervisor:
                     time.sleep(self.backoff * (2 ** (attempt - 1)))
                     continue
                 if self.strict:
+                    # str(exc), not repr: a compiler's diagnostics live
+                    # in the message, and some reprs (MLIRError) drop it.
                     raise StrictIOError(
                         f"{label} failed {attempt + 1}x under strict-io: "
-                        f"{exc!r}"
+                        f"{type(exc).__name__}: {exc}"
                     ) from exc
                 if fallback is not None:
                     self._degrade(label, repr(exc))

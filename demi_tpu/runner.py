@@ -459,6 +459,7 @@ def run_the_gamut(
     checkpoint_dir: Optional[str] = None,
     resume: bool = False,
     stage_budget_seconds: Optional[float] = None,
+    checker=None,
 ) -> GamutResult:
     """Drain ``run_the_gamut_streaming`` to completion — the staged
     entry point. The generator IS the pipeline body, so the staged and
@@ -470,7 +471,7 @@ def run_the_gamut(
         config, fuzz_result, wildcards=wildcards, provenance=provenance,
         internal_strategy=internal_strategy, app=app, device_cfg=device_cfg,
         checkpoint_dir=checkpoint_dir, resume=resume,
-        stage_budget_seconds=stage_budget_seconds,
+        stage_budget_seconds=stage_budget_seconds, checker=checker,
     ))
 
 

@@ -8,6 +8,7 @@ oracle runs never serialize against device-tier sweeps.
 from __future__ import annotations
 
 import functools
+import os
 from typing import Callable
 
 
@@ -15,7 +16,16 @@ from typing import Callable
 def _cpu_device():
     import jax
 
-    return jax.local_devices(backend="cpu")[0]
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError as exc:
+        raise RuntimeError(
+            "demi_tpu's host oracle (fuzz, the device->host lift, MCS "
+            "verification) runs on JAX's CPU backend beside the "
+            "accelerator: leave JAX_PLATFORMS unset or set "
+            "JAX_PLATFORMS=tpu,cpu (got JAX_PLATFORMS="
+            f"{os.environ.get('JAX_PLATFORMS')!r})"
+        ) from exc
 
 
 def host_jit(fn: Callable) -> Callable:

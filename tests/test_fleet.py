@@ -423,16 +423,21 @@ def test_late_result_after_requeue_is_accepted(tmp_path):
         assert msg["op"] == "lease"
         lid = msg["lease"]
         lease, worker, _deadline, t_issue = co._outstanding[lid]
-        # Execute the round in-process with the coordinator's own
-        # kernel: the result bytes a (slow) worker would have sent.
+        # Execute the round in-process the way a worker would (the
+        # coordinator itself holds no kernel): the result bytes a (slow)
+        # worker would have sent.
+        from demi_tpu.device.dpor_sweep import build_dpor_kernel, lane_keys
+
+        kernel = build_dpor_kernel(app, cfg, **co.dpor._sleep_kernel_args())
+        keys = lane_keys(lease.seeds)
         if lease.sleeps is not None:
-            res = co.dpor.kernel(
+            res = kernel(
                 co.dpor._progs(len(lease.batch)), lease.prescs,
-                lease.keys, lease.sleeps, lease.sfrom,
+                keys, lease.sleeps, lease.sfrom,
             )
         else:
-            res = co.dpor.kernel(
-                co.dpor._progs(len(lease.batch)), lease.prescs, lease.keys
+            res = kernel(
+                co.dpor._progs(len(lease.batch)), lease.prescs, keys
             )
         result_msg = {
             "op": "result", "lease": lid, "worker": "w0", "busy_s": 0.01,

@@ -3,14 +3,13 @@
 The pallas kernel (demi_tpu/device/pallas_explore.py) must be bit-identical
 to the XLA explore kernel — the violating-lane lift re-runs a lane's seed
 through the XLA single-lane trace kernel, so the two backends must produce
-the same schedule stream. On CPU the kernel runs in interpret mode; the
-Mosaic-coverage test proves the traced step contains only primitives the
-TPU Mosaic lowering supports, which is as close to "compiles on TPU" as a
-chipless environment gets.
+the same schedule stream. Under JAX_PLATFORMS=cpu the kernel runs in
+interpret mode; the Mosaic-coverage test proves the traced step contains
+only primitives the TPU Mosaic lowering has rules for. Whether the kernels
+compile on a chip is a chip run's finding (PERF.md, "Bring-up").
 """
 
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -279,11 +278,11 @@ def _traced_primitives(app, cfg):
 def test_mosaic_primitive_coverage():
     """Every primitive in the one-hot step (all three fixture apps, incl.
     early-exit while_loop and timer weighting) has a Mosaic TPU lowering
-    rule — the chipless proxy for 'the pallas kernel compiles on TPU'."""
-    try:
-        from jax._src.pallas.mosaic import lowering
-    except ImportError:  # pragma: no cover
-        pytest.skip("mosaic internals unavailable")
+    rule. Necessary for the pallas kernels to compile on a TPU, not
+    sufficient: on the v5e Mosaic refuses them on shapes, not on
+    primitives (PERF.md, "Bring-up")."""
+    from jax._src.pallas.mosaic import lowering
+
     per_kernel_type = list(lowering.lowering_rules.values())
     regs = {
         getattr(k, "name", str(k)) for k in per_kernel_type[0].keys()
