@@ -267,7 +267,9 @@ def _profile_begin(args) -> bool:
     """``--profile-rounds N``: arm the launch profiler (per-launch wall
     attribution keyed by launch shape — obs/profiler.py) and open a
     jax.profiler trace window over the first N round boundaries, written
-    to ``--profile-trace`` (default ./demi_profile)."""
+    to ``--profile-trace`` (default ./demi_profile). The stage spans are
+    live for the whole run (the ledger is fed from them) and land in the
+    trace as ``demi.<name>`` events beside the device's operations."""
     rounds = getattr(args, "profile_rounds", 0) or 0
     if not rounds:
         return False
@@ -294,6 +296,9 @@ def _profile_end(args, summary: dict, app, cfg) -> None:
     PROFILER.stop_trace_window()
     evidence = PROFILER.evidence()
     summary["launch_profile"] = evidence
+    # The stage table of the same run (obs/spans.py): the launch ledger
+    # arms the spans, and the trace holds them as demi.<name> events.
+    summary["stages"] = obs.stage_totals()
     cache = TuningCache()
     key = workload_key(
         app.name, app.num_actors, cfg, jax.devices()[0].platform,

@@ -319,23 +319,9 @@ class ContinuousSweepDriver:
         # refill skips re-lowering — at 1e5+ lanes host-side lowering
         # otherwise dominates the harvest path. The RNG stream still uses
         # the raw seed, so equal programs keep distinct schedules.
-        if program_key is None:
-            self._lower = lambda seed: lower_program(
-                app, cfg, program_gen(seed)
-            )
-        else:
-            memo: dict = {}
-
-            def _lower_memo(seed):
-                k = program_key(seed)
-                prog = memo.get(k)
-                if prog is None:
-                    prog = memo[k] = lower_program(
-                        app, cfg, program_gen(seed)
-                    )
-                return prog
-
-            self._lower = _lower_memo
+        self._program_key = program_key
+        self._lower_memo: dict = {}
+        self._lower_program = lower_program
         self._stack = stack_programs
         impl = resolve_impl(impl, cfg, "ContinuousSweepDriver")
         if impl == "pallas":
@@ -386,6 +372,42 @@ class ContinuousSweepDriver:
         obs.counter("device.continuous.rounds").inc()
         if self.last_occupancy is not None:
             obs.gauge("device.continuous.occupancy").set(self.last_occupancy)
+
+    def _fill(
+        self, seeds: Sequence[int], lanes: Sequence[int], progs_host: List
+    ) -> None:
+        """Lower the program of each seed into its lane of ``progs_host``:
+        ``program_gen`` then ``lower_program``, one seed at a time, each
+        program replacing its lane's old one as it goes. A program's
+        events die before the next is generated, and a retired program's
+        memory serves the next: a fill's programs held together (beside
+        the events, or beside the programs they replace) cost the sweep
+        more than the spans could (PERF.md, PR 24). The loop is per lane,
+        so fuzzing and lowering get no span each: a clock pair per
+        program sums them, and the ``sweep.fill`` span hands the sums to
+        the stages ``sweep.fuzz`` and ``sweep.lower`` (a no-op with spans
+        off)."""
+        fuzz_ns = lower_ns = 0
+        with obs.span("sweep.fill", programs=len(seeds)) as sp:
+            for lane, seed in zip(lanes, seeds):
+                if self._program_key is not None:
+                    key = self._program_key(seed)
+                    prog = self._lower_memo.get(key)
+                    if prog is not None:
+                        progs_host[lane] = prog
+                        continue
+                t0 = time.perf_counter_ns()
+                events = self.program_gen(seed)
+                t1 = time.perf_counter_ns()
+                progs_host[lane] = prog = self._lower_program(
+                    self.app, self.cfg, events
+                )
+                fuzz_ns += t1 - t0
+                lower_ns += time.perf_counter_ns() - t1
+                if self._program_key is not None:
+                    self._lower_memo[key] = prog
+            sp.slice("sweep.fuzz", fuzz_ns)
+            sp.slice("sweep.lower", lower_ns)
 
     def time_to_first_violation(self, max_lanes: int = 1_000_000):
         """Wall-clock seconds until the first violating lane finishes (the
@@ -455,104 +477,133 @@ class ContinuousSweepDriver:
             return self._vkeys(jnp.asarray(seeds, jnp.uint32))
 
         n_live = min(b, total_lanes)
-        # Lane i runs seed_list[i]; surplus (mesh-alignment) lanes run the
-        # first seed inertly — never yielded, never refilled.
-        lane_seed = [
-            seed_list[i] if i < n_live else seed_list[0] for i in range(b)
-        ]
-        next_idx = n_live  # next position in seed_list to hand out
-        progs_host: List = [self._lower(s) for s in lane_seed]
-        progs = self._stack(progs_host)
-        state = self.init(keys_for(lane_seed))
-        steps_run = np.zeros(b, np.int64)
-        done_count = 0
-        active = np.arange(b) < n_live
+        with obs.span("sweep.prime", lanes=b):
+            # Lane i runs seed_list[i]; surplus (mesh-alignment) lanes run
+            # the first seed inertly — never yielded, never refilled.
+            lane_seed = [
+                seed_list[i] if i < n_live else seed_list[0]
+                for i in range(b)
+            ]
+            next_idx = n_live  # next position in seed_list to hand out
+            progs_host: List = [None] * b
+            self._fill(lane_seed, range(b), progs_host)
+            with obs.span("sweep.stack"):
+                progs = self._stack(progs_host)
+            with obs.span("sweep.refill"):
+                state = self.init(keys_for(lane_seed))
+            steps_run = np.zeros(b, np.int64)
+            done_count = 0
+            active = np.arange(b) < n_live
 
-        self.last_segment_seconds = 0.0
-        self.last_harvest_seconds = 0.0
-        self.last_lane_sharding = None
+            self.last_segment_seconds = 0.0
+            self.last_harvest_seconds = 0.0
+            self.last_lane_sharding = None
         while done_count < total_lanes:
-            total_lane_steps += b * self.seg_steps
-            live_lane_steps += int(active.sum()) * self.seg_steps
-            self.last_occupancy = live_lane_steps / total_lane_steps
-            self.last_total_lane_steps = total_lane_steps
-            self.last_live_lane_steps = live_lane_steps
-            t_seg = time.perf_counter()
-            state = self.segment(
-                state, progs, jnp.asarray(steps_run, jnp.int32)
-            )
-            # The status pull is the sync point: everything up to it is
-            # device-segment time, the rest of the iteration is harvest.
-            _status_sync = np.asarray(state.status)
-            t_harvest = time.perf_counter()
-            if self.last_lane_sharding is None:
-                from ..parallel.mesh import lane_sharding_summary
+            with obs.span("sweep.round"):
+                total_lane_steps += b * self.seg_steps
+                live_lane_steps += int(active.sum()) * self.seg_steps
+                self.last_occupancy = live_lane_steps / total_lane_steps
+                self.last_total_lane_steps = total_lane_steps
+                self.last_live_lane_steps = live_lane_steps
+                t_seg = time.perf_counter()
+                with obs.span("sweep.block"):
+                    state = self.segment(
+                        state, progs, jnp.asarray(steps_run, jnp.int32)
+                    )
+                    # The status pull is the sync point: everything up
+                    # to it is device-segment time, the rest of the
+                    # iteration is harvest.
+                    _status_sync = np.asarray(state.status)
+                t_harvest = time.perf_counter()
+                if self.last_lane_sharding is None:
+                    from ..parallel.mesh import lane_sharding_summary
 
-                self.last_lane_sharding = lane_sharding_summary(state.status)
-            self.last_segment_seconds += t_harvest - t_seg
-            steps_run = np.minimum(
-                steps_run + self.seg_steps, self.cfg.max_steps
-            )
-            # Budget exhaustion: force-finalize overdue live lanes (the
-            # plain kernel's run-out-of-steps semantics).
-            status = _status_sync
-            overdue = (
-                active & (status < ST_DONE) & (steps_run >= self.cfg.max_steps)
-            )
-            if overdue.any():
-                finalized = self.finalize(state)
-                state = self.refill(state, jnp.asarray(overdue), finalized)
-                status = np.asarray(state.status)
-            finished = active & (status >= ST_DONE)
-            out = None
-            if finished.any():
-                vio = np.asarray(state.violation)
-                sh = np.asarray(state.sched_hash)
-                if obs.enabled():
-                    # Round-granularity lane telemetry: the status pull
-                    # above is the round's one sync point; deliveries ride
-                    # the same harvest (never per segment step).
-                    self._record_round_stats(state, finished, vio)
-                fin = np.flatnonzero(finished)
-                # Seeds gathered BEFORE refill rewrites lane_seed.
-                out = (
-                    np.asarray(lane_seed, np.int64)[fin],
-                    status[fin].copy(), vio[fin].copy(), sh[fin].copy(),
+                    self.last_lane_sharding = lane_sharding_summary(
+                        state.status
+                    )
+                self.last_segment_seconds += t_harvest - t_seg
+                steps_run = np.minimum(
+                    steps_run + self.seg_steps, self.cfg.max_steps
                 )
-                done_count += len(fin)
-                # Refill finished lanes with fresh seeds (or park them).
-                refill_lanes = set(
-                    int(x) for x in np.flatnonzero(finished)[
-                        : max(0, total_lanes - next_idx)
-                    ]
+                # Budget exhaustion: force-finalize overdue live lanes
+                # (the plain kernel's run-out-of-steps semantics).
+                status = _status_sync
+                overdue = (
+                    active & (status < ST_DONE)
+                    & (steps_run >= self.cfg.max_steps)
                 )
-                for lane in np.flatnonzero(finished):
-                    active[lane] = False
-                if refill_lanes:
-                    fresh_seeds = seed_list[
-                        next_idx : next_idx + len(refill_lanes)
-                    ]
-                    next_idx += len(refill_lanes)
-                    mask = np.zeros(b, bool)
-                    full_seeds = []
-                    k = 0
-                    for lane in range(b):
-                        if lane in refill_lanes and k < len(fresh_seeds):
-                            mask[lane] = True
-                            lane_seed[lane] = fresh_seeds[k]
-                            progs_host[lane] = self._lower(fresh_seeds[k])
-                            full_seeds.append(fresh_seeds[k])
-                            active[lane] = True
-                            steps_run[lane] = 0
-                            k += 1
-                        else:
-                            full_seeds.append(lane_seed[lane])
-                    progs = self._stack(progs_host)
-                    fresh = self.init(keys_for(full_seeds))
-                    state = self.refill(state, jnp.asarray(mask), fresh)
-            # Yield after the timing stop so caller time (a generator
-            # consumer may do arbitrary work per item) never counts as
-            # harvest overhead.
-            self.last_harvest_seconds += time.perf_counter() - t_harvest
+                if overdue.any():
+                    with obs.span("sweep.finalize"):
+                        finalized = self.finalize(state)
+                        state = self.refill(
+                            state, jnp.asarray(overdue), finalized
+                        )
+                        status = np.asarray(state.status)
+                finished = active & (status >= ST_DONE)
+                out = None
+                if finished.any():
+                    with obs.span("sweep.pull"):
+                        vio = np.asarray(state.violation)
+                        sh = np.asarray(state.sched_hash)
+                        if obs.enabled():
+                            # Round-granularity lane telemetry: the
+                            # status pull above is the round's one sync
+                            # point; deliveries ride the same harvest
+                            # (never per segment step).
+                            self._record_round_stats(state, finished, vio)
+                    with obs.span("sweep.retire"):
+                        fin = np.flatnonzero(finished)
+                        # Seeds gathered BEFORE refill rewrites lane_seed.
+                        out = (
+                            np.asarray(lane_seed, np.int64)[fin],
+                            status[fin].copy(), vio[fin].copy(),
+                            sh[fin].copy(),
+                        )
+                        done_count += len(fin)
+                        # Refill finished lanes with fresh seeds (or park
+                        # them).
+                        refill_lanes = set(
+                            int(x) for x in np.flatnonzero(finished)[
+                                : max(0, total_lanes - next_idx)
+                            ]
+                        )
+                        for lane in np.flatnonzero(finished):
+                            active[lane] = False
+                    if refill_lanes:
+                        fresh_seeds = seed_list[
+                            next_idx : next_idx + len(refill_lanes)
+                        ]
+                        next_idx += len(refill_lanes)
+                        # Ascending, as the loop below hands the seeds out.
+                        self._fill(
+                            fresh_seeds, sorted(refill_lanes), progs_host
+                        )
+                        with obs.span("sweep.refill"):
+                            mask = np.zeros(b, bool)
+                            full_seeds = []
+                            k = 0
+                            for lane in range(b):
+                                if lane in refill_lanes and k < len(
+                                    fresh_seeds
+                                ):
+                                    mask[lane] = True
+                                    lane_seed[lane] = fresh_seeds[k]
+                                    full_seeds.append(fresh_seeds[k])
+                                    active[lane] = True
+                                    steps_run[lane] = 0
+                                    k += 1
+                                else:
+                                    full_seeds.append(lane_seed[lane])
+                        with obs.span("sweep.stack"):
+                            progs = self._stack(progs_host)
+                        with obs.span("sweep.refill"):
+                            fresh = self.init(keys_for(full_seeds))
+                            state = self.refill(
+                                state, jnp.asarray(mask), fresh
+                            )
+                self.last_harvest_seconds += time.perf_counter() - t_harvest
+            # Yield outside every span, and after the timing stop: caller
+            # time (a generator consumer may do arbitrary work per item)
+            # never counts as the driver's.
             if out is not None:
                 yield out

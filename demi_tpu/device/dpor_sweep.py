@@ -1569,6 +1569,7 @@ class DeviceDPOR:
         retry is bit-identical and nothing in the search state needs
         rewinding. Exhausted retries re-raise (strict-io makes that a
         StrictIOError); there is no host twin for the DPOR kernel."""
+        from ..obs.profiler import PROFILER
         from ..persist.supervisor import SUPERVISOR
 
         def attempt(n: int):
@@ -1577,7 +1578,19 @@ class DeviceDPOR:
             )
             return self._harvest_round(p, len(batch))
 
-        return SUPERVISOR.run(attempt, label="dpor.launch")
+        with obs.span("dpor.block", lanes=len(batch)) as sp:
+            res = SUPERVISOR.run(attempt, label="dpor.launch")
+        if PROFILER.enabled:
+            PROFILER.block("dpor", len(batch), sp.seconds)
+        return res
+
+    def _pack_round(self, batch: List[Tuple], base: int):
+        """One round's kernel inputs: ``(_pack(batch), _round_keys(...))``
+        with ``base`` the interleaving count the round starts from."""
+        with obs.span("dpor.pack"):
+            return self._pack(batch), self._round_keys(
+                len(batch), base, batch=batch
+            )
 
     def _pack(self, prescriptions: List[Tuple]) -> np.ndarray:
         r, w = self.cfg.max_steps, self.cfg.rec_width
@@ -1730,10 +1743,15 @@ class DeviceDPOR:
         results are bit-identical."""
         from ..obs.profiler import PROFILER
 
-        sleeps = self._pack_sleep(batch) if self.sleep is not None else None
-        sfrom = self._sleep_from(batch) if sleeps is not None else None
-        if self._forker is None or len(batch) < 2:
-            t0 = time.perf_counter() if PROFILER.enabled else 0.0
+        with obs.span("dpor.dispatch", lanes=len(batch)) as sp:
+            sleeps = (
+                self._pack_sleep(batch) if self.sleep is not None else None
+            )
+            sfrom = self._sleep_from(batch) if sleeps is not None else None
+            if self._forker is not None and len(batch) >= 2:
+                return self._dispatch_forked(
+                    prescs, keys, batch, sleeps, sfrom
+                )
             if sleeps is None:
                 out = [
                     (None, self.kernel(self._progs(len(batch)), prescs, keys))
@@ -1745,11 +1763,16 @@ class DeviceDPOR:
                         self._progs(len(batch)), prescs, keys, sleeps, sfrom
                     ),
                 )]
-            if PROFILER.enabled:
-                PROFILER.dispatch(
-                    "dpor", len(batch), time.perf_counter() - t0
-                )
-            return out
+        if PROFILER.enabled:
+            PROFILER.dispatch("dpor", len(batch), sp.seconds)
+        return out
+
+    def _dispatch_forked(
+        self, prescs: np.ndarray, keys, batch: List[Tuple], sleeps, sfrom
+    ):
+        """The prefix-fork half of ``_dispatch_round``: trunk builds and
+        group launches, each timed into the launch ledger by itself."""
+        from ..obs.profiler import PROFILER
         from .fork import padded_size, prefix_digest
 
         keys = np.asarray(keys)
@@ -1877,9 +1900,6 @@ class DeviceDPOR:
         """Block on a dispatched round's parts and merge them back into
         batch order (np arrays quack like the LaneResult — or
         DporSleepResult — the harvesting loops read)."""
-        from ..obs.profiler import PROFILER
-
-        t0 = time.perf_counter() if PROFILER.enabled else 0.0
         if len(parts) == 1 and parts[0][0] is None:
             res = parts[0][1]
             jax.block_until_ready(res.violation)
@@ -1887,10 +1907,6 @@ class DeviceDPOR:
                 from ..parallel.mesh import lane_sharding_summary
 
                 self.lane_sharding = lane_sharding_summary(res.violation)
-            if PROFILER.enabled:
-                PROFILER.block(
-                    "dpor", batch_len, time.perf_counter() - t0
-                )
             return res
         res_type = type(parts[0][1])
         merged = {}
@@ -1903,8 +1919,6 @@ class DeviceDPOR:
                 merged[field][np.asarray(idx)] = np.asarray(
                     getattr(res, field)
                 )[: len(idx)]
-        if PROFILER.enabled:
-            PROFILER.block("dpor", batch_len, time.perf_counter() - t0)
         return res_type(**merged)
 
     def _process_round(
@@ -1932,25 +1946,60 @@ class DeviceDPOR:
         the per-lane scan + per-pair tuple loop; outputs are bit-identical
         (tests/test_host_path.py)."""
         self.interleavings += len(batch)
-        if obs.enabled():
-            # Device-lane totals for the round (one on-device
-            # reduction, one pull) + the exploration-efficiency
-            # counters optimal-DPOR tuning reads (redundant = already
-            # explored, pruned = over the edit-distance cap).
-            from ..obs import lane_stats as _ls
+        with obs.span("dpor.pull"):
+            if obs.enabled():
+                # Device-lane totals for the round (one on-device
+                # reduction, one pull) + the exploration-efficiency
+                # counters optimal-DPOR tuning reads (redundant = already
+                # explored, pruned = over the edit-distance cap).
+                from ..obs import lane_stats as _ls
 
-            _ls.record(
-                _ls.reduce_lanes(
-                    res.status, res.violation, res.deliveries,
-                    len(batch),
-                    invariant_interval=self.cfg.invariant_interval,
-                ),
-                driver="dpor",
+                _ls.record(
+                    _ls.reduce_lanes(
+                        res.status, res.violation, res.deliveries,
+                        len(batch),
+                        invariant_interval=self.cfg.invariant_interval,
+                    ),
+                    driver="dpor",
+                )
+                obs.counter("dpor.interleavings").inc(len(batch))
+            violations = np.asarray(res.violation)[: len(batch)]
+            traces = np.asarray(res.trace)
+            lens = np.asarray(res.trace_len)
+        with obs.span("dpor.violations"):
+            round_codes, hit = self._note_violations(
+                violations, traces, lens, batch, target_code
             )
-            obs.counter("dpor.interleavings").inc(len(batch))
-        violations = np.asarray(res.violation)[: len(batch)]
-        traces = np.asarray(res.trace)
-        lens = np.asarray(res.trace_len)
+        # Local fresh/redundant/pruned counts: the tuner's per-round
+        # signal, needed whether or not telemetry is on (the obs
+        # counters still carry the cross-round totals).
+        if self.host_path != "vectorized":
+            fresh_n, redundant_n, pruned_n = self._derive_legacy(
+                traces, lens, len(batch), frontier, batch=batch, res=res
+            )
+        elif self._sharder is not None:
+            fresh_n, redundant_n, pruned_n = self._derive_sharded(
+                traces, lens, len(batch), frontier, batch=batch, res=res
+            )
+        else:
+            fresh_n, redundant_n, pruned_n = self._derive_batch(
+                traces, lens, len(batch), frontier, batch=batch, res=res
+            )
+        obs.stage_count("dpor.fresh", fresh_n)
+        with obs.span("dpor.account"):
+            self._note_round(
+                batch, frontier, frontier_extra, round_codes,
+                fresh_n, redundant_n, pruned_n,
+            )
+        return hit
+
+    def _note_violations(
+        self, violations, traces, lens, batch: List[Tuple],
+        target_code: Optional[int],
+    ):
+        """The round's violation bookkeeping: the code ledger, the
+        per-code witness (sleep mode) and the hit selection. Returns
+        ``(round_codes, hit)``."""
         # Violation-set ledger (always on — one np.unique per round):
         # every distinct nonzero code any lane of any round produced,
         # the preservation surface the sleep-set A/B asserts against.
@@ -1990,21 +2039,15 @@ class DeviceDPOR:
             if len(hit_lanes)
             else None
         )
-        # Local fresh/redundant/pruned counts: the tuner's per-round
-        # signal, needed whether or not telemetry is on (the obs
-        # counters still carry the cross-round totals).
-        if self.host_path != "vectorized":
-            fresh_n, redundant_n, pruned_n = self._derive_legacy(
-                traces, lens, len(batch), frontier, batch=batch, res=res
-            )
-        elif self._sharder is not None:
-            fresh_n, redundant_n, pruned_n = self._derive_sharded(
-                traces, lens, len(batch), frontier, batch=batch, res=res
-            )
-        else:
-            fresh_n, redundant_n, pruned_n = self._derive_batch(
-                traces, lens, len(batch), frontier, batch=batch, res=res
-            )
+        return round_codes, hit
+
+    def _note_round(
+        self, batch: List[Tuple], frontier: List[Tuple],
+        frontier_extra: int, round_codes: List[int],
+        fresh_n: int, redundant_n: int, pruned_n: int,
+    ) -> None:
+        """The round's closing bookkeeping: journal stash, obs series,
+        tuner feedback, side-table pruning."""
         # Round-local stats for the journal record (obs/journal.py):
         # stashed always — a handful of ints next to a kernel launch.
         self._last_round = {
@@ -2046,10 +2089,10 @@ class DeviceDPOR:
             for p in batch:
                 self._guides.pop(p, None)
                 self._sleep_rows.pop(p, None)
-                # Executed ⇒ no longer pending; witness capture above
-                # already consumed the class attribution for this round.
+                # Executed ⇒ no longer pending; witness capture
+                # (``_note_violations``) already consumed the class
+                # attribution for this round.
                 self._class_of.pop(p, None)
-        return hit
 
     def _admit(
         self, presc: Tuple, key: Optional[bytes], frontier: List[Tuple]
@@ -2212,29 +2255,29 @@ class DeviceDPOR:
             if batch is not None and res is not None
             else None
         )
-        t0 = time.perf_counter() if PROFILER.enabled else 0.0
-        rows, offsets, lanes, digests = racing_prescriptions_batch(
-            traces[:n_lanes], lens[:n_lanes], recw,
-            size_hint=self._batch_size_hint,
-            independence=self.static_independence,
-            sleep=self.sleep, sleep_ctx=sleep_ctx,
-            buffers=self._scan_buffers,
-        )
-        if PROFILER.enabled:
-            PROFILER.host_scan(
-                "dpor-host-scan", n_lanes, time.perf_counter() - t0
+        with obs.span("dpor.scan", lanes=n_lanes) as sp:
+            rows, offsets, lanes, digests = racing_prescriptions_batch(
+                traces[:n_lanes], lens[:n_lanes], recw,
+                size_hint=self._batch_size_hint,
+                independence=self.static_independence,
+                sleep=self.sleep, sleep_ctx=sleep_ctx,
+                buffers=self._scan_buffers,
             )
+            keys = digest_keys(digests)
+        if PROFILER.enabled:
+            PROFILER.host_scan("dpor-host-scan", n_lanes, sp.seconds)
         # Adaptive buffer sizing: the next round's scan allocates for
         # this round's volume (+ slack) instead of a blind worst case.
         self._batch_size_hint = (
             max(64, (len(digests) * 5) // 4),
             max(256, (len(rows) * 5) // 4),
         )
-        keys = digest_keys(digests)
-        return self._admit_stream(
-            rows, offsets, lanes, keys, traces, lens, batch, sleep_ctx,
-            frontier,
-        )
+        obs.stage_count("dpor.candidates", len(keys))
+        with obs.span("dpor.admit", candidates=len(keys)):
+            return self._admit_stream(
+                rows, offsets, lanes, keys, traces, lens, batch, sleep_ctx,
+                frontier,
+            )
 
     def _derive_sharded(
         self, traces, lens, n_lanes: int, frontier: List[Tuple],
@@ -2260,17 +2303,19 @@ class DeviceDPOR:
             # Build the lazily-cached device matrix once, on this
             # thread, before the shard threads read it concurrently.
             self.static_independence.device_matrix()
-        t0 = time.perf_counter() if PROFILER.enabled else 0.0
-        scan = self._sharder.scan_round(
-            traces, lens, n_lanes, recw,
-            independence=self.static_independence,
-            sleep=self.sleep, sleep_ctx=sleep_ctx,
-            explored=self._explored_digests,
-            suppressed=self._suppressed_digests,
-        )
+        with obs.span(
+            "dpor.scan", lanes=n_lanes, shards=self._host_shards
+        ) as sp:
+            scan = self._sharder.scan_round(
+                traces, lens, n_lanes, recw,
+                independence=self.static_independence,
+                sleep=self.sleep, sleep_ctx=sleep_ctx,
+                explored=self._explored_digests,
+                suppressed=self._suppressed_digests,
+            )
         if PROFILER.enabled:
             PROFILER.host_scan(
-                "dpor-host-scan", n_lanes, time.perf_counter() - t0,
+                "dpor-host-scan", n_lanes, sp.seconds,
                 shape=f"b={n_lanes} shards={self._host_shards}",
             )
         # Same global adaptive hint as the sequential path (checkpoint
@@ -2280,18 +2325,20 @@ class DeviceDPOR:
             max(64, (len(scan.keys) * 5) // 4),
             max(256, (len(scan.rows) * 5) // 4),
         )
-        # Phase C: class-key canonicalization (the host half's dominant
-        # cost on class-tracked runs) precomputed per owning shard —
-        # the merge below only looks keys up.
-        class_keys = self._sharder.class_round(
-            scan, traces, lens, recw, self.sleep
-        )
-        return self._admit_stream(
-            scan.rows, scan.offsets, scan.lanes, scan.keys, traces, lens,
-            batch, sleep_ctx, frontier,
-            known_dup=scan.known_dup, shard_ids=scan.shard_ids,
-            shard_stats=scan.stats, class_keys=class_keys,
-        )
+        obs.stage_count("dpor.candidates", len(scan.keys))
+        with obs.span("dpor.admit", candidates=len(scan.keys)):
+            # Phase C: class-key canonicalization (the host half's
+            # dominant cost on class-tracked runs) precomputed per owning
+            # shard — the merge below only looks keys up.
+            class_keys = self._sharder.class_round(
+                scan, traces, lens, recw, self.sleep
+            )
+            return self._admit_stream(
+                scan.rows, scan.offsets, scan.lanes, scan.keys, traces,
+                lens, batch, sleep_ctx, frontier,
+                known_dup=scan.known_dup, shard_ids=scan.shard_ids,
+                shard_stats=scan.stats, class_keys=class_keys,
+            )
 
     def _admit_stream(
         self, rows, offsets, lanes, keys, traces, lens,
@@ -2601,7 +2648,6 @@ class DeviceDPOR:
         economy, and the round's violation codes. Called after every
         ``_account_round``; a detached journal costs one branch."""
         self.round_index += 1
-        obs.profiler.PROFILER.tick_round()
         if obs.journal.JOURNAL is None:
             return
         lr = self._last_round
@@ -2680,6 +2726,16 @@ class DeviceDPOR:
         mid-round — discards the launch unharvested. Either way every
         harvested round is byte-identical to the synchronous loop's,
         which follows the exact same generation policy."""
+        with obs.span(
+            "dpor.search", job=obs.new_job(), max_rounds=max_rounds
+        ):
+            return self._search(target_code, max_rounds, stop_on_violation)
+
+    def _search(
+        self, target_code: Optional[int], max_rounds: int,
+        stop_on_violation: bool,
+    ) -> Optional[Tuple[np.ndarray, int]]:
+        """``explore``'s round loop, under its ``dpor.search`` span."""
         gen = self.frontier
         pending: List[Tuple] = []  # the NEXT generation, fed by harvests
         # (batch, parts, n_real, prescs, keys) for the next round — the
@@ -2687,84 +2743,93 @@ class DeviceDPOR:
         inflight = None
         found = None
         for _ in range(max_rounds):
-            round_t0 = time.perf_counter()
-            if inflight is not None:
-                batch, parts, _, r_prescs, r_keys = inflight
-                inflight = None
-                # A hit is an in-flight launch actually harvested as the
-                # next round — adoption alone isn't enough (the budget
-                # can expire first, which counts as waste, so every
-                # dispatched launch lands in exactly one bucket).
-                self._note_inflight("hits")
-            else:
-                # Fork-group growth: a generation that can't fill a round
-                # pulls the next generation forward (see
-                # ``_merge_generations``).
-                gen, pending = self._merge_generations(gen, pending)
-                if not gen:
-                    break
-                batch, gen = self._select_batch(gen)
-                r_prescs = self._pack(batch)
-                r_keys = self._round_keys(
-                    len(batch), self.interleavings, batch=batch
-                )
-                parts = self._dispatch_round(r_prescs, r_keys, batch)
-            spec = None
-            if self._double_buffer and gen:
-                sbatch, srest = self._select_batch(gen)
-                s_prescs = self._pack(sbatch)
-                s_keys = self._round_keys(
-                    len(sbatch), self.interleavings + len(batch),
-                    batch=sbatch,
-                )
-                sparts = self._dispatch_round(s_prescs, s_keys, sbatch)
-                # len(gen) - len(srest) real entries precede the padding
-                # in sbatch — the count the budget-expiry requeue needs
-                # (a genuine root ``tuple()`` entry is falsy, so
-                # truthiness can't separate it from padding). The pure
-                # (prescs, keys) inputs ride along so a poisoned launch
-                # can re-execute this round at harvest time.
-                spec = (sbatch, sparts, len(gen) - len(srest),
-                        s_prescs, s_keys)
-                self._note_inflight("rounds")
-            with obs.span(
-                "dpor.round", batch=len(batch), frontier=len(gen)
-            ):
+            if inflight is None and not gen and not pending:
+                break
+            with obs.span("dpor.round") as round_span:
+                round_t0 = time.perf_counter()
+                if inflight is not None:
+                    batch, parts, _, r_prescs, r_keys = inflight
+                    inflight = None
+                    # A hit is an in-flight launch actually harvested as
+                    # the next round — adoption alone isn't enough (the
+                    # budget can expire first, which counts as waste, so
+                    # every dispatched launch lands in exactly one
+                    # bucket).
+                    self._note_inflight("hits")
+                else:
+                    with obs.span("dpor.select"):
+                        # Fork-group growth: a generation that can't
+                        # fill a round pulls the next generation forward
+                        # (see ``_merge_generations``).
+                        gen, pending = self._merge_generations(gen, pending)
+                        batch, gen = self._select_batch(gen)
+                    r_prescs, r_keys = self._pack_round(
+                        batch, self.interleavings
+                    )
+                    parts = self._dispatch_round(r_prescs, r_keys, batch)
+                round_span.set(batch=len(batch), frontier=len(gen))
+                spec = None
+                if self._double_buffer and gen:
+                    with obs.span("dpor.select"):
+                        sbatch, srest = self._select_batch(gen)
+                    s_prescs, s_keys = self._pack_round(
+                        sbatch, self.interleavings + len(batch)
+                    )
+                    sparts = self._dispatch_round(s_prescs, s_keys, sbatch)
+                    # len(gen) - len(srest) real entries precede the
+                    # padding in sbatch — the count the budget-expiry
+                    # requeue needs (a genuine root ``tuple()`` entry is
+                    # falsy, so truthiness can't separate it from
+                    # padding). The pure (prescs, keys) inputs ride along
+                    # so a poisoned launch can re-execute this round at
+                    # harvest time.
+                    spec = (sbatch, sparts, len(gen) - len(srest),
+                            s_prescs, s_keys)
+                    self._note_inflight("rounds")
                 t_harvest = time.perf_counter()
                 res = self._supervised_harvest(
                     parts, batch, r_prescs, r_keys
                 )
                 dev_secs = time.perf_counter() - t_harvest
-            hit = self._process_round(
-                res, batch, target_code, pending, frontier_extra=len(gen)
-            )
-            obs.gauge("dpor.frontier_size").set(len(gen) + len(pending))
-            if hit is not None:
-                obs.counter("dpor.violations_found").inc()
-                if found is None:
-                    found = hit
-                if stop_on_violation:
-                    if spec is not None:
+                hit = self._process_round(
+                    res, batch, target_code, pending,
+                    frontier_extra=len(gen),
+                )
+                obs.gauge("dpor.frontier_size").set(len(gen) + len(pending))
+                stop = False
+                if hit is not None:
+                    obs.counter("dpor.violations_found").inc()
+                    if found is None:
+                        found = hit
+                    stop = stop_on_violation
+                if spec is not None and stop:
+                    self._note_inflight("waste")
+                elif spec is not None:
+                    sbatch, sparts, sreal, s_prescs, s_keys = spec
+                    # The speculative batch was selected from the
+                    # UNMERGED remainder; validate against the merged
+                    # pool the synchronous loop would select from at its
+                    # next round top. A merge that changes the selection
+                    # discards the in-flight launch — waste, never
+                    # divergence.
+                    with obs.span("dpor.select"):
+                        mgen, mpending = self._merge_generations(
+                            gen, pending
+                        )
+                        abatch, arest = self._select_batch(mgen)
+                    if abatch == sbatch:
+                        inflight = (sbatch, sparts, sreal, s_prescs, s_keys)
+                        gen, pending = arest, mpending
+                    else:
                         self._note_inflight("waste")
+                with obs.span("dpor.account"):
                     h, d = self._account_round(round_t0, dev_secs)
                     self._journal_round(h, d, len(gen) + len(pending))
-                    break
-            if spec is not None:
-                sbatch, sparts, sreal, s_prescs, s_keys = spec
-                # The speculative batch was selected from the UNMERGED
-                # remainder; validate against the merged pool the
-                # synchronous loop would select from at its next round
-                # top. A merge that changes the selection discards the
-                # in-flight launch — waste, never divergence.
-                mgen, mpending = self._merge_generations(gen, pending)
-                abatch, arest = self._select_batch(mgen)
-                if abatch == sbatch:
-                    inflight = (sbatch, sparts, sreal, s_prescs, s_keys)
-                    gen, pending = arest, mpending
-                else:
-                    self._note_inflight("waste")
-            h, d = self._account_round(round_t0, dev_secs)
-            self._journal_round(h, d, len(gen) + len(pending))
+            # The round boundary of --profile-rounds: outside the round's
+            # span, so a trace window that closes here holds it whole.
+            obs.profiler.PROFILER.tick_round()
+            if stop:
+                break
         if inflight is not None:
             # The round budget expired with a speculative round still on
             # device: it was never harvested, so its prescriptions go

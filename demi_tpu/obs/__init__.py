@@ -7,7 +7,10 @@ The snapshot half (one switch, off by default):
   - ``metrics``: process-wide registry of labeled counters / gauges /
     timing histograms with JSON snapshot + cross-process merge;
   - ``spans``: nested ``span("stage.name", ...)`` tracing with JSONL and
-    Chrome/Perfetto ``trace_event`` export;
+    Chrome/Perfetto ``trace_event`` export, a per-name totals table
+    (``stage_totals()`` / ``stage_counts()``), and, while a
+    ``jax.profiler`` session records, the same spans on its timeline as
+    ``demi.<name>`` (live then with no switch);
   - ``lane_stats`` (import directly — it needs jax): per-sweep device
     counters reduced on-device and pulled once per round.
 
@@ -52,7 +55,16 @@ from .metrics import (  # noqa: F401
     relabel_snapshot,
     timed,
 )
-from .spans import TRACER, Tracer, record_span, span  # noqa: F401
+from .spans import (  # noqa: F401
+    TRACER,
+    Tracer,
+    new_job,
+    record_span,
+    span,
+    stage_count,
+    stage_counts,
+    stage_totals,
+)
 
 __all__ = [
     "REGISTRY",
@@ -69,10 +81,14 @@ __all__ = [
     "histogram",
     "journal",
     "merge_snapshots",
+    "new_job",
     "profiler",
     "record_span",
     "relabel_snapshot",
     "span",
+    "stage_count",
+    "stage_counts",
+    "stage_totals",
     "timed",
     "timeseries",
 ]

@@ -12,9 +12,17 @@ segment/variant). This module collects exactly that ledger:
     jitted call itself (device/explore.py's ``_counted_kernel``, the one
     wrapper every lane kernel already passes through);
   - ``PROFILER.trunk(...)`` — the single-lane trunk builds of the
-    prefix-fork paths (DeviceDPOR._dispatch_round);
+    prefix-fork paths (DeviceDPOR._dispatch_forked);
   - ``PROFILER.block(...)`` — the ``block_until_ready`` harvest waits
-    (DeviceDPOR._harvest_round, SweepDriver._harvest_chunk).
+    (DeviceDPOR._supervised_harvest, SweepDriver._harvest_chunk).
+
+Where a stage span (obs/spans.py) brackets the same interval — DPOR's
+``dpor.dispatch``, ``dpor.block`` and ``dpor.scan`` — the ledger takes
+the span's duration instead of a clock pair of its own, so an enabled
+profiler keeps spans live (``spans.live_while`` below). That has a cost
+the ledger alone had not: finished spans are held in ``TRACER`` (up to
+its ``max_spans``, 200,000: some tens of MB) and each pass of CPython's
+collector under an open span is recorded as a ``gc.pause`` span.
 
 Evidence is exported in the same decision-dict shape the autotuner
 persists (``evidence()`` / ``persist_evidence``): one
@@ -36,6 +44,8 @@ import os
 import sys
 import threading
 from typing import Any, Dict, List, Optional
+
+from . import spans
 
 _enabled = os.environ.get("DEMI_PROFILE", "").strip().lower() in (
     "1", "true", "yes", "on"
@@ -205,3 +215,5 @@ class LaunchProfiler:
 
 #: Process-wide profiler every instrumented launch site reports into.
 PROFILER = LaunchProfiler()
+# The ledger's DPOR rows are span durations: spans are live while it is on.
+spans.live_while(lambda: PROFILER.enabled)

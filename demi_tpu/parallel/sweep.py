@@ -419,6 +419,9 @@ class SweepDriver:
         # sweep.host_share gauge and bench config 5 read this.
         self.host_seconds = 0.0
         self.device_seconds = 0.0
+        # The request number of the sweep in progress (obs.new_job() at
+        # each sweep()/sweep_autotuned() entry): its root spans carry it.
+        self._job: Optional[int] = None
         from ..device.fork import prefix_fork_enabled
 
         self._forker = None
@@ -688,7 +691,7 @@ class SweepDriver:
         # poisoned launch re-dispatches the chunk from the same inputs
         # under the launch supervisor (bounded retry + backoff;
         # --strict-io turns exhausted retries into errors).
-        with obs.span("device.sweep.chunk", lanes=len(seeds)):
+        with obs.span("device.sweep.chunk", job=self._job, lanes=len(seeds)):
             return SUPERVISOR.run(
                 lambda attempt: self._harvest_chunk(
                     self._dispatch_chunk(seeds, base_key), slice_index
@@ -747,7 +750,6 @@ class SweepDriver:
                 lane_stats, driver="sweep",
                 unique_schedules=int(chunk_uniq.size),
             )
-            obs.histogram("device.sweep.chunk_seconds").observe(seconds)
         # One journal record per harvested chunk (obs/journal.py — one
         # branch when detached): the sweep's continuous wire format.
         self.chunk_index += 1
@@ -810,9 +812,7 @@ class SweepDriver:
         partition the seed space — see module docstring)."""
         if mode is None:
             mode = "continuous" if num_slices == 1 else "chunked"
-        obs.counter("device.sweep.lanes_requested").inc(
-            total_lanes, mode=mode
-        )
+        self._job = obs.new_job()
         if mode == "continuous":
             if num_slices != 1:
                 raise ValueError(
@@ -893,37 +893,50 @@ class SweepDriver:
         ``retire_hook(seeds, statuses, codes, hashes)`` observes every
         accumulated retirement batch in order — the autotuned path's
         reward attribution rides it."""
-        drv = self._continuous_driver(batch, base_key, program_gen)
-        acc = _HarvestAccumulator()
-        t0 = time.perf_counter()
-        for seeds, statuses, codes, hashes in drv._run_batches(total_lanes):
-            # Every retirement in this harvest round is PAID-FOR device
-            # work — count them all before deciding to stop. (The old
-            # array path truncated at the first violating retirement,
-            # mimicking the per-item loop's mid-round break; that threw
-            # away already-retired non-violating verdicts in the same
-            # round, undercounting lanes/codes the device had computed.
-            # tests/test_streaming.py pins the retained-lane counts.)
-            acc.add(seeds, statuses, codes, hashes)
-            if retire_hook is not None:
-                retire_hook(seeds, statuses, codes, hashes)
-            vio = np.flatnonzero(codes != 0)
-            if self.violation_hook is not None and len(vio):
-                # Streaming handoff from the continuous driver: the
-                # violating retirements, in retirement order, without
-                # stopping the sweep.
-                self.violation_hook(seeds[vio], codes[vio])
-            if stop_on_violation and len(vio):
-                break
-        chunk = acc.chunk(slice_index=0, seconds=time.perf_counter() - t0)
-        chunk.lane_sharding = drv.last_lane_sharding
-        result = SweepResult(chunks=[chunk])
-        result.occupancy = drv.last_occupancy
-        # One chunk, harvested synchronously: its seconds ARE wall time.
-        result.wall_seconds = chunk.seconds
-        # Host-share attribution: the driver's segment/harvest split is
-        # exact for continuous sweeps (the status pull is the sync point).
-        self._note_share(drv.last_harvest_seconds, drv.last_segment_seconds)
+        with obs.span("sweep.job", job=self._job, lanes=total_lanes):
+            drv = self._continuous_driver(batch, base_key, program_gen)
+            acc = _HarvestAccumulator()
+            t0 = time.perf_counter()
+            for seeds, statuses, codes, hashes in drv._run_batches(
+                total_lanes
+            ):
+                with obs.span("sweep.fold", lanes=len(seeds)):
+                    # Every retirement in this harvest round is PAID-FOR
+                    # device work — count them all before deciding to
+                    # stop. (The old array path truncated at the first
+                    # violating retirement, mimicking the per-item loop's
+                    # mid-round break; that threw away already-retired
+                    # non-violating verdicts in the same round,
+                    # undercounting lanes/codes the device had computed.
+                    # tests/test_streaming.py pins the retained-lane
+                    # counts.)
+                    acc.add(seeds, statuses, codes, hashes)
+                    if retire_hook is not None:
+                        retire_hook(seeds, statuses, codes, hashes)
+                    vio = np.flatnonzero(codes != 0)
+                    if self.violation_hook is not None and len(vio):
+                        # Streaming handoff from the continuous driver:
+                        # the violating retirements, in retirement order,
+                        # without stopping the sweep.
+                        self.violation_hook(seeds[vio], codes[vio])
+                if stop_on_violation and len(vio):
+                    break
+            with obs.span("sweep.finish"):
+                chunk = acc.chunk(
+                    slice_index=0, seconds=time.perf_counter() - t0
+                )
+                chunk.lane_sharding = drv.last_lane_sharding
+                result = SweepResult(chunks=[chunk])
+                result.occupancy = drv.last_occupancy
+                # One chunk, harvested synchronously: its seconds ARE
+                # wall time.
+                result.wall_seconds = chunk.seconds
+                # Host-share attribution: the driver's segment/harvest
+                # split is exact for continuous sweeps (the status pull
+                # is the sync point).
+                self._note_share(
+                    drv.last_harvest_seconds, drv.last_segment_seconds
+                )
         return result
 
     def sweep_autotuned(
@@ -957,6 +970,7 @@ class SweepDriver:
         proposal that generated it; epoch-k lanes still in flight when
         its reward fires land in the sweep result but not the reward
         signal (dropped, never mis-credited)."""
+        self._job = obs.new_job()
         if mode == "continuous":
             # The epoch-tagged reward attribution rides the ONE shared
             # continuous path: a generator wrapper tags each seed with

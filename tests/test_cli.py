@@ -303,3 +303,25 @@ def test_cli_dpor_profile_rounds(tmp_path, capsys, monkeypatch):
     key = summary["launch_profile_cache"]["key"]
     assert "profile=launch" in key
     assert TuningCache(str(cache_path)).get(key)["launches"]
+    # The stage table of the same run, whose spans feed the ledger's
+    # dispatch and block rows; the trace holds them as demi.<stage>.
+    stages = summary["stages"]
+    assert stages["dpor.search"]["count"] == 1
+    block = next(
+        r for r in prof["launches"]
+        if (r["kernel"], r["kind"]) == ("dpor", "block")
+    )
+    assert stages["dpor.block"]["count"] == block["launches"]
+    import glob
+
+    import jax
+
+    (pb,) = glob.glob(
+        str(tmp_path / "trace" / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    names = {
+        ev.name
+        for plane in jax.profiler.ProfileData.from_file(pb).planes
+        for line in plane.lines for ev in line.events
+    }
+    assert {"demi.dpor.round", "demi.dpor.block"} <= names

@@ -2,6 +2,7 @@
 merge, span nesting, Perfetto export validity, and device LaneStats
 agreement with host-side sweep accounting."""
 
+import gc
 import json
 import os
 
@@ -14,12 +15,17 @@ from demi_tpu.obs import spans as obs_spans
 
 @pytest.fixture
 def telemetry():
-    """Clean, enabled telemetry for one test; always restored to off."""
+    """Clean, enabled telemetry for one test; always restored to off.
+    The collector is held off meanwhile: a pass that starts inside an
+    open span is a ``gc.pause`` span of its own (tests/test_stage_spans.py
+    pins that), and the tests here count spans exactly."""
     obs.REGISTRY.reset()
     obs.TRACER.clear()
+    gc.disable()
     obs.enable()
     yield
     obs.disable()
+    gc.enable()
     obs.REGISTRY.reset()
     obs.TRACER.clear()
 
