@@ -347,8 +347,6 @@ def delta_warm_start(dpor, store, app) -> Optional[Dict[str, Any]]:
             sleep.seed_covered(transfer, meta=led.meta)
             stats["transferred"] = len(transfer)
             reseeded = unseedable = pending_noted = 0
-            from ..native import prescription_digest
-
             for k in cone:
                 if k in sleep.classes:
                     continue
@@ -371,10 +369,7 @@ def delta_warm_start(dpor, store, app) -> Optional[Dict[str, Any]]:
                 sleep.note_class(k, guide=guide, plen=plen, dmask=dm)
                 if rep in dpor.explored:
                     continue
-                dpor.explored.add(rep)
-                dpor._explored_log.append(rep)
-                dpor._explored_digests.add(prescription_digest(rep))
-                dpor.frontier.append(rep)
+                dpor.frontier.append(dpor.admit_tuples([rep]))
                 dpor._guides[rep] = np.asarray(guide, np.int32)
                 dpor._class_of[rep] = k
                 reseeded += 1

@@ -54,6 +54,7 @@ from .core import (
 )
 from .encoding import lower_program
 from .explore import ExtProgram, LaneResult, _finalize, make_step_fn
+from .explored_log import ExploredLog, ExploredView, PrescList
 
 
 def make_prescribed_dispatch(app: DSLApp, cfg: DeviceConfig):
@@ -1087,23 +1088,30 @@ def _dpor_search_state(dpor: "DeviceDPOR") -> tuple:
             dict(dpor.sleep.pruned_total),
         )
     return (
-        set(dpor.explored), list(dpor.frontier), dpor.original,
+        dpor._explored_log.copy(), dpor.frontier.copy(), dpor.original,
         dpor.max_distance, dpor.interleavings, dpor.round_batch,
         dict(dpor.async_stats), tuner, set(dpor._explored_digests),
         dpor.host_seconds, dpor.device_seconds,
         dict(dpor._sleep_rows), set(dpor._suppressed),
         set(dpor._suppressed_digests), set(dpor.violation_codes),
-        sleep_state, dict(dpor._guides), list(dpor._explored_log),
+        sleep_state, dict(dpor._guides),
     )
 
 
 def _dpor_restore_state(dpor: "DeviceDPOR", state: tuple) -> None:
+    # The explored log rolls back in place (the lists that index it stay
+    # bound to it); the durable-checkpoint pack cache re-validates itself
+    # against it (prefix + last-entry check) and rebuilds when the
+    # rollback invalidated it.
+    dpor._explored_log.restore(state[0])
+    if dpor._legacy_explored is not None:
+        dpor._legacy_explored = set(dpor._explored_log)
     (
-        dpor.explored, dpor.frontier, dpor.original, dpor.max_distance,
+        dpor.frontier, dpor.original, dpor.max_distance,
         dpor.interleavings, dpor.round_batch, async_stats, tuner,
         dpor._explored_digests, dpor.host_seconds, dpor.device_seconds,
     ) = (
-        set(state[0]), list(state[1]), state[2], state[3], state[4],
+        state[1].copy(), state[2], state[3], state[4],
         state[5], dict(state[6]), state[7], set(state[8]),
         state[9], state[10],
     )
@@ -1124,10 +1132,6 @@ def _dpor_restore_state(dpor: "DeviceDPOR", state: tuple) -> None:
             dpor._host_shards, dpor._suppressed_digests
         )
     dpor._guides = dict(state[16])
-    # The explored log rolls back with the set; the durable-checkpoint
-    # pack cache re-validates itself against it (prefix + last-entry
-    # check) and rebuilds when the rollback invalidated it.
-    dpor._explored_log = list(state[17])
     if state[15] is not None and dpor.sleep is not None:
         dpor.sleep.classes = set(state[15][0])
         dpor.sleep._node_flips = {
@@ -1391,24 +1395,30 @@ class DeviceDPOR:
         # (obs) and bench configs 2/8 read these.
         self.host_seconds = 0.0
         self.device_seconds = 0.0
-        self.explored: Set[Tuple] = set()
-        self.frontier: List[Tuple] = [tuple()]
-        self.explored.add(tuple())
-        # Admission-ordered log of the explored set (kept in lockstep
-        # with ``explored`` — __init__/seed/_admit are the only
-        # writers). The durable-checkpoint codec serializes the log as
-        # one packed int32 blob and the frontier as INDICES into it, and
-        # keeps an incremental pack cache so each snapshot packs only
-        # the entries admitted since the last one (demi_tpu/persist).
-        self._explored_log: List[Tuple] = [tuple()]
+        # What was admitted, in admission order and in columns
+        # (device/explored_log.py): a prescription is its source lane's
+        # delivery rows, kept once per lane, plus a length, the flipped
+        # row and the content digest. ``explored`` is a read-only set
+        # view over it, the frontier a list of indices into it; a Python
+        # tuple exists only where somebody asks for one. The durable-
+        # checkpoint codec serializes the log as delta frames and the
+        # frontier as the indices, with an incremental pack cache so each
+        # snapshot packs only the entries admitted since the last one
+        # (demi_tpu/persist).
+        self._explored_log = ExploredLog(cfg.rec_width)
+        self._explored = ExploredView(self)
         self._persist_pack_cache = None
-        # Digest twin of the explored set (16-byte content keys over the
-        # packed prescription rows): the vectorized path's membership
-        # check, maintained in lockstep with ``explored`` so a redundant
-        # prescription never has to materialize a Python tuple.
-        from ..native import prescription_digest
-
-        self._explored_digests: Set[bytes] = {prescription_digest(tuple())}
+        # The 16-byte content keys of everything in the log: what decides
+        # membership, for the round's candidates (digested in the scan)
+        # and for a tuple from outside (``p in explored`` digests it).
+        self._explored_digests: Set[bytes] = set()
+        # The legacy host path dedups on tuples by definition, so it
+        # alone keeps a tuple set beside the log.
+        self._legacy_explored: Optional[Set[Tuple]] = (
+            set() if self.host_path == "legacy" else None
+        )
+        self.admit_tuples([tuple()])  # the root, log index 0
+        self.frontier = [0]
         # Adaptive (n_presc, n_rows) buffer hint for the batch scan.
         self._batch_size_hint: Optional[Tuple[int, int]] = None
         # Persistent scan output buffers for the unsharded batch path:
@@ -1508,17 +1518,71 @@ class DeviceDPOR:
         self.tuner = None
         self.round_batch = batch_size
 
+    @property
+    def explored(self) -> ExploredView:
+        """Every admitted prescription, as a read-only set of tuples over
+        the columnar log and the digest set."""
+        return self._explored
+
+    @property
+    def frontier(self) -> PrescList:
+        """The worklist: prescriptions admitted and not yet executed, as
+        a list over the explored log whose items read as tuples of row
+        tuples. Assigning a list of tuples (or of log indices) binds it
+        to the log."""
+        return self._frontier
+
+    @frontier.setter
+    def frontier(self, prescriptions) -> None:
+        self._frontier = self._list(prescriptions)
+
+    def _list(self, prescriptions) -> PrescList:
+        """``prescriptions`` as a list over this driver's log: itself
+        when it is one, else each item — an admitted tuple from outside,
+        or a log index — looked up."""
+        log = self._explored_log
+        if isinstance(prescriptions, PrescList) and prescriptions.log is log:
+            return prescriptions
+        return PrescList(
+            log,
+            (
+                p if isinstance(p, (int, np.integer)) else log.index_of(p)
+                for p in prescriptions
+            ),
+        )
+
+    def admit_tuples(
+        self, prescriptions: Sequence[Tuple], keep: bool = True
+    ) -> int:
+        """Record prescriptions that arrive as tuples of row tuples — a
+        seed, a re-seeded class representative, a restored checkpoint,
+        the legacy host path's — as explored: the one way in for writers
+        outside the round's admission. The caller has checked that they
+        are new. Returns the first one's log index (they follow on)."""
+        from ..native import digest_keys
+
+        log = self._explored_log
+        first = log.extend_tuples(prescriptions, keep=keep)
+        self._explored_digests.update(digest_keys(log.digest[first: log.n]))
+        if self._legacy_explored is not None:
+            self._legacy_explored.update(prescriptions)
+        return first
+
+    def load_tuples(self, prescriptions: Sequence[Tuple]) -> None:
+        """Replace everything explored by ``prescriptions``, in their
+        order (a restored checkpoint's log; the first is the root)."""
+        self._explored_log = ExploredLog(self.cfg.rec_width)
+        self._explored_digests = set()
+        if self._legacy_explored is not None:
+            self._legacy_explored = set()
+        self.admit_tuples(prescriptions, keep=False)
+
     def seed(self, prescription: Tuple[Tuple[int, ...], ...]) -> None:
         """Plant an initial prescription at the head of the frontier (and
         fix it as the edit-distance origin)."""
-        from ..native import prescription_digest
-
         self.original = prescription
         if prescription not in self.explored:
-            self.explored.add(prescription)
-            self._explored_log.append(prescription)
-            self._explored_digests.add(prescription_digest(prescription))
-            self.frontier.insert(0, prescription)
+            self.frontier.insert(0, self.admit_tuples([prescription]))
             if self.sleep is not None and prescription:
                 # Seeded rows carry no source-lane positions: creation
                 # edges onto them never fire (class splits, never
@@ -1561,7 +1625,7 @@ class DeviceDPOR:
         restore_device_dpor(self, payload)
 
     def _supervised_harvest(
-        self, parts, batch: List[Tuple], prescs: np.ndarray, keys
+        self, parts, batch: PrescList, prescs: np.ndarray, keys
     ):
         """Harvest one round under the launch supervisor: a failed or
         poisoned launch re-executes the round from its (pure) inputs —
@@ -1584,7 +1648,7 @@ class DeviceDPOR:
             PROFILER.block("dpor", len(batch), sp.seconds)
         return res
 
-    def _pack_round(self, batch: List[Tuple], base: int):
+    def _pack_round(self, batch: PrescList, base: int):
         """One round's kernel inputs: ``(_pack(batch), _round_keys(...))``
         with ``base`` the interleaving count the round starts from."""
         with obs.span("dpor.pack"):
@@ -1592,27 +1656,35 @@ class DeviceDPOR:
                 len(batch), base, batch=batch
             )
 
-    def _pack(self, prescriptions: List[Tuple]) -> np.ndarray:
+    def _pack(self, prescriptions) -> np.ndarray:
+        """The kernel's prescription input for one batch: each lane's
+        rows copied from the log's columns (its source lane's delivery
+        rows, then the flipped row); in sleep mode a wakeup-sequence
+        guide wins."""
+        batch = self._list(prescriptions)
+        log = self._explored_log
         r, w = self.cfg.max_steps, self.cfg.rec_width
-        out = np.zeros((len(prescriptions), r, w), np.int32)
-        for k, presc in enumerate(prescriptions):
-            guide = (
-                self._guides.get(presc) if self.sleep is not None else None
-            )
+        out = np.zeros((len(batch), r, w), np.int32)
+        guides = (
+            [self._guides.get(p) for p in batch.tuples()]
+            if self.sleep is not None and self._guides
+            else None
+        )
+        for k, i in enumerate(batch.idx):
+            guide = guides[k] if guides is not None else None
             if guide is not None:
                 m = min(len(guide), r)
                 out[k, :m] = guide[:m]
-            elif presc:
-                m = min(len(presc), r)
-                out[k, :m] = np.asarray(presc[:m], np.int32)
+            elif i:
+                log.write_rows(i, out[k])
         return out
 
-    def _sleep_from(self, batch: List[Tuple]) -> np.ndarray:
+    def _sleep_from(self, batch) -> np.ndarray:
         """Per-lane node ordinal (sleep mode): the delivery count of the
         lane's IDENTITY prescription (prefix + flip) — wake tracking and
         sleep-membership checks apply at/after it. Guide rows beyond the
         identity are ordinary prescribed deliveries and ARE tracked."""
-        return np.asarray([len(p) for p in batch], np.int32)
+        return self._list(batch).lengths().astype(np.int32)
 
     def _progs(self, b: int) -> ExtProgram:
         from .explore import broadcast_program
@@ -1620,8 +1692,8 @@ class DeviceDPOR:
         return broadcast_program(self.prog, b)
 
     def _select_batch(
-        self, frontier: List[Tuple]
-    ) -> Tuple[List[Tuple], List[Tuple]]:
+        self, frontier: PrescList
+    ) -> Tuple[PrescList, PrescList]:
         """Pure round selection: ``(batch, rest)`` for one frontier round
         — deepest-first with a seeded initial prescription pinned to the
         head, padded to ``batch_size`` with prescription-free lanes. Does
@@ -1645,29 +1717,33 @@ class DeviceDPOR:
         identical schedule space."""
         frontier = self._ordered_frontier(frontier)
         take = max(1, min(self.round_batch, self.batch_size))
-        batch, rest = frontier[:take], frontier[take:]
-        batch = batch + [tuple()] * (self.batch_size - len(batch))
-        return batch, rest
+        batch, rest = frontier.split(take)
+        return batch.padded(self.batch_size), rest
 
-    def _ordered_frontier(self, frontier: List[Tuple]) -> List[Tuple]:
+    def _ordered_frontier(self, frontier) -> PrescList:
         """The ONE round-order rule (see ``_select_batch``): a seeded
         original pinned at the head, then deepest-bucket-first with
         lexicographic content order within a bucket. Bench config 8's
         sibling-clustering measurement calls this too, so it can never
-        measure an ordering the frontier doesn't actually use."""
-        frontier = list(frontier)
-        head, rest = (
-            ([frontier[0]], frontier[1:])
-            if self.original is not None and frontier
+        measure an ordering the frontier doesn't actually use.
+
+        The bucket is a function of the log's length column, so the
+        grouping is one stable argsort; the content order within a
+        bucket needs the tuples, and is worked out only when a read
+        reaches that bucket (``PrescList``): a round materializes the
+        buckets its batch comes from and no others."""
+        frontier = self._list(frontier)
+        if frontier.ordered:
+            return frontier
+        pinned = (
+            self.original is not None and len(frontier) > 0
             and frontier[0] == self.original
-            else ([], frontier)
         )
-        rest.sort(key=lambda p: (-(len(p) // 8), p))
-        return head + rest
+        return frontier.by_depth_bucket(8, head=int(pinned))
 
     def _merge_generations(
-        self, gen: List[Tuple], pending: List[Tuple]
-    ) -> Tuple[List[Tuple], List[Tuple]]:
+        self, gen: PrescList, pending: PrescList
+    ) -> Tuple[PrescList, PrescList]:
         """Cross-generation round filling (fork-group growth): when the
         frozen generation can no longer FILL a round, the next generation
         joins it — so a round's batch carries equal-depth prescriptions
@@ -1677,12 +1753,13 @@ class DeviceDPOR:
         synchronous loop and the double-buffered speculation check derive
         the same decision, so a merge at a generation boundary costs at
         most one discarded in-flight launch, never a divergence."""
+        gen, pending = self._list(gen), self._list(pending)
         if not pending:
             return gen, pending
         take = max(1, min(self.round_batch, self.batch_size))
         if len(gen) >= take:
             return gen, pending
-        return gen + pending, []
+        return gen + pending, PrescList(self._explored_log)
 
     def _sleep_kernel_args(self) -> dict:
         """The (sleep_cap, commute_matrix) pair that fixes this
@@ -1694,7 +1771,7 @@ class DeviceDPOR:
         }
 
     def _round_seeds(
-        self, n: int, base: int, batch: Optional[List[Tuple]] = None
+        self, n: int, base: int, batch: Optional[PrescList] = None
     ) -> np.ndarray:
         """Per-lane rng seeds (uint32) for one round — pure NumPy, so a
         process that only plans rounds (the fleet coordinator) derives
@@ -1710,6 +1787,13 @@ class DeviceDPOR:
         pruning shifts it in the round order — the property the sleep
         A/B's explored-subset/violation-preservation contract rests on."""
         if self.key_mode == "content" and batch is not None:
+            # The first four bytes of each prescription's digest: the
+            # stored one for a list over the log, taken anew for tuples
+            # from outside (which need not have been admitted).
+            log = self._explored_log
+            if isinstance(batch, PrescList) and batch.log is log:
+                idx = np.asarray(batch.idx, np.int64)
+                return log.digest[idx].view(np.uint32)[:, 0].copy()
             from ..native import prescription_digest
 
             return np.asarray(
@@ -1721,11 +1805,11 @@ class DeviceDPOR:
             )
         return np.arange(base, base + n, dtype=np.uint32)
 
-    def _round_keys(self, n: int, base: int, batch: Optional[List[Tuple]] = None):
+    def _round_keys(self, n: int, base: int, batch: Optional[PrescList] = None):
         """Per-lane keys for one round: ``lane_keys(_round_seeds(...))``."""
         return lane_keys(self._round_seeds(n, base, batch=batch))
 
-    def _dispatch_round(self, prescs: np.ndarray, keys, batch: List[Tuple]):
+    def _dispatch_round(self, prescs: np.ndarray, keys, batch: PrescList):
         """Launch one frontier round's lane work WITHOUT pulling results
         — the dispatch half of the round (pair with ``_harvest_round``).
         Returns a list of ``(indices, device LaneResult)`` parts;
@@ -1768,7 +1852,7 @@ class DeviceDPOR:
         return out
 
     def _dispatch_forked(
-        self, prescs: np.ndarray, keys, batch: List[Tuple], sleeps, sfrom
+        self, prescs: np.ndarray, keys, batch: PrescList, sleeps, sfrom
     ):
         """The prefix-fork half of ``_dispatch_round``: trunk builds and
         group launches, each timed into the launch ledger by itself."""
@@ -1776,10 +1860,11 @@ class DeviceDPOR:
         from .fork import padded_size, prefix_digest
 
         keys = np.asarray(keys)
-        lengths = np.asarray(
-            [len(self._guides.get(p, p)) for p in batch]
+        batch = self._list(batch)
+        lengths = (
+            np.asarray([len(self._guides.get(p, p)) for p in batch.tuples()])
             if self.sleep is not None
-            else [len(p) for p in batch]
+            else batch.lengths()
         )
         # Plan and key trunks over MATCH-NORMALIZED rows: the
         # prescribed-dispatch matcher never reads the parent/prev
@@ -1882,14 +1967,16 @@ class DeviceDPOR:
             self._forker.note_scratch(len(scratch))
         return parts
 
-    def _pack_sleep(self, batch: List[Tuple]) -> np.ndarray:
+    def _pack_sleep(self, batch: PrescList) -> np.ndarray:
         """Fixed-shape sleep input for one round: each lane's sleep rows
         ([B, sleep_cap, recw] int32, kind 0 = empty slot) looked up from
         the frontier side-table — prescription-free padding lanes carry
         none."""
         S, w = self.sleep.cap, self.cfg.rec_width
         out = np.zeros((len(batch), S, w), np.int32)
-        for k, presc in enumerate(batch):
+        if not self._sleep_rows:
+            return out
+        for k, presc in enumerate(self._list(batch).tuples()):
             rows = self._sleep_rows.get(presc)
             if rows:
                 for s, row in enumerate(rows[:S]):
@@ -1924,9 +2011,9 @@ class DeviceDPOR:
     def _process_round(
         self,
         res: LaneResult,
-        batch: List[Tuple],
+        batch: PrescList,
         target_code: Optional[int],
-        frontier: List[Tuple],
+        frontier: PrescList,
         frontier_extra: int = 0,
     ) -> Optional[Tuple[np.ndarray, int]]:
         """The host half of a frontier round: telemetry, the violation
@@ -1945,6 +2032,7 @@ class DeviceDPOR:
         prescriptions that actually join the frontier. ``'legacy'`` keeps
         the per-lane scan + per-pair tuple loop; outputs are bit-identical
         (tests/test_host_path.py)."""
+        batch = self._list(batch)
         self.interleavings += len(batch)
         with obs.span("dpor.pull"):
             if obs.enabled():
@@ -1986,6 +2074,8 @@ class DeviceDPOR:
                 traces, lens, len(batch), frontier, batch=batch, res=res
             )
         obs.stage_count("dpor.fresh", fresh_n)
+        # Kept beside it so that a job that built no tuple reads 0.
+        obs.stage_count("dpor.materialized", 0)
         with obs.span("dpor.account"):
             self._note_round(
                 batch, frontier, frontier_extra, round_codes,
@@ -1994,7 +2084,7 @@ class DeviceDPOR:
         return hit
 
     def _note_violations(
-        self, violations, traces, lens, batch: List[Tuple],
+        self, violations, traces, lens, batch: PrescList,
         target_code: Optional[int],
     ):
         """The round's violation bookkeeping: the code ledger, the
@@ -2042,7 +2132,7 @@ class DeviceDPOR:
         return round_codes, hit
 
     def _note_round(
-        self, batch: List[Tuple], frontier: List[Tuple],
+        self, batch: PrescList, frontier: PrescList,
         frontier_extra: int, round_codes: List[int],
         fresh_n: int, redundant_n: int, pruned_n: int,
     ) -> None:
@@ -2052,7 +2142,7 @@ class DeviceDPOR:
         # stashed always — a handful of ints next to a kernel launch.
         self._last_round = {
             "batch": len(batch),
-            "depth": max((len(p) for p in batch), default=0),
+            "depth": int(batch.lengths().max()) if len(batch) else 0,
             "fresh": int(fresh_n),
             "redundant": int(redundant_n),
             "distance_pruned": int(pruned_n),
@@ -2086,7 +2176,7 @@ class DeviceDPOR:
             # frontier instead of the whole explored history. (An
             # unharvested in-flight round that gets requeued was never
             # processed here, so its entries survive for re-dispatch.)
-            for p in batch:
+            for p in batch.tuples():
                 self._guides.pop(p, None)
                 self._sleep_rows.pop(p, None)
                 # Executed ⇒ no longer pending; witness capture
@@ -2094,25 +2184,37 @@ class DeviceDPOR:
                 # attribution for this round.
                 self._class_of.pop(p, None)
 
+    def _pad_lanes(self, batch: Optional[PrescList]) -> Optional[np.ndarray]:
+        """Which lanes of ``batch`` are prescription-free padding, where
+        their races are not to be admitted (``pad_exploration`` off);
+        None where every lane's are."""
+        if self.pad_exploration or batch is None:
+            return None
+        return batch.lengths() == 0
+
     def _admit(
-        self, presc: Tuple, key: Optional[bytes], frontier: List[Tuple]
+        self, presc: Tuple, key: Optional[bytes], frontier: PrescList
     ) -> bool:
-        """Distance-gate + record one non-redundant prescription (shared
-        by both host paths). Returns True when the prescription joined
-        the frontier. ``key=None`` (the legacy path, which dedups on the
-        tuple set alone) skips the digest-set upkeep — the two sets only
-        need lockstep within one host path's lifetime."""
+        """Distance-gate one non-redundant prescription and mark it
+        explored (shared by the per-candidate paths of both host paths):
+        its digest ``key`` on the vectorized path, the tuple itself on
+        the legacy one (``key=None``). Returns True when it is to join
+        the frontier; the caller then records the round's admitted
+        prescriptions in the log and in ``frontier`` together. The bulk
+        path never calls this, so it runs only while this method is the
+        driver's own (``_admit_stream``): an override — a subclass, a
+        fault injected by a control — is honoured one candidate at a
+        time."""
         if (
             self.max_distance is not None
             and self.original is not None
             and arvind_distance(presc, self.original) > self.max_distance
         ):
             return False
-        self.explored.add(presc)
-        self._explored_log.append(presc)
-        if key is not None:
+        if key is None:
+            self._legacy_explored.add(presc)
+        else:
             self._explored_digests.add(key)
-        frontier.append(presc)
         return True
 
     def _sleep_class_check(
@@ -2222,7 +2324,7 @@ class DeviceDPOR:
             rows += list(deliv[branch:flip_ord]) + list(deliv[flip_ord + 1:])
         return np.asarray(rows[: self.cfg.max_steps], np.int32)
 
-    def _sleep_ctx(self, batch: List[Tuple], res) -> Optional[tuple]:
+    def _sleep_ctx(self, batch: PrescList, res) -> Optional[tuple]:
         """The racing scan's per-lane sleep inputs for one harvested
         round: the packed sleep rows the kernel consumed (a pure
         function of the batch — identical to what was dispatched) plus
@@ -2238,8 +2340,8 @@ class DeviceDPOR:
         )
 
     def _derive_batch(
-        self, traces, lens, n_lanes: int, frontier: List[Tuple],
-        batch: Optional[List[Tuple]] = None, res=None,
+        self, traces, lens, n_lanes: int, frontier: PrescList,
+        batch: Optional[PrescList] = None, res=None,
     ) -> Tuple[int, int, int]:
         """Vectorized prescription derivation: one batch-native racing
         call for the whole round, content-digest dedup over the packed
@@ -2280,8 +2382,8 @@ class DeviceDPOR:
             )
 
     def _derive_sharded(
-        self, traces, lens, n_lanes: int, frontier: List[Tuple],
-        batch: Optional[List[Tuple]] = None, res=None,
+        self, traces, lens, n_lanes: int, frontier: PrescList,
+        batch: Optional[PrescList] = None, res=None,
     ) -> Tuple[int, int, int]:
         """Digest-range-sharded derivation (host_shards > 1): the lane
         scan + static/sleep filters + pre-round digest dedup run as N
@@ -2342,23 +2444,117 @@ class DeviceDPOR:
 
     def _admit_stream(
         self, rows, offsets, lanes, keys, traces, lens,
-        batch: Optional[List[Tuple]], sleep_ctx, frontier: List[Tuple],
+        batch: Optional[PrescList], sleep_ctx, frontier: PrescList,
         known_dup=None, shard_ids=None, shard_stats=None, class_keys=None,
     ) -> Tuple[int, int, int]:
-        """The canonical admission loop over one round's candidate
-        stream, in stream (= lane-major scan) order: digest dedup,
-        sleep-class check, distance gate, frontier admission. Shared by
-        the sequential and sharded paths — the sharded path passes
+        """The canonical admission of one round's candidate stream, in
+        stream (= lane-major scan) order: digest dedup, sleep-class
+        check, distance gate, frontier admission. Shared by the
+        sequential and sharded paths — the sharded path passes
         ``known_dup`` (membership against the PRE-round sets, computed
-        per digest-range shard) and this loop then tracks only the keys
-        added DURING the merge (``round_new``), which together decide
-        exactly what the sequential live-set membership check decides,
-        in the same order."""
+        per digest-range shard), and only the keys added DURING the
+        merge are tracked here, which together decide exactly what the
+        sequential live-set membership check decides, in the same order.
+
+        Which candidates are fresh is decided in bulk when nothing needs
+        a tuple — no sleep sets (their side tables are keyed by the
+        tuple), no distance gate (``arvind_distance`` wants the tuple)
+        and no override of ``_admit`` (it takes the tuple) — and one
+        candidate at a time otherwise. Either way the
+        fresh ones go into the explored log as columns, all at once."""
+        if (
+            self.sleep is None and self.max_distance is None
+            and getattr(self._admit, "__func__", None) is _ADMIT
+        ):
+            fresh, redundant_n, pruned_n = self._fresh_bulk(
+                keys, lanes, batch, known_dup
+            )
+            tuples = None
+        else:
+            fresh, tuples, redundant_n, pruned_n = self._fresh_each(
+                rows, offsets, lanes, keys, traces, lens, batch, sleep_ctx,
+                frontier, known_dup, class_keys,
+            )
+        if shard_stats is not None and len(fresh):
+            per_shard = np.bincount(
+                np.asarray(shard_ids, np.int64)[fresh],
+                minlength=len(shard_stats),
+            )
+            for stats, count in zip(shard_stats, per_shard.tolist()):
+                stats["fresh"] += count
+        if len(fresh):
+            self._record_fresh(
+                fresh, tuples, rows, offsets, lanes, keys, traces, lens,
+                frontier,
+            )
+        return len(fresh), redundant_n, pruned_n
+
+    def _fresh_bulk(
+        self, keys, lanes, batch: Optional[PrescList], known_dup
+    ) -> Tuple[np.ndarray, int, int]:
+        """The fresh candidates of a round, with no work per candidate:
+        ``(positions in stream order, redundant, pruned)``, their keys
+        added to the explored digests. A key's first occurrence wins,
+        which is the sequential loop's same-round rule; with
+        ``pad_exploration`` off, padding lanes' candidates are masked
+        out before that (observed, never admitted)."""
+        explored = self._explored_digests
+        suppressed = self._suppressed_digests
+        n = len(keys)
+        if known_dup is None:
+            pos = range(n)
+        else:
+            pos = np.flatnonzero(~known_dup).tolist()
+            keys = [keys[k] for k in pos]
+        pads: List[Tuple[int, bytes]] = []
+        pad_lane = self._pad_lanes(batch)
+        if pad_lane is not None and n:
+            is_pad = pad_lane[np.asarray(lanes)[pos]].tolist()
+            pads = [(k, key) for k, key, p in zip(pos, keys, is_pad) if p]
+            live = [(k, key) for k, key, p in zip(pos, keys, is_pad) if not p]
+            pos, keys = [k for k, _ in live], [key for _, key in live]
+        # key -> its FIRST position: later writes win, so walk backwards.
+        first = dict(zip(reversed(keys), reversed(pos)))
+        if known_dup is None:
+            new_keys = [
+                key for key in first
+                if key not in explored and key not in suppressed
+            ]
+        else:
+            new_keys = list(first)
+        pruned_n = 0
+        for k, key in pads:
+            # A padding lane's candidate is redundant when its key was
+            # explored before it in the stream, pruned otherwise.
+            if known_dup is None and (key in explored or key in suppressed):
+                continue
+            admitted_at = first.get(key)
+            if admitted_at is None or admitted_at > k:
+                pruned_n += 1
+        explored.update(new_keys)
+        fresh = np.sort(
+            np.fromiter((first[key] for key in new_keys), np.int64,
+                        len(new_keys))
+        )
+        return fresh, n - len(fresh) - pruned_n, pruned_n
+
+    def _fresh_each(
+        self, rows, offsets, lanes, keys, traces, lens,
+        batch: Optional[PrescList], sleep_ctx, frontier: PrescList,
+        known_dup, class_keys,
+    ) -> Tuple[np.ndarray, List[Tuple], int, int]:
+        """The fresh candidates of a round, one candidate at a time, for
+        the modes that need each as a tuple (sleep sets, the distance
+        gate): ``(positions, their tuples, redundant, pruned)``."""
         recw = self.cfg.rec_width
-        fresh_n = redundant_n = pruned_n = 0
+        redundant_n = pruned_n = 0
+        fresh: List[int] = []
+        tuples: List[Tuple] = []
         explored_digests = self._explored_digests
         offs = offsets.tolist()
         lane_of = np.asarray(lanes).tolist()
+        pad_lane = self._pad_lanes(batch)
+        lane_tuples = batch.tuples() if self.sleep is not None else None
         # Fresh prescriptions materialize with SHARED per-lane row
         # tuples: a prescription's prefix is by construction the first
         # (mlen - 1) delivery rows of its lane in position order, so one
@@ -2401,11 +2597,7 @@ class DeviceDPOR:
                 continue
             lo, hi = offs[k], offs[k + 1]
             b = lane_of[k]
-            if (
-                not self.pad_exploration
-                and batch is not None
-                and not batch[b]
-            ):
+            if pad_lane is not None and pad_lane[b]:
                 # Closed seeded exploration: padding-lane races are
                 # observed but never admitted (see pad_exploration).
                 pruned_n += 1
@@ -2424,7 +2616,7 @@ class DeviceDPOR:
                 verdict, commit = self._sleep_class_check(
                     presc, rows[lo:hi],
                     list(pos[: m - 1]) + [None], flipped, m - 1,
-                    batch[b] if batch is not None else tuple(),
+                    lane_tuples[b] if lane_tuples is not None else tuple(),
                     wake_row,
                     ckey=(
                         class_keys.get(k)
@@ -2438,24 +2630,59 @@ class DeviceDPOR:
                         round_new.add(key)
                     redundant_n += 1
                     continue
-            if self._admit(presc, key, frontier):
-                fresh_n += 1
-                if round_new is not None:
-                    round_new.add(key)
-                if shard_stats is not None:
-                    shard_stats[shard_ids[k]]["fresh"] += 1
-                if self.sleep is not None:
-                    guide = self._make_guide(deliv, m - 1, flipped, None)
-                    self._guides[presc] = guide
-                    if commit is not None:
-                        commit(guide)
-            else:
+            if not self._admit(presc, key, frontier):
                 pruned_n += 1
-        return fresh_n, redundant_n, pruned_n
+                continue
+            if round_new is not None:
+                round_new.add(key)
+            fresh.append(k)
+            tuples.append(presc)
+            if self.sleep is not None:
+                guide = self._make_guide(deliv, m - 1, flipped, None)
+                self._guides[presc] = guide
+                if commit is not None:
+                    commit(guide)
+        return np.asarray(fresh, np.int64), tuples, redundant_n, pruned_n
+
+    def _record_fresh(
+        self, fresh: np.ndarray, tuples: Optional[List[Tuple]], rows,
+        offsets, lanes, keys, traces, lens, frontier: PrescList,
+    ) -> None:
+        """Append a round's fresh prescriptions (stream positions
+        ``fresh``) to the explored log and the frontier sink. A
+        prescription is its lane's first ``m - 1`` delivery rows plus
+        the flipped row, so the round adds ONE chunk — the delivery rows
+        of the lanes that had a fresh candidate — and a row of columns a
+        prescription; ``tuples``, where the per-candidate path built
+        them, are kept for its side tables."""
+        log = self._explored_log
+        recw = self.cfg.rec_width
+        lanes_f = np.asarray(lanes)[fresh]
+        used = np.unique(lanes_f)
+        recs = traces[used, :, :recw]
+        kinds = recs[:, :, 0]
+        delivered = ((kinds == REC_DELIVERY) | (kinds == REC_TIMER)) & (
+            np.arange(recs.shape[1])[None, :]
+            < np.asarray(lens)[used][:, None]
+        )
+        counts = delivered.sum(axis=1)
+        lane_start = np.zeros(int(used[-1]) + 1, np.int64)
+        lane_start[used] = np.cumsum(counts) - counts
+        hi = offsets[fresh + 1]
+        first = log.extend(
+            log.add_chunk(recs[delivered]),
+            lane_start[lanes_f], hi - offsets[fresh], rows[hi - 1],
+            np.frombuffer(
+                b"".join([keys[k] for k in fresh.tolist()]), np.uint64
+            ).reshape(-1, 2),
+        )
+        if tuples is not None:
+            log.keep_tuples(first, tuples)
+        frontier.extend(range(first, first + len(fresh)))
 
     def _derive_legacy(
-        self, traces, lens, n_lanes: int, frontier: List[Tuple],
-        batch: Optional[List[Tuple]] = None, res=None,
+        self, traces, lens, n_lanes: int, frontier: PrescList,
+        batch: Optional[PrescList] = None, res=None,
     ) -> Tuple[int, int, int]:
         """The pre-vectorization host path — per-lane scans, per-pair
         tuple assembly, tuple-set membership — kept as the parity
@@ -2472,14 +2699,18 @@ class DeviceDPOR:
             if batch is not None and res is not None
             else None
         )
-        fresh_n = redundant_n = pruned_n = 0
+        redundant_n = pruned_n = 0
         sleep_pruned = 0
+        explored = self._legacy_explored
+        fresh: List[Tuple] = []
+        pad_lane = self._pad_lanes(batch)
+        lane_tuples = (
+            batch.tuples()
+            if self.sleep is not None and batch is not None
+            else None
+        )
         for lane in range(n_lanes):
-            if (
-                not self.pad_exploration
-                and batch is not None
-                and not batch[lane]
-            ):
+            if pad_lane is not None and pad_lane[lane]:
                 # Closed seeded exploration (see pad_exploration): skip
                 # the padding lane's harvest wholesale.
                 continue
@@ -2501,7 +2732,8 @@ class DeviceDPOR:
                     asleep = branch > int(slept[lane])
                     if not asleep and branch >= int(presc_deliv[lane]):
                         lane_sleep = self._sleep_rows.get(
-                            batch[lane] if batch is not None else tuple(), ()
+                            lane_tuples[lane]
+                            if lane_tuples is not None else tuple(), ()
                         )
                         for s, srow in enumerate(lane_sleep):
                             if int(wake[lane][s]) < branch:
@@ -2514,7 +2746,7 @@ class DeviceDPOR:
                         if self.sleep.audit:
                             self.sleep.note_pruned_prescription(presc)
                         continue
-                if presc in self.explored:
+                if presc in explored:
                     redundant_n += 1
                     continue
                 if presc in self._suppressed:
@@ -2532,7 +2764,8 @@ class DeviceDPOR:
                         presc, np.asarray(presc, np.int32),
                         list(positions[: m - 1]) + [None], presc[-1],
                         branch,
-                        batch[lane] if batch is not None else tuple(),
+                        lane_tuples[lane]
+                        if lane_tuples is not None else tuple(),
                         wake_row,
                     )
                     if verdict == "class":
@@ -2540,7 +2773,7 @@ class DeviceDPOR:
                         redundant_n += 1
                         continue
                 if self._admit(presc, None, frontier):
-                    fresh_n += 1
+                    fresh.append(presc)
                     if self.sleep is not None:
                         if lane_deliv is None:
                             recs = traces[lane, : int(lens[lane]), :recw]
@@ -2562,7 +2795,10 @@ class DeviceDPOR:
                     pruned_n += 1
         if sleep_pruned:
             self.sleep.note_pruned(sleep=sleep_pruned, tier="device")
-        return fresh_n, redundant_n, pruned_n
+        if fresh:
+            first = self.admit_tuples(fresh)
+            frontier.extend(range(first, first + len(fresh)))
+        return len(fresh), redundant_n, pruned_n
 
     def _note_inflight(self, outcome: str) -> None:
         self.async_stats[f"inflight_{outcome}"] += 1
@@ -2737,7 +2973,8 @@ class DeviceDPOR:
     ) -> Optional[Tuple[np.ndarray, int]]:
         """``explore``'s round loop, under its ``dpor.search`` span."""
         gen = self.frontier
-        pending: List[Tuple] = []  # the NEXT generation, fed by harvests
+        # The NEXT generation, fed by harvests.
+        pending = PrescList(self._explored_log)
         # (batch, parts, n_real, prescs, keys) for the next round — the
         # pure round inputs ride along for poisoned-launch re-dispatch.
         inflight = None
@@ -2836,10 +3073,15 @@ class DeviceDPOR:
             # back to the worklist head and the next explore() call
             # re-selects (and re-dispatches) them.
             batch, _parts, n_real, _prescs, _keys = inflight
-            gen = list(batch[:n_real]) + gen
+            gen = batch[:n_real] + gen
             self._note_inflight("waste")
         self.frontier = gen + pending
         return found
+
+
+# The driver's own per-candidate admission: while it is in place (no
+# subclass or patch overrides it) a round may be admitted in bulk.
+_ADMIT = DeviceDPOR._admit
 
 
 def explore_window(
@@ -2866,8 +3108,8 @@ def explore_window(
     # from the frozen generation, fresh prescriptions join the pending
     # next generation — same policy, so committed states match the
     # sequential path exactly.
-    frontiers = [list(d.frontier) for d in dpors]
-    pendings: List[List[Tuple]] = [[] for _ in dpors]
+    frontiers = [d.frontier.copy() for d in dpors]
+    pendings = [PrescList(d._explored_log) for d in dpors]
     for _ in range(max_rounds):
         live = []
         for i in range(n):
@@ -2895,7 +3137,7 @@ def explore_window(
             and all(dpors[i].sleep is None for i, *_ in staged)
             and len({id(dpors[i].kernel) for i, *_ in staged}) == 1
         )
-        results: List[Tuple[int, List[Tuple], LaneResult]] = []
+        results: List[Tuple[int, PrescList, LaneResult]] = []
         if combined:
             # One launch for the whole window: lanes are elementwise
             # under vmap, so concatenating the instances' (prog, presc,

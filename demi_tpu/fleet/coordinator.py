@@ -112,13 +112,14 @@ def _no_local_kernel(*_args, **_kwargs):
 
 class Lease(NamedTuple):
     """One generation-frozen frontier round, leased as pure kernel
-    inputs. ``batch`` keeps the identity tuples for host-side
+    inputs. ``batch`` keeps the identities (a ``PrescList`` over the
+    coordinator's explored log, items reading as tuples) for host-side
     processing; ``n_real`` counts the non-padding entries (what a
     revoked-and-never-run lease returns to the frontier)."""
 
     lease_id: int
     round_no: int
-    batch: List[tuple]
+    batch: Any
     n_real: int
     prescs: np.ndarray
     # Per-lane rng SEEDS (uint32): the worker folds them into keys
@@ -292,8 +293,10 @@ class FleetCoordinator:
         self._lock = threading.Lock()
         self.done = threading.Event()
         self._server: Optional[socketserver.ThreadingTCPServer] = None
-        self._gen: List[tuple] = []
-        self._pending: List[tuple] = []
+        # The frozen generation and the next one: lists over the
+        # explorer's explored log (device/explored_log.py).
+        self._gen = self.dpor._list([])
+        self._pending = self.dpor._list([])
         self._planned = 0
         self._processed = 0
         self._next_lease_id = 0
@@ -317,8 +320,8 @@ class FleetCoordinator:
     def serve(self, host: str = "127.0.0.1") -> str:
         """Start the lease server; returns ``host:port``. Also freezes
         the starting generation (call after any ``dpor.seed``)."""
-        self._gen = list(self.dpor.frontier)
-        self._pending = []
+        self._gen = self.dpor.frontier.copy()
+        self._pending = self.dpor._list([])
         self._started = True
         self.wall_t0 = time.perf_counter()
 
@@ -698,9 +701,9 @@ class FleetCoordinator:
             frontier_bytes = ledger_bytes = None
             if obs.enabled() or obs.journal.JOURNAL is not None:
                 row_bytes = 4 * self.cfg.rec_width
-                frontier_bytes = row_bytes * (
-                    sum(len(p) for p in self._gen)
-                    + sum(len(p) for p in self._pending)
+                frontier_bytes = row_bytes * int(
+                    self.dpor._list(self._gen).lengths().sum()
+                    + self.dpor._list(self._pending).lengths().sum()
                 )
                 obs.gauge("fleet.frontier_bytes").force_set(frontier_bytes)
                 if self.dpor.sleep is not None:
@@ -777,7 +780,9 @@ class FleetCoordinator:
                 + self._requeue,
                 key=lambda l: l.round_no,
             )
-            front = [p for l in leftovers for p in l.batch[: l.n_real]]
+            front = self.dpor._list([])
+            for lease in leftovers:
+                front = front + lease.batch[: lease.n_real]
             self.dpor.frontier = front + self._gen + self._pending
             self._outstanding.clear()
             self._requeue.clear()

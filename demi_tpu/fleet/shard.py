@@ -128,6 +128,10 @@ class DigestShards:
     def add(self, key: bytes) -> None:
         self.slices[shard_of_key(key, self.n)].add(key)
 
+    def update(self, keys: Iterable[bytes]) -> None:
+        for key in keys:
+            self.add(key)
+
     def __contains__(self, key: bytes) -> bool:
         return key in self.slices[shard_of_key(key, self.n)]
 

@@ -2,6 +2,7 @@ from .analysis import (
     ScanBuffers,
     analysis_native_available,
     digest_keys,
+    prefixed_digests,
     prescription_digest,
     prescription_digests,
     racing_pair_scan,
@@ -27,6 +28,7 @@ __all__ = [
     "racing_pair_scan",
     "racing_prescriptions_batch",
     "prescription_digests",
+    "prefixed_digests",
     "prescription_digest",
     "digest_keys",
     "scan_backend",

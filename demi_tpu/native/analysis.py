@@ -736,6 +736,20 @@ def _mix64(x: np.ndarray) -> np.ndarray:
     return x ^ (x >> np.uint64(31))
 
 
+def _row_values(rows: np.ndarray) -> np.ndarray:
+    """Per-row value: polynomial over the columns (uint64 wraparound)."""
+    rows = np.asarray(rows)
+    n, w = rows.shape
+    if not n:
+        return np.zeros(0, np.uint64)
+    col_pow = np.ones(w, np.uint64)
+    if w > 1:
+        col_pow[1:] = _COL_MULT
+    col_pow = np.cumprod(col_pow)[::-1]
+    r64 = rows.astype(np.uint32).astype(np.uint64)
+    return (r64 * col_pow[None, :]).sum(axis=1, dtype=np.uint64)
+
+
 def prescription_digests(rows: np.ndarray, offsets: np.ndarray) -> np.ndarray:
     """[n_presc, 2] uint64 content digests of the packed prescription
     stream (``rows``/``offsets`` as returned by
@@ -748,18 +762,8 @@ def prescription_digests(rows: np.ndarray, offsets: np.ndarray) -> np.ndarray:
     out = np.empty((n_presc, 2), np.uint64)
     if n_presc == 0:
         return out
-    rows = np.asarray(rows)
-    n, w = rows.shape
-    # Per-row value: polynomial over the columns (uint64 wraparound).
-    if n:
-        col_pow = np.ones(w, np.uint64)
-        if w > 1:
-            col_pow[1:] = _COL_MULT
-        col_pow = np.cumprod(col_pow)[::-1]
-        r64 = rows.astype(np.uint32).astype(np.uint64)
-        rv = (r64 * col_pow[None, :]).sum(axis=1, dtype=np.uint64)
-    else:
-        rv = np.zeros(0, np.uint64)
+    rv = _row_values(rows)
+    n = len(rv)
     starts, ends = offsets[:-1], offsets[1:]
     mlen = ends - starts
     for lane, (P, OFF, SALT, PINV) in enumerate(
@@ -780,6 +784,46 @@ def prescription_digests(rows: np.ndarray, offsets: np.ndarray) -> np.ndarray:
         seg = csum[ends] - csum[starts]
         h = OFF * ppow[mlen] + ppow[np.maximum(ends, 1) - 1] * seg
         out[:, lane] = h
+    return out
+
+
+def prefixed_digests(
+    rows: np.ndarray, starts: np.ndarray, lengths: np.ndarray,
+    flips: np.ndarray,
+) -> np.ndarray:
+    """[n, 2] uint64 digests of prescriptions held as a shared prefix
+    plus one row of their own: prescription k is ``rows[starts[k] :
+    starts[k] + lengths[k] - 1]`` followed by ``flips[k]``
+    (``lengths[k] == 0`` is the empty prescription) — the explored
+    log's columnar form. Same key space as ``prescription_digests``,
+    from the same one pass of cumulative products over ``rows``."""
+    starts = np.asarray(starts, np.int64)
+    mlen = np.asarray(lengths, np.int64)
+    out = np.empty((len(starts), 2), np.uint64)
+    if not len(starts):
+        return out
+    rv, fv = _row_values(rows), _row_values(flips)
+    n = len(rv)
+    ends = starts + np.maximum(mlen - 1, 0)
+    top = max(n, int(mlen.max())) + 1
+    for lane, (P, OFF, SALT, PINV) in enumerate(
+        zip(_BLOCK_P, _BLOCK_OFF, _SALTS, _BLOCK_PINV)
+    ):
+        ppow = np.ones(top, np.uint64)
+        ppow[1:] = P
+        ppow = np.cumprod(ppow)
+        csum = np.zeros(n + 1, np.uint64)
+        if n:
+            pinv_pow = np.ones(n, np.uint64)
+            pinv_pow[1:] = PINV
+            csum[1:] = np.cumsum(
+                _mix64(rv ^ SALT) * np.cumprod(pinv_pow), dtype=np.uint64
+            )
+        prefix = ppow[np.maximum(ends, 1) - 1] * (csum[ends] - csum[starts])
+        own = prefix * P + _mix64(fv ^ SALT)
+        out[:, lane] = OFF * ppow[mlen] + np.where(
+            mlen > 0, own, np.uint64(0)
+        )
     return out
 
 

@@ -480,7 +480,7 @@ def _encode_explored_frame(items, prev: tuple, w_expect: int):
         rows_b = flat.tobytes()
     else:
         rows_b = b""
-    head = np.asarray([len(items), w], np.int32).tobytes()
+    head = np.asarray([len(lcps), w], np.int32).tobytes()
     body = (
         head
         + np.asarray(lcps, np.int32).tobytes()
@@ -526,61 +526,33 @@ def _packed_explored(dpor) -> Dict[str, Any]:
     compressed delta frames of everything already packed and each
     snapshot encodes only the suffix admitted since — O(delta) encode
     per checkpoint, not O(explored). The cache self-validates with a
-    prefix-length + last-entry check and rebuilds from scratch when a
-    rollback invalidated it."""
+    prefix-length + last-entry (content digest) check and rebuilds from
+    scratch when a rollback invalidated it. The log is columnar
+    (device/explored_log.py); the frames' tuples are materialized here,
+    a slab at a time, and not kept."""
     log = dpor._explored_log
     cache = dpor._persist_pack_cache
     if (
         cache is None
         or cache["count"] > len(log)
-        or (cache["count"] > 0 and log[cache["count"] - 1] != cache["last"])
+        or (cache["count"] > 0 and log.key(cache["count"] - 1) != cache["last"])
     ):
         cache = {"count": 0, "w": 0, "frames": [], "last": None}
-    new = log[cache["count"]:]
-    if new:
-        prev = cache["last"] if cache["last"] is not None else ()
-        frame, w, last = _encode_explored_frame(new, prev, cache["w"])
+    count = cache["count"]
+    if count < len(log):
+        prev = log[count - 1] if count else ()
+        frame, w, _last = _encode_explored_frame(
+            log.stream(range(count, len(log))), prev, cache["w"]
+        )
         cache["frames"] = list(cache["frames"]) + [_b64(frame)]
         cache["w"] = w
         cache["count"] = len(log)
-        cache["last"] = last
+        cache["last"] = log.key(len(log) - 1)
     dpor._persist_pack_cache = cache
     return {
         "n": cache["count"], "w": cache["w"],
         "frames": list(cache["frames"]),
     }
-
-
-def _log_indexer(dpor):
-    """Identity-keyed position index over the explored log (grown
-    incrementally in the pack cache). Frontier entries and the
-    per-prescription side-table keys ARE the log's tuple objects
-    (``_admit`` appends the same object everywhere), so an ``id()``
-    lookup avoids re-hashing thousands of multi-KB tuples per snapshot;
-    a foreign-but-equal tuple falls back to a one-time equality map."""
-    cache = dpor._persist_pack_cache
-    log = dpor._explored_log
-    ids = cache.get("index_ids")
-    start = cache.get("index_count", 0)
-    if ids is None or start > len(log):
-        ids = cache["index_ids"] = {}
-        start = 0
-    for i in range(start, len(log)):
-        ids[id(log[i])] = i
-    cache["index_count"] = len(log)
-    eq_map: Dict[tuple, int] = {}
-
-    def lookup(p: tuple) -> int:
-        i = ids.get(id(p))
-        # ``log[i] is p`` guards against id() reuse after a rollback
-        # replaced log objects (a stale id must never alias silently).
-        if i is not None and i < len(log) and log[i] is p:
-            return i
-        if not eq_map:
-            eq_map.update({q: j for j, q in enumerate(log)})
-        return eq_map[p]
-
-    return lookup
 
 
 def device_dpor_payload(dpor) -> Dict[str, Any]:
@@ -593,7 +565,8 @@ def device_dpor_payload(dpor) -> Dict[str, Any]:
     import numpy as np
 
     explored = _packed_explored(dpor)  # also refreshes the pack cache
-    log_index = _log_indexer(dpor)
+    # The side tables are keyed by tuples: their log index by digest.
+    log_index = dpor._explored_log.index_of
     tuner = None
     if dpor.tuner is not None:
         tuner = {
@@ -659,7 +632,7 @@ def device_dpor_payload(dpor) -> Dict[str, Any]:
         "workload": device_dpor_workload(dpor),
         "explored": explored,
         "explored_digests": _pack_digests(dpor._explored_digests),
-        "frontier": _pack_ints(log_index(p) for p in dpor.frontier),
+        "frontier": _pack_ints(dpor.frontier.indices()),
         "original": (
             None if dpor.original is None
             else [list(r) for r in dpor.original]
@@ -713,18 +686,18 @@ def restore_device_dpor(dpor, payload: Dict[str, Any]) -> None:
             f"checkpoint workload {got!r} != this explorer's {want!r}"
         )
     log = _decode_explored_frames(payload["explored"]["frames"])
-    dpor._explored_log = log
-    dpor.explored = set(log)
+    # The log and its digest set, rebuilt in columns from the tuples
+    # (the digests are recomputed: the payload's set is theirs).
+    dpor.load_tuples(log)
     # Seed the pack cache from the loaded frames so the first checkpoint
     # after a resume encodes only what the resumed run adds.
     dpor._persist_pack_cache = {
         "count": len(log),
         "w": int(payload["explored"]["w"]),
         "frames": list(payload["explored"]["frames"]),
-        "last": log[-1] if log else None,
+        "last": dpor._explored_log.key(len(log) - 1) if log else None,
     }
-    dpor._explored_digests = _unpack_digests(payload["explored_digests"])
-    dpor.frontier = [log[i] for i in _unpack_ints(payload["frontier"])]
+    dpor.frontier = _unpack_ints(payload["frontier"])
     dpor.original = (
         None if payload["original"] is None else _tt(payload["original"])
     )

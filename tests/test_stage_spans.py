@@ -261,10 +261,41 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     counts = obs.stage_counts()
     # Every count kept has a reader (the benchmark's dpor.fresh_share and
     # dpor.admit_us_per_candidate); the sweep takes none.
-    assert set(counts) == {"dpor.candidates", "dpor.fresh"}
+    assert set(counts) == {
+        "dpor.candidates", "dpor.fresh", "dpor.materialized",
+    }
     assert counts["dpor.candidates"] >= counts["dpor.fresh"] > 0
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
     assert result.lanes == 24
+
+
+@pytest.mark.parametrize("mode", ["default", "sleep_sets", "max_distance"])
+def test_tuples_are_materialized_only_where_a_mode_needs_them(
+    clean, reversal, mode
+):
+    """``dpor.materialized`` counts log entries turned into Python
+    tuples: every admitted one where the driver's own configuration
+    needs the tuple (sleep sets key their side tables by it, the
+    distance gate measures it), and only what a round's selection
+    compared otherwise."""
+    from demi_tpu.device.dpor_sweep import DeviceDPOR
+
+    app, cfg, program, kernel = reversal
+    if mode == "sleep_sets":
+        d = DeviceDPOR(app, cfg, program, batch_size=2, sleep_sets=True)
+    else:
+        d = DeviceDPOR(app, cfg, program, batch_size=2, kernel=kernel)
+    if mode == "max_distance":
+        d.max_distance = 1 << 20
+    obs.enable()
+    d.explore(target_code=2, max_rounds=3)
+    obs.disable()
+    counts = obs.stage_counts()
+    assert counts["dpor.fresh"] == len(d.explored) - 1 > 0
+    if mode == "default":
+        assert counts["dpor.materialized"] < counts["dpor.fresh"]
+    else:
+        assert counts["dpor.materialized"] == counts["dpor.fresh"]
 
 
 def test_launch_ledger_is_fed_from_the_stage_spans(clean, reversal):
