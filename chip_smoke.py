@@ -75,7 +75,9 @@ class Smoke:
         self.size = size
         self.device = device
         self.phases: dict = {}
-        self._compile = {"total": 0.0, "backend": 0.0}
+        # (start, end) of every trace/lower/compile event, and the
+        # backend compiles' seconds.
+        self._compile = {"spans": [], "backend": 0.0}
         self._cache = {"hits": 0, "misses": 0}
         jax.monitoring.register_event_duration_secs_listener(
             self._on_duration
@@ -91,7 +93,8 @@ class Smoke:
     # -- measurement -------------------------------------------------------
     def _on_duration(self, event: str, seconds: float, **_kw) -> None:
         if event in _COMPILE_EVENTS:
-            self._compile["total"] += seconds
+            end = time.perf_counter()
+            self._compile["spans"].append((end - seconds, end))
         if event == _COMPILE_EVENTS[2]:
             self._compile["backend"] += seconds
 
@@ -117,21 +120,29 @@ class Smoke:
         seconds (JAX's own trace/lower/compile duration events), the
         persistent cache's hits and misses, and the peak device memory so
         far. The record is kept only if the body did not raise."""
-        before = (dict(self._compile), dict(self._cache))
+        before = (
+            len(self._compile["spans"]), self._compile["backend"],
+            dict(self._cache),
+        )
         record: dict = {}
         t0 = time.perf_counter()
         yield record
         wall = time.perf_counter() - t0
-        compile_s = self._compile["total"] - before[0]["total"]
+        # A function traced inside another's tracing reports both, one
+        # inside the other: the seconds they cover together, not their
+        # sum, which can pass the wall's.
+        compile_s, covered = 0.0, t0
+        for start, end in sorted(self._compile["spans"][before[0]:]):
+            if end > covered:
+                compile_s += end - max(start, covered)
+                covered = end
         record.update(
             wall_s=round(wall, 3),
             compile_s=round(compile_s, 3),
-            backend_compile_s=round(
-                self._compile["backend"] - before[0]["backend"], 3
-            ),
+            backend_compile_s=round(self._compile["backend"] - before[1], 3),
             run_s=round(wall - compile_s, 3),
-            cache_hits=self._cache["hits"] - before[1]["hits"],
-            cache_misses=self._cache["misses"] - before[1]["misses"],
+            cache_hits=self._cache["hits"] - before[2]["hits"],
+            cache_misses=self._cache["misses"] - before[2]["misses"],
             peak_bytes_in_use=self._peak_bytes(),
         )
         self.phases[name] = record

@@ -27,6 +27,7 @@ from .config import SchedulerConfig
 from .dsl import DSLApp
 from .external_events import WaitQuiescence
 from .fuzzing import Fuzzer, FuzzerWeights
+from .parallel.distributed import FAULT_PLANE_DEFAULTS
 
 
 def build_app(args) -> DSLApp:
@@ -34,7 +35,7 @@ def build_app(args) -> DSLApp:
         return make_broadcast_app(args.nodes, reliable=args.bug is None)
     if args.app == "raft":
         return make_raft_app(
-            args.nodes, bug=args.bug,
+            args.nodes, log_cap=args.log_cap, bug=args.bug,
             handler_edit=getattr(args, "handler_edit", None),
         )
     if args.app == "spark":
@@ -65,18 +66,42 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         gen = raft_send_generator(app)
     weights = FuzzerWeights(
         kill=args.kill_weight,
-        send=0.6,
-        wait_quiescence=0.15,
+        send=args.send_weight,
+        wait_quiescence=args.wait_weight,
         partition=args.partition_weight,
         unpartition=args.partition_weight,
+        hard_kill=args.hard_kill_weight,
+        restart=args.restart_weight,
     )
     return Fuzzer(
         num_events=args.num_events,
         weights=weights,
         message_gen=gen,
         prefix=dsl_start_events(app),
-        max_kills=1,
+        max_kills=args.max_kills,
+        wait_budget=(
+            None if args.wait_budget is None else tuple(args.wait_budget)
+        ),
     )
+
+
+def _workload_dict(args) -> dict:
+    """The CLI-args-shaped workload dict ``build_workload`` takes: what
+    the distributed launcher, the fleet and the service ship to their
+    processes, so a flag means the same thing in every one of them."""
+    return {
+        "app": args.app,
+        "nodes": args.nodes,
+        "bug": args.bug,
+        "seed": args.seed,
+        "num_events": args.num_events,
+        "max_messages": args.max_messages,
+        "timer_weight": args.timer_weight,
+        "kill_weight": args.kill_weight,
+        "partition_weight": args.partition_weight,
+        "pool": args.pool,
+        **{k: getattr(args, k) for k in FAULT_PLANE_DEFAULTS},
+    }
 
 
 def _workload_discriminator(args) -> dict:
@@ -328,6 +353,7 @@ _RESUME_COMMON = (
     "app", "nodes", "bug", "seed", "num_events", "max_messages",
     "timer_weight", "kill_weight", "partition_weight",
     "trace_out", "stats_out", "checkpoint_every", "strict_io",
+    *FAULT_PLANE_DEFAULTS,
 )
 _RESUME_FIELDS = {
     "dpor": _RESUME_COMMON + (
@@ -1048,7 +1074,11 @@ def cmd_resume(args) -> int:
         raise SystemExit(
             f"resume: checkpoint names unknown command {command!r}"
         )
-    ns = argparse.Namespace(**dict(ckpt.meta.get("cli_args", {})))
+    # A manifest written before the fault plane's knobs were flags has
+    # none of them: it meant the literals.
+    ns = argparse.Namespace(
+        **{**FAULT_PLANE_DEFAULTS, **ckpt.meta.get("cli_args", {})}
+    )
     ns.checkpoint_dir = args.dir
     ns._resume_checkpoint = ckpt
     print(
@@ -1407,18 +1437,7 @@ def cmd_sweep(args) -> int:
             num_processes=args.processes,
             total_lanes=args.batch,
             chunk_size=max(1, args.batch // (4 * args.processes)),
-            workload={
-                "app": args.app,
-                "nodes": args.nodes,
-                "bug": args.bug,
-                "seed": args.seed,
-                "num_events": args.num_events,
-                "max_messages": args.max_messages,
-                "timer_weight": args.timer_weight,
-                "kill_weight": args.kill_weight,
-                "partition_weight": args.partition_weight,
-                "pool": args.pool,
-            },
+            workload=_workload_dict(args),
         )
         summary["rehearsal"] = True
         print(json.dumps(summary))
@@ -1705,16 +1724,7 @@ def cmd_fleet(args) -> int:
     from .fleet import run_fleet
 
     workload = {
-        "app": args.app,
-        "nodes": args.nodes,
-        "bug": args.bug,
-        "seed": args.seed,
-        "num_events": args.num_events,
-        "max_messages": args.max_messages,
-        "timer_weight": args.timer_weight,
-        "kill_weight": args.kill_weight,
-        "partition_weight": args.partition_weight,
-        "pool": args.pool,
+        **_workload_dict(args),
         "handler_edit": getattr(args, "handler_edit", None),
     }
     delta = bool(getattr(args, "delta", False)) or bool(
@@ -2099,18 +2109,7 @@ def _service_workload(args) -> dict:
     """CLI-args-shaped workload dict for the service wire — the same
     fields the fleet ships, so a submission means the same thing on any
     daemon host."""
-    w = {
-        "app": args.app,
-        "nodes": args.nodes,
-        "bug": args.bug,
-        "seed": args.seed,
-        "num_events": args.num_events,
-        "max_messages": args.max_messages,
-        "timer_weight": args.timer_weight,
-        "kill_weight": args.kill_weight,
-        "partition_weight": args.partition_weight,
-        "pool": args.pool,
-    }
+    w = _workload_dict(args)
     if getattr(args, "commands", 0):
         w["commands"] = args.commands
     return w
@@ -2226,6 +2225,35 @@ def main(argv: Optional[list] = None) -> int:
         p.add_argument(
             "--partition-weight", type=float, default=0.0, dest="partition_weight"
         )
+        # The rest of the fault plane (crash-recovery, healing links,
+        # bounded waits); each default is what was a literal before.
+        knobs = FAULT_PLANE_DEFAULTS
+        p.add_argument("--send-weight", type=float,
+                       default=knobs["send_weight"], dest="send_weight")
+        p.add_argument("--wait-weight", type=float,
+                       default=knobs["wait_weight"], dest="wait_weight",
+                       help="weight of a generated WaitQuiescence")
+        p.add_argument("--hard-kill-weight", type=float,
+                       default=knobs["hard_kill_weight"],
+                       dest="hard_kill_weight",
+                       help="weight of HardKill: the node stops, its state "
+                            "and its pending messages are gone")
+        p.add_argument("--restart-weight", type=float,
+                       default=knobs["restart_weight"], dest="restart_weight",
+                       help="weight of restarting a killed node (recovery)")
+        p.add_argument("--max-kills", type=int, default=knobs["max_kills"],
+                       dest="max_kills",
+                       help="kills of either kind a program may hold; a "
+                            "restart gives none back")
+        p.add_argument("--wait-budget", type=int, nargs=2,
+                       default=knobs["wait_budget"], dest="wait_budget",
+                       metavar=("LO", "HI"),
+                       help="deliveries a generated wait lasts, drawn from "
+                            "LO..HI, so later events land mid-flood "
+                            "(default: every wait drains)")
+        p.add_argument("--log-cap", type=int, default=knobs["log_cap"],
+                       dest="log_cap",
+                       help="raft: log entries a node holds")
         p.add_argument(
             "--handler-edit", default=None, dest="handler_edit",
             metavar="KIND[:TAG]",

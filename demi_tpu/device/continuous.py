@@ -293,7 +293,7 @@ class ContinuousSweepDriver:
         block_lanes: int = 128,
         program_key: Optional[Callable] = None,
     ):
-        from .encoding import lower_program, stack_programs
+        from .encoding import count_ops, lower_program, stack_programs
 
         self.app = app
         self.cfg = cfg
@@ -323,6 +323,7 @@ class ContinuousSweepDriver:
         self._lower_memo: dict = {}
         self._lower_program = lower_program
         self._stack = stack_programs
+        self._count_ops = count_ops
         impl = resolve_impl(impl, cfg, "ContinuousSweepDriver")
         if impl == "pallas":
             self.segment = make_segment_kernel_pallas(
@@ -408,6 +409,12 @@ class ContinuousSweepDriver:
                     self._lower_memo[key] = prog
             sp.slice("sweep.fuzz", fuzz_ns)
             sp.slice("sweep.lower", lower_ns)
+            if obs.spans.live():
+                # What this fill lowered, by kind of external op: how
+                # much of the fault plane the traffic engages.
+                filled = [progs_host[lane] for lane in lanes]
+                for kind, n in self._count_ops(filled).items():
+                    obs.stage_count(f"sweep.ops.{kind}", n)
 
     def time_to_first_violation(self, max_lanes: int = 1_000_000):
         """Wall-clock seconds until the first violating lane finishes (the
@@ -500,11 +507,14 @@ class ContinuousSweepDriver:
             self.last_lane_sharding = None
         while done_count < total_lanes:
             with obs.span("sweep.round"):
+                round_live = int(active.sum()) * self.seg_steps
                 total_lane_steps += b * self.seg_steps
-                live_lane_steps += int(active.sum()) * self.seg_steps
+                live_lane_steps += round_live
                 self.last_occupancy = live_lane_steps / total_lane_steps
                 self.last_total_lane_steps = total_lane_steps
                 self.last_live_lane_steps = live_lane_steps
+                obs.stage_count("sweep.lane_steps", b * self.seg_steps)
+                obs.stage_count("sweep.live_lane_steps", round_live)
                 t_seg = time.perf_counter()
                 with obs.span("sweep.block"):
                     state = self.segment(

@@ -141,11 +141,11 @@ class DeviceConfig:
     # sequential srcdst_fifo kernels (parity pin for the incremental
     # maintenance; tests/test_device_srcdst.py).
     head_recompute: bool = False
-    # Bit-packed boolean gathers on the one-hot path: the network/
-    # liveness tests in deliverable_mask pack their bool tables into
-    # uint32 words, cutting the one-hot compare cost by ~32x (the cut-
-    # matrix gather is O(P*N^2) unpacked — 18.9M ops/step at the
-    # config-5 shape). Opt-in TPU lever (bit-identical; parity-pinned in
+    # Bit-packed boolean gathers on the one-hot path: the liveness and
+    # isolation tests in deliverable_mask pack their bool tables into
+    # uint32 words, cutting the one-hot compare cost by ~32x (the cut
+    # matrix, once the largest of them, is no longer read there: cut
+    # links drop). Opt-in TPU lever (bit-identical; parity-pinned in
     # tests/test_device.py; ranked by bench_matrix): the shift/mask ops
     # are XLA-validated but their Mosaic lowering is not, so the pallas
     # backends reject it.
@@ -322,7 +322,14 @@ def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
 
 def deliverable_mask(state: ScheduleState, cfg: DeviceConfig) -> jnp.ndarray:
     """Which pool entries could be delivered right now. Mirrors the host
-    ControlledActorSystem.deliverable predicate exactly."""
+    ControlledActorSystem.deliverable predicate exactly.
+
+    The ``cut`` matrix is not read here: a cut link drops its messages
+    (``external_effects`` scrubs what is pending on it at the Partition,
+    ``insert_rows`` masks what is sent over it while it is cut), so no
+    pool entry ever crosses one. A stopped node's mail goes the same way
+    (scrubbed at the HardKill, masked while it is down); only an external
+    send can wait in the pool for one. Isolation (soft ``Kill``) holds."""
     n = cfg.num_actors
     oh = cfg.use_onehot
     dst = state.pool_dst
@@ -342,21 +349,17 @@ def deliverable_mask(state: ScheduleState, cfg: DeviceConfig) -> jnp.ndarray:
             ops.packed_gather_bool(state.stopped, dst)
         )
         dst_reachable = ~ops.packed_gather_bool(state.isolated, dst)
-        link_cut = ops.packed_gather_mat(
-            state.cut, src_clamped, dst
-        ) | ops.packed_gather_bool(state.isolated, src_clamped)
+        src_isolated = ops.packed_gather_bool(state.isolated, src_clamped)
     else:
         dst_ok = ops.gather_vec(state.started, dst, oh) & ~ops.gather_vec(
             state.stopped, dst, oh
         )
         dst_reachable = ~ops.gather_vec(state.isolated, dst, oh)
-        link_cut = ops.gather_mat(
-            state.cut, src_clamped, dst, oh
-        ) | ops.gather_vec(state.isolated, src_clamped, oh)
-    # timers/externals only need the receiver un-isolated; internal messages
-    # must not cross a partition (either endpoint isolated or link cut).
+        src_isolated = ops.gather_vec(state.isolated, src_clamped, oh)
+    # timers/externals only need the receiver un-isolated; internal
+    # messages also need an un-isolated sender.
     passes_network = jnp.where(
-        state.pool_timer | src_is_external, True, ~link_cut
+        state.pool_timer | src_is_external, True, ~src_isolated
     ) & dst_reachable
     return state.pool_valid & ~state.pool_parked & dst_ok & passes_network
 
@@ -410,6 +413,16 @@ def insert_rows(
 ) -> ScheduleState:
     """Scatter up to K new entries into free pool slots. Overflow (more valid
     rows than free slots) flips the lane status to ST_OVERFLOW."""
+    # A cut link and a stopped node drop at the send: an actor's message
+    # to a peer it is partitioned from, or to a hard-killed one, never
+    # enters the pool (timers are self-sends; an external send crosses no
+    # link and waits for its node). Host twin:
+    # ControlledActorSystem._capture_send.
+    n = cfg.num_actors
+    lost = ops.gather_mat(
+        state.cut, jnp.minimum(row_src, n - 1), row_dst, cfg.use_onehot
+    ) | ops.gather_vec(state.stopped, row_dst, cfg.use_onehot)
+    row_valid = row_valid & ~(lost & (row_src < n) & ~row_timer)
     # Proposals carry int32 payloads; storage may be narrower (msg_dtype).
     row_msg = row_msg.astype(state.pool_msg.dtype)
     free = ~state.pool_valid
@@ -765,9 +778,18 @@ def external_effects(
         cut = state.cut.at[a_c, b_c].set(cut_val)
         cut = cut.at[b_c, a_c].set(cut_val)
 
-    # HardKill scrub, branchless (the fused step can't afford a lax.cond
-    # whose both sides run under vmap anyway).
-    touch = ((state.pool_src == a_c) | (state.pool_dst == a_c)) & is_hardkill
+    # HardKill and Partition scrubs, branchless (the fused step can't
+    # afford a lax.cond whose both sides run under vmap anyway). A
+    # HardKill drops everything to or from the actor; a Partition drops
+    # the messages pending on the link, in both directions (timers are
+    # self-sends and externals have src == n: neither matches).
+    on_link = (
+        ((state.pool_src == a_c) & (state.pool_dst == b_c))
+        | ((state.pool_src == b_c) & (state.pool_dst == a_c))
+    ) & ~state.pool_timer
+    touch = (
+        ((state.pool_src == a_c) | (state.pool_dst == a_c)) & is_hardkill
+    ) | (on_link & is_partition)
     state = state._replace(
         started=started, isolated=isolated, stopped=stopped,
         actor_state=actor_state, cut=cut,

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -140,6 +140,32 @@ def stack_programs(programs: Sequence[ExtProgram]) -> ExtProgram:
         b=np.stack([p.b for p in programs]),
         msg=np.stack([p.msg for p in programs]),
     )
+
+
+#: The kinds by which a sweep counts the external ops it lowered
+#: (``sweep.ops.<kind>``); ``restart`` is told from ``start`` below.
+_OP_KIND = {
+    OP_START: "start", OP_KILL: "kill", OP_SEND: "send", OP_WAIT: "wait",
+    OP_WAITCOND: "wait", OP_PARTITION: "partition",
+    OP_UNPARTITION: "unpartition", OP_HARDKILL: "hard_kill",
+}
+
+
+def count_ops(programs: Sequence[ExtProgram]) -> Dict[str, int]:
+    """External ops of lowered programs, by kind. A Start of an actor
+    its program has started before (recovery) counts as ``restart``."""
+    op = np.stack([p.op for p in programs])
+    a = np.stack([p.a for p in programs])
+    by_code = np.bincount(op.ravel(), minlength=max(_OP_KIND) + 1)
+    counts: Dict[str, int] = {}
+    for code, kind in _OP_KIND.items():
+        counts[kind] = counts.get(kind, 0) + int(by_code[code])
+    starts = op == OP_START
+    first = np.zeros((len(programs), int(a[starts].max(initial=0)) + 1), bool)
+    first[np.nonzero(starts)[0], a[starts]] = True
+    counts["restart"] = counts["start"] - int(first.sum())
+    counts["start"] = int(first.sum())
+    return counts
 
 
 def _actor_or_external(app: DSLApp, name: str) -> int:

@@ -154,18 +154,6 @@ def packed_gather_bool(vec: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return _extract_bit(pack_bits(vec), idx)
 
 
-def packed_gather_mat(
-    mat: jnp.ndarray, ri: jnp.ndarray, ci: jnp.ndarray
-) -> jnp.ndarray:
-    """mat[ri, ci] for bool mat[N, M], paired idx vectors [P] — the
-    row-word contraction is O(P*N*M/32) vs the one-hot form's O(P*N*M)
-    (the dominant per-step cost at config-5 scale: P=4608, N=64 is 18.9M
-    ops unpacked)."""
-    packed = jax.vmap(pack_bits)(mat)  # [N, W32]
-    row_words = gather_rows(packed, ri, True)  # [P, W32] one-hot form
-    return _extract_bit(row_words, ci)
-
-
 def first_true_index(mask: jnp.ndarray, k, oh: bool):
     """Index of the (k+1)-th True in ``mask`` (k 0-based); mask.shape[0] when
     there are fewer. The one-hot form avoids searchsorted (binary-search

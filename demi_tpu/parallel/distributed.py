@@ -27,6 +27,20 @@ import sys
 from typing import Optional
 
 
+#: The fault plane's knobs (crash-recovery, link partitions, bounded
+#: waits, the raft log's capacity) with the values that were literals in
+#: ``cli.build_fuzzer`` / ``cli.build_app`` before they became flags: the
+#: CLI's flags and ``DEFAULT_WORKLOAD`` default to these.
+FAULT_PLANE_DEFAULTS = {
+    "send_weight": 0.6,
+    "wait_weight": 0.15,
+    "hard_kill_weight": 0.0,
+    "restart_weight": 0.0,
+    "max_kills": 1,
+    "wait_budget": None,  # (lo, hi) deliveries of a generated wait; None = drain
+    "log_cap": 8,
+}
+
 DEFAULT_WORKLOAD = {
     "app": "broadcast",
     "nodes": 4,
@@ -38,6 +52,7 @@ DEFAULT_WORKLOAD = {
     "kill_weight": 0.05,
     "partition_weight": 0.0,
     "pool": 64,
+    **FAULT_PLANE_DEFAULTS,
 }
 
 

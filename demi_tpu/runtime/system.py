@@ -78,7 +78,21 @@ class PendingEntry:
 class Network:
     """Simulated network state: symmetric link cuts + isolated ("Kill"ed)
     actors. Reference: EventOrchestrator.scala:51-59 (partitioned/
-    inaccessible sets) and crosses_partition:345-351."""
+    inaccessible sets) and crosses_partition:345-351.
+
+    A cut link drops its messages, by the rule the device tier shares
+    (device/core.py: external_effects, insert_rows): what is pending
+    between the two ends when the link is cut is gone
+    (BaseScheduler._cut_link), and what one end sends the other while it
+    is cut never becomes pending (_capture_send). So no pending entry
+    ever crosses a cut link; the reference drops such a message when it
+    is picked instead, which differs for one sent before the cut and
+    picked after the heal. A hard-killed actor's mail goes the same way:
+    what is pending to or from it at the HardKill is scrubbed
+    (Scheduler.actor_terminated) and what a peer sends it while it is
+    down is dropped at the send (dead letters), so heartbeats to a dead
+    node cannot pile up; an external send waits for the restart. An
+    isolated actor's messages are held."""
 
     def __init__(self):
         self.cut: Set[frozenset] = set()
@@ -329,6 +343,8 @@ class ControlledActorSystem:
 
     def _capture_send(self, snd: str, rcv: str, msg: Any) -> None:
         assert self._capturing is not None, "send outside a delivery"
+        if frozenset((snd, rcv)) in self.network.cut or rcv in self.stopped:
+            return  # a cut link or a dead node drops at the send (see Network)
         vc = dict(self.vector_clocks.get(snd, {}))
         san = self._active_sanitizer
         self._capturing.append(

@@ -119,6 +119,16 @@ class BaseScheduler:
     def reset_pending(self) -> None:
         raise NotImplementedError
 
+    def _cut_link(self, a: str, b: str) -> None:
+        """Partition(a, b): cut the link and drop what is pending on it,
+        in both directions (timers are self-sends and externals cross no
+        link; see runtime.system.Network for the rule both tiers keep)."""
+        self.system.network.partition(a, b)
+        link = frozenset((a, b))
+        for entry in self.pending_entries():
+            if not entry.is_timer and frozenset((entry.snd, entry.rcv)) == link:
+                self.remove_pending(entry)
+
     # Optional hooks ----------------------------------------------------
     def on_delivery(self, unique: Unique, entry: PendingEntry) -> None:
         pass
@@ -307,7 +317,7 @@ class BaseScheduler:
             entry = system.inject(event.name, event.message())
             self._record_send(entry)
         elif isinstance(event, Partition):
-            system.network.partition(event.a, event.b)
+            self._cut_link(event.a, event.b)
             self.trace.append(self._unique(PartitionEvent(event.a, event.b)))
             if self.fd:
                 self.fd.handle_partition_event(event.a, event.b)

@@ -254,7 +254,7 @@ class TraceFollowingScheduler(BaseScheduler):
             if self.fd:
                 self.fd.handle_kill_event(event.name)
         elif isinstance(event, PartitionEvent):
-            self.system.network.partition(event.a, event.b)
+            self._cut_link(event.a, event.b)
             self.trace.append(self._unique(PartitionEvent(event.a, event.b)))
             if self.fd:
                 self.fd.handle_partition_event(event.a, event.b)

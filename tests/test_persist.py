@@ -398,13 +398,13 @@ def test_fuzz_resume_matches_uninterrupted():
     functions of (seed, i))."""
     from demi_tpu.runner import fuzz
     from demi_tpu.cli import build_app, build_fuzzer
-    import argparse
+    from demi_tpu.parallel.distributed import workload_args
 
-    args = argparse.Namespace(
+    args = workload_args(dict(
         app="broadcast", nodes=3, bug="drop", seed=0, num_events=8,
         max_messages=60, timer_weight=0.2, kill_weight=0.05,
         partition_weight=0.0,
-    )
+    ))
     app = build_app(args)
     config = SchedulerConfig(invariant_check=make_host_invariant(app))
     full = fuzz(config, build_fuzzer(app, args), max_executions=40,

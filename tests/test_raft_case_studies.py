@@ -194,27 +194,18 @@ def test_lost_vote_durability_on_crash_recovery():
     the fuzzer's wait_budget knob exists. Reference analog: the raft-NN
     known-bug branches exercised via Kill/Start atoms
     (tools/rerun_experiments.sh:7, ExternalEvents.scala:62-91)."""
-    from demi_tpu.device.encoding import device_trace_to_guide
-    from demi_tpu.device.explore import make_single_lane_trace_kernel
-    from demi_tpu.fuzzing import Fuzzer, FuzzerWeights
-    from demi_tpu.apps.raft import raft_send_generator
-    from demi_tpu.schedulers.guided import GuidedScheduler
+    from demi_tpu.parallel.distributed import build_workload
 
-    app = make_raft_app(3)  # no seeded bug flag: volatility IS the bug
-    cfg = DeviceConfig.for_app(
-        app, pool_capacity=96, max_steps=224, max_external_ops=24,
-        invariant_interval=1, timer_weight=0.05,
-    )
-    fz = Fuzzer(
-        num_events=10,
-        weights=FuzzerWeights(
-            send=0.1, wait_quiescence=0.35, hard_kill=0.25, restart=0.3
-        ),
-        message_gen=raft_send_generator(app),
-        prefix=dsl_start_events(app),
-        max_kills=2,
-        wait_budget=(1, 25),
-    )
+    # The builder the CLI verbs share (`demi_tpu sweep --hard-kill-weight
+    # 0.25 --restart-weight 0.3 ...`); no seeded bug flag: volatility IS
+    # the bug.
+    app, cfg, fz = build_workload({
+        "app": "raft", "nodes": 3, "bug": None, "num_events": 10,
+        "max_messages": 224, "pool": 96, "timer_weight": 0.05,
+        "kill_weight": 0.01, "send_weight": 0.1, "wait_weight": 0.35,
+        "hard_kill_weight": 0.25, "restart_weight": 0.3, "max_kills": 2,
+        "wait_budget": [1, 25],
+    })
     base, B = 768, 256  # empirically violating region of the seed space
     programs = [fz.generate_fuzz_test(seed=base + s) for s in range(B)]
     kernel = make_explore_kernel(app, cfg)

@@ -259,14 +259,24 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     result = _sweep(sweeper)
     obs.disable()
     counts = obs.stage_counts()
-    # Every count kept has a reader (the benchmark's dpor.fresh_share and
-    # dpor.admit_us_per_candidate); the sweep takes none.
+    # Every count kept has a reader: the benchmark's dpor.fresh_share,
+    # dpor.admit_us_per_candidate and dpor.materialized_share; the sweep's
+    # sweep.live_step_share and sweep.fault_op_share (PR 27).
+    op_kinds = {
+        "start", "send", "wait", "kill", "hard_kill", "restart",
+        "partition", "unpartition",
+    }
     assert set(counts) == {
         "dpor.candidates", "dpor.fresh", "dpor.materialized",
-    }
+        "sweep.lane_steps", "sweep.live_lane_steps",
+    } | {f"sweep.ops.{kind}" for kind in op_kinds}
     assert counts["dpor.candidates"] >= counts["dpor.fresh"] > 0
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
     assert result.lanes == 24
+    assert 0 < counts["sweep.live_lane_steps"] <= counts["sweep.lane_steps"]
+    # 24 programs of the sweeper's: every actor started once in each
+    assert counts["sweep.ops.start"] == 24 * sweeper.app.num_actors
+    assert counts["sweep.ops.restart"] == 0
 
 
 @pytest.mark.parametrize("mode", ["default", "sleep_sets", "max_distance"])
