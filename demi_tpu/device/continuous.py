@@ -12,12 +12,31 @@ occupancy stays ~100% for any schedule-length distribution.
 Per-seed results are bit-identical to the plain explore kernel: a lane's
 step stream depends only on its own state/key, frozen lanes are no-ops,
 and refill replaces whole lanes atomically (tests/test_continuous.py).
+
+One round of the harvest loop (``_run_batches``), in order: dispatch the
+segment (asynchronous) -> MAKE AHEAD -> status pull (the sync point) ->
+harvest -> fill -> stack -> refill. Which lane gets which program is
+decided after the harvest, but what the coming programs are is a
+function of ``seed_list[next_idx:]`` alone, so between the dispatch and
+the pull the one host thread fuzzes and lowers them into a stock, in seed
+order, while the device runs the segment. It stops at the first of: the
+segment's result is ready (asked once a program); the stock holds as
+many programs as lanes are active (no round can refill more, so the
+host never holds more than one resident set); the call's seeds are used
+up. The fill hands out the stock first and makes the rest on the spot,
+so lane, seed, program and key pair up exactly as without it. The stock
+is a local of one ``_run_batches`` call: a caller's generator may change
+between calls (the benchmark's closes over a per-job base), and a
+consumer that stops early just drops it. Only a generator the
+constructor is told is a function of the seed (``seed_pure``) is called
+ahead; any other is called at refill, in refill order.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Callable, List, Optional, Sequence
+from collections import deque
+from typing import Callable, Deque, List, Optional, Sequence
 
 import numpy as np
 
@@ -237,6 +256,14 @@ def make_segment_kernel_pallas(
     return jax.jit(sharded_call)
 
 
+def _ready(array) -> bool:
+    """Whether a dispatched kernel's result has landed, without blocking
+    (the idiom of ``pipeline/orchestrator._handle_ready``). An array with
+    no such probe reads ready: then nothing is made ahead."""
+    probe = getattr(array, "is_ready", None)
+    return True if probe is None else bool(probe())
+
+
 def make_init_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     """jitted ``keys[B] -> ScheduleState[B]`` batch initializer."""
     return _maybe_shard(
@@ -292,12 +319,19 @@ class ContinuousSweepDriver:
         mesh=None,
         block_lanes: int = 128,
         program_key: Optional[Callable] = None,
+        seed_pure: bool = False,
     ):
         from .encoding import count_ops, lower_program, stack_programs
 
         self.app = app
         self.cfg = cfg
         self.program_gen = program_gen
+        # Whether program_gen is a function of the seed within one sweep
+        # call. Only then are programs made ahead of their refill (module
+        # doc); a generator that reads live state (the autotuned sweep's
+        # closes over the controller's weights and tags each seed with
+        # the proposal it was drawn under) is called at refill only.
+        self.seed_pure = seed_pure
         self.batch = batch
         self.seg_steps = seg_steps
         if mesh is not None and batch % mesh.size:
@@ -374,42 +408,91 @@ class ContinuousSweepDriver:
         if self.last_occupancy is not None:
             obs.gauge("device.continuous.occupancy").set(self.last_occupancy)
 
-    def _fill(
-        self, seeds: Sequence[int], lanes: Sequence[int], progs_host: List
+    def _memo_hit(self, seed: int):
+        """The lowered program the ``program_key`` memo holds for
+        ``seed``, or None (no memo, or not lowered yet)."""
+        if self._program_key is None:
+            return None
+        return self._lower_memo.get(self._program_key(seed))
+
+    def _make(self, seed: int, clock: List[int]):
+        """``program_gen`` then ``lower_program`` for one seed, its
+        nanoseconds added to ``clock`` (fuzz, lower). The events die
+        here, before the next program's are generated."""
+        t0 = time.perf_counter_ns()
+        events = self.program_gen(seed)
+        t1 = time.perf_counter_ns()
+        prog = self._lower_program(self.app, self.cfg, events)
+        clock[0] += t1 - t0
+        clock[1] += time.perf_counter_ns() - t1
+        if self._program_key is not None:
+            self._lower_memo[self._program_key(seed)] = prog
+        return prog
+
+    def _make_ahead(
+        self, pending, seed_list: Sequence[int], start: int, room: int,
+        stock: Deque,
     ) -> None:
-        """Lower the program of each seed into its lane of ``progs_host``:
-        ``program_gen`` then ``lower_program``, one seed at a time, each
+        """Between a segment's dispatch and its status pull: make the
+        programs of ``seed_list[start + len(stock):]`` into ``stock``, in
+        seed order, until ``pending`` (the segment's status) is ready,
+        the stock holds ``room`` programs, or the seeds end (module
+        doc). A ``program_key`` memo hit costs nothing at refill either,
+        so it is not made ahead: it holds its place in the stock as
+        None and the fill looks it up. Timed as a fill: a ``sweep.fill``
+        span whose slices are ``sweep.fuzz`` and ``sweep.lower``."""
+        had = len(stock)
+        todo = seed_list[start + had : start + room]
+        if not todo or _ready(pending):
+            return
+        clock = [0, 0]
+        with obs.span("sweep.fill", ahead=True) as sp:
+            for seed in todo:
+                stock.append(
+                    None if self._memo_hit(seed) is not None
+                    else self._make(seed, clock)
+                )
+                if _ready(pending):
+                    break
+            sp.set(programs=len(stock) - had)
+            sp.slice("sweep.fuzz", clock[0])
+            sp.slice("sweep.lower", clock[1])
+
+    def _fill(
+        self, seeds: Sequence[int], lanes: Sequence[int], progs_host: List,
+        stock: Deque = (),
+    ) -> None:
+        """Put the program of each seed into its lane of ``progs_host``:
+        from ``stock`` while it lasts (made ahead for exactly these
+        seeds, in this order: ``_make_ahead``), else ``program_gen``
+        then ``lower_program`` on the spot, one seed at a time, each
         program replacing its lane's old one as it goes. A program's
         events die before the next is generated, and a retired program's
-        memory serves the next: a fill's programs held together (beside
-        the events, or beside the programs they replace) cost the sweep
-        more than the spans could (PERF.md, PR 24). The loop is per lane,
-        so fuzzing and lowering get no span each: a clock pair per
-        program sums them, and the ``sweep.fill`` span hands the sums to
-        the stages ``sweep.fuzz`` and ``sweep.lower`` (a no-op with spans
-        off)."""
-        fuzz_ns = lower_ns = 0
+        memory serves the next: a fill's programs held together beside
+        the events cost the sweep more than the spans could (PERF.md,
+        PR 24). The loop is per lane, so fuzzing and lowering get no
+        span each: a clock pair per program sums them, and the
+        ``sweep.fill`` span hands the sums to the stages ``sweep.fuzz``
+        and ``sweep.lower`` (a no-op with spans off). Counted beside
+        them: ``sweep.programs`` put in a lane, and ``sweep.prefetched``
+        of those that were made ahead."""
+        clock = [0, 0]
+        ahead = 0
         with obs.span("sweep.fill", programs=len(seeds)) as sp:
             for lane, seed in zip(lanes, seeds):
-                if self._program_key is not None:
-                    key = self._program_key(seed)
-                    prog = self._lower_memo.get(key)
-                    if prog is not None:
-                        progs_host[lane] = prog
-                        continue
-                t0 = time.perf_counter_ns()
-                events = self.program_gen(seed)
-                t1 = time.perf_counter_ns()
-                progs_host[lane] = prog = self._lower_program(
-                    self.app, self.cfg, events
-                )
-                fuzz_ns += t1 - t0
-                lower_ns += time.perf_counter_ns() - t1
-                if self._program_key is not None:
-                    self._lower_memo[key] = prog
-            sp.slice("sweep.fuzz", fuzz_ns)
-            sp.slice("sweep.lower", lower_ns)
+                prog = stock.popleft() if stock else None
+                if prog is not None:
+                    ahead += 1
+                else:
+                    prog = self._memo_hit(seed)
+                    if prog is None:
+                        prog = self._make(seed, clock)
+                progs_host[lane] = prog
+            sp.slice("sweep.fuzz", clock[0])
+            sp.slice("sweep.lower", clock[1])
             if obs.spans.live():
+                obs.stage_count("sweep.programs", len(seeds))
+                obs.stage_count("sweep.prefetched", ahead)
                 # What this fill lowered, by kind of external op: how
                 # much of the fault plane the traffic engages.
                 filled = [progs_host[lane] for lane in lanes]
@@ -464,7 +547,9 @@ class ContinuousSweepDriver:
         hashes)`` array quadruple per segment round (only rounds that
         retired lanes yield). Array-granular retirement is what lets the
         SweepDriver's harvest accumulation stay vectorized — per-lane
-        Python tuples exist only for callers that ask (``_run``)."""
+        Python tuples exist only for callers that ask (``_run``). The
+        round's order, and the stock of programs made ahead while the
+        segment runs, are in the module doc."""
         seed_list = (
             list(range(total_lanes)) if seeds is None else list(seeds)
         )
@@ -501,13 +586,17 @@ class ContinuousSweepDriver:
             steps_run = np.zeros(b, np.int64)
             done_count = 0
             active = np.arange(b) < n_live
+            # Lowered programs of seed_list[next_idx:], made while a
+            # segment ran; this call's alone (module doc).
+            stock: Deque = deque()
 
             self.last_segment_seconds = 0.0
             self.last_harvest_seconds = 0.0
             self.last_lane_sharding = None
         while done_count < total_lanes:
             with obs.span("sweep.round"):
-                round_live = int(active.sum()) * self.seg_steps
+                n_active = int(active.sum())
+                round_live = n_active * self.seg_steps
                 total_lane_steps += b * self.seg_steps
                 live_lane_steps += round_live
                 self.last_occupancy = live_lane_steps / total_lane_steps
@@ -520,18 +609,29 @@ class ContinuousSweepDriver:
                     state = self.segment(
                         state, progs, jnp.asarray(steps_run, jnp.int32)
                     )
-                    # The status pull is the sync point: everything up
-                    # to it is device-segment time, the rest of the
-                    # iteration is harvest.
+                t_gap = time.perf_counter()
+                if self.seed_pure:
+                    self._make_ahead(
+                        state.status, seed_list, next_idx, n_active, stock
+                    )
+                t_pull = time.perf_counter()
+                with obs.span("sweep.block"):
+                    # The status pull is the sync point: the dispatch
+                    # and the wait here are device-segment time; what
+                    # was made in the gap, and the rest of the
+                    # iteration, is harvest.
                     _status_sync = np.asarray(state.status)
                 t_harvest = time.perf_counter()
+                self.last_harvest_seconds += t_pull - t_gap
                 if self.last_lane_sharding is None:
                     from ..parallel.mesh import lane_sharding_summary
 
                     self.last_lane_sharding = lane_sharding_summary(
                         state.status
                     )
-                self.last_segment_seconds += t_harvest - t_seg
+                self.last_segment_seconds += (t_gap - t_seg) + (
+                    t_harvest - t_pull
+                )
                 steps_run = np.minimum(
                     steps_run + self.seg_steps, self.cfg.max_steps
                 )
@@ -586,7 +686,8 @@ class ContinuousSweepDriver:
                         next_idx += len(refill_lanes)
                         # Ascending, as the loop below hands the seeds out.
                         self._fill(
-                            fresh_seeds, sorted(refill_lanes), progs_host
+                            fresh_seeds, sorted(refill_lanes), progs_host,
+                            stock,
                         )
                         with obs.span("sweep.refill"):
                             mask = np.zeros(b, bool)

@@ -848,7 +848,9 @@ class SweepDriver:
         verdicts identical to ``run_chunk`` exists in exactly one copy.
         ``program_gen`` overrides the driver's generator (the autotuned
         path's epoch-tagging wrapper); overridden drivers bypass the
-        cache — the wrapper closes over live controller state."""
+        cache — the wrapper closes over live controller state, so the
+        driver is not told it is a function of the seed and calls it at
+        refill only, never ahead (``seed_pure``)."""
         from ..device.continuous import ContinuousSweepDriver
 
         if self.mesh is not None:
@@ -873,6 +875,7 @@ class SweepDriver:
             key_fn=lambda s: jax.random.fold_in(
                 jax.random.PRNGKey(base_key), s
             ),
+            seed_pure=program_gen is None,
         )
         if program_gen is None:
             self._cont_cache = (key, drv)

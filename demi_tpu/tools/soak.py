@@ -169,6 +169,7 @@ def main(argv=None) -> int:
             drv = ContinuousSweepDriver(
                 app, cfg, gen, batch=8,
                 seg_steps=int(rng.choice([16, 28, 32])),
+                seed_pure=True,
                 **variant_kw[name],
             )
             st, vio = drv.sweep(n)

@@ -311,3 +311,208 @@ def test_continuous_arbitrary_seed_partition():
     for i, s in enumerate(seeds):
         assert statuses[s] == int(np.asarray(ref.status)[i]), s
         assert violations[s] == int(np.asarray(ref.violation)[i]), s
+
+
+# -- programs made ahead, while the segment runs (PR 28) ---------------------
+
+import itertools
+
+import pytest
+
+from demi_tpu.device import continuous
+
+
+class _Ahead:
+    """One driver (one set of kernels) whose generator logs its calls,
+    reads a per-call ``base`` as the benchmark's does, and whose
+    readiness probe is a stub that logs too: ``busy`` decides whether
+    the device still runs the segment at the k-th probe."""
+
+    N = 40          # more than three resident sets of 8
+    BATCH = 8
+
+    def __init__(self):
+        app, cfg, gen = _broadcast_fixture()
+        def logged(seed):
+            self.log.append((self.base, seed))
+            return gen(self.base + seed)
+
+        # 28 does not divide the 96-step budget.
+        self.drv = ContinuousSweepDriver(
+            app, cfg, logged, batch=self.BATCH, seg_steps=28
+        )
+        self.reset(seed_pure=False)
+
+    def ready(self, _array) -> bool:
+        busy = self.busy(next(self._probes))
+        self.log.append("busy" if busy else "ready")
+        return not busy
+
+    def reset(self, seed_pure, base=0, busy=lambda k: True):
+        self.base, self.busy, self.log = base, busy, []
+        self._probes = itertools.count()
+        self.drv.seed_pure = seed_pure
+
+    def run(self, seed_pure, base=0, busy=lambda k: True):
+        """Every yielded batch of one whole sweep, as lists."""
+        self.reset(seed_pure, base, busy)
+        return [
+            tuple(a.tolist() for a in batch)
+            for batch in self.drv._run_batches(self.N)
+        ]
+
+    def made_ahead(self):
+        """Seeds whose generator call came straight after a probe that
+        found the device busy: made between a dispatch and its pull."""
+        return [
+            cur[1] for prev, cur in zip(self.log, self.log[1:])
+            if prev == "busy" and not isinstance(cur, str)
+        ]
+
+    def generated(self):
+        return [e for e in self.log if not isinstance(e, str)]
+
+
+@pytest.fixture(scope="module")
+def ahead():
+    return _Ahead()
+
+
+@pytest.fixture
+def stub_ready(ahead, monkeypatch):
+    monkeypatch.setattr(continuous, "_ready", ahead.ready)
+
+
+@pytest.mark.parametrize("busy", ["always", "three_probes_in_four", "never"])
+def test_made_ahead_changes_no_verdict_hash_or_batch_order(
+    ahead, stub_ready, busy
+):
+    """(status, code, hash) per seed and the order of the yielded
+    batches equal a run that makes nothing ahead, whether the gap
+    serves the whole refill, part of it, or none."""
+    plain = ahead.run(seed_pure=False)
+    assert ahead.log == [(0, s) for s in range(ahead.N)]    # no probe
+    policy = {
+        "always": lambda k: True,
+        "three_probes_in_four": lambda k: k % 4 != 3,
+        "never": lambda k: False,
+    }[busy]
+    got = ahead.run(seed_pure=True, busy=policy)
+    assert got == plain
+    assert sum(len(b[0]) for b in got) == ahead.N
+    # the generator still sees every seed once, in seed order
+    assert ahead.generated() == [(0, s) for s in range(ahead.N)]
+    made = ahead.made_ahead()
+    if busy == "always":
+        # every refill was served from the stock; the prime fill cannot be
+        assert made == list(range(ahead.BATCH, ahead.N))
+    elif busy == "never":
+        assert made == []
+    else:
+        assert 0 < len(made) < ahead.N - ahead.BATCH
+
+
+def test_a_stock_is_not_used_by_the_next_call(ahead, stub_ready):
+    """The benchmark's generator closes over a base that changes with
+    the job: a consumer that stops early leaves programs made under the
+    old base, and the next call must not hand them out."""
+    want = ahead.run(seed_pure=False, base=1000)
+    ahead.reset(seed_pure=True)
+    for _batch in ahead.drv._run_batches(ahead.N):
+        break                       # as stop_on_violation does
+    assert len(ahead.log) > ahead.BATCH      # a stock had been made
+    got = ahead.run(seed_pure=True, base=1000)
+    assert got == want
+    # every program of the second call was generated in it, under its base
+    assert ahead.generated() == [(1000, s) for s in range(ahead.N)]
+
+
+def test_a_stateful_generator_is_called_only_at_refill(ahead, stub_ready):
+    """Not declared a function of the seed (the autotuned sweep's
+    epoch-tagging wrapper): no probe, and between two yields exactly the
+    programs of the lanes the round refills, as before this PR."""
+    ahead.reset(seed_pure=False)
+    handed = ahead.BATCH
+    seen = ahead.BATCH     # the prime fill, before the first segment
+    for seeds, *_ in ahead.drv._run_batches(ahead.N):
+        refilled = min(len(seeds), ahead.N - handed)
+        handed += refilled
+        seen += refilled
+        assert len(ahead.log) == seen
+    assert ahead.log == [(0, s) for s in range(ahead.N)]    # no probe
+
+
+def test_only_the_cached_driver_is_told_its_generator_is_seed_pure():
+    """``SweepDriver._continuous_driver`` draws the line where it
+    already did: an overriding generator closes over live state."""
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    app, cfg, gen = _broadcast_fixture()
+    driver = SweepDriver(app, cfg, gen)
+    assert driver._continuous_driver(8).seed_pure is True
+    assert driver._continuous_driver(8, program_gen=gen).seed_pure is False
+    assert ContinuousSweepDriver(app, cfg, gen, batch=8).seed_pure is False
+
+
+def test_breaking_out_early_leaves_the_driver_reusable(ahead, stub_ready):
+    want = ahead.run(seed_pure=False)
+    ahead.reset(seed_pure=True)
+    for _ in range(2):
+        for _batch in ahead.drv._run_batches(ahead.N):
+            break
+    assert ahead.run(seed_pure=True) == want
+    statuses, violations = ahead.drv.sweep(ahead.N)
+    assert sorted(statuses) == list(range(ahead.N))
+
+
+def test_memo_hits_are_not_made_ahead(stub_ready, ahead):
+    """With a ``program_key`` memo a hit costs nothing at refill either:
+    only the first period's programs are generated, ahead or not, and
+    the verdicts equal the unmemoized driver's."""
+    app, cfg, gen = _broadcast_fixture()
+    calls = []
+
+    def logged(seed):
+        calls.append(seed)
+        return gen(seed % 12)
+
+    drv = ContinuousSweepDriver(
+        app, cfg, logged, batch=8, seg_steps=16,
+        program_key=lambda s: s % 12, seed_pure=True,
+    )
+    ahead.reset(seed_pure=True)      # the stub's policy: always busy
+    got = drv.sweep(40)
+    assert calls == list(range(12))
+    drv.seed_pure, drv._lower_memo = False, {}
+    assert drv.sweep(40) == got
+
+
+def test_the_three_stop_rules_of_making_ahead(ahead, stub_ready):
+    """Ready, room (active lanes), seeds used up; and an array with no
+    readiness probe reads ready, so nothing is made ahead of it."""
+    from collections import deque
+
+    seeds = list(range(ahead.N))
+    ahead.reset(seed_pure=True)
+    stock = deque()
+    ahead.drv._make_ahead(None, seeds, 8, 3, stock)
+    assert len(stock) == 3 and ahead.generated() == [(0, 8), (0, 9), (0, 10)]
+    ahead.drv._make_ahead(None, seeds, 8, 3, stock)     # no room left
+    assert len(stock) == 3
+    ahead.drv._make_ahead(None, seeds, 8, 5, stock)     # continues in order
+    assert ahead.generated()[3:] == [(0, 11), (0, 12)]
+    stock.clear()
+    ahead.drv._make_ahead(None, seeds, ahead.N - 2, 8, stock)   # seeds end
+    assert len(stock) == 2
+    ahead.reset(seed_pure=True, busy=lambda k: k == 0)
+    stock = deque()
+    ahead.drv._make_ahead(None, seeds, 0, 8, stock)     # ready after one
+    assert len(stock) == 1
+
+
+def test_an_array_with_no_probe_reads_ready():
+    import jax.numpy as jnp
+
+    assert continuous._ready(object()) is True
+    assert continuous._ready(np.zeros(3)) is True
+    assert continuous._ready(jnp.zeros(3).block_until_ready()) is True

@@ -261,7 +261,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     counts = obs.stage_counts()
     # Every count kept has a reader: the benchmark's dpor.fresh_share,
     # dpor.admit_us_per_candidate and dpor.materialized_share; the sweep's
-    # sweep.live_step_share and sweep.fault_op_share (PR 27).
+    # sweep.live_step_share and sweep.fault_op_share (PR 27) and
+    # sweep.prefetch_share (PR 28).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -269,11 +270,15 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     assert set(counts) == {
         "dpor.candidates", "dpor.fresh", "dpor.materialized",
         "sweep.lane_steps", "sweep.live_lane_steps",
+        "sweep.programs", "sweep.prefetched",
     } | {f"sweep.ops.{kind}" for kind in op_kinds}
     assert counts["dpor.candidates"] >= counts["dpor.fresh"] > 0
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
     assert result.lanes == 24
     assert 0 < counts["sweep.live_lane_steps"] <= counts["sweep.lane_steps"]
+    # one program a schedule put in a lane; the prime fill's 8 never ahead
+    assert counts["sweep.programs"] == 24
+    assert 0 <= counts["sweep.prefetched"] <= 24 - 8
     # 24 programs of the sweeper's: every actor started once in each
     assert counts["sweep.ops.start"] == 24 * sweeper.app.num_actors
     assert counts["sweep.ops.restart"] == 0
@@ -353,7 +358,70 @@ def test_spans_change_nothing_the_driver_computes(
 
 # -- (f) no span across the yield --------------------------------------------
 
-def test_consumer_time_is_not_the_drivers(clean, sweeper):
+@pytest.fixture
+def busy_device(monkeypatch):
+    """The segment never reads ready before its pull: every gap between
+    a dispatch and the pull makes programs ahead, up to its room."""
+    from demi_tpu.device import continuous
+
+    monkeypatch.setattr(continuous, "_ready", lambda _array: False)
+
+
+def test_programs_made_ahead_are_a_fill_outside_the_block(
+    clean, sweeper, busy_device
+):
+    """``sweep.block`` is the dispatch and the wait at the pull, twice a
+    round, and holds no fill; programs made in the gap are a
+    ``sweep.fill`` under the round whose slices reach ``sweep.fuzz`` and
+    ``sweep.lower`` like any other's; every refill is served from the
+    stock when the device stays busy."""
+    slept = []
+
+    def slow(seed):
+        time.sleep(0.002)
+        slept.append(seed)
+        return gen(seed)
+
+    drv = sweeper._continuous_driver(8)
+    gen, drv.program_gen = drv.program_gen, slow
+    obs.enable()
+    try:
+        result = _sweep(sweeper)
+    finally:
+        obs.disable()
+        drv.program_gen = gen
+    assert result.lanes == 24 and slept == list(range(24))
+    spans = obs.TRACER.spans
+    by_op = {s["op_b"]: s for s in spans}
+    fills = [s for s in spans if s["name"] == "sweep.fill"]
+    ahead = [s for s in fills if s["args"].get("ahead")]
+    assert ahead and sum(s["args"]["programs"] for s in ahead) == 24 - 8
+    assert {by_op[s["parent"]]["name"] for s in ahead} == {"sweep.round"}
+    assert sum(s["args"]["programs"] for s in fills if s not in ahead) == 24
+    totals = obs.stage_totals()
+    rounds = totals["sweep.round"]["count"]
+    assert totals["sweep.block"]["count"] == 2 * rounds
+    # nothing ran under a block but, at most, a collector pass
+    blocks = {s["op_b"] for s in spans if s["name"] == "sweep.block"}
+    assert {s["name"] for s in spans if s["parent"] in blocks} <= {"gc.pause"}
+    # every program made, ahead or not, is in the two slices
+    assert totals["sweep.fuzz"]["seconds"] >= 24 * 0.002
+    assert totals["sweep.fuzz"]["count"] == len(fills) == totals["sweep.lower"]["count"]
+    counts = obs.stage_counts()
+    assert counts["sweep.programs"] == 24 and counts["sweep.prefetched"] == 16
+    _tree_closes("sweep.job")
+    # the driver's own split: what was made in the gap is harvest time
+    # (the prime fill is before either clock starts)
+    assert drv.last_harvest_seconds >= 16 * 0.002
+    assert drv.last_segment_seconds == pytest.approx(
+        totals["sweep.block"]["seconds"], abs=5e-3
+    )
+
+
+@pytest.mark.parametrize("device", ["as_it_is", "busy"])
+def test_consumer_time_is_not_the_drivers(clean, sweeper, device, request):
+    if device == "busy":
+        request.getfixturevalue("busy_device")
     drv = sweeper._continuous_driver(8)
     obs.enable()
     t0 = time.perf_counter()
