@@ -129,13 +129,11 @@ def bench_device_raft(jax):
     can tell signal from noise (VERDICT r3 weak #7).
 
     DEMI_BENCH_IMPL forces a single variant: xla | xla-trailing |
-    xla-trailing-ee | pallas | pallas-trailing | pallas-trailing-ee |
-    xla-round-ee | xla-trailing-round-ee ('-ee' = early-exit while_loop
-    instead of the fixed-length scan; '-round' = round-delivery mode,
+    xla-trailing-ee | xla-round-ee | xla-trailing-round-ee ('-ee' =
+    early-exit while_loop instead of the fixed-length scan; '-round' = round-delivery mode,
     whose invariant checks are round-granularity — such variants are
     excluded from the per-delivery headline and summarized under
-    "round", unless forced alone, which relabels the metric).
-    DEMI_BENCH_BLOCK_LANES sets the pallas block size."""
+    "round", unless forced alone, which relabels the metric)."""
     from demi_tpu.device import DeviceConfig
     from demi_tpu.device.core import ST_OVERFLOW
     from demi_tpu.device.encoding import lower_program, stack_programs
@@ -157,22 +155,11 @@ def bench_device_raft(jax):
     progs = stack_programs([lower_program(app, cfg, program)] * batch)
 
     impl = os.environ.get("DEMI_BENCH_IMPL")
-    block_lanes = int(os.environ.get("DEMI_BENCH_BLOCK_LANES", 256))
-    # Default on an accelerator: measure the whole backend/layout/loop
-    # family; headline = the best. CPU default measures the XLA variants
-    # (interpret-mode pallas is an emulation, not a measurement).
-    impls = [impl] if impl else (
-        [
-            "xla", "xla-trailing", "xla-trailing-ee",
-            "pallas", "pallas-trailing", "pallas-trailing-ee",
-            "xla-round-ee", "xla-trailing-round-ee",
-        ]
-        if platform not in ("cpu",)
-        else [
-            "xla", "xla-trailing", "xla-trailing-ee",
-            "xla-round-ee", "xla-trailing-round-ee",
-        ]
-    )
+    # Default: measure the whole layout/loop family; headline = the best.
+    impls = [impl] if impl else [
+        "xla", "xla-trailing", "xla-trailing-ee",
+        "xla-round-ee", "xla-trailing-round-ee",
+    ]
 
     def build(name):
         # Round-delivery variants check the invariant at round (not
@@ -181,9 +168,7 @@ def bench_device_raft(jax):
         # grammar itself lives in device/explore.py, shared with the
         # autotuner's calibration so bench and tuner measure the same
         # kernels by the same names.
-        return make_explore_kernel_variant(
-            app, cfg, name, block_lanes=block_lanes
-        )
+        return make_explore_kernel_variant(app, cfg, name)
 
     kernels = {}
     for name in impls:
@@ -194,9 +179,9 @@ def bench_device_raft(jax):
             )
             kernels[name] = kernel
         except Exception as e:  # pragma: no cover - accelerator-dependent
-            # A Mosaic lowering gap on real hardware must not cost the
-            # whole benchmark run; record the failure and keep the other
-            # backends' numbers.
+            # A lowering gap on real hardware must not cost the whole
+            # benchmark run; record the failure and keep the other
+            # variants' numbers.
             kernels[name] = None
             print(f"# bench: {name} backend failed: {e!r}", file=sys.stderr)
     ok_names = [n for n, k in kernels.items() if k is not None]

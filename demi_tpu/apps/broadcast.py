@@ -46,7 +46,7 @@ def make_broadcast_app(
         already = (state[0] & bit) != 0
         deliver = (tag == TAG_BCAST) & ~already & (bit != 0)
         # Index-free write (width-1 state): keeps the handler free of
-        # scatter ops, which have no Mosaic lowering (pallas kernels).
+        # scatter ops, which XLA serialises on the TPU (device/ops.py).
         new_state = jnp.where(deliver, state[0] | bit, state[0])[None]
         dsts = jnp.arange(max_outbox, dtype=jnp.int32)
         if reliable:

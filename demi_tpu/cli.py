@@ -357,11 +357,11 @@ _RESUME_COMMON = (
 )
 _RESUME_FIELDS = {
     "dpor": _RESUME_COMMON + (
-        "batch", "pool", "rounds", "impl", "static_prune", "sleep_sets",
+        "batch", "pool", "rounds", "static_prune", "sleep_sets",
         "prefix_fork", "async_min", "autotune",
     ),
     "sweep": _RESUME_COMMON + (
-        "batch", "pool", "chunk", "sweep_mode", "impl", "processes",
+        "batch", "pool", "chunk", "sweep_mode", "processes",
         "prefix_fork", "autotune",
     ),
     "fuzz": _RESUME_COMMON + ("max_executions", "output", "autotune",
@@ -1075,7 +1075,9 @@ def cmd_resume(args) -> int:
             f"resume: checkpoint names unknown command {command!r}"
         )
     # A manifest written before the fault plane's knobs were flags has
-    # none of them: it meant the literals.
+    # none of them: it meant the literals. One written while the verbs
+    # took a kernel backend carries an ``impl`` key nothing reads: the
+    # backends were held bit-identical, so the search continues the same.
     ns = argparse.Namespace(
         **{**FAULT_PLANE_DEFAULTS, **ckpt.meta.get("cli_args", {})}
     )
@@ -1217,13 +1219,10 @@ def cmd_minimize(args) -> int:
             "--peek applies to the gamut's replay oracle; incddmin "
             "replays exact DPOR prescriptions and never peeks"
         )
-    # The flag is authoritative: it must also override a pre-set
-    # DEMI_DEVICE_IMPL in the caller's environment.
-    os.environ["DEMI_DEVICE_IMPL"] = getattr(args, "impl", "xla")
     _strict_io_begin(args)
     if getattr(args, "prefix_fork", False):
-        # Same contract as --impl: the env switch is what the checker /
-        # DPOR constructors read, so the flag reaches every stage.
+        # The env switch is what the checker / DPOR constructors read,
+        # so the flag reaches every stage.
         os.environ["DEMI_PREFIX_FORK"] = "1"
     if getattr(args, "async_min", False):
         # The checker and every minimizer read DEMI_ASYNC_MIN, so the
@@ -1444,7 +1443,6 @@ def cmd_sweep(args) -> int:
         _obs_end(args)
         return 0
 
-    os.environ["DEMI_DEVICE_IMPL"] = getattr(args, "impl", "xla")
     _strict_io_begin(args)
     if getattr(args, "prefix_fork", False):
         os.environ["DEMI_PREFIX_FORK"] = "1"
@@ -1488,7 +1486,7 @@ def cmd_sweep(args) -> int:
         )
 
         platform = jax.devices()[0].platform
-        axes = sweep_axes(cfg, chunk, platform)
+        axes = sweep_axes(cfg, chunk)
         # Never calibrate a chunk the sweep can't run: the decision must
         # describe the configuration that actually executes (and gets
         # cached), so cap the axis at the sweep's own lane budget.
@@ -1569,7 +1567,6 @@ def cmd_sweep(args) -> int:
 def cmd_dpor(args) -> int:
     """Systematic batched DPOR search (BASELINE config 2 shape)."""
     _obs_begin(args)
-    os.environ["DEMI_DEVICE_IMPL"] = getattr(args, "impl", "xla")
     _strict_io_begin(args)
     if getattr(args, "host_shards", 0):
         # DeviceDPOROracle builds its DeviceDPOR internally; the env var
@@ -1989,7 +1986,7 @@ def cmd_tune(args) -> int:
                 {
                     "dry_run": True,
                     "key": key,
-                    "axes": sweep_axes(cfg, chunk, platform),
+                    "axes": sweep_axes(cfg, chunk),
                     "cached": cache.get(key),
                     "cache_path": cache.path,
                 }
@@ -2408,10 +2405,6 @@ def main(argv: Optional[list] = None) -> int:
     p.set_defaults(fn=cmd_fuzz)
 
     p = sub.add_parser("minimize", help="run the minimization gamut on an experiment")
-    p.add_argument(
-        "--impl", choices=("xla", "pallas"), default="xla",
-        help="device-batched oracle backend",
-    )
     common(p)
     obs_flags(p)
     fork_flags(p)
@@ -2482,10 +2475,6 @@ def main(argv: Optional[list] = None) -> int:
     p.set_defaults(fn=cmd_replay)
 
     p = sub.add_parser("sweep", help="device-batched fuzz sweep")
-    p.add_argument(
-        "--impl", choices=("xla", "pallas"), default="xla",
-        help="kernel backend: xla (default) or pallas VMEM-resident blocks",
-    )
     common(p)
     obs_flags(p)
     tune_flags(p)
@@ -2515,10 +2504,6 @@ def main(argv: Optional[list] = None) -> int:
     p.set_defaults(fn=cmd_sweep)
 
     p = sub.add_parser("dpor", help="systematic batched DPOR search")
-    p.add_argument(
-        "--impl", choices=("xla", "pallas"), default="xla",
-        help="DPOR sweep kernel backend",
-    )
     common(p)
     obs_flags(p)
     tune_flags(p)

@@ -45,7 +45,6 @@ from .fork import (
     make_replay_prefix_runner,
     prefix_fork_enabled,
 )
-from .pallas_explore import make_explore_kernel_pallas, make_replay_kernel_pallas
 from .replay import make_replay_kernel
 
 __all__ = [
@@ -59,9 +58,7 @@ __all__ = [
     "fork_lanes",
     "make_dpor_prefix_runner",
     "make_explore_kernel",
-    "make_explore_kernel_pallas",
     "make_explore_prefix_runner",
-    "make_replay_kernel_pallas",
     "make_replay_prefix_runner",
     "make_single_lane_trace_kernel",
     "make_replay_kernel",

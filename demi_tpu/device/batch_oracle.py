@@ -24,7 +24,6 @@ so every async answer is bit-identical to the synchronous path's
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -104,7 +103,6 @@ class DeviceReplayChecker:
         app: DSLApp,
         cfg: DeviceConfig,
         config: SchedulerConfig,
-        impl: Optional[str] = None,
         mesh=None,
         prefix_fork: Optional[bool] = None,
         fork_bucket: int = 8,
@@ -114,13 +112,8 @@ class DeviceReplayChecker:
         self.cfg = cfg
         self.config = config
         self.mesh = mesh
-        # Kernel backend: 'xla' (default) or 'pallas' (VMEM-resident lane
-        # blocks, device/pallas_explore.py). DEMI_DEVICE_IMPL sets the
-        # default so a whole minimize pipeline can be flipped from the
-        # environment for TPU experiments. A mesh shards each candidate
-        # batch over its lane axis instead (one DDMin level spread across
-        # chips, SURVEY.md §2.8).
-        impl = impl or os.environ.get("DEMI_DEVICE_IMPL", "xla")
+        # A mesh shards each candidate batch over its lane axis (one DDMin
+        # level spread across chips, SURVEY.md §2.8).
         # Launch-telemetry + profiler parity with the explore kernels:
         # every replay launch passes through _counted_kernel, so the
         # launch profiler (--profile-rounds on minimize) attributes
@@ -130,19 +123,8 @@ class DeviceReplayChecker:
         if mesh is not None:
             from ..parallel.mesh import shard_replay_kernel
 
-            if impl == "pallas":
-                raise ValueError(
-                    "DeviceReplayChecker: mesh sharding runs the XLA "
-                    "replay kernel; impl='pallas' has no sharded replay twin"
-                )
             self.kernel = _counted_kernel(
                 shard_replay_kernel(app, cfg, mesh), "replay-mesh"
-            )
-        elif impl == "pallas":
-            from .pallas_explore import make_replay_kernel_pallas
-
-            self.kernel = _counted_kernel(
-                make_replay_kernel_pallas(app, cfg), "replay-pallas"
             )
         else:
             self.kernel = _counted_kernel(
@@ -163,12 +145,6 @@ class DeviceReplayChecker:
         if prefix_fork_enabled(prefix_fork):
             from .fork import PrefixForker, make_replay_prefix_runner
 
-            if impl == "pallas":
-                raise ValueError(
-                    "DeviceReplayChecker: prefix-fork trunk/fork lanes run "
-                    "on the XLA replay kernel; drop impl='pallas' or "
-                    "prefix_fork"
-                )
             if mesh is not None:
                 from ..parallel.mesh import shard_replay_kernel
 

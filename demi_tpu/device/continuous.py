@@ -51,7 +51,6 @@ from .explore import (
     _finalize,
     init_state,
     make_any_step_fn,
-    resolve_impl,
 )
 
 LANES = "lanes"
@@ -79,11 +78,11 @@ def _maybe_shard(fn, mesh, n_args: int, axis: str = LANES):
 
 
 def _segment_lane_fn(app: DSLApp, cfg: DeviceConfig, seg_steps: int):
-    """Per-lane segment body shared by the XLA and pallas backends: advance
-    one lane by ``seg_steps`` steps, masking steps at or past the lane's
-    ``cfg.max_steps`` budget (finished lanes are frozen no-ops). The
-    counter rides the carry (not scan xs) so the same trace lowers under
-    Mosaic, where xs-slicing has no lowering."""
+    """Per-lane segment body: advance one lane by ``seg_steps`` steps,
+    masking steps at or past the lane's ``cfg.max_steps`` budget (finished
+    lanes are frozen no-ops). The step counter rides the scan's carry, not
+    its xs: a form first chosen for the Pallas twin (removed in PR 29) and
+    kept because it is the compiled program the chip's numbers are of."""
     step = make_any_step_fn(app, cfg)
 
     def seg_lane(state: ScheduleState, prog: ExtProgram, steps_run):
@@ -120,140 +119,6 @@ def make_segment_kernel(
     refill path; the batch must be a multiple of the mesh size)."""
     seg_lane = _segment_lane_fn(app, cfg, seg_steps)
     return _maybe_shard(jax.vmap(seg_lane), mesh, 3)
-
-
-def make_segment_kernel_pallas(
-    app: DSLApp,
-    cfg: DeviceConfig,
-    seg_steps: int,
-    block_lanes: int = 128,
-    interpret: Optional[bool] = None,
-    mesh=None,
-    axis: str = LANES,
-):
-    """Pallas twin of ``make_segment_kernel``: each grid cell keeps a lane
-    block's full ScheduleState in VMEM for the whole segment, so the state
-    round-trips HBM once per *segment* instead of once per step — the
-    VMEM-residency win of the pallas explore backend composed with lane
-    refill. Bit-identical to the XLA segment kernel (same
-    ``_segment_lane_fn`` trace).
-
-    Bool state leaves ride as int32 kernel operands (Mosaic mask operands
-    are awkward); zero-size leaves (the disabled trace buffer) bypass the
-    kernel untouched. ``mesh`` wraps the blocked call in shard_map over
-    ``axis`` — each device runs the VMEM-blocked segment on its local lane
-    shard."""
-    from .pallas_explore import _check_pallas_cfg, _make_blocked_kernel
-
-    if cfg.record_trace:
-        raise ValueError(
-            "pallas segment kernel records verdicts only (sweeps re-trace "
-            "interesting lanes via the XLA single-lane kernel)"
-        )
-    interpret = _check_pallas_cfg(cfg, interpret)
-    seg_lane = _segment_lane_fn(app, cfg, seg_steps)
-
-    # Leaf inventory from the state/program avals.
-    state_avals = jax.eval_shape(
-        lambda k: init_state(app, cfg, k),
-        jax.ShapeDtypeStruct((2,), jnp.uint32),
-    )
-    state_leaves, state_def = jax.tree_util.tree_flatten(state_avals)
-    e, w = cfg.max_external_ops, cfg.msg_width
-    prog_leaf_shapes = [(e,), (e,), (e,), (e, w)]
-    bl = block_lanes
-
-    kernel_idx = [
-        i for i, leaf in enumerate(state_leaves) if np.prod(leaf.shape) > 0
-    ]
-    passthrough_idx = [
-        i for i in range(len(state_leaves)) if i not in kernel_idx
-    ]
-    leaf_dtypes = [state_leaves[i].dtype for i in kernel_idx]
-
-    def _wire_dtype(dt):
-        return jnp.int32 if dt == jnp.bool_ else dt
-
-    in_structs = [
-        jax.ShapeDtypeStruct(
-            (bl,) + tuple(state_leaves[i].shape), _wire_dtype(state_leaves[i].dtype)
-        )
-        for i in kernel_idx
-    ]
-    in_structs += [
-        jax.ShapeDtypeStruct((bl,) + shape, jnp.int32)
-        for shape in prog_leaf_shapes
-    ]
-    in_structs.append(jax.ShapeDtypeStruct((bl,), jnp.int32))
-    n_state = len(kernel_idx)
-
-    def _rebuild_state(flat_kernel, batch: int):
-        leaves = [None] * len(state_leaves)
-        for i, val in zip(kernel_idx, flat_kernel):
-            leaves[i] = val
-        for i in passthrough_idx:
-            aval = state_leaves[i]
-            leaves[i] = jnp.zeros((batch,) + tuple(aval.shape), aval.dtype)
-        return jax.tree_util.tree_unflatten(state_def, leaves)
-
-    def block_fn(*flat):
-        state_flat = [
-            v.astype(dt) for v, dt in zip(flat[:n_state], leaf_dtypes)
-        ]
-        op, a, b, msg = flat[n_state : n_state + 4]
-        steps_run = flat[n_state + 4]
-        state = _rebuild_state(state_flat, bl)
-        out = jax.vmap(seg_lane)(
-            state, ExtProgram(op=op, a=a, b=b, msg=msg), steps_run
-        )
-        out_flat = jax.tree_util.tree_leaves(out)
-        return tuple(
-            out_flat[i].astype(_wire_dtype(state_leaves[i].dtype))
-            for i in kernel_idx
-        )
-
-    blocked = _make_blocked_kernel(block_fn, in_structs, bl, interpret)
-
-    def call(state: ScheduleState, progs: ExtProgram, steps_run):
-        batch = steps_run.shape[0]
-        flat = jax.tree_util.tree_leaves(state)
-        ins = [
-            flat[i].astype(_wire_dtype(state_leaves[i].dtype))
-            for i in kernel_idx
-        ]
-        ins += [progs.op, progs.a, progs.b, progs.msg]
-        ins.append(steps_run.astype(jnp.int32))
-        outs = blocked(*ins)
-        outs = [v.astype(dt) for v, dt in zip(outs, leaf_dtypes)]
-        return _rebuild_state(outs, batch)
-
-    if mesh is None:
-        return jax.jit(call)
-
-    from jax.sharding import PartitionSpec as P
-
-    lane = P(axis)
-    spec = jax.tree_util.tree_map(lambda _: lane, state_avals)
-    prog_spec = ExtProgram(op=lane, a=lane, b=lane, msg=lane)
-    smapped = jax.shard_map(
-        call,
-        mesh=mesh,
-        in_specs=(spec, prog_spec, lane),
-        out_specs=spec,
-        # pallas_call outputs carry no varying-mesh-axes annotation;
-        # lanes are fully independent, nothing is replicated.
-        check_vma=False,
-    )
-    sharding = _lane_sharding(mesh, axis)
-
-    def sharded_call(state, progs, steps_run):
-        out = smapped(state, progs, steps_run)
-        # Zero-size passthrough leaves (the disabled trace buffer) fall
-        # out of shard_map replicated; re-constrain the whole tree so the
-        # strictly-sharded refill/finalize jits accept it.
-        return jax.lax.with_sharding_constraint(out, sharding)
-
-    return jax.jit(sharded_call)
 
 
 def _ready(array) -> bool:
@@ -315,9 +180,7 @@ class ContinuousSweepDriver:
         batch: int = 256,
         seg_steps: int = 32,
         key_fn: Optional[Callable] = None,
-        impl: str = "xla",
         mesh=None,
-        block_lanes: int = 128,
         program_key: Optional[Callable] = None,
         seed_pure: bool = False,
     ):
@@ -358,13 +221,7 @@ class ContinuousSweepDriver:
         self._lower_program = lower_program
         self._stack = stack_programs
         self._count_ops = count_ops
-        impl = resolve_impl(impl, cfg, "ContinuousSweepDriver")
-        if impl == "pallas":
-            self.segment = make_segment_kernel_pallas(
-                app, cfg, seg_steps, block_lanes=block_lanes, mesh=mesh
-            )
-        else:
-            self.segment = make_segment_kernel(app, cfg, seg_steps, mesh=mesh)
+        self.segment = make_segment_kernel(app, cfg, seg_steps, mesh=mesh)
         self.mesh = mesh
         self.init = make_init_kernel(app, cfg, mesh=mesh)
         self.refill = make_refill_kernel(app, cfg, mesh=mesh)

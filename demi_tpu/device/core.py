@@ -146,9 +146,7 @@ class DeviceConfig:
     # uint32 words, cutting the one-hot compare cost by ~32x (the cut
     # matrix, once the largest of them, is no longer read there: cut
     # links drop). Opt-in TPU lever (bit-identical; parity-pinned in
-    # tests/test_device.py; ranked by bench_matrix): the shift/mask ops
-    # are XLA-validated but their Mosaic lowering is not, so the pallas
-    # backends reject it.
+    # tests/test_device.py; ranked by bench_matrix).
     packed_gathers: bool = False
 
     def __post_init__(self):
@@ -433,8 +431,8 @@ def insert_rows(
     )  # i-th valid row wants want[i]-th free slot
     # slot index for each row: first index where prefix == want[i] and free
     slots = ops.rank_slots(prefix, want, cfg.use_onehot)  # [K]
-    # Totals as reductions, not prefix[-1]/want[-1] reads: trailing-element
-    # gathers have no Mosaic lowering (bit-identical either way).
+    # Totals as reductions, not prefix[-1]/want[-1] reads (bit-identical
+    # either way).
     n_free = jnp.sum(free.astype(jnp.int32))
     n_rows = jnp.sum(row_valid.astype(jnp.int32))
     overflow = jnp.any(row_valid & (want > n_free))

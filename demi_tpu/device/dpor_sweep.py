@@ -133,7 +133,7 @@ def make_prescribed_dispatch(app: DSLApp, cfg: DeviceConfig):
 def make_dpor_run_lane(app: DSLApp, cfg: DeviceConfig):
     """Unjitted single-lane DPOR sweep ``run_lane(prog, prescription, key,
     start_state=None) -> LaneResult`` (composable with vmap/jit by callers
-    — the XLA kernel below and the pallas twin in pallas_explore.py).
+    — the kernel below and parallel/mesh.py's sharded twin).
     cfg must have record_trace and record_parents on.
 
     Dispatch follows the prescription while records match (absent records
@@ -752,17 +752,11 @@ class DeviceDPOROracle:
         self.autotune = autotune
         self._async = async_min_enabled(async_min)
         self._double_buffer = double_buffer
-        # Shared kernels (pallas builds its own per-instance closures):
-        # under a mesh every instance's rounds shard over the same
-        # lane-sharded twin.
-        impl = os.environ.get("DEMI_DEVICE_IMPL", "xla")
-        self._kernel = (
-            build_dpor_kernel(
-                app, cfg, mesh=mesh, sleep_cap=self._sleep_kernel_cap,
-                commute_matrix=self._sleep_matrix,
-            )
-            if impl != "pallas"
-            else None
+        # Shared kernels: under a mesh every instance's rounds shard over
+        # the same lane-sharded twin.
+        self._kernel = build_dpor_kernel(
+            app, cfg, mesh=mesh, sleep_cap=self._sleep_kernel_cap,
+            commute_matrix=self._sleep_matrix,
         )
         self._fork_kernel = (
             build_dpor_kernel(
@@ -770,7 +764,7 @@ class DeviceDPOROracle:
                 sleep_cap=self._sleep_kernel_cap,
                 commute_matrix=self._sleep_matrix,
             )
-            if impl != "pallas" and prefix_fork_enabled(prefix_fork)
+            if prefix_fork_enabled(prefix_fork)
             else None
         )
         self._instances: Dict[Tuple, DeviceDPOR] = {}
@@ -1197,7 +1191,6 @@ class DeviceDPOR:
         cfg: DeviceConfig,
         program: Sequence[ExternalEvent],
         batch_size: int = 64,
-        impl: Optional[str] = None,
         mesh=None,
         prefix_fork: Optional[bool] = None,
         fork_bucket: int = 8,
@@ -1246,12 +1239,6 @@ class DeviceDPOR:
                 f"key_mode must be 'position' or 'content', got {key_mode!r}"
             )
         self.key_mode = key_mode
-        impl = impl or os.environ.get("DEMI_DEVICE_IMPL", "xla")
-        if self.sleep is not None and impl == "pallas" and mesh is None:
-            raise ValueError(
-                "sleep sets run on the XLA DPOR kernels (the pallas twin "
-                "does not carry the sleep inputs yet)"
-            )
         if mesh is not None:
             # Frontier rounds sharded over the device mesh (SURVEY.md
             # §2.8: the batch axis covers EVERY batched workload, the
@@ -1259,23 +1246,12 @@ class DeviceDPOR:
             # which must divide over the mesh axis.
             from ..parallel.mesh import LANES
 
-            if impl == "pallas":
-                raise ValueError(
-                    "DeviceDPOR: mesh sharding runs the XLA DPOR kernel; "
-                    "impl='pallas' has no sharded DPOR twin"
-                )
             if batch_size % mesh.shape[LANES]:
                 raise ValueError(
                     f"batch_size {batch_size} must be a multiple of the "
                     f"mesh axis {mesh.shape[LANES]}"
                 )
-        if impl == "pallas":
-            from .pallas_explore import make_dpor_kernel_pallas
-
-            self.kernel = make_dpor_kernel_pallas(
-                app, cfg, block_lanes=min(64, batch_size)
-            )
-        elif kernel is not None:
+        if kernel is not None:
             # A caller-shared kernel (DeviceDPOROracle keeps one per
             # app/cfg/mesh): every fresh DeviceDPOR otherwise jits its own
             # closure, so a DDMin run probing many subsequences would
@@ -1305,11 +1281,6 @@ class DeviceDPOR:
                 make_dpor_prefix_runner,
             )
 
-            if impl == "pallas":
-                raise ValueError(
-                    "DeviceDPOR: prefix-fork trunk/fork lanes run on the "
-                    "XLA DPOR kernel; drop impl='pallas' or prefix_fork"
-                )
             self._fork_kernel = fork_kernel or build_dpor_kernel(
                 app, cfg, mesh=mesh, start_state=True,
                 **self._sleep_kernel_args(),

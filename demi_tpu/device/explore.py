@@ -242,7 +242,7 @@ def make_step_fn(app: DSLApp, cfg: DeviceConfig):
         count = jnp.sum(mask.astype(jnp.int32))
         any_deliverable = count > 0
 
-        key, sub = ops.rng_split(state.rng)  # Mosaic-safe split (pallas)
+        key, sub = ops.rng_split(state.rng)  # == jax.random.split, bit for bit
         if cfg.timer_weight != 1.0:
             # Two-stage choice: class (timer vs message) by weighted counts,
             # then uniform within class (host counterpart: FullyRandom with
@@ -352,10 +352,10 @@ def make_any_step_fn(app: DSLApp, cfg: DeviceConfig):
     return make_step_fn(app, cfg)
 
 
-#: The explore-kernel variant family: backend (xla | pallas) × lane axis
-#: (leading | '-trailing') × loop form ('-ee' = early-exit while_loop) ×
-#: delivery granularity ('-round' = round-delivery mode, whose invariant
-#: checks are round-granularity — semantics-preserving only when
+#: The explore-kernel variant family: lane axis (leading | '-trailing') ×
+#: loop form ('-ee' = early-exit while_loop) × delivery granularity
+#: ('-round' = round-delivery mode, whose invariant checks are
+#: round-granularity — semantics-preserving only when
 #: ``invariant_interval == 0``). These are the names bench.py measures
 #: and the autotuner (demi_tpu/tune) selects among.
 EXPLORE_VARIANTS: Tuple[str, ...] = (
@@ -365,17 +365,16 @@ EXPLORE_VARIANTS: Tuple[str, ...] = (
     "xla-trailing-ee",
     "xla-round-ee",
     "xla-trailing-round-ee",
-    "pallas",
-    "pallas-trailing",
-    "pallas-trailing-ee",
 )
 
 
 def variant_config(cfg: DeviceConfig, name: str) -> DeviceConfig:
     """The DeviceConfig a variant name implies ('-ee' / '-round' are cfg
-    toggles; backend and lane axis are kernel-construction choices)."""
+    toggles; the lane axis is a kernel-construction choice)."""
     import dataclasses
 
+    if name.split("-")[0] != "xla":
+        raise ValueError(f"unknown explore variant {name!r}")
     overrides = {}
     if name.endswith("-ee"):
         overrides["early_exit"] = True
@@ -384,47 +383,15 @@ def variant_config(cfg: DeviceConfig, name: str) -> DeviceConfig:
     return dataclasses.replace(cfg, **overrides) if overrides else cfg
 
 
-def make_explore_kernel_variant(
-    app: DSLApp, cfg: DeviceConfig, name: str, block_lanes: int = 256
-):
+def make_explore_kernel_variant(app: DSLApp, cfg: DeviceConfig, name: str):
     """Build the explore kernel for a named variant — ONE parser for the
     variant grammar, shared by bench.py's measurement matrix and the
     autotuner's calibration reps so the two can never mean different
     kernels by the same name."""
-    base = name.split("-")[0]
-    if base not in ("xla", "pallas"):
-        raise ValueError(f"unknown explore variant {name!r}")
     lane_axis = "trailing" if "-trailing" in name else "leading"
-    k_cfg = variant_config(cfg, name)
-    if base == "pallas":
-        from .pallas_explore import make_explore_kernel_pallas
-
-        # Launch telemetry parity with the XLA builds (which wrap inside
-        # make_explore_kernel): an unwrapped backend would read as zero
-        # launches next to populated lane counters.
-        return _counted_kernel(
-            make_explore_kernel_pallas(
-                app, k_cfg, block_lanes=block_lanes, lane_axis=lane_axis
-            ),
-            name,
-        )
-    return make_explore_kernel(app, k_cfg, lane_axis=lane_axis)
-
-
-def resolve_impl(impl: str, cfg: DeviceConfig, driver: str) -> str:
-    """Backend validation shared by the sweep drivers. A backend the
-    caller asked for and cannot have is an error, never a substitution:
-    round mode is XLA-only (pallas_explore guard)."""
-    if impl not in ("xla", "pallas"):
-        raise ValueError(
-            f"{driver}: impl must be 'xla' or 'pallas', got {impl!r}"
-        )
-    if impl == "pallas" and cfg.round_delivery:
-        raise ValueError(
-            f"{driver}: round_delivery is XLA-only; drop impl='pallas' "
-            "(or round mode)"
-        )
-    return impl
+    return make_explore_kernel(
+        app, variant_config(cfg, name), lane_axis=lane_axis
+    )
 
 
 def _finalize(state: ScheduleState, app, cfg) -> ScheduleState:

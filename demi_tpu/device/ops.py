@@ -35,10 +35,11 @@ def prefix_sum(x: jnp.ndarray, oh: bool) -> jnp.ndarray:
     """Inclusive prefix sum over an int vector.
 
     One-hot mode uses Hillis-Steele shifted adds (log2(n) pad+slice+add
-    rounds): bit-identical to cumsum (integer adds are associative) while
-    avoiding the ``cumsum`` primitive, which has no Mosaic lowering — this
-    keeps the kernels traceable inside Pallas TPU kernels
-    (device/pallas_explore.py)."""
+    rounds) in place of the ``cumsum`` primitive: bit-identical to cumsum
+    (integer adds are associative; tests/test_device.py). The form was
+    chosen for the Pallas twin of the kernels (removed in PR 29; Mosaic
+    lowers no ``cumsum``) and stays because it is the compiled program
+    the chip's numbers are of: replacing it is a measured change."""
     if not oh:
         return jnp.cumsum(x)
     n = x.shape[0]
@@ -50,10 +51,11 @@ def prefix_sum(x: jnp.ndarray, oh: bool) -> jnp.ndarray:
 
 
 def rng_split(key: jnp.ndarray, n: int = 2) -> jnp.ndarray:
-    """``jax.random.split`` replacement that traces to threefry2x32 +
-    iota_2x32_shape instead of the opaque ``random_split`` primitive
-    (unsupported by Mosaic). Bit-identical to jax.random.split for raw
-    uint32 keys (verified in tests/test_pallas.py)."""
+    """``jax.random.split`` for raw uint32 keys, traced to threefry2x32 +
+    iota_2x32_shape instead of the opaque ``random_split`` primitive.
+    Bit-identical to jax.random.split (tests/test_device.py); kept, like
+    ``prefix_sum``'s form, because every lane's RNG stream and the
+    compiled step are built on it."""
     return _prng.threefry_split(key, (n,))
 
 

@@ -123,7 +123,7 @@ def vgather(vec, idx):
 def seg_set(vec, start: int, seg):
     """Functional ``vec[start:start+len(seg)] = seg`` for a STATIC start.
     Static slice + concatenate instead of dynamic_update_slice: under vmap
-    the latter lowers to scatter, which has no Mosaic lowering (pallas)."""
+    the latter lowers to scatter, which XLA serialises on the TPU."""
     return jnp.concatenate([vec[:start], seg, vec[start + seg.shape[0]:]])
 
 

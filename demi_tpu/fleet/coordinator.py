@@ -245,7 +245,7 @@ class FleetCoordinator:
         # and initialises none — on a TPU host the chips stay free for
         # the workers, one process per chip.
         self.dpor = DeviceDPOR(
-            app, cfg, program, batch_size=batch_size, impl="xla",
+            app, cfg, program, batch_size=batch_size,
             kernel=_no_local_kernel,
             prefix_fork=False, double_buffer=False,
             sleep_sets=sleep_obj,

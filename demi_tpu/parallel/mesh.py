@@ -184,40 +184,6 @@ def shard_dpor_sleep_kernel(
     )
 
 
-def shard_explore_kernel_pallas(
-    app: DSLApp,
-    cfg: DeviceConfig,
-    mesh: Mesh,
-    block_lanes: int = 128,
-    axis: str = LANES,
-):
-    """Explore sweep on the pallas backend, lane batch sharded over the
-    mesh via shard_map: each device runs the blocked VMEM-resident kernel
-    on its local lane shard; no collectives inside the sweep (lanes are
-    independent), so throughput scales with chips exactly as the XLA
-    path does."""
-    from ..device.explore import ExtProgram, LaneResult
-    from ..device.pallas_explore import make_explore_kernel_pallas
-
-    kernel = make_explore_kernel_pallas(app, cfg, block_lanes=block_lanes)
-    lane = P(axis)
-    in_specs = (ExtProgram(op=lane, a=lane, b=lane, msg=lane), lane)
-    out_specs = LaneResult(
-        status=lane, violation=lane, deliveries=lane, trace=lane,
-        trace_len=lane, sched_hash=lane,
-    )
-    # pallas_call's out_shape ShapeDtypeStructs carry no varying-mesh-
-    # axes annotation; skip the vma check (lanes are fully independent,
-    # nothing is replicated).
-    return jax.jit(
-        jax.shard_map(
-            lambda progs, keys: kernel(progs, keys),
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    )
-
-
 def pad_batch_to_devices(n: int, mesh: Mesh, axis: str = LANES) -> int:
     """Round a batch size up to a multiple of the mesh axis size."""
     size = mesh.shape[axis]

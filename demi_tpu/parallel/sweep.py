@@ -14,7 +14,6 @@ headline metric against the JVM reference.
 
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence, Tuple
@@ -325,12 +324,10 @@ class SweepDriver:
     ):
         """``variant`` (an ``EXPLORE_VARIANTS`` name, e.g. the autotuner's
         calibrated pick) selects the single-host kernel build: '-ee' /
-        '-round' fold into cfg, lane axis and backend into kernel
-        construction. Round variants coarsen invariant checks to round
-        granularity — callers pass them only when that is
-        semantics-preserving (``invariant_interval == 0``), which is the
-        rule the autotuner itself applies. None keeps the env-selected
-        backend (DEMI_DEVICE_IMPL) on the default build.
+        '-round' fold into cfg, the lane axis into kernel construction.
+        Round variants coarsen invariant checks to round granularity —
+        callers pass them only when that is semantics-preserving (``invariant_interval == 0``), which is the
+        rule the autotuner itself applies. None is the default build.
 
         ``prefix_fork`` (default: the DEMI_PREFIX_FORK env switch) makes
         the CHUNKED dispatch path group a chunk's lanes by shared
@@ -343,7 +340,7 @@ class SweepDriver:
         single-slice default) refill mid-flight and keep their own
         compaction; forking applies to run_chunk / sweep(mode='chunked')
         / sweep_async / sweep_autotuned."""
-        from ..device.explore import resolve_impl, variant_config
+        from ..device.explore import variant_config
 
         if variant is not None:
             cfg = variant_config(cfg, variant)
@@ -351,49 +348,22 @@ class SweepDriver:
         self.cfg = cfg
         self.program_gen = program_gen
         self.variant = variant
-        impl = resolve_impl(
-            variant.split("-")[0]
-            if variant is not None
-            else os.environ.get("DEMI_DEVICE_IMPL", "xla"),
-            cfg,
-            "SweepDriver",
-        )
-        self.impl = impl
-        # The mesh/pallas builds are wrapped in _counted_kernel here for
-        # launch-telemetry parity: make_explore_kernel (XLA) and
-        # make_explore_kernel_variant wrap their own, but the sharded
-        # and plain-pallas constructors don't.
-        from ..device.explore import _counted_kernel
-
         if use_mesh:
+            # make_explore_kernel and make_explore_kernel_variant count
+            # their own launches; the sharded constructor does not.
+            from ..device.explore import _counted_kernel
+
             self.mesh = mesh or make_mesh()
-            if impl == "pallas":
-                from .mesh import shard_explore_kernel_pallas
-
-                self.kernel = _counted_kernel(
-                    shard_explore_kernel_pallas(app, cfg, self.mesh),
-                    "explore-mesh-pallas",
-                )
-            else:
-                self.kernel = _counted_kernel(
-                    shard_explore_kernel(app, cfg, self.mesh),
-                    "explore-mesh",
-                )
+            self.kernel = _counted_kernel(
+                shard_explore_kernel(app, cfg, self.mesh), "explore-mesh"
+            )
             self._align = self.mesh.shape[LANES]
-        elif variant is not None:
-            from ..device.explore import make_explore_kernel_variant
-
-            self.mesh = None
-            self.kernel = make_explore_kernel_variant(app, cfg, variant)
-            self._align = 1
         else:
             self.mesh = None
-            if impl == "pallas":
-                from ..device.pallas_explore import make_explore_kernel_pallas
+            if variant is not None:
+                from ..device.explore import make_explore_kernel_variant
 
-                self.kernel = _counted_kernel(
-                    make_explore_kernel_pallas(app, cfg), "explore-pallas"
-                )
+                self.kernel = make_explore_kernel_variant(app, cfg, variant)
             else:
                 self.kernel = make_explore_kernel(app, cfg)
             self._align = 1
@@ -433,11 +403,6 @@ class SweepDriver:
                 make_explore_prefix_runner,
             )
 
-            if self.impl == "pallas":
-                raise ValueError(
-                    "SweepDriver: prefix-fork trunk/fork lanes run on the "
-                    "XLA explore kernel; drop impl='pallas' or prefix_fork"
-                )
             self._fork_kernel = (
                 shard_explore_kernel(app, self.cfg, self.mesh, start_state=True)
                 if self.mesh is not None
@@ -801,12 +766,11 @@ class SweepDriver:
         own slice_index's chunks).
 
         ``mode``: 'continuous' (the default for single-slice sweeps, mesh
-        or not, XLA or pallas) harvests+refills finished lanes at short
-        segment boundaries, so a fixed sweep never pays max_steps for its
+        or not) harvests+refills finished lanes at short segment
+        boundaries, so a fixed sweep never pays max_steps for its
         short lanes (TPU-first lane compaction; per-seed verdicts
         bit-identical to 'chunked' — tests/test_continuous.py). Under a
-        mesh the segment/refill kernels run lane-sharded (pallas: the
-        VMEM-blocked segment inside shard_map); only O(batch) status
+        mesh the segment/refill kernels run lane-sharded; only O(batch) status
         vectors reach the host between segments. 'chunked' launches fixed
         whole-batch kernels; multi-slice sweeps always use it (slices
         partition the seed space — see module docstring)."""
@@ -866,7 +830,6 @@ class SweepDriver:
             self.app, self.cfg, program_gen or self.program_gen,
             batch=batch,
             seg_steps=max(8, min(64, self.cfg.max_steps // 4)),
-            impl=self.impl,
             mesh=self.mesh,
             # Same per-seed key scheme as run_chunk => identical verdicts.
             # No np.uint32() wrapper: the seed must stay traceable so the

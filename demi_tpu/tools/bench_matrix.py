@@ -1,12 +1,12 @@
-"""Kernel benchmark matrix: explore throughput across backends and sizes.
+"""Kernel benchmark matrix: explore throughput across variants and sizes.
 
-For the (scarce) real-TPU windows: one run measures the XLA and pallas
-explore kernels across batch sizes and pallas block sizes on the 5-node
-raft headline workload, printing one JSON line per cell as it goes (so a
-killed run still leaves data).
+For the (scarce) real-TPU windows: one run measures the explore kernel's
+variants across batch sizes on the 5-node raft headline workload,
+printing one JSON line per cell as it goes (so a killed run still leaves
+data).
 
     python -m demi_tpu.tools.bench_matrix
-    python -m demi_tpu.tools.bench_matrix --batches 4096,8192 --blocks 128,256
+    python -m demi_tpu.tools.bench_matrix --batches 4096,8192
 """
 
 from __future__ import annotations
@@ -21,7 +21,6 @@ import time
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--batches", default="2048,8192,16384")
-    p.add_argument("--blocks", default="128,256,512")
     p.add_argument("--reps", type=int, default=3)
     p.add_argument("--msg-dtype", default="int32", dest="msg_dtype",
                    choices=("int32", "int16"))
@@ -33,11 +32,7 @@ def main(argv=None):
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
     from bench import _raft_workload
 
-    from ..device import (
-        DeviceConfig,
-        make_explore_kernel,
-        make_explore_kernel_pallas,
-    )
+    from ..device import DeviceConfig, make_explore_kernel
     from ..device.encoding import lower_program, stack_programs
 
     app, program = _raft_workload()
@@ -62,7 +57,6 @@ def main(argv=None):
         return args.reps * batch / secs, compile_s
 
     batches = [int(x) for x in args.batches.split(",")]
-    blocks = [int(x) for x in args.blocks.split(",")]
     for lane_axis in ("leading", "trailing"):
         for batch in batches:
             tag = "xla" if lane_axis == "leading" else "xla-trailing"
@@ -123,30 +117,6 @@ def main(argv=None):
                 print(json.dumps({
                     "impl": tag, "batch": batch, "error": repr(e)[:300]
                 }), flush=True)
-    for lane_axis in ("leading", "trailing"):
-        for batch in batches:
-            for bl in blocks:
-                if bl > batch:
-                    continue
-                tag = f"pallas-{lane_axis}"
-                try:
-                    sps, comp = measure(
-                        make_explore_kernel_pallas(
-                            app, cfg, block_lanes=bl, lane_axis=lane_axis
-                        ),
-                        batch,
-                    )
-                    print(json.dumps({
-                        "impl": tag, "platform": platform, "batch": batch,
-                        "block_lanes": bl,
-                        "schedules_per_sec": round(sps, 1),
-                        "compile_s": round(comp, 1),
-                    }), flush=True)
-                except Exception as e:
-                    print(json.dumps({
-                        "impl": tag, "batch": batch, "block_lanes": bl,
-                        "error": repr(e)[:300],
-                    }), flush=True)
     # Prefix-fork explore (start_state=): the trunk runs the shared
     # injection prefix once, lanes fork from the snapshot with per-lane
     # rng — results bit-identical to scratch. This column keeps the fork
@@ -226,26 +196,20 @@ def main(argv=None):
         early_exit=True,
     )
     for batch in batches:
-        for tag, build in (
-            ("xla-trailing-ee",
-             lambda: make_explore_kernel(app, ee_cfg, lane_axis="trailing")),
-            ("pallas-trailing-ee",
-             lambda: make_explore_kernel_pallas(
-                 app, ee_cfg, block_lanes=blocks[len(blocks) // 2],
-                 lane_axis="trailing",
-             )),
-        ):
-            try:
-                sps, comp = measure(build(), batch)
-                print(json.dumps({
-                    "impl": tag, "platform": platform, "batch": batch,
-                    "schedules_per_sec": round(sps, 1),
-                    "compile_s": round(comp, 1),
-                }), flush=True)
-            except Exception as e:
-                print(json.dumps({
-                    "impl": tag, "batch": batch, "error": repr(e)[:300],
-                }), flush=True)
+        tag = "xla-trailing-ee"
+        try:
+            sps, comp = measure(
+                make_explore_kernel(app, ee_cfg, lane_axis="trailing"), batch
+            )
+            print(json.dumps({
+                "impl": tag, "platform": platform, "batch": batch,
+                "schedules_per_sec": round(sps, 1),
+                "compile_s": round(comp, 1),
+            }), flush=True)
+        except Exception as e:
+            print(json.dumps({
+                "impl": tag, "batch": batch, "error": repr(e)[:300],
+            }), flush=True)
 
     # Config-5 fixture pair (64-actor reliable flood, P=4608): the
     # per-delivery step cost is pool-linear, so this is where round

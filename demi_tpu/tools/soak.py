@@ -77,8 +77,8 @@ def main(argv=None) -> int:
     p.add_argument("--seconds", type=float, default=600.0)
     p.add_argument("--rounds", type=int, default=None,
                    help="stop after N rounds instead of --seconds")
-    p.add_argument("--variants", default="xla,pallas,mesh",
-                   help="comma list: xla, pallas, mesh, mesh-pallas")
+    p.add_argument("--variants", default="xla,mesh",
+                   help="comma list: xla, mesh")
     p.add_argument("--lanes", type=int, default=24)
     p.add_argument("--seed", type=int, default=20260730)
     p.add_argument(
@@ -119,21 +119,13 @@ def main(argv=None) -> int:
     from ..fuzzing import Fuzzer
     from ..parallel.mesh import make_mesh
 
-    variant_kw = {
-        "xla": dict(),
-        "pallas": dict(impl="pallas", block_lanes=4),
-        "mesh": dict(mesh=None),  # filled below (mesh built lazily)
-        "mesh-pallas": dict(impl="pallas", block_lanes=1, mesh=None),
-    }
+    variant_kw = {"xla": {}, "mesh": {}}  # mesh: built only if asked for
     names = [v.strip() for v in args.variants.split(",") if v.strip()]
     for v in names:
         if v not in variant_kw:
             raise SystemExit(f"unknown variant {v!r}")
-    if any(v.startswith("mesh") for v in names):
-        mesh = make_mesh()
-        for v in names:
-            if v.startswith("mesh"):
-                variant_kw[v]["mesh"] = mesh
+    if "mesh" in names:
+        variant_kw["mesh"]["mesh"] = make_mesh()
 
     rng = np.random.RandomState(args.seed)
     rounds = 0

@@ -2,7 +2,7 @@
 violating lane → traced re-run → host lift (GuidedScheduler) → DDMin →
 verified MCS.
 
-Run: ``python -m demi_tpu.tools.verify_slice [--impl xla|pallas]``.
+Run: ``python -m demi_tpu.tools.verify_slice``.
 Exits nonzero if any stage fails; prints one status line per stage.
 
 This is the smoke path the verify skill drives; it lives in-repo so it
@@ -17,7 +17,6 @@ import sys
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser()
-    parser.add_argument("--impl", choices=["xla", "pallas"], default="xla")
     parser.add_argument("--lanes", type=int, default=256)
     parser.add_argument(
         "--adapter", action="store_true",
@@ -34,11 +33,7 @@ def main(argv=None) -> int:
     from ..apps.common import dsl_start_events, make_host_invariant
     from ..apps.raft import T_CLIENT, make_raft_app
     from ..config import SchedulerConfig
-    from ..device import (
-        DeviceConfig,
-        make_explore_kernel,
-        make_explore_kernel_pallas,
-    )
+    from ..device import DeviceConfig, make_explore_kernel
     from ..device.core import ST_OVERFLOW, ST_VIOLATION
     from ..device.encoding import lower_program, stack_programs
     from ..external_events import MessageConstructor, Send, WaitQuiescence
@@ -63,17 +58,14 @@ def main(argv=None) -> int:
     ]
 
     B = args.lanes
-    if args.impl == "pallas":
-        kernel = make_explore_kernel_pallas(app, cfg, block_lanes=64)
-    else:
-        kernel = make_explore_kernel(app, cfg)
+    kernel = make_explore_kernel(app, cfg)
     progs = stack_programs([lower_program(app, cfg, program)] * B)
     keys = jax.random.split(jax.random.PRNGKey(0), B)
     res = kernel(progs, keys)
     st = np.asarray(res.status)
     assert int((st == ST_OVERFLOW).sum()) == 0, "pool overflow: raise pool_capacity"
     lanes = np.flatnonzero(st == ST_VIOLATION)
-    print(f"[1/5] {args.impl} sweep: {len(lanes)} violating of {B} lanes")
+    print(f"[1/5] sweep: {len(lanes)} violating of {B} lanes")
     assert len(lanes) > 0, "sweep found no violation"
 
     lane = int(lanes[0])

@@ -121,7 +121,7 @@ def coordinate_descent(
 
 
 def sweep_axes(
-    cfg, chunk: int, platform: str, continuous: bool = False
+    cfg, chunk: int, continuous: bool = False
 ) -> Dict[str, List[Any]]:
     """Candidate axes for a sweep on this workload.
 
@@ -129,16 +129,14 @@ def sweep_axes(
     and early-exit change nothing observable; round-delivery coarsens
     invariant checks to round granularity, so it is only a candidate
     when ``invariant_interval == 0`` (checks only at quiescence — same
-    verdicts either way, the bench config-5 equivalence). Pallas is
-    excluded on CPU (interpret mode is an emulation, not a measurement).
+    verdicts either way, the bench config-5 equivalence).
     The ``seg`` (segment length) axis only exists for continuous drivers;
     a chunked launch has no segment knob."""
     from ..device.explore import EXPLORE_VARIANTS
 
     variants = [
         v for v in EXPLORE_VARIANTS
-        if (cfg.invariant_interval == 0 or "-round" not in v)
-        and (platform != "cpu" or not v.startswith("pallas"))
+        if cfg.invariant_interval == 0 or "-round" not in v
     ]
     axes: Dict[str, List[Any]] = {
         "variant": variants,
@@ -232,7 +230,7 @@ def calibrate_sweep(
         _record_sweep_decision(decision)
         return decision
 
-    axes = dict(axes) if axes is not None else sweep_axes(cfg, chunk, platform)
+    axes = dict(axes) if axes is not None else sweep_axes(cfg, chunk)
     defaults = {
         "variant": "xla",
         "chunk": chunk,

@@ -22,7 +22,7 @@ if "xla_force_host_platform_device_count" not in flags:
 # The tier-1 gate (ROADMAP.md) runs this suite under a hard wall-clock cap,
 # and the full suite is slower than the cap on small CPU boxes — whatever
 # runs last gets truncated. Alphabetical order put the kernel-compiling
-# device/pallas/continuous modules mid-run, so a timeout used to cut the
+# device/continuous modules mid-run, so a timeout used to cut the
 # *breadth* tests behind them. Scheduling the dozens of fast host-tier
 # modules first makes a truncation cost the fewest tests: the expensive
 # kernel-parity modules run at the end, each still whole (module fixtures
@@ -50,7 +50,6 @@ _HEAVY_TEST_MODULES = {
     "test_device_srcdst": 5,
     "test_device_dpor": 6,
     "test_device": 6,
-    "test_pallas": 6,
     "test_continuous": 6,
     # Subprocess-heavy (each fleet run spawns worker processes that
     # import jax + compile): last, so a tier-1 time-cap truncation cuts

@@ -22,7 +22,7 @@ def _run_bench(config: str, env_extra: dict) -> dict:
     # The smoke must measure the DEFAULT paths: strip switches that would
     # change kernels or output keys.
     for var in ("DEMI_OBS", "DEMI_AUTOTUNE", "DEMI_PREFIX_FORK",
-                "DEMI_ASYNC_MIN", "DEMI_DEVICE_IMPL", "DEMI_BENCH_IMPL",
+                "DEMI_ASYNC_MIN", "DEMI_BENCH_IMPL",
                 "DEMI_STATIC_PRUNE", "DEMI_SANITIZE", "DEMI_SLEEP_SETS"):
         env.pop(var, None)
     out = subprocess.run(

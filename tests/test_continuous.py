@@ -164,22 +164,6 @@ def _broadcast_fixture():
     return app, cfg, lambda s: fz.generate_fuzz_test(seed=s)
 
 
-def test_continuous_pallas_matches_xla_segment():
-    """The pallas (interpret-mode) segment kernel is bit-identical to the
-    XLA segment path: same verdicts per seed, including budget-exhausted
-    finalization."""
-    app, cfg, gen = _broadcast_fixture()
-    xla = ContinuousSweepDriver(app, cfg, gen, batch=8, seg_steps=16)
-    pls = ContinuousSweepDriver(
-        app, cfg, gen, batch=8, seg_steps=16, impl="pallas", block_lanes=4
-    )
-    st_x, vio_x = xla.sweep(24)
-    st_p, vio_p = pls.sweep(24)
-    assert st_x == st_p
-    assert vio_x == vio_p
-    assert any(vio_p.values())
-
-
 def test_continuous_mesh_parity():
     """Lane-sharded continuous refill over the 8-device mesh: per-seed
     verdicts identical to the unsharded driver, occupancy accounting
@@ -201,30 +185,10 @@ def test_continuous_mesh_parity():
     assert sharded.last_occupancy is not None
 
 
-def test_continuous_mesh_pallas_parity():
-    """shard_map around the VMEM-blocked pallas segment: same verdicts as
-    the plain XLA driver."""
-    from demi_tpu.parallel.mesh import make_mesh
-
-    app, cfg, gen = _broadcast_fixture()
-    mesh = make_mesh()
-    plain = ContinuousSweepDriver(app, cfg, gen, batch=8, seg_steps=16)
-    sharded = ContinuousSweepDriver(
-        app, cfg, gen, batch=8, seg_steps=16, impl="pallas", block_lanes=1,
-        mesh=mesh,
-    )
-    st_a, vio_a = plain.sweep(16)
-    st_b, vio_b = sharded.sweep(16)
-    assert st_a == st_b
-    assert vio_a == vio_b
-
-
-def test_sweep_driver_continuous_under_mesh_and_pallas():
-    """SweepDriver end-to-end: continuous mode is now the default for
-    mesh-sharded and pallas drivers too, with verdict parity against the
-    chunked path."""
-    import os
-
+def test_sweep_driver_continuous_under_mesh():
+    """SweepDriver end-to-end: continuous mode is the default for
+    mesh-sharded drivers too, with verdict parity against the chunked
+    path."""
     from demi_tpu.parallel.sweep import SweepDriver
 
     app, cfg, gen = _broadcast_fixture()
@@ -236,16 +200,6 @@ def test_sweep_driver_continuous_under_mesh_and_pallas():
     assert cont.violations == chunked.violations
     assert cont.codes == chunked.codes
     assert cont.unique_schedules == chunked.unique_schedules
-
-    os.environ["DEMI_DEVICE_IMPL"] = "pallas"
-    try:
-        driver_p = SweepDriver(app, cfg, gen)
-        cont_p = driver_p.sweep(24, 8)
-        assert cont_p.occupancy is not None
-        assert cont_p.violations == chunked.violations
-        assert cont_p.codes == chunked.codes
-    finally:
-        del os.environ["DEMI_DEVICE_IMPL"]
 
 
 def test_sweep_async_non_blocking_explore():

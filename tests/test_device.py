@@ -126,9 +126,9 @@ def test_traced_lane_lifts_to_host_and_agrees():
         assert host_code == int(res.violation[lane])
 
 
-def test_replay_kernel_matches_host_sts_oracle():
-    """Lower DDMin-style candidates and compare device replay verdicts with
-    the host STS oracle."""
+def _hand_built_candidates():
+    """One violating 3-node broadcast execution and four DDMin-style
+    candidates whose verdicts are known by hand."""
     app = make_broadcast_app(3, reliable=False)
     config = SchedulerConfig(invariant_check=make_host_invariant(app))
     starts = dsl_start_events(app)
@@ -136,17 +136,77 @@ def test_replay_kernel_matches_host_sts_oracle():
     program = starts + [s0, s1, WaitQuiescence()]
     result = RandomScheduler(config, seed=3).execute(program)
     assert result.violation is not None
-
     cfg = DeviceConfig.for_app(app, pool_capacity=64, max_steps=64, max_external_ops=8)
-    kernel = make_replay_kernel(app, cfg)
-    oracle = sts_oracle(config, result.trace)
-
     candidates = [
         program,  # full
         starts + [s0, WaitQuiescence()],  # drop second send
         starts[:2] + [s0, WaitQuiescence()],  # drop third actor + second send
         starts[:1] + [s0, WaitQuiescence()],  # single actor: no disagreement
     ]
+    return app, config, cfg, result, candidates, [True, True, True, False]
+
+
+def _corpus_candidates(name, peek):
+    """The first violating execution of tests/test_differential.py's
+    corpus for ``name``, lifted to the host, and every candidate that
+    removes one of its externals (a DDMin level's shape), replayed with
+    ``replay_peek=peek`` on the device and the same peek on the host."""
+    import dataclasses
+
+    from helpers import lift_lane_to_host
+    from test_differential import CASES
+
+    app, cfg, fz = CASES[name][0]()
+    config = SchedulerConfig(invariant_check=make_host_invariant(app))
+    traced = make_single_lane_trace_kernel(app, cfg)
+    for seed in range(16):
+        prog = lower_program(app, cfg, fz.generate_fuzz_test(seed=seed))
+        key = jax.random.PRNGKey(seed)
+        if int(traced(prog, key).violation) != 0:
+            break
+    else:
+        raise AssertionError(f"{name}: no violating lane in 16 seeds")
+    progs1 = jax.tree_util.tree_map(lambda x: np.asarray(x)[None], prog)
+    _, host = lift_lane_to_host(app, cfg, progs1, key[None], 0, config)
+    assert host.violation is not None
+    # The lifted trace's own externals: the program's objects never ran
+    # in this trace (runner.py).
+    externals = list(host.trace.original_externals)
+    candidates = [externals] + [
+        externals[:i] + externals[i + 1:] for i in range(len(externals))
+    ]
+    return (
+        app, config, dataclasses.replace(cfg, replay_peek=peek), host,
+        candidates, None,
+    )
+
+
+REPLAY_CASES = {
+    "hand-built": _hand_built_candidates,
+    **{
+        f"{name}-peek{peek}": (
+            lambda name=name, peek=peek: _corpus_candidates(name, peek)
+        )
+        for name, peeks in (
+            ("raft-faults", (0, 2)), ("broadcast-faults", (0, 2)),
+            ("twopc-faults", (0,)),
+        )
+        for peek in peeks
+    },
+}
+
+
+@pytest.mark.parametrize("case", list(REPLAY_CASES))
+def test_replay_kernel_matches_host_sts_oracle(case):
+    """Lower DDMin-style candidates and compare device replay verdicts with
+    the host STS oracle."""
+    app, config, cfg, result, candidates, known = REPLAY_CASES[case]()
+    kernel = make_replay_kernel(app, cfg)
+    oracle = sts_oracle(
+        config, result.trace,
+        allow_peek=cfg.replay_peek > 0, max_peek_messages=cfg.replay_peek,
+    )
+    max_records = cfg.max_steps + cfg.max_external_ops
     records = np.stack(
         [
             lower_expected_trace(
@@ -156,19 +216,22 @@ def test_replay_kernel_matches_host_sts_oracle():
                 .filter_checkpoint_messages()
                 .subsequence_intersection(c),
                 c,
-                max_records=64,
+                max_records=max_records,
             )
             for c in candidates
         ]
     )
     keys = jax.random.split(jax.random.PRNGKey(0), len(candidates))
     res = kernel(records, keys)
-    device_verdicts = [int(v) == 1 for v in res.violation]
+    code = result.violation.code
+    device_verdicts = [int(v) == code for v in res.violation]
     host_verdicts = [
         oracle.test(c, result.violation) is not None for c in candidates
     ]
     assert device_verdicts == host_verdicts
-    assert device_verdicts == [True, True, True, False]
+    assert device_verdicts[0], "the full execution must reproduce"
+    if known is not None:
+        assert device_verdicts == known
 
 
 def test_pool_overflow_flags_lane():
@@ -326,6 +389,31 @@ def test_index_mode_parity_explore_and_replay():
             np.asarray(getattr(out["scatter"], field)),
             np.asarray(getattr(out["onehot"], field)),
         ), f"replay {field}"
+
+
+def test_rng_split_bit_identical():
+    """ops.rng_split must match jax.random.split exactly: every lane's
+    schedule stream is drawn through it."""
+    from demi_tpu.device.ops import rng_split
+
+    key = jax.random.PRNGKey(1234)
+    for n in (2, 3, 5):
+        assert np.array_equal(
+            np.asarray(jax.random.split(key, n)), np.asarray(rng_split(key, n))
+        )
+
+
+def test_prefix_sum_matches_cumsum():
+    import jax.numpy as jnp
+
+    from demi_tpu.device.ops import prefix_sum
+
+    rng = np.random.default_rng(0)
+    for n in (1, 2, 7, 96, 100):
+        x = jnp.asarray(rng.integers(0, 5, n), jnp.int32)
+        assert np.array_equal(
+            np.asarray(prefix_sum(x, True)), np.cumsum(np.asarray(x))
+        )
 
 
 def test_int16_msg_storage_parity():

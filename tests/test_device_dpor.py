@@ -2,6 +2,7 @@
 exploration."""
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from demi_tpu.apps.common import dsl_start_events
@@ -204,13 +205,76 @@ def test_incremental_ddmin_with_device_oracle():
     assert len(kept) < len(program)
 
 
-def test_device_dpor_pallas_backend_finds_reversal():
-    """DeviceDPOR on the pallas kernel (impl='pallas'): the systematic
-    frontier search finds the 1/k!-rare reversal just like the XLA path."""
-    app, cfg, program = _setup(4)
-    dpor = DeviceDPOR(app, cfg, program, batch_size=8, impl="pallas")
-    found = dpor.explore(target_code=1, max_rounds=40)
-    assert found is not None, "pallas DPOR sweep missed the reversal"
+def _raft3_multivote():
+    from demi_tpu.apps.raft import make_raft_app
+
+    app = make_raft_app(3, bug="multivote")
+    cfg = DeviceConfig.for_app(
+        app, pool_capacity=96, max_steps=96, max_external_ops=16,
+        invariant_interval=1, timer_weight=0.2, record_trace=True,
+        record_parents=True,
+    )
+    return app, cfg, dsl_start_events(app) + [WaitQuiescence()]
+
+
+def _twopc4_presume_commit():
+    from demi_tpu.apps.twopc import T_BEGIN, make_twopc_app
+
+    app = make_twopc_app(4, bug="presume_commit")
+    cfg = DeviceConfig.for_app(
+        app, pool_capacity=64, max_steps=64, max_external_ops=8,
+        invariant_interval=1, timer_weight=0.1, record_trace=True,
+        record_parents=True,
+    )
+    return app, cfg, dsl_start_events(app) + [
+        Send(app.actor_name(0), MessageConstructor(lambda: (T_BEGIN, 1, 0))),
+        WaitQuiescence(),
+    ]
+
+
+@pytest.mark.parametrize(
+    "setup,rounds",
+    [
+        (lambda: _setup(4), 3), (_raft3_multivote, 2),
+        (_twopc4_presume_commit, 2),
+    ],
+    ids=["reversal", "raft3-multivote", "twopc4-presume-commit"],
+)
+def test_every_dpor_lane_lifts_to_the_host_code_for_code(setup, rounds):
+    """The DPOR kernel against the host oracle: every lane of a round
+    (prescribed prefix, then the explore step's own choices; padding
+    lanes too) is a schedule the host delivers without divergence and
+    judges with the same code."""
+    from demi_tpu.apps.common import make_host_invariant
+    from demi_tpu.config import SchedulerConfig
+    from demi_tpu.device.encoding import device_trace_to_guide
+    from demi_tpu.schedulers.guided import GuidedScheduler
+
+    app, cfg, program = setup()
+    config = SchedulerConfig(invariant_check=make_host_invariant(app))
+    dpor = DeviceDPOR(app, cfg, program, batch_size=16)
+    harvested = []
+    harvest = dpor._harvest_round
+
+    def keep(parts, batch_len):
+        res = harvest(parts, batch_len)
+        harvested.append(res)
+        return res
+
+    dpor._harvest_round = keep
+    dpor.explore(max_rounds=rounds, stop_on_violation=False)
+    assert len(harvested) == rounds
+    codes = set()
+    for res in harvested:
+        traces, lens = np.asarray(res.trace), np.asarray(res.trace_len)
+        violation = np.asarray(res.violation)
+        for lane in range(len(lens)):
+            guide = device_trace_to_guide(app, traces[lane], int(lens[lane]))
+            host = GuidedScheduler(config, app).execute_guide(guide)
+            host_code = host.violation.code if host.violation else 0
+            assert host_code == int(violation[lane]), lane
+            codes.add(host_code)
+    assert 0 in codes and len(codes) > 1, "clean and violating lanes both"
 
 
 def test_device_racing_scan_matches_host_dpor_racing_set():

@@ -40,40 +40,6 @@ def test_sharded_explore_matches_single_device():
     )
 
 
-@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs multi-device mesh")
-def test_sharded_pallas_explore_matches_single_device():
-    """The pallas backend composes with the mesh (shard_map over lanes):
-    per-lane results identical to the unsharded XLA kernel."""
-    from demi_tpu.apps.broadcast import make_broadcast_app
-    from demi_tpu.apps.common import dsl_start_events
-    from demi_tpu.device import DeviceConfig, make_explore_kernel
-    from demi_tpu.device.encoding import lower_program, stack_programs
-    from demi_tpu.external_events import MessageConstructor, Send, WaitQuiescence
-    from demi_tpu.parallel.mesh import make_mesh, shard_explore_kernel_pallas
-
-    app = make_broadcast_app(3, reliable=False)
-    cfg = DeviceConfig.for_app(app, pool_capacity=32, max_steps=32, max_external_ops=8)
-    program = dsl_start_events(app) + [
-        Send(app.actor_name(0), MessageConstructor(lambda: (1, 0))),
-        WaitQuiescence(),
-    ]
-    n = len(jax.devices())
-    batch = 4 * n
-    progs = stack_programs([lower_program(app, cfg, program)] * batch)
-    keys = jax.random.split(jax.random.PRNGKey(0), batch)
-
-    single = make_explore_kernel(app, cfg)(progs, keys)
-    mesh = make_mesh()
-    sharded = shard_explore_kernel_pallas(app, cfg, mesh, block_lanes=2)(
-        progs, keys
-    )
-    for field in ("status", "violation", "deliveries"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(single, field)),
-            np.asarray(getattr(sharded, field)),
-        )
-
-
 def _bad_fixture():
     from demi_tpu.apps.broadcast import make_broadcast_app
     from demi_tpu.apps.common import dsl_start_events

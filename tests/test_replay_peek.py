@@ -125,28 +125,3 @@ def test_replay_peek_rolls_back_on_failure():
     assert int(peeky.deliveries[0]) == int(plain.deliveries[0])
     assert int(peeky.violation[0]) == int(plain.violation[0])
     assert int(peeky.ignored_absent[0]) == int(plain.ignored_absent[0])
-
-
-def test_replay_peek_pallas_parity():
-    """Interpret-mode pallas replay with peek matches the XLA kernel."""
-    import dataclasses
-
-    from demi_tpu.device.pallas_explore import make_replay_kernel_pallas
-
-    app, config, program, doctored, full_deliveries = _doctored_fixture()
-    base = DeviceConfig.for_app(
-        app, pool_capacity=64, max_steps=64, max_external_ops=8,
-        replay_peek=3,
-    )
-    records = np.stack(
-        [lower_expected_trace(app, base, doctored, program, max_records=64)]
-        * 4
-    )
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    xla = make_replay_kernel(app, base)(records, keys)
-    pls = make_replay_kernel_pallas(app, base, block_lanes=2)(records, keys)
-    for field in ("status", "violation", "deliveries", "ignored_absent",
-                  "peeked"):
-        assert np.array_equal(
-            np.asarray(getattr(xla, field)), np.asarray(getattr(pls, field))
-        ), field

@@ -316,7 +316,7 @@ def test_bench_config14_smoke():
     bar needs the default deep shapes, so strict is off here."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     for var in ("DEMI_OBS", "DEMI_AUTOTUNE", "DEMI_PREFIX_FORK",
-                "DEMI_ASYNC_MIN", "DEMI_DEVICE_IMPL", "DEMI_BENCH_IMPL",
+                "DEMI_ASYNC_MIN", "DEMI_BENCH_IMPL",
                 "DEMI_STATIC_PRUNE", "DEMI_SANITIZE", "DEMI_SLEEP_SETS"):
         env.pop(var, None)
     env.update({

@@ -31,7 +31,7 @@ def test_bench_config17_smoke():
         DEMI_BENCH_CONFIG17_STRICT="0",
     )
     for var in ("DEMI_OBS", "DEMI_AUTOTUNE", "DEMI_PREFIX_FORK",
-                "DEMI_ASYNC_MIN", "DEMI_DEVICE_IMPL", "DEMI_BENCH_IMPL",
+                "DEMI_ASYNC_MIN", "DEMI_BENCH_IMPL",
                 "DEMI_STATIC_PRUNE", "DEMI_SANITIZE", "DEMI_SLEEP_SETS"):
         env.pop(var, None)
     out = subprocess.run(
