@@ -10,12 +10,12 @@ checkpoint-based invariant signature (externals, {name -> CheckpointReply})
 from __future__ import annotations
 
 import random as _random
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..dsl import DSLApp
-from ..external_events import MessageConstructor, Send, Start
+from ..external_events import Send, Start, constant_message
 from ..minimization.test_oracle import IntViolation
 from ..runtime.actor import dsl_actor_factory
 
@@ -89,12 +89,21 @@ class DSLSendGenerator:
     def reset(self) -> None:
         self._counter = 0
 
-    def generate(self, rng: _random.Random, alive: Sequence[str]) -> Optional[Send]:
+    def generate_row(
+        self, rng: _random.Random, alive: Sequence[str]
+    ) -> Optional[Tuple[str, tuple]]:
+        """The send as the fuzzer records it: (target name, payload)."""
         if not alive:
             return None
         self._counter += 1
         msg = self.make_msg(rng, self._counter)
         if msg is None:
             return None
-        target = rng.choice(list(alive))
-        return Send(target, MessageConstructor(lambda m=msg: m))
+        return rng.choice(list(alive)), msg
+
+    def generate(self, rng: _random.Random, alive: Sequence[str]) -> Optional[Send]:
+        row = self.generate_row(rng, alive)
+        if row is None:
+            return None
+        target, msg = row
+        return Send(target, constant_message(msg))

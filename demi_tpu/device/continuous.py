@@ -15,7 +15,7 @@ and refill replaces whole lanes atomically (tests/test_continuous.py).
 
 One round of the harvest loop (``_run_batches``), in order: dispatch the
 segment (asynchronous) -> MAKE AHEAD -> status pull (the sync point) ->
-harvest -> fill -> stack -> refill. Which lane gets which program is
+harvest -> fill -> refill. Which lane gets which program is
 decided after the harvest, but what the coming programs are is a
 function of ``seed_list[next_idx:]`` alone, so between the dispatch and
 the pull the one host thread fuzzes and lowers them into a stock, in seed
@@ -24,7 +24,19 @@ segment's result is ready (asked once a program); the stock holds as
 many programs as lanes are active (no round can refill more, so the
 host never holds more than one resident set); the call's seeds are used
 up. The fill hands out the stock first and makes the rest on the spot,
-so lane, seed, program and key pair up exactly as without it. The stock
+so lane, seed, program and key pair up exactly as without it.
+
+The resident programs are ONE set of host arrays (``op/a/b [b, E]``,
+``msg [b, E, W]``: the ``ExtProgram`` every segment takes), allocated
+once a call and written in place: a fill lowers each program straight
+into its lane's rows (``encoding.lower_into``; a fuzzed program from its
+op rows, with no event object), so a retired program's memory serves
+the next and nothing is stacked. They are written only between a status
+pull and the next dispatch. The stock is a second such block, so what is
+made while the device may still read the resident set touches none of
+it (the CPU backend may alias NumPy memory); the refill copies the
+stock's rows onto the refilled lanes in one indexed assignment per
+array. The stock
 is a local of one ``_run_batches`` call: a caller's generator may change
 between calls (the benchmark's closes over a per-job base), and a
 consumer that stops early just drops it. Only a generator the
@@ -35,8 +47,7 @@ ahead; any other is called at refill, in refill order.
 from __future__ import annotations
 
 import time
-from collections import deque
-from typing import Callable, Deque, List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
@@ -46,6 +57,7 @@ import jax.numpy as jnp
 from .. import obs
 from ..dsl import DSLApp
 from .core import ST_DONE, ST_VIOLATION, DeviceConfig, ScheduleState
+from .encoding import count_op_arrays, empty_programs, lower_into
 from .explore import (
     ExtProgram,
     _finalize,
@@ -165,6 +177,32 @@ def make_finalize_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     return _maybe_shard(jax.vmap(fin), mesh, 1)
 
 
+class _Stock:
+    """Programs made ahead of their refill, in seed order: a ring of
+    ``room`` lowered programs in one block of host arrays, apart from
+    the resident set's. A slot whose program the ``program_key`` memo
+    already held is not made: it keeps its place, ``made`` unset, and
+    the fill looks it up."""
+
+    def __init__(self, cfg: DeviceConfig, room: int):
+        self.progs = empty_programs(cfg, room)
+        self.room = room
+        self.head = 0
+        self.count = 0
+        self.made = np.zeros(room, bool)
+        self.from_rows = np.zeros(room, bool)  # lowered from op rows
+
+    def next_slot(self) -> int:
+        return (self.head + self.count) % self.room
+
+    def take(self, k: int) -> np.ndarray:
+        """The slots of the ``k`` oldest programs, given up."""
+        slots = (self.head + np.arange(k)) % self.room
+        self.head = (self.head + k) % self.room
+        self.count -= k
+        return slots
+
+
 class ContinuousSweepDriver:
     """Seed-space sweep with continuous refill.
 
@@ -184,8 +222,6 @@ class ContinuousSweepDriver:
         program_key: Optional[Callable] = None,
         seed_pure: bool = False,
     ):
-        from .encoding import count_ops, lower_program, stack_programs
-
         self.app = app
         self.cfg = cfg
         self.program_gen = program_gen
@@ -218,9 +254,6 @@ class ContinuousSweepDriver:
         # the raw seed, so equal programs keep distinct schedules.
         self._program_key = program_key
         self._lower_memo: dict = {}
-        self._lower_program = lower_program
-        self._stack = stack_programs
-        self._count_ops = count_ops
         self.segment = make_segment_kernel(app, cfg, seg_steps, mesh=mesh)
         self.mesh = mesh
         self.init = make_init_kernel(app, cfg, mesh=mesh)
@@ -272,88 +305,114 @@ class ContinuousSweepDriver:
             return None
         return self._lower_memo.get(self._program_key(seed))
 
-    def _make(self, seed: int, clock: List[int]):
-        """``program_gen`` then ``lower_program`` for one seed, its
-        nanoseconds added to ``clock`` (fuzz, lower). The events die
-        here, before the next program's are generated."""
+    def _make(
+        self, seed: int, clock: List[int], out: ExtProgram, lane: int
+    ) -> bool:
+        """``program_gen`` then ``lower_into`` row ``lane`` of ``out``
+        for one seed, its nanoseconds added to ``clock`` (fuzz, lower).
+        Nothing of the program outlives the call but its rows in
+        ``out`` (and the memo's copy, where there is a memo). Returns
+        whether it was lowered from op rows."""
         t0 = time.perf_counter_ns()
         events = self.program_gen(seed)
         t1 = time.perf_counter_ns()
-        prog = self._lower_program(self.app, self.cfg, events)
+        from_rows = lower_into(self.app, self.cfg, events, out, lane)
         clock[0] += t1 - t0
         clock[1] += time.perf_counter_ns() - t1
         if self._program_key is not None:
-            self._lower_memo[self._program_key(seed)] = prog
-        return prog
+            self._lower_memo[self._program_key(seed)] = ExtProgram(
+                *(x[lane].copy() for x in out)
+            )
+        return from_rows
 
     def _make_ahead(
         self, pending, seed_list: Sequence[int], start: int, room: int,
-        stock: Deque,
+        stock: _Stock,
     ) -> None:
         """Between a segment's dispatch and its status pull: make the
-        programs of ``seed_list[start + len(stock):]`` into ``stock``, in
-        seed order, until ``pending`` (the segment's status) is ready,
-        the stock holds ``room`` programs, or the seeds end (module
-        doc). A ``program_key`` memo hit costs nothing at refill either,
-        so it is not made ahead: it holds its place in the stock as
-        None and the fill looks it up. Timed as a fill: a ``sweep.fill``
-        span whose slices are ``sweep.fuzz`` and ``sweep.lower``."""
-        had = len(stock)
+        programs of ``seed_list[start + stock.count:]`` into ``stock``,
+        in seed order, until ``pending`` (the segment's status) is
+        ready, the stock holds ``room`` programs, or the seeds end
+        (module doc). A ``program_key`` memo hit costs nothing at refill
+        either, so it is not made ahead: it holds its place in the
+        stock, unmade, and the fill looks it up. Timed as a fill: a
+        ``sweep.fill`` span whose slices are ``sweep.fuzz`` and
+        ``sweep.lower``."""
+        had = stock.count
         todo = seed_list[start + had : start + room]
         if not todo or _ready(pending):
             return
         clock = [0, 0]
         with obs.span("sweep.fill", ahead=True) as sp:
             for seed in todo:
-                stock.append(
-                    None if self._memo_hit(seed) is not None
-                    else self._make(seed, clock)
-                )
+                slot = stock.next_slot()
+                stock.made[slot] = self._memo_hit(seed) is None
+                if stock.made[slot]:
+                    stock.from_rows[slot] = self._make(
+                        seed, clock, stock.progs, slot
+                    )
+                stock.count += 1
                 if _ready(pending):
                     break
-            sp.set(programs=len(stock) - had)
+            sp.set(programs=stock.count - had)
             sp.slice("sweep.fuzz", clock[0])
             sp.slice("sweep.lower", clock[1])
 
     def _fill(
-        self, seeds: Sequence[int], lanes: Sequence[int], progs_host: List,
-        stock: Deque = (),
+        self, seeds: Sequence[int], lanes: Sequence[int], progs: ExtProgram,
+        stock: Optional[_Stock] = None,
     ) -> None:
-        """Put the program of each seed into its lane of ``progs_host``:
-        from ``stock`` while it lasts (made ahead for exactly these
-        seeds, in this order: ``_make_ahead``), else ``program_gen``
-        then ``lower_program`` on the spot, one seed at a time, each
-        program replacing its lane's old one as it goes. A program's
-        events die before the next is generated, and a retired program's
-        memory serves the next: a fill's programs held together beside
-        the events cost the sweep more than the spans could (PERF.md,
+        """Write the program of each seed into its lane of the resident
+        arrays ``progs``: from ``stock`` while it lasts (made ahead for
+        exactly these seeds, in this order: ``_make_ahead``), one
+        indexed copy per array; the rest ``program_gen`` then
+        ``lower_into`` on the spot, one seed at a time, each over its
+        lane's old program. A program's events, if it ever had any, die
+        before the next is generated, and a retired program's memory
+        serves the next: a fill's programs held together beside the
+        events cost the sweep more than the spans could (PERF.md,
         PR 24). The loop is per lane, so fuzzing and lowering get no
         span each: a clock pair per program sums them, and the
         ``sweep.fill`` span hands the sums to the stages ``sweep.fuzz``
-        and ``sweep.lower`` (a no-op with spans off). Counted beside
-        them: ``sweep.programs`` put in a lane, and ``sweep.prefetched``
-        of those that were made ahead."""
+        and ``sweep.lower`` (a no-op with spans off). ``sweep.stack`` is
+        the copy out of the stock, all that is left of stacking.
+        Counted beside them: ``sweep.programs`` put in a lane,
+        ``sweep.prefetched`` of those that were made ahead, and
+        ``sweep.row_lowered`` of those lowered from a fuzzed program's
+        op rows, here or ahead (a memo hit is lowered by nobody)."""
         clock = [0, 0]
-        ahead = 0
+        lanes = np.asarray(lanes, np.intp)
+        k = min(stock.count, len(seeds)) if stock is not None else 0
+        made = np.zeros(len(seeds), bool)
+        rows = 0
         with obs.span("sweep.fill", programs=len(seeds)) as sp:
-            for lane, seed in zip(lanes, seeds):
-                prog = stock.popleft() if stock else None
-                if prog is not None:
-                    ahead += 1
+            with obs.span("sweep.stack"):
+                if k:
+                    slots = stock.take(k)
+                    made[:k] = stock.made[slots]
+                    src, dst = slots[made[:k]], lanes[:k][made[:k]]
+                    for resident, ahead in zip(progs, stock.progs):
+                        resident[dst] = ahead[src]
+                    rows = int(stock.from_rows[src].sum())
+            lane_of = lanes.tolist()
+            for j in np.flatnonzero(~made).tolist():
+                lane, seed = lane_of[j], seeds[j]
+                hit = self._memo_hit(seed)
+                if hit is None:
+                    rows += self._make(seed, clock, progs, lane)
                 else:
-                    prog = self._memo_hit(seed)
-                    if prog is None:
-                        prog = self._make(seed, clock)
-                progs_host[lane] = prog
+                    for resident, held in zip(progs, hit):
+                        resident[lane] = held
             sp.slice("sweep.fuzz", clock[0])
             sp.slice("sweep.lower", clock[1])
             if obs.spans.live():
                 obs.stage_count("sweep.programs", len(seeds))
-                obs.stage_count("sweep.prefetched", ahead)
+                obs.stage_count("sweep.prefetched", int(made.sum()))
+                obs.stage_count("sweep.row_lowered", rows)
                 # What this fill lowered, by kind of external op: how
                 # much of the fault plane the traffic engages.
-                filled = [progs_host[lane] for lane in lanes]
-                for kind, n in self._count_ops(filled).items():
+                counts = count_op_arrays(progs.op[lanes], progs.a[lanes])
+                for kind, n in counts.items():
                     obs.stage_count(f"sweep.ops.{kind}", n)
 
     def time_to_first_violation(self, max_lanes: int = 1_000_000):
@@ -434,10 +493,10 @@ class ContinuousSweepDriver:
                 for i in range(b)
             ]
             next_idx = n_live  # next position in seed_list to hand out
-            progs_host: List = [None] * b
-            self._fill(lane_seed, range(b), progs_host)
-            with obs.span("sweep.stack"):
-                progs = self._stack(progs_host)
+            # The resident set's programs: what every segment takes,
+            # written in place between a pull and the next dispatch.
+            progs = empty_programs(self.cfg, b)
+            self._fill(lane_seed, range(b), progs)
             with obs.span("sweep.refill"):
                 state = self.init(keys_for(lane_seed))
             steps_run = np.zeros(b, np.int64)
@@ -445,7 +504,7 @@ class ContinuousSweepDriver:
             active = np.arange(b) < n_live
             # Lowered programs of seed_list[next_idx:], made while a
             # segment ran; this call's alone (module doc).
-            stock: Deque = deque()
+            stock = _Stock(self.cfg, b) if self.seed_pure else None
 
             self.last_segment_seconds = 0.0
             self.last_harvest_seconds = 0.0
@@ -543,8 +602,7 @@ class ContinuousSweepDriver:
                         next_idx += len(refill_lanes)
                         # Ascending, as the loop below hands the seeds out.
                         self._fill(
-                            fresh_seeds, sorted(refill_lanes), progs_host,
-                            stock,
+                            fresh_seeds, sorted(refill_lanes), progs, stock
                         )
                         with obs.span("sweep.refill"):
                             mask = np.zeros(b, bool)
@@ -562,9 +620,6 @@ class ContinuousSweepDriver:
                                     k += 1
                                 else:
                                     full_seeds.append(lane_seed[lane])
-                        with obs.span("sweep.stack"):
-                            progs = self._stack(progs_host)
-                        with obs.span("sweep.refill"):
                             fresh = self.init(keys_for(full_seeds))
                             state = self.refill(
                                 state, jnp.asarray(mask), fresh

@@ -33,22 +33,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..dsl import DSLApp
-from . import ops
-
 # External-op codes (device program encoding of ExternalEvents;
 # closure-form WaitCondition and CodeBlock are host-tier-only — the
-# cond_id WaitCondition form lowers to OP_WAITCOND).
-OP_END = 0
-OP_START = 1
-OP_KILL = 2
-OP_SEND = 3
-OP_WAIT = 4
-OP_PARTITION = 5
-OP_UNPARTITION = 6
-OP_HARDKILL = 7
-# Wait until app condition `a` holds (DSLApp.conditions[a]), with optional
-# delivery budget `b` — the device-lowerable WaitCondition form.
-OP_WAITCOND = 8
+# cond_id WaitCondition form lowers to OP_WAITCOND). Defined beside the
+# events they encode: the fuzzer records its programs in them.
+from ..external_events import (  # noqa: F401
+    OP_END,
+    OP_HARDKILL,
+    OP_KILL,
+    OP_PARTITION,
+    OP_SEND,
+    OP_START,
+    OP_UNPARTITION,
+    OP_WAIT,
+    OP_WAITCOND,
+)
+from . import ops
 
 # Record kinds.
 REC_NONE = 0

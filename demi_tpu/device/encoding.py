@@ -43,6 +43,7 @@ from ..external_events import (
     WaitQuiescence,
 )
 from ..events import WildCardMatch
+from ..fuzzing.program import ACTOR_KINDS, FuzzProgram
 from ..trace import EventTrace
 from .core import (
     OP_END,
@@ -70,19 +71,96 @@ def _msg_row(app: DSLApp, msg, width: int) -> List[int]:
     return row + [0] * (width - len(row))
 
 
+def empty_programs(cfg: DeviceConfig, lanes: int) -> ExtProgram:
+    """``lanes`` all-``OP_END`` programs as one set of host arrays, for
+    ``lower_into`` to write lane by lane."""
+    e, w = cfg.max_external_ops, cfg.msg_width
+    return ExtProgram(
+        op=np.zeros((lanes, e), np.int32),
+        a=np.zeros((lanes, e), np.int32),
+        b=np.zeros((lanes, e), np.int32),
+        msg=np.zeros((lanes, e, w), np.int32),
+    )
+
+
 def lower_program(
     app: DSLApp, cfg: DeviceConfig, externals: Sequence[ExternalEvent]
 ) -> ExtProgram:
     """Lower an external-event program to op arrays. WaitCondition lowers
     via its ``cond_id`` (DSLApp.conditions); host-closure WaitCondition
     and CodeBlock are host-tier-only and rejected here."""
-    e, w = cfg.max_external_ops, cfg.msg_width
-    ops = np.zeros(e, np.int32)
-    a = np.zeros(e, np.int32)
-    b = np.zeros(e, np.int32)
-    msg = np.zeros((e, w), np.int32)
-    if len(externals) > e:
-        raise ValueError(f"program length {len(externals)} > max_external_ops {e}")
+    out = empty_programs(cfg, 1)
+    lower_into(app, cfg, externals, out, 0)
+    return ExtProgram(*(x[0] for x in out))
+
+
+def lower_into(
+    app: DSLApp,
+    cfg: DeviceConfig,
+    externals: Sequence[ExternalEvent],
+    out: ExtProgram,
+    lane: int,
+) -> bool:
+    """``lower_program`` into row ``lane`` of the batch arrays ``out``
+    (``empty_programs``), over whatever program the lane held. A
+    ``FuzzProgram`` (never edited: it has no way to be) is lowered from
+    its op rows, and no event object is made; anything else, a
+    minimizer's list or a hand-written program, through the per-event
+    loop. Returns whether the rows were what was lowered."""
+    # (``type is``: an ABC's isinstance costs a lane's share of the call.)
+    from_rows = type(externals) is FuzzProgram and externals.lowerable
+    n = len(externals.kind) if from_rows else len(externals)
+    e = cfg.max_external_ops
+    if n > e:
+        raise ValueError(f"program length {n} > max_external_ops {e}")
+    msg = out.msg[lane]
+    msg.fill(0)
+    if from_rows:
+        _lower_rows(app, externals, [0] * (e - n), out, lane, msg)
+    else:
+        ops, a, b = out.op[lane], out.a[lane], out.b[lane]
+        ops.fill(0)
+        a.fill(0)
+        b.fill(0)
+        _lower_events(app, cfg.msg_width, externals, ops, a, b, msg)
+    _check_msg_range(cfg, msg)
+    return from_rows
+
+
+def _lower_rows(
+    app: DSLApp, prog: FuzzProgram, pad: List[int], out: ExtProgram,
+    lane: int, msg: np.ndarray,
+) -> None:
+    """A fuzzed program's rows into lane ``lane`` of ``out`` (``msg``
+    its zeroed payload block, ``pad`` the zeros up to the arrays'
+    length): the columns as they are (the rows are in the device's
+    numbering), one assignment each; the actor indices through the
+    fuzzer's name table where that is not the app's own order; the
+    payloads as the ints they are."""
+    kind, col_a, col_b = prog.kind, prog.a, prog.b
+    ids = prog.frame.actor_ids(app)
+    if ids is not None:
+        col_a = [ids[x] if k in ACTOR_KINDS else x for k, x in zip(kind, col_a)]
+        col_b = [
+            ids[x] if k == OP_PARTITION or k == OP_UNPARTITION else x
+            for k, x in zip(kind, col_b)
+        ]
+    if OP_WAITCOND in kind:
+        worst = max(x for k, x in zip(kind, col_a) if k == OP_WAITCOND)
+        if worst >= len(app.conditions):
+            raise ValueError(
+                f"cond_id {worst} out of range for "
+                f"{len(app.conditions)} app conditions"
+            )
+    out.op[lane] = kind + pad
+    out.a[lane] = col_a + pad
+    out.b[lane] = col_b + pad
+    for i, payload in prog.payloads:
+        msg[i, : len(payload)] = payload
+
+
+def _lower_events(app: DSLApp, w: int, externals, ops, a, b, msg) -> None:
+    """The per-event lowering, into one lane's zeroed arrays."""
     for i, ev in enumerate(externals):
         if isinstance(ev, Start):
             ops[i], a[i] = OP_START, app.actor_id(ev.name)
@@ -117,8 +195,6 @@ def lower_program(
             ops[i], a[i], b[i] = OP_UNPARTITION, app.actor_id(ev.a), app.actor_id(ev.b)
         else:
             raise TypeError(f"{type(ev).__name__} is not lowerable to the device tier")
-    _check_msg_range(cfg, msg)
-    return ExtProgram(op=ops, a=a, b=b, msg=msg)
 
 
 def _check_msg_range(cfg: DeviceConfig, msg: np.ndarray) -> None:
@@ -152,16 +228,22 @@ _OP_KIND = {
 
 
 def count_ops(programs: Sequence[ExtProgram]) -> Dict[str, int]:
-    """External ops of lowered programs, by kind. A Start of an actor
-    its program has started before (recovery) counts as ``restart``."""
-    op = np.stack([p.op for p in programs])
-    a = np.stack([p.a for p in programs])
+    """External ops of lowered programs, by kind (``count_op_arrays``)."""
+    return count_op_arrays(
+        np.stack([p.op for p in programs]), np.stack([p.a for p in programs])
+    )
+
+
+def count_op_arrays(op: np.ndarray, a: np.ndarray) -> Dict[str, int]:
+    """External ops of the lowered programs ``op/a [n, E]``, by kind. A
+    Start of an actor its program has started before (recovery) counts
+    as ``restart``."""
     by_code = np.bincount(op.ravel(), minlength=max(_OP_KIND) + 1)
     counts: Dict[str, int] = {}
     for code, kind in _OP_KIND.items():
         counts[kind] = counts.get(kind, 0) + int(by_code[code])
     starts = op == OP_START
-    first = np.zeros((len(programs), int(a[starts].max(initial=0)) + 1), bool)
+    first = np.zeros((len(op), int(a[starts].max(initial=0)) + 1), bool)
     first[np.nonzero(starts)[0], a[starts]] = True
     counts["restart"] = counts["start"] - int(first.sum())
     counts["start"] = int(first.sum())

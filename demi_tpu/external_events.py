@@ -13,6 +13,23 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, List, Optional, Sequence
 
 
+# Op codes of an external event's lowered row: one numbering for the
+# fuzzer's op rows (fuzzing/program.py) and the device's program arrays
+# (device/core.py re-exports them). A closure-form WaitCondition and a
+# CodeBlock are host-tier-only and have none.
+OP_END = 0
+OP_START = 1
+OP_KILL = 2
+OP_SEND = 3
+OP_WAIT = 4  # a = delivery budget, 0 = until quiescence
+OP_PARTITION = 5
+OP_UNPARTITION = 6
+OP_HARDKILL = 7
+# Wait until app condition `a` holds (DSLApp.conditions[a]), with optional
+# delivery budget `b` — the device-lowerable WaitCondition form.
+OP_WAITCOND = 8
+
+
 class _EidCounter:
     def __init__(self):
         self._next = 1

@@ -1,3 +1,4 @@
 from .fuzzer import Fuzzer, FuzzerWeights, MessageGenerator
+from .program import FuzzProgram
 
-__all__ = ["Fuzzer", "FuzzerWeights", "MessageGenerator"]
+__all__ = ["Fuzzer", "FuzzerWeights", "FuzzProgram", "MessageGenerator"]

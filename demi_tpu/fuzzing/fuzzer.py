@@ -17,23 +17,40 @@ from typing import Callable, List, Optional, Sequence
 from .. import obs
 
 from ..external_events import (
+    OP_HARDKILL,
+    OP_KILL,
+    OP_PARTITION,
+    OP_SEND,
+    OP_START,
+    OP_UNPARTITION,
+    OP_WAIT,
+    OP_WAITCOND,
     ExternalEvent,
-    HardKill,
-    Kill,
-    Partition,
     Send,
-    Start,
-    UnPartition,
-    WaitCondition,
-    WaitQuiescence,
-    atomic_block,
-    sanity_check_externals,
+)
+from .program import FuzzProgram, ProgramFrame
+
+
+# The two choices that are no op of their own: a restart lowers to a
+# Start, an atomic block to its sends.
+_RESTART = -1
+_ATOMIC = -2
+_NO_WAITCOND_AFTER = (None, OP_WAIT, OP_WAITCOND)
+# The choice scan's order (FuzzerWeights' field order).
+_CHOICE_KINDS = (
+    OP_KILL, OP_SEND, OP_WAIT, OP_PARTITION, OP_UNPARTITION, OP_HARDKILL,
+    _RESTART, OP_WAITCOND, _ATOMIC,
 )
 
 
 class MessageGenerator:
     """App-supplied generator of external Send events
-    (reference: Fuzzer.scala:8-10)."""
+    (reference: Fuzzer.scala:8-10). ``generate`` is the contract. A
+    generator may also give the row form the fuzzer records,
+    ``generate_row(rng, alive) -> (target name, payload tuple) | None``
+    with the same draws (``apps/common.DSLSendGenerator`` does); the
+    fuzzer calls it where the class defines it at least as specifically
+    as ``generate``, and unpacks ``generate``'s ``Send`` otherwise."""
 
     def generate(self, rng: _random.Random, alive: Sequence[str]) -> Optional[Send]:
         raise NotImplementedError
@@ -97,8 +114,17 @@ class Fuzzer:
         self.num_events = num_events
         self.weights = weights
         self.message_gen = message_gen
+        # Prefix, postfix and generator are fixed at construction: what
+        # every program shares of them (the actor-name table, the
+        # prefix's rows, the generator's row form) is made once, here.
         self.prefix = list(prefix)
         self.postfix = list(postfix)
+        self._frame = ProgramFrame(self.prefix, self.postfix)
+        self._send_row, self._row_sends = _send_rows(
+            message_gen, self._frame.index
+        )
+        self._choice_key: Optional[tuple] = None
+        self._choices: tuple = (0, ())
         # How many named wait predicates the app declares
         # (len(DSLApp.conditions)); wait_condition draws cond_ids < this.
         self.num_conditions = num_conditions
@@ -138,146 +164,212 @@ class Fuzzer:
     def restore_state(self, state: dict) -> None:
         self.set_weights(FuzzerWeights.from_dict(state["weights"]))
 
-    def generate_fuzz_test(self, seed: int) -> List[ExternalEvent]:
+    def _choice_table(self):
+        """``(total weight, ((kind, weight), ...))`` of the live weights,
+        read anew at every call (the autotune loop swaps and edits them
+        between programs) and rebuilt when a value changed. A zero
+        weight is never chosen and takes nothing from the scan's ``r``,
+        so the table leaves those out, to the same choice."""
+        w = self.weights
+        key = (
+            w.kill, w.send, w.wait_quiescence, w.partition, w.unpartition,
+            w.hard_kill, w.restart, w.wait_condition, w.atomic_block,
+        )
+        if key != self._choice_key:
+            self._choice_key = key
+            self._choices = (
+                sum(key),
+                tuple((c, wt) for c, wt in zip(_CHOICE_KINDS, key) if wt != 0),
+            )
+        return self._choices
+
+    def generate_fuzz_test(self, seed: int) -> FuzzProgram:
+        """The program of ``seed`` under the live weights: one loop of
+        draws that records each op as a row (``fuzzing/program.py``).
+        The events the host tier reads are made from the rows when
+        first looked at; ``list(program)`` is a real list."""
         rng = _random.Random(seed)
-        self.message_gen.reset()
-        starts = {e.name: e for e in self.prefix if isinstance(e, Start)}
-        alive = list(starts)
+        gen = self.message_gen
+        gen.reset()
+        frame = self._frame
+        index = frame.index
+        send_row = self._send_row
+        prog = FuzzProgram(frame, self._row_sends)
+        kind, col_a, col_b = prog.kind, prog.a, prog.b
+        payloads = prog.payloads
+        alive = list(frame.names)
         killed: List[str] = []
         kills = 0
         partitions: List[tuple] = []
+        cut: set = set()  # the same pairs, for the membership test
+        # Kind and wait budget of the event before the next drawn one
+        # (the wait rules look back one event; the prefix's last counts).
+        last = frame.last_kind
 
-        events: List[ExternalEvent] = list(self.prefix)
-        choices = [
-            ("kill", self.weights.kill),
-            ("send", self.weights.send),
-            ("wait", self.weights.wait_quiescence),
-            ("partition", self.weights.partition),
-            ("unpartition", self.weights.unpartition),
-            ("hard_kill", self.weights.hard_kill),
-            ("restart", self.weights.restart),
-            ("wait_condition", self.weights.wait_condition),
-            ("atomic_block", self.weights.atomic_block),
-        ]
-        total = sum(w for _, w in choices)
+        total, choices = self._choice_table()
+        random = rng.random
+        num_events = self.num_events
+        max_kills = self.max_kills
         generated = 0
         futile = 0
-        while generated < self.num_events:
+        while generated < num_events:
             if futile > 1000:
                 # Every choice is exhausted (send generator dry, kills
                 # capped, ...) — stop with what we have rather than spin.
                 break
             before = generated
-            r = rng.uniform(0, total)
-            kind = "send"
-            for name, w in choices:
-                if r < w:
-                    kind = name
+            r = total * random()  # rng.uniform(0, total), to the bit
+            op = OP_SEND
+            for code, wt in choices:
+                if r < wt:
+                    op = code
                     break
-                r -= w
-            if kind in ("kill", "hard_kill"):
-                can_kill = self.max_kills is None or kills < self.max_kills
-                if alive and can_kill:
+                r -= wt
+            if op == OP_SEND:
+                row = send_row(rng, alive)
+                if row is not None:
+                    payloads.append((len(kind), row[1]))
+                    kind.append(OP_SEND)
+                    col_a.append(index[row[0]])
+                    col_b.append(0)
+                    generated += 1
+            elif op == OP_WAIT:
+                if last is not None and last != OP_WAIT:
+                    budget = 0
+                    if self.wait_budget is not None:
+                        budget = rng.randint(*self.wait_budget)
+                        if budget < 1:
+                            raise ValueError(
+                                "WaitQuiescence budget must be None or >= 1"
+                            )
+                    kind.append(OP_WAIT)
+                    col_a.append(budget)
+                    col_b.append(0)
+                    generated += 1
+            elif op == OP_KILL or op == OP_HARDKILL:
+                if alive and (max_kills is None or kills < max_kills):
                     victim = rng.choice(alive)
                     alive.remove(victim)
                     killed.append(victim)
                     kills += 1
-                    events.append(
-                        Kill(victim) if kind == "kill" else HardKill(victim)
-                    )
+                    kind.append(op)
+                    col_a.append(index[victim])
+                    col_b.append(0)
                     generated += 1
-            elif kind == "restart":
+            elif op == _RESTART:
                 if killed:
                     name = rng.choice(killed)
                     killed.remove(name)
                     alive.append(name)
-                    orig = starts[name]
-                    events.append(Start(name, ctor=orig.ctor))
+                    kind.append(OP_START)
+                    col_a.append(index[name])
+                    col_b.append(0)
                     generated += 1
-            elif kind == "send":
-                send = self.message_gen.generate(rng, alive)
-                if send is not None:
-                    events.append(send)
-                    generated += 1
-            elif kind == "atomic_block":
+            elif op == _ATOMIC:
                 # Cap the batch at the remaining event budget so generated
                 # programs never overshoot num_events; with <2 remaining a
                 # block is impossible — fall back to a plain send.
-                remaining = self.num_events - generated
-                batch = []
-                if remaining >= 2:
-                    for _ in range(rng.randint(2, min(4, remaining))):
-                        send = self.message_gen.generate(rng, alive)
-                        if send is None:
-                            break
-                        batch.append(send)
-                else:
-                    send = self.message_gen.generate(rng, alive)
-                    if send is not None:
-                        batch.append(send)
-                if len(batch) >= 2:
-                    events.extend(atomic_block(batch))
-                    generated += len(batch)
-                elif batch:  # generator ran dry mid-batch: plain send
-                    events.extend(batch)
+                remaining = num_events - generated
+                start = len(kind)
+                want = rng.randint(2, min(4, remaining)) if remaining >= 2 else 1
+                for _ in range(want):
+                    row = send_row(rng, alive)
+                    if row is None:
+                        break
+                    payloads.append((len(kind), row[1]))
+                    kind.append(OP_SEND)
+                    col_a.append(index[row[0]])
+                    col_b.append(0)
+                got = len(kind) - start
+                if got >= 2:
+                    prog.blocks.append((start, start + got))
+                    generated += got
+                elif got:  # generator ran dry mid-batch: plain send
                     generated += 1
-            elif kind == "wait_condition":
-                if self.num_conditions > 0 and events and not isinstance(
-                    events[-1], (WaitQuiescence, WaitCondition)
-                ):
+            elif op == OP_WAITCOND:
+                if self.num_conditions > 0 and last not in _NO_WAITCOND_AFTER:
                     lo, hi = self.wait_budget or (5, 40)
-                    events.append(
-                        WaitCondition(
-                            cond_id=rng.randrange(self.num_conditions),
-                            # Clamp: budget 0 would encode as strict/
-                            # unbudgeted, breaking the always-budgeted
-                            # guarantee for wait_budget ranges with lo=0.
-                            budget=max(1, rng.randint(lo, hi)),
-                        )
-                    )
+                    kind.append(OP_WAITCOND)
+                    col_a.append(rng.randrange(self.num_conditions))
+                    # Clamp: budget 0 would encode as strict/unbudgeted,
+                    # breaking the always-budgeted guarantee for
+                    # wait_budget ranges with lo=0.
+                    col_b.append(max(1, rng.randint(lo, hi)))
                     generated += 1
-            elif kind == "wait":
-                if events and not isinstance(events[-1], WaitQuiescence):
-                    budget = (
-                        rng.randint(*self.wait_budget)
-                        if self.wait_budget is not None
-                        else None
-                    )
-                    events.append(WaitQuiescence(budget=budget))
-                    generated += 1
-            elif kind == "partition":
+            elif op == OP_PARTITION:
                 pairs = [
                     (a, b)
                     for i, a in enumerate(alive)
                     for b in alive[i + 1 :]
-                    if (a, b) not in partitions
+                    if (a, b) not in cut
                 ]
                 if pairs:
                     pair = rng.choice(pairs)
                     partitions.append(pair)
-                    events.append(Partition(*pair))
+                    cut.add(pair)
+                    kind.append(OP_PARTITION)
+                    col_a.append(index[pair[0]])
+                    col_b.append(index[pair[1]])
                     generated += 1
-            elif kind == "unpartition":
+            elif op == OP_UNPARTITION:
                 if partitions:
                     pair = rng.choice(partitions)
                     partitions.remove(pair)
-                    events.append(UnPartition(*pair))
+                    cut.discard(pair)
+                    kind.append(OP_UNPARTITION)
+                    col_a.append(index[pair[0]])
+                    col_b.append(index[pair[1]])
                     generated += 1
-            futile = futile + 1 if generated == before else 0
+            if generated == before:
+                futile += 1
+            else:
+                futile = 0
+                last = kind[-1]
 
-        had_postfix = bool(self.postfix)
-        events.extend(self.postfix)
         if obs.enabled():
             obs.counter("fuzz.programs_generated").inc()
             obs.counter("fuzz.events_generated").inc(generated)
             obs.histogram("fuzz.program_events").observe(generated)
-        if not events or not isinstance(events[-1], WaitQuiescence):
-            events.append(WaitQuiescence())
-        elif events[-1].budget is not None and not had_postfix:
-            # The run ends with the last segment (reference semantics); a
-            # *generated* budgeted trailing wait would cap the final drain.
-            # A user-supplied postfix wait is kept verbatim — a bounded
-            # final drain there is deliberate.
-            events[-1] = WaitQuiescence()
-        sanity_check_externals(events)
-        return events
+        if frame.plain:
+            # Always end in a wait, and that one unbounded: the run ends
+            # with the last segment (reference semantics), so a
+            # *generated* budgeted trailing wait would cap the final
+            # drain. (With a prefix or postfix that is not rows, the
+            # view applies the rule to the events: program.py.)
+            if not kind or kind[-1] != OP_WAIT:
+                kind.append(OP_WAIT)
+                col_a.append(0)
+                col_b.append(0)
+            else:
+                col_a[-1] = 0
+        return prog
+
+
+def _send_rows(gen, index):
+    """``(rng, alive) -> (target name, payload) | None`` over ``gen``,
+    and whether the payloads are int tuples (the row form): its
+    ``generate_row`` where the class defines one at least as
+    specifically as ``generate`` (a subclass that overrides only
+    ``generate`` means that one), else ``generate`` with its ``Send``
+    unpacked, the ``Send`` itself standing as the payload (one to an
+    actor the prefix never started is rejected here, as the events'
+    sanity check would)."""
+    row_form = False
+    for klass in type(gen).__mro__:
+        if "generate_row" in vars(klass):
+            row_form = True
+            break
+        if "generate" in vars(klass):
+            break
+    if row_form:
+        return gen.generate_row, True
+
+    def unpacked(rng, alive):
+        send = gen.generate(rng, alive)
+        if send is None:
+            return None
+        if send.name not in index:
+            raise ValueError(f"{send} targets never-started actor {send.name}")
+        return send.name, send
+
+    return unpacked, False

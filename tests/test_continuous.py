@@ -444,24 +444,22 @@ def test_memo_hits_are_not_made_ahead(stub_ready, ahead):
 def test_the_three_stop_rules_of_making_ahead(ahead, stub_ready):
     """Ready, room (active lanes), seeds used up; and an array with no
     readiness probe reads ready, so nothing is made ahead of it."""
-    from collections import deque
-
     seeds = list(range(ahead.N))
     ahead.reset(seed_pure=True)
-    stock = deque()
+    stock = continuous._Stock(ahead.drv.cfg, 8)
     ahead.drv._make_ahead(None, seeds, 8, 3, stock)
-    assert len(stock) == 3 and ahead.generated() == [(0, 8), (0, 9), (0, 10)]
+    assert stock.count == 3 and ahead.generated() == [(0, 8), (0, 9), (0, 10)]
     ahead.drv._make_ahead(None, seeds, 8, 3, stock)     # no room left
-    assert len(stock) == 3
+    assert stock.count == 3
     ahead.drv._make_ahead(None, seeds, 8, 5, stock)     # continues in order
     assert ahead.generated()[3:] == [(0, 11), (0, 12)]
-    stock.clear()
+    stock.take(stock.count)
     ahead.drv._make_ahead(None, seeds, ahead.N - 2, 8, stock)   # seeds end
-    assert len(stock) == 2
+    assert stock.count == 2
     ahead.reset(seed_pure=True, busy=lambda k: k == 0)
-    stock = deque()
+    stock = continuous._Stock(ahead.drv.cfg, 8)
     ahead.drv._make_ahead(None, seeds, 0, 8, stock)     # ready after one
-    assert len(stock) == 1
+    assert stock.count == 1
 
 
 def test_an_array_with_no_probe_reads_ready():
@@ -470,3 +468,179 @@ def test_an_array_with_no_probe_reads_ready():
     assert continuous._ready(object()) is True
     assert continuous._ready(np.zeros(3)) is True
     assert continuous._ready(jnp.zeros(3).block_until_ready()) is True
+
+
+# -- PR 30: the resident set is one set of host arrays, written in place --
+
+class _InPlace:
+    """The broadcast fixture, its ``SweepDriver.run_chunk`` references
+    (the same seeds through the plain explore kernel, under the sweep's
+    ``fold_in(PRNGKey(0), seed)`` keys) and the ways a continuous driver
+    can be fed, each held to them seed for seed."""
+
+    N = 40          # five resident sets of 8: refills span many rounds
+    BATCH = 8
+    PERIOD = 12
+
+    def __init__(self):
+        from demi_tpu.parallel.sweep import SweepDriver
+
+        self.app, self.cfg, self.gen = _broadcast_fixture()
+        self.periodic = lambda s: self.gen(s % self.PERIOD)
+        self.want = {
+            name: SweepDriver(self.app, self.cfg, gen).run_chunk(range(self.N))
+            for name, gen in (("gen", self.gen), ("periodic", self.periodic))
+        }
+
+    def driver(self, gen, **kwargs):
+        return ContinuousSweepDriver(
+            self.app, self.cfg, gen, batch=self.BATCH, seg_steps=28,
+            key_fn=lambda s: jax.random.fold_in(jax.random.PRNGKey(0), s),
+            **kwargs,
+        )
+
+
+@pytest.fixture(scope="module")
+def in_place():
+    return _InPlace()
+
+
+# how the driver is fed -> (reference, programs lowered from rows of 40)
+_FEEDS = {
+    "every_refill_from_the_stock": ("gen", 40),
+    "nothing_made_ahead": ("gen", 40),
+    "some_made_ahead": ("gen", 40),
+    "not_seed_pure": ("gen", 40),
+    "program_key_memo": ("periodic", 12),
+    "program_key_memo_not_seed_pure": ("periodic", 12),
+    "plain_lists": ("gen", 0),
+    "plain_lists_made_ahead": ("gen", 0),
+    "two_device_mesh": ("gen", 40),
+    "two_device_mesh_odd_batch": ("gen", 40),
+}
+
+
+@pytest.mark.parametrize("feed", list(_FEEDS))
+def test_in_place_resident_arrays_match_run_chunk(in_place, monkeypatch, feed):
+    """Statuses, codes and ``sched_hash``es, seed for seed (the order-free
+    ``lanes_digest`` over them, the code ledger and the delivered
+    sequences), equal ``SweepDriver.run_chunk`` on the same seeds however
+    the programs reach the lanes; and the fills count what they lowered
+    from op rows."""
+    from demi_tpu import obs
+    from demi_tpu.parallel.mesh import make_mesh
+    from demi_tpu.parallel.sweep import lanes_digest
+
+    ref, rows = _FEEDS[feed]
+    want = in_place.want[ref]
+    gen = in_place.gen
+    probes = itertools.count()
+    busy = {
+        "nothing_made_ahead": lambda: False,
+        "some_made_ahead": lambda: next(probes) % 3 != 2,
+    }.get(feed, lambda: True)
+    monkeypatch.setattr(continuous, "_ready", lambda _array: not busy())
+    if feed in ("every_refill_from_the_stock", "nothing_made_ahead",
+                "some_made_ahead"):
+        drv = in_place.driver(gen, seed_pure=True)
+    elif feed == "not_seed_pure":
+        drv = in_place.driver(gen)
+    elif feed.startswith("program_key_memo"):
+        drv = in_place.driver(
+            in_place.periodic, program_key=lambda s: s % in_place.PERIOD,
+            seed_pure=feed == "program_key_memo",
+        )
+    elif feed.startswith("plain_lists"):
+        drv = in_place.driver(
+            lambda s: list(gen(s)), seed_pure=feed.endswith("made_ahead")
+        )
+    else:
+        mesh = make_mesh(jax.devices()[:2])
+        drv = in_place.driver(gen, seed_pure=True, mesh=mesh)
+        if feed.endswith("odd_batch"):
+            drv.batch = 7    # rounded up to a mesh multiple
+    n = in_place.N
+    obs.disable()
+    obs.TRACER.clear()
+    obs.enable()
+    try:
+        batches = list(drv._run_batches(n))
+        counts = obs.stage_counts()
+    finally:
+        obs.disable()
+        obs.TRACER.clear()
+    seeds, statuses, codes, hashes = (
+        np.concatenate([b[k] for b in batches]) for k in range(4)
+    )
+    assert sorted(seeds.tolist()) == list(range(n))
+    assert lanes_digest(seeds, statuses, codes, hashes) == want.lanes_digest
+    assert int((codes != 0).sum()) == want.violations
+    assert np.array_equal(np.unique(hashes), want.unique_hashes)
+    ledger = {
+        int(c): int(k)
+        for c, k in zip(*np.unique(codes[codes != 0], return_counts=True))
+    }
+    assert ledger == want.codes
+    assert counts["sweep.programs"] == n
+    assert counts["sweep.row_lowered"] == rows
+    if feed.startswith("two_device_mesh"):
+        assert drv.last_lane_sharding["devices"] == 2
+    if feed == "every_refill_from_the_stock":
+        assert counts["sweep.prefetched"] == n - in_place.BATCH
+    if feed in ("nothing_made_ahead", "not_seed_pure", "plain_lists"):
+        assert counts["sweep.prefetched"] == 0
+
+
+def test_the_continuous_path_stacks_nothing(in_place, monkeypatch):
+    """``stack_programs`` is off the continuous path: the resident set is
+    allocated once a call and every fill writes into it."""
+    from demi_tpu.device import encoding
+
+    def refuse(_programs):
+        raise AssertionError("stack_programs called on the continuous path")
+
+    monkeypatch.setattr(encoding, "stack_programs", refuse)
+    assert not hasattr(continuous, "stack_programs")
+    made = []
+    real = continuous.empty_programs
+    monkeypatch.setattr(
+        continuous, "empty_programs",
+        lambda cfg, lanes: made.append(lanes) or real(cfg, lanes),
+    )
+    monkeypatch.setattr(continuous, "_ready", lambda _array: False)
+    drv = in_place.driver(in_place.gen, seed_pure=True)
+    statuses, _violations = drv.sweep(in_place.N)
+    assert len(statuses) == in_place.N
+    # one resident block and one stock block, for the whole sweep
+    assert made == [in_place.BATCH, in_place.BATCH]
+
+
+def test_a_made_ahead_program_never_touches_the_resident_arrays(
+    in_place, monkeypatch
+):
+    """What is made while the device may still read the resident set goes
+    to the stock's own block (the CPU backend may alias NumPy memory)."""
+    drv = in_place.driver(in_place.gen, seed_pure=True)
+    resident, stock = continuous.empty_programs(drv.cfg, 8), continuous._Stock(drv.cfg, 8)
+    for arr in resident:
+        arr[:] = 5
+    monkeypatch.setattr(continuous, "_ready", lambda _array: False)
+    drv._make_ahead(None, list(range(40)), 8, 6, stock)
+    assert stock.count == 6 and all((arr == 5).all() for arr in resident)
+    assert stock.made[:6].all() and stock.from_rows[:6].all()
+    drv._fill(list(range(8, 12)), [1, 3, 4, 6], resident, stock)
+    assert stock.count == 2 and stock.head == 4
+    for lane, seed in zip([1, 3, 4, 6], range(8, 12)):
+        want = lower_program(drv.app, drv.cfg, in_place.gen(seed))
+        for arr, ref in zip(resident, want):
+            assert np.array_equal(arr[lane], ref)
+    assert all((arr[[0, 2, 5, 7]] == 5).all() for arr in resident)
+    # the ring wraps: six more made, then a fill longer than the stock
+    drv._make_ahead(None, list(range(40)), 12, 8, stock)
+    assert stock.count == 8
+    drv._fill(list(range(12, 22)), list(range(8)) + [0, 1], resident, stock)
+    assert stock.count == 0
+    for lane, seed in zip([2, 3, 4, 5, 6, 7, 0, 1], range(14, 22)):
+        want = lower_program(drv.app, drv.cfg, in_place.gen(seed))
+        for arr, ref in zip(resident, want):
+            assert np.array_equal(arr[lane], ref)

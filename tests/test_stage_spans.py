@@ -261,8 +261,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     counts = obs.stage_counts()
     # Every count kept has a reader: the benchmark's dpor.fresh_share,
     # dpor.admit_us_per_candidate and dpor.materialized_share; the sweep's
-    # sweep.live_step_share and sweep.fault_op_share (PR 27) and
-    # sweep.prefetch_share (PR 28).
+    # sweep.live_step_share and sweep.fault_op_share (PR 27),
+    # sweep.prefetch_share (PR 28) and sweep.row_lowered_share (PR 30).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -270,7 +270,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     assert set(counts) == {
         "dpor.candidates", "dpor.fresh", "dpor.materialized",
         "sweep.lane_steps", "sweep.live_lane_steps",
-        "sweep.programs", "sweep.prefetched",
+        "sweep.programs", "sweep.prefetched", "sweep.row_lowered",
     } | {f"sweep.ops.{kind}" for kind in op_kinds}
     assert counts["dpor.candidates"] >= counts["dpor.fresh"] > 0
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
@@ -279,6 +279,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # one program a schedule put in a lane; the prime fill's 8 never ahead
     assert counts["sweep.programs"] == 24
     assert 0 <= counts["sweep.prefetched"] <= 24 - 8
+    # the sweeper's generator is the fuzzer: every program from its rows
+    assert counts["sweep.row_lowered"] == 24
     # 24 programs of the sweeper's: every actor started once in each
     assert counts["sweep.ops.start"] == 24 * sweeper.app.num_actors
     assert counts["sweep.ops.restart"] == 0
