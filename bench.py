@@ -581,7 +581,11 @@ def bench_config3(jax):
 
 
 def bench_config5(jax, total_lanes=None):
-    """BASELINE config 5: 64-actor reliable broadcast schedule sweep."""
+    """BASELINE config 5: 64-actor reliable broadcast schedule sweep.
+    Since PR 31 the deployment is a configuration of the benchmark
+    (benchmarks/configs/bcast64-flood.json, cell bcast64-flood-sweep, on
+    the CLI's normal path); this hand-built variant matrix is read by
+    nothing there (its deletion is ROADMAP C3's)."""
     from demi_tpu.apps.broadcast import make_broadcast_app
     from demi_tpu.apps.common import dsl_start_events
     from demi_tpu.device import DeviceConfig

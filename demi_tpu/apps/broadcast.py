@@ -82,6 +82,7 @@ def make_broadcast_app(
         init_state=init_state,
         handler=handler,
         invariant=invariant,
+        invariant_at="quiescence",
         tag_names=("", "BCAST"),
     )
 

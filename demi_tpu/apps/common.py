@@ -66,6 +66,9 @@ def make_host_invariant(app: DSLApp) -> Callable:
             return IntViolation(code, affected)
         return None
 
+    # When it may be judged travels with the invariant (the schedulers
+    # read it through ``SchedulerConfig.quiescence_invariant``).
+    invariant.at_quiescence = app.invariant_at == "quiescence"
     return invariant
 
 

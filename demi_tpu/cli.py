@@ -79,6 +79,7 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         message_gen=gen,
         prefix=dsl_start_events(app),
         max_kills=args.max_kills,
+        max_sends=args.max_sends,
         wait_budget=(
             None if args.wait_budget is None else tuple(args.wait_budget)
         ),
@@ -230,18 +231,10 @@ def _device_confirm_sweep(app, args, program, lanes: int = 32):
     from .device import DeviceConfig
     from .parallel.sweep import SweepDriver
 
-    cfg = DeviceConfig.for_app(
-        app,
-        pool_capacity=getattr(args, "pool", 256),
-        max_steps=args.max_messages,
-        max_external_ops=max(
-            16,
-            (len(program) if program is not None else args.num_events
-             + app.num_actors) + 2,
-        ),
-        invariant_interval=1,
-        timer_weight=args.timer_weight,
-    )
+    overrides = {}
+    if program is not None:
+        overrides["max_external_ops"] = max(16, len(program) + 2)
+    cfg = DeviceConfig.for_workload(app, args, **overrides)
     if program is not None:
         gen = lambda s: program  # noqa: E731
     else:
@@ -655,7 +648,8 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
     chunk = min(args.batch, getattr(args, "chunk", None) or args.batch)
     state = {
         "seeds_done": 0, "chunks": 0, "violations": 0, "codes": {},
-        "overflow_lanes": 0, "first_violating_seed": None,
+        "overflow_lanes": 0, "unfinished_lanes": 0,
+        "first_violating_seed": None,
         "unique_hashes": [],
     }
     resumed = False
@@ -713,6 +707,7 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
                 key = str(code)
                 state["codes"][key] = state["codes"].get(key, 0) + k
             state["overflow_lanes"] += c.overflow_lanes
+            state["unfinished_lanes"] += c.unfinished_lanes
             if (
                 state["first_violating_seed"] is None
                 and c.first_violating_seed is not None
@@ -736,6 +731,7 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
         "codes": dict(state["codes"]),
         "first_violating_seed": state["first_violating_seed"],
         "overflow_lanes": state["overflow_lanes"],
+        "unfinished_lanes": state["unfinished_lanes"],
         "resumed": resumed,
         "checkpoints": dict(store.stats),
         **_device_fields(),
@@ -827,7 +823,8 @@ def _fuzz_checkpoint_run(args, app, config, fuzzer, controller) -> int:
             config, fuzzer,
             max_executions=args.max_executions,
             seed=args.seed, max_messages=args.max_messages,
-            invariant_check_interval=1, timer_weight=args.timer_weight,
+            invariant_check_interval=app.invariant_interval,
+            timer_weight=args.timer_weight,
             validate_replay=True, controller=controller,
             start_execution=start, round_hook=hook,
         )
@@ -883,14 +880,7 @@ def _streaming_device_cfg(args, app):
     fuzzer's own programs)."""
     from .device import DeviceConfig
 
-    return DeviceConfig.for_app(
-        app,
-        pool_capacity=getattr(args, "pool", None) or 256,
-        max_steps=args.max_messages,
-        max_external_ops=max(16, args.num_events + app.num_actors + 2),
-        invariant_interval=1,
-        timer_weight=args.timer_weight,
-    )
+    return DeviceConfig.for_workload(app, args)
 
 
 def _resolve_split(args, app, cfg) -> float:
@@ -1158,7 +1148,7 @@ def cmd_fuzz(args) -> int:
             max_executions=args.max_executions,
             seed=args.seed,
             max_messages=args.max_messages,
-            invariant_check_interval=1,
+            invariant_check_interval=app.invariant_interval,
             timer_weight=args.timer_weight,
             validate_replay=True,
             controller=controller,
@@ -1450,14 +1440,7 @@ def cmd_sweep(args) -> int:
     from .parallel.sweep import SweepDriver
 
     app = build_app(args)
-    cfg = DeviceConfig.for_app(
-        app,
-        pool_capacity=args.pool,
-        max_steps=args.max_messages,
-        max_external_ops=max(16, args.num_events + app.num_actors + 2),
-        invariant_interval=1,
-        timer_weight=args.timer_weight,
-    )
+    cfg = DeviceConfig.for_workload(app, args)
     fuzzer = build_fuzzer(app, args)
     if getattr(args, "checkpoint_dir", None):
         return _sweep_checkpoint_run(args, app, cfg, fuzzer)
@@ -1538,6 +1521,9 @@ def cmd_sweep(args) -> int:
         # (runner.lift_lane_to_host) without sweeping again.
         "violating_seeds": violating,
         "overflow_lanes": result.overflow_lanes,
+        # Lanes cut by --max-messages before quiescence under an
+        # invariant judged at quiescence only: no verdict.
+        "unfinished_lanes": result.unfinished_lanes,
         # Order-free digest of every lane's (seed, status, code,
         # sched_hash): equal across modes, chunkings and device counts
         # for the same seeds (parallel/sweep.lanes_digest).
@@ -1585,15 +1571,8 @@ def cmd_dpor(args) -> int:
 
     app = build_app(args)
     config = SchedulerConfig(invariant_check=make_host_invariant(app))
-    cfg = DeviceConfig.for_app(
-        app,
-        pool_capacity=args.pool,
-        max_steps=args.max_messages,
-        max_external_ops=max(16, args.num_events + app.num_actors + 2),
-        invariant_interval=1,
-        timer_weight=args.timer_weight,
-        record_trace=True,
-        record_parents=True,
+    cfg = DeviceConfig.for_workload(
+        app, args, record_trace=True, record_parents=True
     )
     if getattr(args, "checkpoint_dir", None):
         return _dpor_checkpoint_run(args, app, cfg)
@@ -1963,14 +1942,7 @@ def cmd_tune(args) -> int:
 
     _obs_begin(args)
     app = build_app(args)
-    cfg = DeviceConfig.for_app(
-        app,
-        pool_capacity=args.pool,
-        max_steps=args.max_messages,
-        max_external_ops=max(16, args.num_events + app.num_actors + 2),
-        invariant_interval=1,
-        timer_weight=args.timer_weight,
-    )
+    cfg = DeviceConfig.for_workload(app, args)
     fuzzer = build_fuzzer(app, args)
     gen = lambda s: fuzzer.generate_fuzz_test(seed=args.seed + s)  # noqa: E731
     cache = TuningCache(args.cache)
@@ -2048,20 +2020,13 @@ def cmd_stats(args) -> int:
             max_executions=args.max_executions,
             seed=args.seed,
             max_messages=args.max_messages,
-            invariant_check_interval=1,
+            invariant_check_interval=app.invariant_interval,
             timer_weight=args.timer_weight,
         )
         from .device import DeviceConfig
         from .parallel.sweep import SweepDriver
 
-        cfg = DeviceConfig.for_app(
-            app,
-            pool_capacity=args.pool,
-            max_steps=args.max_messages,
-            max_external_ops=max(16, args.num_events + app.num_actors + 2),
-            invariant_interval=1,
-            timer_weight=args.timer_weight,
-        )
+        cfg = DeviceConfig.for_workload(app, args)
         fuzzer = build_fuzzer(app, args)
         driver = SweepDriver(
             app, cfg, lambda s: fuzzer.generate_fuzz_test(seed=args.seed + s)
@@ -2242,6 +2207,11 @@ def main(argv: Optional[list] = None) -> int:
                        dest="max_kills",
                        help="kills of either kind a program may hold; a "
                             "restart gives none back")
+        p.add_argument("--max-sends", type=int, default=knobs["max_sends"],
+                       dest="max_sends",
+                       help="client sends a program may hold (one flood a "
+                            "schedule keeps a broadcast's pool bounded); "
+                            "default: unlimited")
         p.add_argument("--wait-budget", type=int, nargs=2,
                        default=knobs["wait_budget"], dest="wait_budget",
                        metavar=("LO", "HI"),

@@ -28,3 +28,11 @@ class SchedulerConfig:
     abort_upon_divergence: bool = False
     abort_upon_divergence_lax: bool = False
     original_dep_graph: Optional[Any] = None
+
+    @property
+    def quiescence_invariant(self) -> bool:
+        """Whether ``invariant_check`` may be judged at quiescence only
+        (it says so itself: ``apps.common.make_host_invariant`` marks the
+        one it makes from ``DSLApp.invariant_at``). A run then ends at
+        quiescence or has no verdict, as on the device."""
+        return bool(getattr(self.invariant_check, "at_quiescence", False))

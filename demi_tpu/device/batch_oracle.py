@@ -84,7 +84,7 @@ def default_device_config(
         pool_capacity=_round8(max(64, 2 * n_events)),
         max_steps=_round8(max(64, 2 * n_events)),
         max_external_ops=_round8(len(externals) + 8),
-        invariant_interval=1,
+        invariant_interval=app.invariant_interval,
         # Minimization candidates shrink far below the shared static
         # record shape; early exit makes replay wall-clock track the
         # longest live candidate instead of the shape.

@@ -56,7 +56,13 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..dsl import DSLApp
-from .core import ST_DONE, ST_VIOLATION, DeviceConfig, ScheduleState
+from .core import (
+    ST_DONE,
+    ST_UNFINISHED,
+    ST_VIOLATION,
+    DeviceConfig,
+    ScheduleState,
+)
 from .encoding import count_op_arrays, empty_programs, lower_into
 from .explore import (
     ExtProgram,
@@ -275,6 +281,19 @@ class ContinuousSweepDriver:
         self.last_harvest_seconds: float = 0.0
         # Devices the last _run's lane state spanned, lanes on each.
         self.last_lane_sharding: Optional[dict] = None
+        # Lanes of the last _run that ran out of steps before quiescence
+        # under an invariant judged at quiescence only (ST_UNFINISHED:
+        # no verdict).
+        self.last_unfinished_lanes: int = 0
+        # The fullest pool of the last _run, in rows, sampled at its
+        # segment boundaries while spans are live (a traced job); None
+        # when not sampled. A sample costs a [B, P] reduce and a [B]
+        # pull a round, so an untraced sweep takes none, and the step
+        # kernel carries no count for it.
+        self.last_pool_peak: Optional[int] = None
+        self._occupancy = jax.jit(
+            lambda valid: jnp.sum(valid, axis=1, dtype=jnp.int32)
+        )
 
     def _record_round_stats(self, state, finished, vio) -> None:
         """Fold one harvest round's finished lanes into the registry
@@ -509,6 +528,9 @@ class ContinuousSweepDriver:
             self.last_segment_seconds = 0.0
             self.last_harvest_seconds = 0.0
             self.last_lane_sharding = None
+            self.last_unfinished_lanes = 0
+            sample_pool = obs.spans.live()
+            self.last_pool_peak = 0 if sample_pool else None
         while done_count < total_lanes:
             with obs.span("sweep.round"):
                 n_active = int(active.sum())
@@ -525,6 +547,10 @@ class ContinuousSweepDriver:
                     state = self.segment(
                         state, progs, jnp.asarray(steps_run, jnp.int32)
                     )
+                    occupancy = (
+                        self._occupancy(state.pool_valid)
+                        if sample_pool else None
+                    )
                 t_gap = time.perf_counter()
                 if self.seed_pure:
                     self._make_ahead(
@@ -537,6 +563,11 @@ class ContinuousSweepDriver:
                     # was made in the gap, and the rest of the
                     # iteration, is harvest.
                     _status_sync = np.asarray(state.status)
+                    if sample_pool:
+                        self.last_pool_peak = max(
+                            self.last_pool_peak,
+                            int(np.asarray(occupancy).max()),
+                        )
                 t_harvest = time.perf_counter()
                 self.last_harvest_seconds += t_pull - t_gap
                 if self.last_lane_sharding is None:
@@ -586,6 +617,17 @@ class ContinuousSweepDriver:
                             sh[fin].copy(),
                         )
                         done_count += len(fin)
+                        unfinished = int(
+                            (out[1] == ST_UNFINISHED).sum()
+                        )
+                        self.last_unfinished_lanes += unfinished
+                        if sample_pool:
+                            obs.stage_count("sweep.retired", len(fin))
+                            obs.stage_count(
+                                "sweep.quiesced",
+                                int((out[1] <= ST_VIOLATION).sum()),
+                            )
+                            obs.stage_count("sweep.unfinished", unfinished)
                         # Refill finished lanes with fresh seeds (or park
                         # them).
                         refill_lanes = set(
@@ -630,3 +672,7 @@ class ContinuousSweepDriver:
             # never counts as the driver's.
             if out is not None:
                 yield out
+        if sample_pool:
+            # Per job, so their ratio is the mean fullest pool's share.
+            obs.stage_count("sweep.pool_peak_rows", self.last_pool_peak)
+            obs.stage_count("sweep.pool_rows", self.cfg.pool_capacity)

@@ -65,6 +65,10 @@ ST_INJECT = 1
 ST_DONE = 2
 ST_VIOLATION = 3
 ST_OVERFLOW = 4
+# Under an invariant judged at quiescence only (``DSLApp.invariant_at``):
+# the lane ran out of steps before its program ended in quiescence. It has
+# no verdict (violation stays 0) and is counted, like an overflowed lane.
+ST_UNFINISHED = 5
 
 
 @dataclasses.dataclass(frozen=True)
@@ -217,6 +221,24 @@ class DeviceConfig:
         )
         defaults.update(overrides)
         return DeviceConfig(**defaults)
+
+    @staticmethod
+    def for_workload(app: DSLApp, args, **overrides) -> "DeviceConfig":
+        """The verbs' one ``DeviceConfig``: shapes from the app,
+        capacities from the shared workload flags (``--pool``,
+        ``--max-messages``, ``--num-events``, ``--timer-weight``; ``args``
+        is the CLI's namespace or one from ``distributed.workload_args``),
+        and when the invariant is judged from the app
+        (``DSLApp.invariant_at``), which is no verb's to choose."""
+        defaults = dict(
+            pool_capacity=getattr(args, "pool", None) or 256,
+            max_steps=args.max_messages,
+            max_external_ops=max(16, args.num_events + app.num_actors + 2),
+            invariant_interval=app.invariant_interval,
+            timer_weight=args.timer_weight,
+        )
+        defaults.update(overrides)
+        return DeviceConfig.for_app(app, **defaults)
 
 
 class ScheduleState(NamedTuple):

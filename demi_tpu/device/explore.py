@@ -30,6 +30,7 @@ from .core import (
     ST_DISPATCH,
     ST_DONE,
     ST_INJECT,
+    ST_UNFINISHED,
     ST_VIOLATION,
     DeviceConfig,
     RowProposal,
@@ -165,6 +166,11 @@ def _injection_phase(
         | (new_cursor >= e)
         | (is_wait_like & (next_op == OP_END))
     )
+    if app.invariant_at == "quiescence":
+        # A run ends at quiescence or has no verdict: the final segment
+        # drains whatever budget its wait carries (the bounded waits
+        # before it keep theirs). Host twin: BaseScheduler._run_program.
+        seg_budget = jnp.where(final_seg, 0, seg_budget).astype(jnp.int32)
     state = state._replace(
         ext_cursor=new_cursor,
         seg_budget=seg_budget,
@@ -395,6 +401,12 @@ def make_explore_kernel_variant(app: DSLApp, cfg: DeviceConfig, name: str):
 
 
 def _finalize(state: ScheduleState, app, cfg) -> ScheduleState:
+    """The verdict of a lane that ran out of steps mid-flight: under an
+    invariant judged after any delivery, the invariant of whatever state
+    was reached; under one judged at quiescence only, none (the lane is
+    unfinished; host twin: ``BaseScheduler.execute``)."""
+    if app.invariant_at == "quiescence":
+        return state._replace(status=jnp.int32(ST_UNFINISHED))
     code = check_invariant(state, app)
     return state._replace(
         status=jnp.where(code != 0, ST_VIOLATION, ST_DONE).astype(jnp.int32),

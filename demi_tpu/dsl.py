@@ -65,6 +65,30 @@ class DSLApp:
     # the same jax predicate gates injection on the host oracle and ends
     # the dispatch segment inside the device kernels.
     conditions: Tuple[Callable, ...] = ()
+    # When the invariant may be judged: "delivery" (after any delivery: a
+    # state predicate that must hold throughout, raft's election safety)
+    # or "quiescence" (only once nothing is deliverable and the program
+    # has ended: a property of the outcome, broadcast's agreement, false
+    # in the middle of any flood). A property of the invariant, so every
+    # verb reads it here (``DeviceConfig.for_workload``, the host tier
+    # through ``make_host_invariant``). Under "quiescence" a run ends at
+    # quiescence or has no verdict: the program's final wait drains
+    # whatever budget it carries, and a run cut by its step budget is
+    # unfinished, not judged.
+    invariant_at: str = "delivery"
+
+    def __post_init__(self):
+        if self.invariant_at not in ("delivery", "quiescence"):
+            raise ValueError(
+                f"invariant_at must be 'delivery' or 'quiescence', "
+                f"got {self.invariant_at!r}"
+            )
+
+    @property
+    def invariant_interval(self) -> int:
+        """The deliveries between invariant checks, in both tiers'
+        numbering: 1, or 0 for "at the run's end only"."""
+        return 1 if self.invariant_at == "delivery" else 0
 
     # -- naming ------------------------------------------------------------
     def actor_name(self, actor_id: int) -> str:

@@ -110,6 +110,7 @@ class Fuzzer:
         max_kills: Optional[int] = None,
         wait_budget: Optional[tuple] = None,
         num_conditions: int = 0,
+        max_sends: Optional[int] = None,
     ):
         self.num_events = num_events
         self.weights = weights
@@ -131,6 +132,11 @@ class Fuzzer:
         # Keeping a quorum alive is the app's concern; cap kills so fuzz runs
         # don't trivially kill everyone (the reference relies on weights).
         self.max_kills = max_kills
+        # Cap on client sends, atomic blocks' members included: where one
+        # send fans out to thousands of deliveries (a 64-node broadcast
+        # floods 4,033) the pool's bound is a bound on the floods in
+        # flight. A capped send is a futile draw, like a dry generator.
+        self.max_sends = max_sends
         # (lo, hi) delivery budget for generated WaitQuiescence events.
         # Bounded waits leave messages PENDING at the segment boundary, so
         # later externals (crashes, restarts) interleave mid-flood — without
@@ -210,6 +216,9 @@ class Fuzzer:
         random = rng.random
         num_events = self.num_events
         max_kills = self.max_kills
+        # The sends drawn so far are ``len(payloads)``: the cap keeps no
+        # count of its own, and costs an uncapped program one test a send.
+        max_sends = self.max_sends
         generated = 0
         futile = 0
         while generated < num_events:
@@ -226,7 +235,11 @@ class Fuzzer:
                     break
                 r -= wt
             if op == OP_SEND:
-                row = send_row(rng, alive)
+                row = (
+                    send_row(rng, alive)
+                    if max_sends is None or len(payloads) < max_sends
+                    else None
+                )
                 if row is not None:
                     payloads.append((len(kind), row[1]))
                     kind.append(OP_SEND)
@@ -270,8 +283,14 @@ class Fuzzer:
                 # programs never overshoot num_events; with <2 remaining a
                 # block is impossible — fall back to a plain send.
                 remaining = num_events - generated
+                if max_sends is not None:
+                    remaining = min(remaining, max_sends - len(payloads))
                 start = len(kind)
-                want = rng.randint(2, min(4, remaining)) if remaining >= 2 else 1
+                # Under 2 it is 1, or 0 where the send cap is spent.
+                want = (
+                    rng.randint(2, min(4, remaining)) if remaining >= 2
+                    else remaining
+                )
                 for _ in range(want):
                     row = send_row(rng, alive)
                     if row is None:

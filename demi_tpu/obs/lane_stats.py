@@ -80,7 +80,7 @@ def reduce_lanes(status, violation, deliveries, lanes,
     finished-this-round set). Called on device arrays this runs as one
     on-device reduction with a single host pull; host numpy arrays work
     too (the continuous driver's already-pulled harvest vectors)."""
-    from ..device.core import ST_DONE, ST_OVERFLOW
+    from ..device.core import ST_DONE, ST_OVERFLOW, ST_VIOLATION
 
     lanes = jnp.asarray(lanes)
     if lanes.ndim == 0:
@@ -89,7 +89,8 @@ def reduce_lanes(status, violation, deliveries, lanes,
         real = lanes
     finished = real & (status >= ST_DONE)
     overflow = real & (status == ST_OVERFLOW)
-    counted = finished & ~overflow
+    # A verdict: neither overflowed nor cut unfinished (ST_UNFINISHED).
+    counted = finished & (status <= ST_VIOLATION)
     deliv = jnp.sum(jnp.where(real, deliveries, 0))
     if invariant_interval:
         checks = (

@@ -37,6 +37,7 @@ FAULT_PLANE_DEFAULTS = {
     "hard_kill_weight": 0.0,
     "restart_weight": 0.0,
     "max_kills": 1,
+    "max_sends": None,  # client sends a program may hold; None = unlimited
     "wait_budget": None,  # (lo, hi) deliveries of a generated wait; None = drain
     "log_cap": 8,
 }
@@ -75,15 +76,8 @@ def build_workload(workload: Optional[dict], record: bool = False):
 
     args = workload_args(workload)
     app = build_app(args)
-    cfg = DeviceConfig.for_app(
-        app,
-        pool_capacity=args.pool,
-        max_steps=args.max_messages,
-        max_external_ops=max(16, args.num_events + app.num_actors + 2),
-        invariant_interval=1,
-        timer_weight=args.timer_weight,
-        record_trace=record,
-        record_parents=record,
+    cfg = DeviceConfig.for_workload(
+        app, args, record_trace=record, record_parents=record
     )
     fuzzer = build_fuzzer(app, args)
     return app, cfg, fuzzer

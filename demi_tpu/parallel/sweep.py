@@ -25,7 +25,12 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..dsl import DSLApp
-from ..device.core import ST_OVERFLOW, ST_VIOLATION, DeviceConfig
+from ..device.core import (
+    ST_OVERFLOW,
+    ST_UNFINISHED,
+    ST_VIOLATION,
+    DeviceConfig,
+)
 from ..device.encoding import lower_program, stack_programs
 from ..device.explore import make_explore_kernel
 from ..external_events import ExternalEvent
@@ -52,6 +57,9 @@ class SweepChunkResult:
     # Lanes aborted with ST_OVERFLOW (pool too small): these completed no
     # verdict, so any nonzero count means the sweep's numbers undercount.
     overflow_lanes: int = 0
+    # Lanes that ran out of steps before quiescence under an invariant
+    # judged at quiescence only (ST_UNFINISHED): no verdict either.
+    unfinished_lanes: int = 0
     # Deduped device-side schedule fingerprints (LaneResult.sched_hash)
     # for this chunk's real lanes: the honest "unique schedules" numerator.
     unique_hashes: Optional[np.ndarray] = None
@@ -143,6 +151,10 @@ class SweepResult:
         return sum(c.overflow_lanes for c in self.chunks)
 
     @property
+    def unfinished_lanes(self) -> int:
+        return sum(c.unfinished_lanes for c in self.chunks)
+
+    @property
     def lanes_digest(self) -> int:
         return sum(c.lanes_digest for c in self.chunks) % (1 << 64)
 
@@ -176,6 +188,7 @@ class _HarvestAccumulator:
         self.lanes = 0
         self.violations = 0
         self.overflow = 0
+        self.unfinished = 0
         self.codes: dict = {}
         self.first_seed: Optional[int] = None
         self.first_code: Optional[int] = None
@@ -188,8 +201,10 @@ class _HarvestAccumulator:
             self.digest + lanes_digest(seeds, statuses, codes, hashes)
         ) % (1 << 64)
         self.overflow += int((statuses == ST_OVERFLOW).sum())
+        self.unfinished += int((statuses == ST_UNFINISHED).sum())
+        # Lanes with a verdict: a cut lane's fingerprint is no schedule.
         self._hash_parts.append(
-            np.asarray(hashes)[statuses != ST_OVERFLOW]
+            np.asarray(hashes)[statuses <= ST_VIOLATION]
         )
         vio = codes != 0
         if vio.any():
@@ -219,6 +234,7 @@ class _HarvestAccumulator:
             first_violation_code=self.first_code,
             seconds=seconds,
             overflow_lanes=self.overflow,
+            unfinished_lanes=self.unfinished,
             unique_hashes=self.unique_hashes(),
             first_violating_seed=self.first_seed,
             lanes_digest=self.digest,
@@ -277,7 +293,7 @@ class _RewardBucket:
             self.lanes += int(m.sum())
             st, cd = statuses[sl][m], codes[sl][m]
             self._hash_parts.append(
-                np.asarray(hashes[sl])[m][st != ST_OVERFLOW]
+                np.asarray(hashes[sl])[m][st <= ST_VIOLATION]
             )
             self.violations += int((cd != 0).sum())
             if self.lanes >= self.chunk_size:
@@ -707,7 +723,7 @@ class SweepDriver:
             if c != 0
         }
         hashes = np.asarray(res.sched_hash)[:n_real]
-        chunk_uniq = np.unique(hashes[statuses != ST_OVERFLOW])
+        chunk_uniq = np.unique(hashes[statuses <= ST_VIOLATION])
         if lane_stats is not None:
             from ..obs import lane_stats as _ls
 
@@ -745,6 +761,7 @@ class SweepDriver:
             ),
             seconds=seconds,
             overflow_lanes=int((statuses == ST_OVERFLOW).sum()),
+            unfinished_lanes=int((statuses == ST_UNFINISHED).sum()),
             # Overflowed lanes aborted mid-schedule: their truncated
             # fingerprints are not explored schedules, keep them out.
             unique_hashes=chunk_uniq,

@@ -82,7 +82,11 @@ class BaseScheduler:
         self.max_messages = max_messages
         # 0 = only check at quiescence / end (reference default behavior;
         # RandomScheduler's interval checks via setInvariantCheckInterval).
-        self.invariant_check_interval = invariant_check_interval
+        # An invariant that may be judged at quiescence only is never
+        # judged mid-run, whatever interval a caller passes.
+        self.invariant_check_interval = (
+            0 if config.quiescence_invariant else invariant_check_interval
+        )
         self.system: Optional[ControlledActorSystem] = None
         self.trace = EventTrace()
         self.fd: Optional[FDMessageOrchestrator] = None
@@ -173,7 +177,12 @@ class BaseScheduler:
         ) as sp:
             self.prepare(externals)
             violation = self._run_program(list(externals))
-            if violation is None:
+            quiescent = self.deliveries < self.max_messages
+            # An invariant judged at quiescence only gives a run that was
+            # cut by the cap no verdict (device twin: explore._finalize).
+            if violation is None and (
+                quiescent or not self.config.quiescence_invariant
+            ):
                 violation = self.check_invariant()
             if violation is not None:
                 self.meta_trace.set_caused_violation()
@@ -187,7 +196,7 @@ class BaseScheduler:
             trace=self.trace,
             violation=violation,
             deliveries=self.deliveries,
-            quiescent=self.deliveries < self.max_messages,
+            quiescent=quiescent,
         )
 
     def _run_program(self, program: List[ExternalEvent]) -> Optional[Any]:
@@ -195,6 +204,11 @@ class BaseScheduler:
         violation: Optional[Any] = None
         while True:
             cursor, waiting_cond, budget = self._inject_until_wait(program, cursor)
+            if cursor >= len(program) and self.config.quiescence_invariant:
+                # The run ends at quiescence: the program's final wait
+                # drains whatever budget it carries (device twin:
+                # explore._injection_phase).
+                budget = None
             violation = self._dispatch_until_quiescence(waiting_cond, budget)
             self.trace.append(self._unique(Quiescence()))
             self.on_quiescence()

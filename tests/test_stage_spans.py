@@ -262,7 +262,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # Every count kept has a reader: the benchmark's dpor.fresh_share,
     # dpor.admit_us_per_candidate and dpor.materialized_share; the sweep's
     # sweep.live_step_share and sweep.fault_op_share (PR 27),
-    # sweep.prefetch_share (PR 28) and sweep.row_lowered_share (PR 30).
+    # sweep.prefetch_share (PR 28), sweep.row_lowered_share (PR 30),
+    # sweep.quiesced_share and sweep.pool_peak_share (PR 31).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -271,6 +272,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
         "dpor.candidates", "dpor.fresh", "dpor.materialized",
         "sweep.lane_steps", "sweep.live_lane_steps",
         "sweep.programs", "sweep.prefetched", "sweep.row_lowered",
+        "sweep.retired", "sweep.quiesced", "sweep.unfinished",
+        "sweep.pool_peak_rows", "sweep.pool_rows",
     } | {f"sweep.ops.{kind}" for kind in op_kinds}
     assert counts["dpor.candidates"] >= counts["dpor.fresh"] > 0
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
@@ -284,6 +287,11 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # 24 programs of the sweeper's: every actor started once in each
     assert counts["sweep.ops.start"] == 24 * sweeper.app.num_actors
     assert counts["sweep.ops.restart"] == 0
+    # every lane retired with a verdict; one job's fullest pool, sampled
+    assert counts["sweep.retired"] == counts["sweep.quiesced"] == 24
+    assert counts["sweep.unfinished"] == 0
+    assert counts["sweep.pool_rows"] == sweeper.cfg.pool_capacity
+    assert 0 < counts["sweep.pool_peak_rows"] <= counts["sweep.pool_rows"]
 
 
 @pytest.mark.parametrize("mode", ["default", "sleep_sets", "max_distance"])

@@ -595,7 +595,9 @@ def test_autotune_defaults_off_and_sweep_output_unchanged(capsys):
     cfg = DeviceConfig.for_app(
         app, pool_capacity=64, max_steps=96,
         max_external_ops=max(16, 12 + app.num_actors + 2),
-        invariant_interval=1, timer_weight=0.2,
+        # The verb takes the cadence from the app (agreement is judged at
+        # quiescence), so the direct run does too.
+        invariant_interval=app.invariant_interval, timer_weight=0.2,
     )
     fuzzer = Fuzzer(
         num_events=12,
@@ -675,6 +677,16 @@ def test_cli_tune_dry_run_smoke(capsys, tmp_path):
     assert data["dry_run"] is True
     assert data["cached"] is None
     assert "variant" in data["axes"] and "chunk" in data["axes"]
+    # Agreement is judged at quiescence only (the app says so, the verb
+    # builds interval 0 from it): round variants are candidates.
+    assert any("-round" in v for v in data["axes"]["variant"])
+    rc = main([
+        "tune", "--app", "raft", "--nodes", "3", "--batch", "16",
+        "--pool", "64", "--max-messages", "64",
+        "--cache", str(tmp_path / "c.json"), "--dry-run",
+    ])
+    assert rc == 0
+    data = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     # interval=1 workload: round variants are not semantics-preserving
     # candidates.
     assert all("-round" not in v for v in data["axes"]["variant"])
