@@ -451,16 +451,13 @@ def insert_rows(
     want = ops.prefix_sum(
         row_valid.astype(jnp.int32), cfg.use_onehot
     )  # i-th valid row wants want[i]-th free slot
-    # slot index for each row: first index where prefix == want[i] and free
-    slots = ops.rank_slots(prefix, want, cfg.use_onehot)  # [K]
     # Totals as reductions, not prefix[-1]/want[-1] reads (bit-identical
     # either way).
     n_free = jnp.sum(free.astype(jnp.int32))
     n_rows = jnp.sum(row_valid.astype(jnp.int32))
-    overflow = jnp.any(row_valid & (want > n_free))
+    overflow = n_rows > n_free  # the last valid row wants a slot past the end
     ok = row_valid & (want <= n_free)
 
-    seqs = state.seq_counter + want  # arrival order follows row order
     k = row_valid.shape[0]
     if cfg.track_fifo_heads:
         # A new row heads its channel iff the pool holds no valid
@@ -483,43 +480,78 @@ def insert_rows(
         )
         row_head = ok & ~row_timer & ~exists_pool & ~prior_batch
     if cfg.use_onehot:
-        oh_kp = ok[:, None] & (
-            slots[:, None] == jnp.arange(cfg.pool_capacity)[None, :]
-        )  # [K, P] — at most one True per column (slots strictly increase)
-        hit = jnp.any(oh_kp, axis=0)
-        new_head = (
-            ops.scatter_vec_bool(state.pool_head, oh_kp, row_head)
-            if cfg.track_fifo_heads
-            else state.pool_head
-        )
+        # Decided from the slot's side: the i-th valid row lands in the
+        # i-th free slot, so a free slot of rank r receives a row iff
+        # r <= n_rows, and that row is the one with want == r. What a slot
+        # knows from its own rank (whether it is hit, its arrival seq, a
+        # scalar creator link) is O(P); what it must read from its row is
+        # ONE [K, P] compare, contracted against a few [K] columns: a word
+        # packing src, dst and the row's bits (src is at most n, the
+        # external sender; dst is an actor id: every caller clips), the W
+        # payload words, and the creator links where they are per row.
+        hit = free & (prefix <= n_rows)
+        bits = n.bit_length()
+        flags = [row_timer, row_parked]
+        if cfg.track_fifo_heads:
+            flags.append(row_head)
+        word = row_src | (row_dst << bits)
+        for i, flag in enumerate(flags):
+            word = word | (flag.astype(jnp.int32) << (2 * bits + i))
+        # An invalid row matches no slot (prefix is never negative), and
+        # neither does a valid one past the last free slot.
+        sel = jnp.where(row_valid, want, -1)[:, None] == prefix[None, :]
+
+        def landed(col):  # [K] int32 -> [P]: the value of the row a slot gets
+            # One select-and-sum a column over the shared ``sel``: XLA
+            # fuses them all into one pass with K outermost and writes
+            # nothing of [K, P] out (ranked on the v5e against an einsum
+            # over a [K, C] table, which writes ``sel`` out first, and a
+            # variadic reduce: PERF.md, PR 32).
+            return jnp.sum(jnp.where(sel, col[:, None], 0), axis=0)
+
+        word = landed(word)
+        field = (1 << bits) - 1
+
+        def bit(i):
+            return (word >> (2 * bits + i)) & 1 != 0
+
         new_state = state._replace(
-            pool_head=new_head,
             pool_valid=state.pool_valid | hit,
-            pool_src=ops.scatter_vec_int(state.pool_src, oh_kp, row_src),
-            pool_dst=ops.scatter_vec_int(state.pool_dst, oh_kp, row_dst),
-            pool_timer=ops.scatter_vec_bool(state.pool_timer, oh_kp, row_timer),
-            pool_parked=ops.scatter_vec_bool(
-                state.pool_parked, oh_kp, row_parked
+            pool_src=jnp.where(hit, word & field, state.pool_src),
+            pool_dst=jnp.where(hit, (word >> bits) & field, state.pool_dst),
+            pool_timer=jnp.where(hit, bit(0), state.pool_timer),
+            pool_parked=jnp.where(hit, bit(1), state.pool_parked),
+            pool_msg=jnp.where(
+                hit[:, None],
+                jnp.stack(
+                    [  # row_msg is already narrowed: the round trip is exact
+                        landed(row_msg[:, j].astype(jnp.int32))
+                        for j in range(cfg.msg_width)
+                    ],
+                    axis=1,
+                ).astype(state.pool_msg.dtype),
+                state.pool_msg,
             ),
-            pool_msg=ops.scatter_rows_int(state.pool_msg, oh_kp, row_msg),
-            pool_seq=ops.scatter_vec_int(state.pool_seq, oh_kp, seqs),
+            # arrival order follows row order: the row of rank r is r-th
+            pool_seq=jnp.where(hit, state.seq_counter + prefix, state.pool_seq),
             seq_counter=state.seq_counter + n_rows,
             status=jnp.where(overflow, jnp.int32(ST_OVERFLOW), state.status),
         )
+        if cfg.track_fifo_heads:
+            new_state = new_state._replace(
+                pool_head=jnp.where(hit, bit(2), state.pool_head)
+            )
         if crec is not None:
             crec = jnp.asarray(crec, jnp.int32)
-            if crec.ndim == 0:
-                new_crec = jnp.where(hit, crec, state.pool_crec)
-            else:  # per-row creator links ([K], round-delivery inserts)
-                new_crec = jnp.where(
-                    hit,
-                    jnp.sum(
-                        jnp.where(oh_kp, crec[:, None], 0), axis=0
-                    ),
-                    state.pool_crec,
+            # per-row creator links ([K]) come from round-delivery inserts
+            new_state = new_state._replace(
+                pool_crec=jnp.where(
+                    hit, landed(crec) if crec.ndim else crec, state.pool_crec
                 )
-            new_state = new_state._replace(pool_crec=new_crec)
+            )
         return new_state
+    seqs = state.seq_counter + want  # arrival order follows row order
+    slots = ops.rank_slots(prefix, want)  # [K]
     slots = jnp.where(ok, slots, cfg.pool_capacity)  # out-of-range => dropped
     new_state = state._replace(
         pool_head=(

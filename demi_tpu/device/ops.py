@@ -14,6 +14,11 @@ exactly when the default JAX backend is a TPU).
 
 Both modes are bit-identical by construction (tests/test_device.py parity
 case runs the explore kernel in both and compares all outputs).
+
+The pool insert is the one access with no helper here: ``core.insert_rows``
+scatters by slot number in scatter mode (``rank_slots`` below) and, in
+one-hot mode, never computes a slot number at all: each slot reads its
+row off its own rank among the free slots (tests/test_insert_parity.py).
 """
 
 from __future__ import annotations
@@ -166,33 +171,9 @@ def first_true_index(mask: jnp.ndarray, k, oh: bool):
     return jnp.searchsorted(cum, k + 1, side="left").astype(jnp.int32)
 
 
-def rank_slots(prefix: jnp.ndarray, want: jnp.ndarray, oh: bool):
+def rank_slots(prefix: jnp.ndarray, want: jnp.ndarray):
     """For each want[i] (1-indexed rank), the first index where the
-    nondecreasing ``prefix`` reaches it — vectorized searchsorted-left."""
-    if oh:
-        return jnp.sum(
-            (prefix[None, :] < want[:, None]).astype(jnp.int32), axis=1
-        )
+    nondecreasing ``prefix`` reaches it — vectorized searchsorted-left.
+    Scatter mode only: the one-hot insert never needs a slot's number
+    (``core.insert_rows``)."""
     return jnp.searchsorted(prefix, want, side="left").astype(jnp.int32)
-
-
-def scatter_rows_int(dest: jnp.ndarray, oh_kp: jnp.ndarray, rows: jnp.ndarray):
-    """One-hot multi-row scatter: dest[p] = rows[k] where oh_kp[k, p]
-    (at most one True per column). dest [P, W] int, rows [K, W]."""
-    contrib = jnp.einsum("kp,kw->pw", oh_kp.astype(dest.dtype), rows)
-    hit = jnp.any(oh_kp, axis=0)
-    return jnp.where(hit[:, None], contrib, dest)
-
-
-def scatter_vec_int(dest: jnp.ndarray, oh_kp: jnp.ndarray, vals: jnp.ndarray):
-    """One-hot multi-element scatter into an int vector [P]."""
-    contrib = jnp.einsum("kp,k->p", oh_kp.astype(dest.dtype), vals)
-    hit = jnp.any(oh_kp, axis=0)
-    return jnp.where(hit, contrib, dest)
-
-
-def scatter_vec_bool(dest: jnp.ndarray, oh_kp: jnp.ndarray, vals: jnp.ndarray):
-    """One-hot multi-element scatter into a bool vector [P]."""
-    hit = jnp.any(oh_kp, axis=0)
-    val = jnp.any(oh_kp & vals[:, None], axis=0)
-    return jnp.where(hit, val, dest)
