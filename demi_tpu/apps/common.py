@@ -82,11 +82,19 @@ def dsl_start_events(app: DSLApp) -> List[Start]:
 
 class DSLSendGenerator:
     """Fuzzer message generator sending app-provided messages to random alive
-    actors. ``make_msg(rng, counter) -> tuple`` builds the payload."""
+    actors, or all to ``target`` (an actor name: a protocol whose clients
+    speak to one node). ``make_msg(rng, counter) -> tuple`` builds the
+    payload."""
 
-    def __init__(self, app: DSLApp, make_msg: Callable[[_random.Random, int], tuple]):
+    def __init__(
+        self,
+        app: DSLApp,
+        make_msg: Callable[[_random.Random, int], tuple],
+        target: Optional[str] = None,
+    ):
         self.app = app
         self.make_msg = make_msg
+        self.target = target
         self._counter = 0
 
     def reset(self) -> None:
@@ -95,14 +103,24 @@ class DSLSendGenerator:
     def generate_row(
         self, rng: _random.Random, alive: Sequence[str]
     ) -> Optional[Tuple[str, tuple]]:
-        """The send as the fuzzer records it: (target name, payload)."""
+        """The send as the fuzzer records it: (target name, payload).
+        With a ``target`` the draws are the same (a program's other
+        events stay where a random addressee left them), and a send
+        drawn while the target is down is futile: not sent, not
+        counted."""
         if not alive:
             return None
         self._counter += 1
         msg = self.make_msg(rng, self._counter)
         if msg is None:
             return None
-        return rng.choice(list(alive)), msg
+        drawn = rng.choice(list(alive))
+        if self.target is None:
+            return drawn, msg
+        if self.target not in alive:
+            self._counter -= 1
+            return None
+        return self.target, msg
 
     def generate(self, rng: _random.Random, alive: Sequence[str]) -> Optional[Send]:
         row = self.generate_row(rng, alive)

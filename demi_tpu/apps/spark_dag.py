@@ -9,7 +9,10 @@ the current stage's mask completes; after the last stage it declares the
 job done.
 
 Safety invariant (code 1): job_done ⇒ every task the master credited was
-actually executed by some worker — masters must not credit work nobody did.
+actually executed by some worker that is up — masters must not credit work
+nobody holds. A worker that is killed, or hard-killed and restarted (its
+executed set starts empty), holds nothing: under crash-recovery the
+master, which resubmits nothing, can declare a job done whose work is gone.
 
 Seeded bug ``bug="stale_task"``: the master ignores the stage field of
 TASK_DONE and credits late/duplicate completions from earlier stages to the
@@ -41,6 +44,16 @@ CUR = 0
 DONE_FLAG = 1
 MASKS = 2
 # Worker state layout: [_, _, executed_mask[stage 0..S-1]] (same width).
+# A stage's mask is one int32 word up to 31 tasks, and ceil(T / 32) words
+# past that (``mask_words``): task t is bit ``t % 32`` of word ``t // 32``
+# of its stage, stage s's words at MASKS + s * words. A SQL shuffle's
+# default 200 tasks a stage are 7 words.
+WORD_BITS = 32
+
+
+def mask_words(tasks_per_stage: int) -> int:
+    """int32 words of one stage's task mask."""
+    return max(1, -(-tasks_per_stage // WORD_BITS))
 
 
 def make_spark_app(
@@ -53,9 +66,26 @@ def make_spark_app(
     n = num_workers + 1  # + master (actor 0)
     S = num_stages
     T = tasks_per_stage
-    state_width = MASKS + S
-    full_mask = (1 << T) - 1
+    W = mask_words(T)
+    state_width = MASKS + S * W
     max_outbox = 2 * T + 1
+    # Per state word: the stage whose mask it is part of (-1: none) and
+    # what it reads when every task it holds is in (a whole word is -1).
+    stage_of = np.full(state_width, -1, np.int32)
+    stage_of[MASKS:] = np.repeat(np.arange(S, dtype=np.int32), W)
+    full_words = np.zeros(state_width, np.uint32)
+    for w in range(W):
+        bits = min(WORD_BITS, T - WORD_BITS * w)
+        full_words[MASKS + w : state_width : W] = (1 << bits) - 1
+    full_words = full_words.astype(np.int32)
+
+    def _task_bit(stage, task):
+        """``(state index, bit)`` of ``task`` in the mask of ``stage``
+        (clipped to a stage that is there); bit 0 for no such task."""
+        known = (task >= 0) & (task < T)
+        word = jnp.clip(task >> 5, 0, W - 1)  # task // WORD_BITS
+        bit = jnp.where(known, jnp.int32(1) << (task & 31), 0)
+        return MASKS + jnp.clip(stage, 0, S - 1) * W + word, bit
 
     def init_state(actor_id: int) -> np.ndarray:
         return np.zeros(state_width, np.int32)
@@ -84,10 +114,8 @@ def make_spark_app(
     def on_launch(actor_id, state, snd, msg):
         stage, task = msg[1], msg[2]
         is_worker = actor_id != 0
-        safe_stage = jnp.clip(stage, 0, S - 1)
-        bit = jnp.where((task >= 0) & (task < T), jnp.int32(1) << task, 0)
-        new_mask = vget(state, MASKS + safe_stage) | bit
-        state = vset(state, MASKS + safe_stage, new_mask, is_worker)
+        at, bit = _task_bit(stage, task)
+        state = vset(state, at, vget(state, at) | bit, is_worker)
         out = jnp.zeros((max_outbox, 2 + MSG_W), jnp.int32)
         row = jnp.stack(
             [jnp.int32(1), jnp.int32(0), jnp.int32(T_DONE), stage, task]
@@ -106,11 +134,12 @@ def make_spark_app(
             relevant = is_master & running
         else:
             relevant = is_master & running & (stage == cur)
-        safe_cur = jnp.clip(cur, 0, S - 1)
-        bit = jnp.where((task >= 0) & (task < T), jnp.int32(1) << task, 0)
-        mask = vget(state, MASKS + safe_cur) | jnp.where(relevant, bit, 0)
-        state = vset(state, MASKS + safe_cur, mask)
-        stage_complete = relevant & (mask == full_mask)
+        at, bit = _task_bit(cur, task)
+        state = vset(state, at, vget(state, at) | jnp.where(relevant, bit, 0))
+        in_cur = jnp.asarray(stage_of) == jnp.clip(cur, 0, S - 1)
+        stage_complete = relevant & jnp.all(
+            ~in_cur | (state == jnp.asarray(full_words))
+        )
         next_stage = cur + 1
         state = vset(state, CUR, jnp.where(stage_complete, next_stage, cur))
         job_done = stage_complete & (next_stage >= S)
@@ -129,10 +158,16 @@ def make_spark_app(
         )
 
     def invariant(states, alive):
-        """job_done ⇒ every credited task was executed by some worker."""
+        """job_done ⇒ every credited task was executed by some worker
+        that is up."""
         master = states[0]
-        credited = master[MASKS : MASKS + S]
-        executed = states[1:, MASKS : MASKS + S]  # [workers, S]
+        credited = master[MASKS:]
+        # A worker that is down (killed, or stopped and not restarted)
+        # holds nothing, as the host oracle's checkpoint has it (no
+        # reply from a crashed or isolated actor): without the mask the
+        # device read a dead worker's last state and the tiers could
+        # part on a clean lane.
+        executed = jnp.where(alive[1:, None], states[1:, MASKS:], 0)
         executed_union = jnp.bitwise_or.reduce(executed, axis=0)
         phantom = credited & ~executed_union
         bad = (master[DONE_FLAG] == 1) & jnp.any(phantom != 0) & alive[0]
@@ -152,11 +187,12 @@ def make_spark_app(
 
 
 def spark_send_generator(app: DSLApp) -> DSLSendGenerator:
-    """External SubmitJob to the master."""
+    """External SubmitJob, addressed to the master (a worker ignores
+    one, and a program holds at most one)."""
 
     def make_msg(rng: _random.Random, counter: int):
         if counter > 1:
             return None  # one job per program
         return (T_SUBMIT, 0, 0)
 
-    return DSLSendGenerator(app, make_msg)
+    return DSLSendGenerator(app, make_msg, target=app.actor_name(0))

@@ -41,7 +41,10 @@ def build_app(args) -> DSLApp:
     if args.app == "spark":
         from .apps.spark_dag import make_spark_app
 
-        return make_spark_app(num_workers=max(1, args.nodes - 1), bug=args.bug)
+        return make_spark_app(
+            num_workers=max(1, args.nodes - 1), num_stages=args.stages,
+            tasks_per_stage=args.tasks, bug=args.bug,
+        )
     if args.app == "twopc":
         from .apps.twopc import make_twopc_app
 
@@ -112,7 +115,12 @@ def _workload_discriminator(args) -> dict:
     without a seeded bug, reliable vs unreliable broadcast) would
     otherwise collide on one cache entry and inherit each other's
     calibrated rates."""
-    return {"workload": f"{args.app}:{args.bug or 'none'}"}
+    workload = f"{args.app}:{args.bug or 'none'}"
+    if args.app == "spark":
+        # The job's shape is the handler's: a 4 x 200 job's launch is
+        # 401 rows where the 2 x 4 one's is 9.
+        workload += f":{args.stages}x{args.tasks}"
+    return {"workload": workload}
 
 
 #: How many violating lanes the sweep summary names (``violating_seeds``).
@@ -2221,6 +2229,11 @@ def main(argv: Optional[list] = None) -> int:
         p.add_argument("--log-cap", type=int, default=knobs["log_cap"],
                        dest="log_cap",
                        help="raft: log entries a node holds")
+        p.add_argument("--stages", type=int, default=knobs["stages"],
+                       help="spark: stages a job has")
+        p.add_argument("--tasks", type=int, default=knobs["tasks"],
+                       help="spark: tasks a stage has (each launched "
+                            "twice; a SQL shuffle's default is 200)")
         p.add_argument(
             "--handler-edit", default=None, dest="handler_edit",
             metavar="KIND[:TAG]",

@@ -622,6 +622,20 @@ class ContinuousSweepDriver:
                         )
                         self.last_unfinished_lanes += unfinished
                         if sample_pool:
+                            # What the retired lanes put in their pools
+                            # (every insert advances ``seq_counter`` by
+                            # its rows) against the outbox rows their
+                            # deliveries carried through the insert: a
+                            # [B] pull each, beside the status pull.
+                            obs.stage_count(
+                                "sweep.rows_inserted",
+                                int(np.asarray(state.seq_counter)[fin].sum()),
+                            )
+                            obs.stage_count(
+                                "sweep.outbox_rows",
+                                int(np.asarray(state.deliveries)[fin].sum())
+                                * self.cfg.max_outbox,
+                            )
                             obs.stage_count("sweep.retired", len(fin))
                             obs.stage_count(
                                 "sweep.quiesced",

@@ -28,9 +28,10 @@ from typing import Optional
 
 
 #: The fault plane's knobs (crash-recovery, link partitions, bounded
-#: waits, the raft log's capacity) with the values that were literals in
-#: ``cli.build_fuzzer`` / ``cli.build_app`` before they became flags: the
-#: CLI's flags and ``DEFAULT_WORKLOAD`` default to these.
+#: waits, the raft log's capacity, the spark job's shape) with the values
+#: that were literals in ``cli.build_fuzzer`` / ``cli.build_app`` before
+#: they became flags: the CLI's flags and ``DEFAULT_WORKLOAD`` default to
+#: these.
 FAULT_PLANE_DEFAULTS = {
     "send_weight": 0.6,
     "wait_weight": 0.15,
@@ -40,6 +41,10 @@ FAULT_PLANE_DEFAULTS = {
     "max_sends": None,  # client sends a program may hold; None = unlimited
     "wait_budget": None,  # (lo, hi) deliveries of a generated wait; None = drain
     "log_cap": 8,
+    # App-shape keys the other apps ignore, as they ignore ``log_cap``:
+    # spark's stages a job and tasks a stage.
+    "stages": 2,
+    "tasks": 4,
 }
 
 DEFAULT_WORKLOAD = {

@@ -40,6 +40,11 @@ from demi_tpu.device.encoding import empty_programs, lower_into, lower_program
 from demi_tpu.fuzzing import Fuzzer, FuzzerWeights
 
 
+def _untargeted(gen):
+    gen.target = None
+    return gen
+
+
 def _all_started(states, alive):
     return jnp.all(alive)
 
@@ -47,6 +52,13 @@ def _all_started(states, alive):
 APPS = {
     "raft": (lambda: make_raft_app(5, log_cap=8, bug="multivote"), raft_send_generator),
     "spark": (lambda: make_spark_app(num_workers=3), spark_send_generator),
+    # The spark generator as it was at the parent commit: SubmitJob to
+    # any alive actor (since PR 33 it names the driver, with the same
+    # draws); keeps the parent's four hashes pinned.
+    "spark-anywhere": (
+        lambda: make_spark_app(num_workers=3),
+        lambda app: _untargeted(spark_send_generator(app)),
+    ),
     "twopc": (lambda: make_twopc_app(4), twopc_send_generator),
     "broadcast": (
         lambda: dataclasses.replace(
@@ -133,10 +145,15 @@ PARENT_HASH = {
     "raft-nemesis": "7ffc6c12500fa4f1b9add23e4a82e8c5",
     "raft-atomic": "3e13e204cee61e2d0ab6a2885dc6805b",
     "raft-dry": "a06d24d915d033d815f112833b765a4c",
-    "spark-defaults": "5685124ff12f851eb40380b268eac045",
-    "spark-nemesis": "7abc724d06b0f6318b8d538ffb602026",
-    "spark-atomic": "874e7fa00aaa488170f3cb9ec5a4295d",
-    "spark-dry": "ed29a37b7fb2733556bbc86629620bd0",
+    # recorded at PR 33, which addressed SubmitJob to the driver
+    "spark-defaults": "cd45f2e2d9422ca773fcb840295cdc18",
+    "spark-nemesis": "71d671040e93fca3a88d50573202246a",
+    "spark-atomic": "46353034e1c357fd6fce170f344f920b",
+    "spark-dry": "8d3d33071e67b9353e3c4085c373e4c5",
+    "spark-anywhere-defaults": "5685124ff12f851eb40380b268eac045",
+    "spark-anywhere-nemesis": "7abc724d06b0f6318b8d538ffb602026",
+    "spark-anywhere-atomic": "874e7fa00aaa488170f3cb9ec5a4295d",
+    "spark-anywhere-dry": "ed29a37b7fb2733556bbc86629620bd0",
     "twopc-defaults": "fca04ff18a6e7f5f663e32bef5000040",
     "twopc-nemesis": "3fa1f75012346385fd400c50801eac73",
     "twopc-atomic": "9092040dc7d7b5cf679c10864ca5de86",

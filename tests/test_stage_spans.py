@@ -263,7 +263,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # dpor.admit_us_per_candidate and dpor.materialized_share; the sweep's
     # sweep.live_step_share and sweep.fault_op_share (PR 27),
     # sweep.prefetch_share (PR 28), sweep.row_lowered_share (PR 30),
-    # sweep.quiesced_share and sweep.pool_peak_share (PR 31).
+    # sweep.quiesced_share and sweep.pool_peak_share (PR 31),
+    # sweep.outbox_fill_share (PR 33).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -274,6 +275,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
         "sweep.programs", "sweep.prefetched", "sweep.row_lowered",
         "sweep.retired", "sweep.quiesced", "sweep.unfinished",
         "sweep.pool_peak_rows", "sweep.pool_rows",
+        "sweep.rows_inserted", "sweep.outbox_rows",
     } | {f"sweep.ops.{kind}" for kind in op_kinds}
     assert counts["dpor.candidates"] >= counts["dpor.fresh"] > 0
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
@@ -292,6 +294,12 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     assert counts["sweep.unfinished"] == 0
     assert counts["sweep.pool_rows"] == sweeper.cfg.pool_capacity
     assert 0 < counts["sweep.pool_peak_rows"] <= counts["sweep.pool_rows"]
+    # what the 24 lanes put in their pools, against their deliveries'
+    # outbox rows through the insert
+    assert counts["sweep.outbox_rows"] % sweeper.cfg.max_outbox == 0
+    assert 0 < counts["sweep.rows_inserted"] <= (
+        counts["sweep.outbox_rows"] + 24 * sweeper.cfg.max_external_ops
+    )
 
 
 @pytest.mark.parametrize("mode", ["default", "sleep_sets", "max_distance"])
