@@ -145,13 +145,6 @@ class DeviceConfig:
     # sequential srcdst_fifo kernels (parity pin for the incremental
     # maintenance; tests/test_device_srcdst.py).
     head_recompute: bool = False
-    # Bit-packed boolean gathers on the one-hot path: the liveness and
-    # isolation tests in deliverable_mask pack their bool tables into
-    # uint32 words, cutting the one-hot compare cost by ~32x (the cut
-    # matrix, once the largest of them, is no longer read there: cut
-    # links drop). Opt-in TPU lever (bit-identical; parity-pinned in
-    # tests/test_device.py; ranked by bench_matrix).
-    packed_gathers: bool = False
 
     def __post_init__(self):
         if self.index_mode not in ("auto", "onehot", "scatter"):
@@ -162,11 +155,6 @@ class DeviceConfig:
         if self.msg_dtype not in ("int32", "int16"):
             raise ValueError(
                 f"msg_dtype must be 'int32' or 'int16', got {self.msg_dtype!r}"
-            )
-        if self.packed_gathers and self.index_mode == "scatter":
-            raise ValueError(
-                "packed_gathers applies to the one-hot path; "
-                "index_mode='scatter' would silently ignore it"
             )
         if self.round_delivery and self.record_trace and not self.trace_capacity:
             # Round mode appends up to num_actors records per step; the
@@ -351,37 +339,27 @@ def deliverable_mask(state: ScheduleState, cfg: DeviceConfig) -> jnp.ndarray:
     (scrubbed at the HardKill, masked while it is down); only an external
     send can wait in the pool for one. Isolation (soft ``Kill``) holds."""
     n = cfg.num_actors
-    oh = cfg.use_onehot
     dst = state.pool_dst
     src = state.pool_src
     src_is_external = src >= n
     src_clamped = jnp.minimum(src, n - 1)
-    if cfg.packed_gathers and not oh:
-        # Loud at trace time: 'auto' resolved to the scatter path, so
-        # the flag would silently measure nothing.
-        raise ValueError(
-            "packed_gathers requires one-hot mode; on this backend "
-            "index_mode='auto' resolves to scatter — set "
-            "index_mode='onehot' explicitly"
-        )
-    if oh and cfg.packed_gathers:
-        dst_ok = ops.packed_gather_bool(state.started, dst) & ~(
-            ops.packed_gather_bool(state.stopped, dst)
-        )
-        dst_reachable = ~ops.packed_gather_bool(state.isolated, dst)
+    # Three booleans an actor fold to the two tables read here: "the
+    # receiver is alive" at dst, "the sender is isolated" at src. The
+    # one-hot path packs each into 32-bit words and takes an entry's bit
+    # from its word (ops.packed_gather_bool): ceil(N/32) selects over [P]
+    # where a one-hot compare is [P, N].
+    if cfg.use_onehot:
+        dst_alive = ops.packed_gather_bool(alive_mask(state), dst)
         src_isolated = ops.packed_gather_bool(state.isolated, src_clamped)
     else:
-        dst_ok = ops.gather_vec(state.started, dst, oh) & ~ops.gather_vec(
-            state.stopped, dst, oh
-        )
-        dst_reachable = ~ops.gather_vec(state.isolated, dst, oh)
-        src_isolated = ops.gather_vec(state.isolated, src_clamped, oh)
-    # timers/externals only need the receiver un-isolated; internal
-    # messages also need an un-isolated sender.
+        dst_alive = alive_mask(state)[dst]
+        src_isolated = state.isolated[src_clamped]
+    # timers/externals only need the receiver alive; internal messages
+    # also need an un-isolated sender.
     passes_network = jnp.where(
         state.pool_timer | src_is_external, True, ~src_isolated
-    ) & dst_reachable
-    return state.pool_valid & ~state.pool_parked & dst_ok & passes_network
+    )
+    return state.pool_valid & ~state.pool_parked & dst_alive & passes_network
 
 
 def fifo_head_mask(state: ScheduleState, cfg: "DeviceConfig") -> jnp.ndarray:

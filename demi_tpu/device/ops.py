@@ -142,23 +142,21 @@ def pack_bits(vec: jnp.ndarray) -> jnp.ndarray:
     )
 
 
-def _extract_bit(words: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """Select word idx>>5 from ``words`` ([W32] shared table or [P, W32]
-    per-entry rows) and extract bit idx&31 -> bool[P]."""
-    widx = idx >> 5
-    woh = widx[:, None] == jnp.arange(words.shape[-1])[None, :]
-    table = words[None, :] if words.ndim == 1 else words
-    w = jnp.sum(jnp.where(woh, table, jnp.uint32(0)), axis=1)
-    return ((w >> (idx & 31).astype(jnp.uint32)) & 1).astype(bool)
-
-
 def packed_gather_bool(vec: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    """vec[idx] for bool vec[N], idx[P] — O(P*N/32) instead of the [P, N]
-    one-hot compare's O(P*N): the table packs to ceil(N/32) words, the
-    per-entry word select is a tiny one-hot, and the bit extract is
-    elementwise shift/mask (VPU-friendly; no dynamic gathers). Out-of-
-    range idx reads False, like the one-hot form."""
-    return _extract_bit(pack_bits(vec), idx)
+    """vec[idx] for bool vec[N], idx[P], without a dynamic gather and
+    without the [P, N] one-hot compare: the table packs to ceil(N/32)
+    words, each entry takes its word (idx >> 5) by one [P] select per
+    word (nothing [P, words]-shaped: on the v5e the selects read 4%
+    under a [P, 2] one-hot and its sum at N = 64, PERF.md PR 34) and
+    its bit (idx & 31) by shift and mask. Out-of-range idx reads False,
+    like the one-hot form. ``core.deliverable_mask`` reads its liveness
+    bits this way on the one-hot path."""
+    words = pack_bits(vec)
+    widx = idx >> 5
+    w = jnp.zeros(idx.shape, jnp.uint32)
+    for j in range(words.shape[0]):
+        w = jnp.where(widx == j, words[j], w)
+    return ((w >> (idx & 31).astype(jnp.uint32)) & 1).astype(bool)
 
 
 def first_true_index(mask: jnp.ndarray, k, oh: bool):

@@ -12,6 +12,7 @@ data).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import pathlib
 import sys
@@ -73,30 +74,6 @@ def main(argv=None):
                 print(json.dumps({
                     "impl": tag, "batch": batch, "error": repr(e)[:300]
                 }), flush=True)
-    # Packed-gather variant (bit-packed network/liveness tests on the
-    # one-hot path; bit-identical, ~32x fewer VPU ops in
-    # deliverable_mask's cut gather) — only meaningful where one-hot
-    # mode is active, i.e. on TPU.
-    import dataclasses
-
-    pcfg = dataclasses.replace(
-        cfg, packed_gathers=True, index_mode="onehot"
-    )
-    if pcfg.use_onehot and platform not in ("cpu",):
-        for batch in batches[:1]:
-            try:
-                sps, comp = measure(make_explore_kernel(app, pcfg), batch)
-                print(json.dumps({
-                    "impl": "xla-packed", "platform": platform,
-                    "batch": batch, "schedules_per_sec": round(sps, 1),
-                    "compile_s": round(comp, 1),
-                }), flush=True)
-            except Exception as e:
-                print(json.dumps({
-                    "impl": "xla-packed", "batch": batch,
-                    "error": repr(e)[:300],
-                }), flush=True)
-
     # Round-delivery variants (round-granularity invariant checks; see
     # DESIGN.md §3b) — the per-step-parallelism lever on this hardware.
     rcfg = dataclasses.replace(cfg, round_delivery=True, early_exit=True)

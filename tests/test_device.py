@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from demi_tpu.apps.broadcast import TAG_BCAST, make_broadcast_app
 from demi_tpu.apps.common import dsl_start_events, make_host_invariant
@@ -19,10 +20,12 @@ from demi_tpu.device.encoding import (
 )
 from demi_tpu.device.explore import make_single_lane_trace_kernel
 from demi_tpu.external_events import (
+    HardKill,
     Kill,
     MessageConstructor,
     Partition,
     Send,
+    Start,
     UnPartition,
     WaitQuiescence,
 )
@@ -477,31 +480,13 @@ def test_int16_out_of_range_payload_rejected():
         lower_program(app, cfg, program)
 
 
-def test_packed_gathers_bit_identical():
-    """DeviceConfig.packed_gathers (bit-packed network/liveness tests on
-    the one-hot path, round 5): whole lanes must run bit-identical with
-    and without it, across partitions/kills/timers (the packed path
-    covers started/stopped/isolated AND the cut matrix)."""
-    import dataclasses
-
-    import jax
-
+def _faulted_raft_case():
     from demi_tpu.apps.raft import T_CLIENT, make_raft_app
-    from demi_tpu.device.encoding import lower_program, stack_programs
-    from demi_tpu.device.explore import make_explore_kernel
-    from demi_tpu.external_events import (
-        Kill,
-        MessageConstructor,
-        Partition,
-        Send,
-        UnPartition,
-        WaitQuiescence,
-    )
 
     app = make_raft_app(3)
-    cfg = DeviceConfig.for_app(
-        app, pool_capacity=96, max_steps=128, max_external_ops=24,
-        index_mode="onehot", timer_weight=0.3,
+    shapes = dict(
+        pool_capacity=96, max_steps=128, max_external_ops=24,
+        timer_weight=0.3,
     )
     program = dsl_start_events(app) + [
         Send(app.actor_name(0),
@@ -512,15 +497,75 @@ def test_packed_gathers_bit_identical():
         Kill(app.actor_name(2)),
         WaitQuiescence(30),
     ]
-    batch = 16
-    progs = stack_programs([lower_program(app, cfg, program)] * batch)
+    return app, shapes, program, 16
+
+
+def _faulted_flood_case():
+    # The flood cell's shape class: 64 actors are two packed words, the
+    # pool is bcast64-flood's 4,608; a soft kill, a hard kill and a
+    # restart land inside the flood.
+    app = make_broadcast_app(64)
+    shapes = dict(
+        pool_capacity=4608, max_steps=300, max_external_ops=72,
+        invariant_interval=app.invariant_interval,
+    )
+    program = dsl_start_events(app) + [
+        _send(app, 3, 1),
+        WaitQuiescence(40),
+        Kill(app.actor_name(40)),
+        HardKill(app.actor_name(7)),
+        WaitQuiescence(60),
+        Start(app.actor_name(7)),
+        WaitQuiescence(),
+    ]
+    return app, shapes, program, 4
+
+
+@pytest.mark.parametrize(
+    "case", [_faulted_raft_case, _faulted_flood_case],
+    ids=["raft3-partition-kill-timers", "bcast64-kills-restart"],
+)
+def test_deliverable_mask_onehot_matches_scatter(case):
+    """The one-hot path reads deliverable_mask's liveness bits from
+    packed words, the scatter path by ``vec[idx]``: whole lanes must run
+    bit-identical in both, across partitions, kills, restarts and
+    timers."""
+    app, shapes, program, batch = case()
     keys = jax.random.split(jax.random.PRNGKey(11), batch)
-    plain = make_explore_kernel(app, cfg)(progs, keys)
-    packed = make_explore_kernel(
-        app, dataclasses.replace(cfg, packed_gathers=True)
-    )(progs, keys)
+    results = {}
+    for index_mode in ("onehot", "scatter"):
+        cfg = DeviceConfig.for_app(app, index_mode=index_mode, **shapes)
+        progs = stack_programs([lower_program(app, cfg, program)] * batch)
+        results[index_mode] = make_explore_kernel(app, cfg)(progs, keys)
+    assert int(np.asarray(results["scatter"].deliveries).min()) > 0
     for field in ("status", "violation", "deliveries", "sched_hash"):
         np.testing.assert_array_equal(
-            np.asarray(getattr(plain, field)),
-            np.asarray(getattr(packed, field)),
+            np.asarray(getattr(results["onehot"], field)),
+            np.asarray(getattr(results["scatter"], field)),
+            err_msg=field,
         )
+
+
+@pytest.mark.parametrize("n", [1, 5, 17, 31, 32, 33, 64, 65])
+def test_packed_gather_bool_matches_indexing(n):
+    """ops.packed_gather_bool against ``vec[idx]`` on random tables and
+    indices, word boundaries included; an index out of range reads False,
+    as the one-hot form does."""
+    from demi_tpu.device import ops
+
+    rng = np.random.default_rng(n)
+    for _ in range(8):
+        vec = rng.random(n) < 0.5
+        idx = rng.integers(0, n, size=97).astype(np.int32)
+        idx[:4] = [n, n + 31, 4096, -1]   # out of range, both ends
+        got = np.asarray(ops.packed_gather_bool(jnp.asarray(vec), jnp.asarray(idx)))
+        want = np.where((idx >= 0) & (idx < n), vec[np.clip(idx, 0, n - 1)], False)
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(
+            got, np.asarray(ops.gather_vec(jnp.asarray(vec), jnp.asarray(idx), True))
+        )
+    # all-True table: every in-range bit reads True, so a mis-selected
+    # word or shift cannot hide behind a random zero
+    ones = jnp.ones(n, bool)
+    every = jnp.arange(n, dtype=jnp.int32)
+    assert bool(jnp.all(ops.packed_gather_bool(ones, every)))
