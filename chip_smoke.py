@@ -45,13 +45,6 @@ SIZES = {
     ),
 }
 
-_COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
-
-
 class _Tee(io.TextIOBase):
     """Standard output that also keeps what a verb printed, so its JSON
     summary can be read back."""
@@ -70,40 +63,13 @@ class _Tee(io.TextIOBase):
 
 class Smoke:
     def __init__(self, size: dict, device: dict):
-        import jax
+        import demi_tpu.device  # noqa: F401  (the compile ledger listens from here)
 
         self.size = size
         self.device = device
         self.phases: dict = {}
-        # (start, end) of every trace/lower/compile event, and the
-        # backend compiles' seconds.
-        self._compile = {"spans": [], "backend": 0.0}
-        self._cache = {"hits": 0, "misses": 0}
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration
-        )
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def close(self) -> None:
-        import jax
-
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        jax.monitoring.unregister_event_listener(self._on_event)
 
     # -- measurement -------------------------------------------------------
-    def _on_duration(self, event: str, seconds: float, **_kw) -> None:
-        if event in _COMPILE_EVENTS:
-            end = time.perf_counter()
-            self._compile["spans"].append((end - seconds, end))
-        if event == _COMPILE_EVENTS[2]:
-            self._compile["backend"] += seconds
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self._cache["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self._cache["misses"] += 1
-
     def _peak_bytes(self):
         import jax
 
@@ -119,30 +85,29 @@ class Smoke:
         """Time one phase: wall seconds, compile seconds apart from run
         seconds (JAX's own trace/lower/compile duration events), the
         persistent cache's hits and misses, and the peak device memory so
-        far. The record is kept only if the body did not raise."""
-        before = (
-            len(self._compile["spans"]), self._compile["backend"],
-            dict(self._cache),
-        )
+        far. The compile numbers are the program's own ledger's
+        (``demi_tpu.obs.compile_ledger``: one listener in the process),
+        whose ``covered_s`` counts a function traced inside another's
+        tracing once, so it cannot pass the wall's. The record is kept
+        only if the body did not raise."""
+        from demi_tpu.obs import compile_ledger
+
+        before = compile_ledger()["total"]
         record: dict = {}
         t0 = time.perf_counter()
         yield record
         wall = time.perf_counter() - t0
-        # A function traced inside another's tracing reports both, one
-        # inside the other: the seconds they cover together, not their
-        # sum, which can pass the wall's.
-        compile_s, covered = 0.0, t0
-        for start, end in sorted(self._compile["spans"][before[0]:]):
-            if end > covered:
-                compile_s += end - max(start, covered)
-                covered = end
+        total = compile_ledger()["total"]
+        took = {k: total[k] - before[k] for k in total}
         record.update(
             wall_s=round(wall, 3),
-            compile_s=round(compile_s, 3),
-            backend_compile_s=round(self._compile["backend"] - before[1], 3),
-            run_s=round(wall - compile_s, 3),
-            cache_hits=self._cache["hits"] - before[2]["hits"],
-            cache_misses=self._cache["misses"] - before[2]["misses"],
+            compile_s=round(took["covered_s"], 3),
+            backend_compile_s=round(
+                took["compile_s"] + took["cache_load_s"], 3
+            ),
+            run_s=round(wall - took["covered_s"], 3),
+            cache_hits=took["cache_hits"],
+            cache_misses=took["cache_misses"],
             peak_bytes_in_use=self._peak_bytes(),
         )
         self.phases[name] = record
@@ -476,7 +441,6 @@ def run(size: dict, device: dict) -> dict:
             phase_mesh_parity(smoke)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
-        smoke.close()
     return smoke.phases
 
 

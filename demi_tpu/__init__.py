@@ -12,12 +12,25 @@ SURVEY.md for the structural map. Two tiers:
     TPU mesh.
 """
 
+import sys as _sys
+import time as _time
+
+_T0 = _time.perf_counter_ns()   # the set-up ledger's first stage starts here
+
 __version__ = "0.1.0"
 
-from . import events, external_events, fingerprints, trace, config, dsl  # noqa: F401
-from .config import SchedulerConfig
-from .trace import EventTrace
-from .events import Unique
+from .obs import spans as _spans
+
+with _spans.stage("setup.import", start_ns=_T0, module=__name__):
+    if "jax" not in _sys.modules:
+        # dsl.py is the first of the program to want jax: a stage of its
+        # own, absent where the caller imported jax first.
+        with _spans.stage("setup.import", module="jax"):
+            import jax as _jax  # noqa: F401
+    from . import events, external_events, fingerprints, trace, config, dsl  # noqa: F401
+    from .config import SchedulerConfig
+    from .trace import EventTrace
+    from .events import Unique
 
 __all__ = ["SchedulerConfig", "EventTrace", "Unique", "__version__"]
 

@@ -20,14 +20,16 @@ import sys
 from typing import Optional
 
 from . import obs
-from .apps.broadcast import broadcast_send_generator, make_broadcast_app
-from .apps.common import dsl_start_events, make_host_invariant
-from .apps.raft import make_raft_app, raft_send_generator
-from .config import SchedulerConfig
-from .dsl import DSLApp
-from .external_events import WaitQuiescence
-from .fuzzing import Fuzzer, FuzzerWeights
-from .parallel.distributed import FAULT_PLANE_DEFAULTS
+
+with obs.stage("setup.import", module=__name__):
+    from .apps.broadcast import broadcast_send_generator, make_broadcast_app
+    from .apps.common import dsl_start_events, make_host_invariant
+    from .apps.raft import make_raft_app, raft_send_generator
+    from .config import SchedulerConfig
+    from .dsl import DSLApp
+    from .external_events import WaitQuiescence
+    from .fuzzing import Fuzzer, FuzzerWeights
+    from .parallel.distributed import FAULT_PLANE_DEFAULTS
 
 
 def build_app(args) -> DSLApp:
@@ -223,8 +225,13 @@ def _obs_end(args, experiment_dir: Optional[str] = None) -> None:
         )
     snap = obs.REGISTRY.snapshot()
     if getattr(args, "stats_out", None):
+        # With the registry: where the seconds before the first job went
+        # (the setup.* stages) and the per-function compile table.
+        doc = dict(
+            snap, setup=obs.setup_ledger(), compile=obs.compile_ledger()
+        )
         with open(args.stats_out, "w") as f:
-            json.dump(snap, f, indent=2, sort_keys=True)
+            json.dump(doc, f, indent=2, sort_keys=True)
         print(f"metrics snapshot written to {args.stats_out}")
     if experiment_dir and os.path.isdir(experiment_dir):
         with open(os.path.join(experiment_dir, "obs_snapshot.json"), "w") as f:

@@ -2,6 +2,8 @@ import os as _os
 
 import jax as _jax
 
+from ..obs import spans as _spans
+
 
 def compile_cache_dir(environ=_os.environ):
     """Where this program points JAX's persistent compilation cache, or
@@ -31,21 +33,25 @@ def compile_cache_dir(environ=_os.environ):
 _cache_dir = compile_cache_dir()
 if _cache_dir is not None:
     _jax.config.update("jax_compilation_cache_dir", _cache_dir)
+# The compile ledger (obs/spans.py) hears every trace, lowering, compile
+# and cache load from here on: the process's one listener pair.
+_spans.listen_to_compiles(_jax.monitoring)
 
-from .continuous import ContinuousSweepDriver
-from .core import DeviceConfig, ScheduleState
-from .explore import make_explore_kernel, make_single_lane_trace_kernel
-from .fork import (
-    PrefixCache,
-    PrefixPlanner,
-    PrefixSnapshot,
-    fork_lanes,
-    make_dpor_prefix_runner,
-    make_explore_prefix_runner,
-    make_replay_prefix_runner,
-    prefix_fork_enabled,
-)
-from .replay import make_replay_kernel
+with _spans.stage("setup.import", module=__name__):
+    from .continuous import ContinuousSweepDriver
+    from .core import DeviceConfig, ScheduleState
+    from .explore import make_explore_kernel, make_single_lane_trace_kernel
+    from .fork import (
+        PrefixCache,
+        PrefixPlanner,
+        PrefixSnapshot,
+        fork_lanes,
+        make_dpor_prefix_runner,
+        make_explore_prefix_runner,
+        make_replay_prefix_runner,
+        prefix_fork_enabled,
+    )
+    from .replay import make_replay_kernel
 
 __all__ = [
     "compile_cache_dir",

@@ -121,6 +121,7 @@ def _segment_lane_fn(app: DSLApp, cfg: DeviceConfig, seg_steps: int):
     return seg_lane
 
 
+@obs.spans.staged("setup.build", what="make_segment_kernel")
 def make_segment_kernel(
     app: DSLApp, cfg: DeviceConfig, seg_steps: int, mesh=None
 ):
@@ -147,6 +148,7 @@ def _ready(array) -> bool:
     return True if probe is None else bool(probe())
 
 
+@obs.spans.staged("setup.build", what="make_init_kernel")
 def make_init_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     """jitted ``keys[B] -> ScheduleState[B]`` batch initializer."""
     return _maybe_shard(
@@ -154,6 +156,7 @@ def make_init_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     )
 
 
+@obs.spans.staged("setup.build", what="make_refill_kernel")
 def make_refill_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     """jitted ``(state[B], refill[B] bool, fresh[B]) -> state'[B]``:
     lanes with ``refill`` set are replaced by the fresh state wholesale."""
@@ -168,6 +171,7 @@ def make_refill_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     return _maybe_shard(refill, mesh, 3)
 
 
+@obs.spans.staged("setup.build", what="make_finalize_kernel")
 def make_finalize_kernel(app: DSLApp, cfg: DeviceConfig, mesh=None):
     """jitted forced finalization for lanes that exhausted their step
     budget mid-flight (parity: the plain kernel's run-out path)."""
@@ -216,6 +220,7 @@ class ContinuousSweepDriver:
     per seed are identical to running each seed through the plain explore
     kernel with ``PRNGKey(seed)``."""
 
+    @obs.spans.staged("setup.build", what="ContinuousSweepDriver")
     def __init__(
         self,
         app: DSLApp,

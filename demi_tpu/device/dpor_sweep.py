@@ -462,6 +462,7 @@ def lane_keys(seeds):
     )(np.asarray(seeds, np.uint32))
 
 
+@obs.spans.staged("setup.build", what="build_dpor_kernel")
 def build_dpor_kernel(
     app: DSLApp, cfg: DeviceConfig, mesh=None, start_state: bool = False,
     sleep_cap: int = 0, commute_matrix=None,
@@ -1185,6 +1186,7 @@ class DeviceDPOR:
     stay bit-identical to the synchronous loop (lane keys depend only on
     the round index, which speculation preserves)."""
 
+    @obs.spans.staged("setup.build", what="DeviceDPOR")
     def __init__(
         self,
         app: DSLApp,
@@ -2933,8 +2935,9 @@ class DeviceDPOR:
         mid-round — discards the launch unharvested. Either way every
         harvested round is byte-identical to the synchronous loop's,
         which follows the exact same generation policy."""
-        with obs.span(
-            "dpor.search", job=obs.new_job(), max_rounds=max_rounds
+        job = obs.new_job()
+        with obs.spans.first_job(job, "dpor"), obs.span(
+            "dpor.search", job=job, max_rounds=max_rounds
         ):
             return self._search(target_code, max_rounds, stop_on_violation)
 

@@ -15,6 +15,8 @@ import os
 import subprocess
 from typing import List, Optional
 
+from ..obs import spans as _spans
+
 _PKG_DIR = os.path.dirname(os.path.abspath(__file__))
 REPO_ROOT = os.path.dirname(os.path.dirname(_PKG_DIR))
 BUILD_DIR = os.path.join(_PKG_DIR, "_build")
@@ -65,21 +67,23 @@ def build_library(
     """Path of the shared library built from ``src`` (compiling it when no
     library for this exact source content exists yet, or when ``rebuild``),
     or None when the source is missing or no compiler links it."""
-    try:
-        with open(src, "rb") as f:
-            digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    except OSError:
-        return None
-    so = os.path.join(BUILD_DIR, f"{stem}-{digest}.so")
-    if rebuild or not os.path.exists(so):
-        os.makedirs(BUILD_DIR, exist_ok=True)
-        if not _compile(src, so):
+    with _spans.stage("setup.native_build", stem=stem, compiled=False) as st:
+        try:
+            with open(src, "rb") as f:
+                digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        except OSError:
             return None
-        # Libraries built from other source contents are dead weight.
-        for old in glob.glob(os.path.join(BUILD_DIR, f"{stem}*.so")):
-            if old != so:
-                try:
-                    os.unlink(old)
-                except OSError:
-                    pass
-    return so
+        so = os.path.join(BUILD_DIR, f"{stem}-{digest}.so")
+        if rebuild or not os.path.exists(so):
+            os.makedirs(BUILD_DIR, exist_ok=True)
+            st.set(compiled=True)
+            if not _compile(src, so):
+                return None
+            # Libraries built from other source contents are dead weight.
+            for old in glob.glob(os.path.join(BUILD_DIR, f"{stem}*.so")):
+                if old != so:
+                    try:
+                        os.unlink(old)
+                    except OSError:
+                        pass
+        return so

@@ -10,7 +10,10 @@ The snapshot half (one switch, off by default):
     Chrome/Perfetto ``trace_event`` export, a per-name totals table
     (``stage_totals()`` / ``stage_counts()``), and, while a
     ``jax.profiler`` session records, the same spans on its timeline as
-    ``demi.<name>`` (live then with no switch);
+    ``demi.<name>`` (live then with no switch); and, with no switch at
+    all, the set-up stages and the compile ledger (``setup_ledger()`` /
+    ``compile_ledger()``): where the seconds before a command's first
+    job went, and which jitted functions they were spent on;
   - ``lane_stats`` (import directly — it needs jax): per-sweep device
     counters reduced on-device and pulled once per round.
 
@@ -58,9 +61,12 @@ from .metrics import (  # noqa: F401
 from .spans import (  # noqa: F401
     TRACER,
     Tracer,
+    compile_ledger,
     new_job,
     record_span,
+    setup_ledger,
     span,
+    stage,
     stage_count,
     stage_counts,
     stage_totals,
@@ -71,6 +77,7 @@ __all__ = [
     "MetricsRegistry",
     "TRACER",
     "Tracer",
+    "compile_ledger",
     "counter",
     "describe",
     "disable",
@@ -85,7 +92,9 @@ __all__ = [
     "profiler",
     "record_span",
     "relabel_snapshot",
+    "setup_ledger",
     "span",
+    "stage",
     "stage_count",
     "stage_counts",
     "stage_totals",

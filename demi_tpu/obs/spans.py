@@ -34,10 +34,19 @@ is bounded by the number of names; ``stage_count`` keeps plain counts
 beside it (``stage_counts()``). While any span is open, each pass of
 CPython's collector is a ``gc.pause`` span nested in the stage that
 triggered it, so a stage's self time leaves the collector out.
+
+Set-up is measured with no switch at all (``stage`` and the compile
+ledger, at the end of this file): it happens once a process, before any
+flag has been read, and has a few dozen stages. ``setup_ledger()`` and
+``compile_ledger()`` say where the seconds before a command's first job
+went, and which jitted functions were traced, lowered, compiled or
+loaded from the persistent cache in them.
 """
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import gc
 import itertools
 import json
@@ -265,15 +274,19 @@ def process_metadata_events(pid: int, process: str,
 TRACER = Tracer()
 
 
+def _as_table(totals) -> Dict[str, Dict[str, float]]:
+    return {
+        name: {"count": c, "seconds": d / 1e9, "self_seconds": s / 1e9}
+        for name, (c, d, s) in totals.items()
+    }
+
+
 def stage_totals() -> Dict[str, Dict[str, float]]:
     """Per span name, over every span finished since ``TRACER.clear()``:
     ``count``, ``seconds`` and ``self_seconds`` (duration less what
     child spans cover, ``gc.pause`` among them)."""
     with _lock:
-        return {
-            name: {"count": c, "seconds": d / 1e9, "self_seconds": s / 1e9}
-            for name, (c, d, s) in TRACER.totals.items()
-        }
+        return _as_table(TRACER.totals)
 
 
 def stage_counts() -> Dict[str, int]:
@@ -425,3 +438,381 @@ class span:
 def current_depth() -> int:
     """Testing hook: open-span depth on this thread."""
     return len(getattr(_local, "stack", ()))
+
+
+# ---------------------------------------------------------------------------
+# Set-up stages and the compile ledger: always on, bounded
+# ---------------------------------------------------------------------------
+
+_TIMELINE_MAX = 512     # stages kept with their intervals; totals fold on
+_FUNCTIONS_MAX = 1024   # rows of the compile table; the rest share one
+_COVER_MAX = 256        # disjoint compile intervals remembered a thread
+_SETUP_PREFIXES = ("setup.", "compile.")
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_KINDS = {_TRACE_EVENT: "trace", _LOWER_EVENT: "lower",
+          _BACKEND_EVENT: "backend"}
+# kind -> (count column, seconds column) of a compile-table row
+_COLUMNS = {
+    "trace": ("traces", "trace_s"),
+    "lower": ("lowerings", "lower_s"),
+    "backend": ("compiles", "compile_s"),
+    "cache_load": ("cache_hits", "cache_load_s"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _process_start_ns() -> Optional[int]:
+    """This process's start as a ``perf_counter_ns`` reading, from the
+    kernel's start time (``/proc/self/stat``, in clock ticks since boot)
+    and ``/proc/uptime``; None where there is no such ``/proc``. Read
+    when first asked for: the answer does not depend on when."""
+    try:
+        with open("/proc/self/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        now = time.perf_counter_ns()
+        age = uptime - int(fields[19]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+    return now - int(max(0.0, age) * 1e9)
+
+
+def _new_row() -> Dict[str, float]:
+    return {
+        "traces": 0, "trace_s": 0.0, "lowerings": 0, "lower_s": 0.0,
+        "compiles": 0, "compile_s": 0.0, "cache_hits": 0,
+        "cache_load_s": 0.0, "retrieval_s": 0.0, "saved_s": 0.0, "late": 0,
+    }
+
+
+class _Setup:
+    """What the stages and the compile listeners keep, under ``_lock``."""
+
+    def __init__(self):
+        self.timeline: List[Dict[str, Any]] = []
+        self.handed = 0             # timeline entries already in TRACER
+        self.dropped = 0
+        self.first_stage_ns: Optional[int] = None
+        self.functions: Dict[str, Dict[str, float]] = {}
+        self.total = dict(_new_row(), covered_s=0.0, cache_misses=0)
+        # Job 1: its verb, its clock pair, and the set-up totals and the
+        # compile total as they stood at each end of it.
+        self.verb: Optional[str] = None
+        self.job_start_ns: Optional[int] = None
+        self.job_end_ns: Optional[int] = None
+        self.before_job: Optional[Dict[str, tuple]] = None
+        self.at_job_end: Optional[Dict[str, tuple]] = None
+        self.compile_at_job_end: Optional[Dict[str, float]] = None
+
+
+_SETUP = _Setup()
+_listening = False      # the listener pair is registered once a process
+
+
+def _setup_totals() -> Dict[str, tuple]:
+    return {
+        name: tuple(t) for name, t in TRACER.totals.items()
+        if name.startswith(_SETUP_PREFIXES)
+    }
+
+
+def _hand(rec: Dict[str, Any]) -> None:
+    """One finished stage into TRACER, for ``--trace-out``. A stage that
+    began before the span epoch (the import of this package) is drawn
+    from 0: the export's timestamps stay non-negative."""
+    start = max(0, rec["ts_ns"] - _EPOCH_NS)
+    end = max(start, rec["ts_ns"] + rec["dur_ns"] - _EPOCH_NS)
+    TRACER.record(
+        rec["name"], start // 1000, end // 1000 - start // 1000, rec["tid"],
+        rec["op_b"], rec["op_e"], rec["args"],
+    )
+
+
+class stage:
+    """A span of set-up: it records whether or not spans are live,
+    because it runs a bounded number of times a process (an import, a
+    native build, a driver's constructor, the first job). It folds into
+    the totals table under its name, a child stage taking its time out of
+    its parent's self seconds, and the first ``_TIMELINE_MAX`` are kept
+    with their intervals (``setup_ledger()["timeline"]``). Once spans are
+    live those kept are handed to TRACER, so ``--trace-out`` draws set-up
+    before the run's own spans.
+
+    Stages keep a stack of their own: an open stage does not make
+    ``live()`` true, does not turn the collector's ``gc.pause`` spans on,
+    and takes no time out of a span's self seconds, nor a span out of
+    its own. ``start_ns`` is for a stage whose first lines ran before
+    this module could be imported."""
+
+    __slots__ = ("name", "args", "seconds", "_ts", "_op", "_child_ns")
+
+    def __init__(self, name: str, start_ns: Optional[int] = None, **args):
+        self.name = name
+        self.args = args
+        self.seconds = 0.0
+        self._ts = start_ns
+
+    def __enter__(self) -> "stage":
+        stack = getattr(_local, "stages", None)
+        if stack is None:
+            stack = _local.stages = []
+        self._op = next(_ops)
+        self._child_ns = 0
+        stack.append(self)
+        if self._ts is None:
+            self._ts = time.perf_counter_ns()
+        if _SETUP.first_stage_ns is None:
+            _SETUP.first_stage_ns = self._ts
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        end = time.perf_counter_ns()
+        stack = _local.stages
+        if self in stack:
+            del stack[stack.index(self):]
+        if exc_type is not None:
+            self.args["error"] = exc_type.__name__
+        dur = max(0, end - self._ts)
+        self.seconds = dur / 1e9
+        if stack:
+            stack[-1]._child_ns += dur
+        TRACER.fold(self.name, dur, dur - self._child_ns)
+        rec = {
+            "name": self.name, "ts_ns": self._ts, "dur_ns": dur,
+            "tid": threading.get_ident() & 0xFFFF, "op_b": self._op,
+            "op_e": next(_ops), "args": self.args,
+        }
+        on = live()
+        with _lock:
+            if len(_SETUP.timeline) < _TIMELINE_MAX:
+                _SETUP.timeline.append(rec)
+            else:
+                _SETUP.dropped += 1
+            if on:
+                for kept in _SETUP.timeline[_SETUP.handed:]:
+                    _hand(kept)
+                _SETUP.handed = len(_SETUP.timeline)
+
+    def set(self, **args) -> None:
+        """Attach result attributes discovered mid-stage."""
+        self.args.update(args)
+
+
+def staged(name: str, **args):
+    """Decorator: the call is a ``stage(name, **args)``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*a, **kw):
+            with stage(name, **args):
+                return fn(*a, **kw)
+
+        return inner
+
+    return wrap
+
+
+class _FirstJob(stage):
+    """``setup.first_job``: besides the stage, the set-up totals as they
+    stand at each end of it, so that set-up can be read cut at the end of
+    job 1 whatever the process runs afterwards."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "stage":
+        with _lock:
+            _SETUP.verb = self.args.get("verb")
+            _SETUP.before_job = _setup_totals()
+        super().__enter__()
+        _SETUP.job_start_ns = self._ts
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        super().__exit__(exc_type, exc, tb)
+        with _lock:
+            _SETUP.at_job_end = _setup_totals()
+            _SETUP.compile_at_job_end = dict(_SETUP.total)
+            _SETUP.job_end_ns = self._ts + int(self.seconds * 1e9)
+
+
+_NO_STAGE = contextlib.nullcontext()
+
+
+def first_job(job: int, verb: str):
+    """The root stage of the process's first job (the first number
+    ``new_job()`` handed out: a command's only job, the benchmark's warm
+    job), for ``SweepDriver.sweep`` / ``DeviceDPOR.explore`` to enter
+    around the whole job; nothing for any later job."""
+    if job != 1:
+        return _NO_STAGE
+    return _FirstJob("setup.first_job", verb=verb)
+
+
+def _own_ns(start: int, end: int) -> int:
+    """The nanoseconds of ``[start, end]`` that no earlier compile event
+    of this thread covers. A function traced inside another's tracing
+    reports both, the inner one first: the outer keeps what is left of
+    its interval, so the seconds add up to what they cover together.
+    Events arrive in the order of their ends."""
+    cover = getattr(_local, "cover", None)
+    if cover is None:
+        cover = _local.cover = []
+    lo, inside = start, 0
+    while cover and cover[-1][1] > start:
+        s, e = cover.pop()
+        inside += min(e, end) - max(s, start)
+        lo = min(lo, s)
+    cover.append((lo, end))
+    if len(cover) > _COVER_MAX:
+        del cover[0]
+    return max(0, end - start - inside)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _local.cache_hit = True
+    elif event == _CACHE_MISS_EVENT:
+        with _lock:
+            _SETUP.total["cache_misses"] += 1
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    kind = _KINDS.get(event)
+    if kind is None:
+        # A hit reports what it saved and what the read took just
+        # before the compile's own duration, on the same thread.
+        if event == _RETRIEVAL_EVENT:
+            _local.retrieval_s = seconds
+        elif event == _SAVED_EVENT:
+            _local.saved_s = seconds
+        return
+    end = time.perf_counter_ns()
+    own = _own_ns(end - int(seconds * 1e9), end)
+    retrieval = saved = 0.0
+    if kind == "backend" and getattr(_local, "cache_hit", False):
+        kind = "cache_load"
+        _local.cache_hit = False
+        retrieval = getattr(_local, "retrieval_s", 0.0)
+        saved = getattr(_local, "saved_s", 0.0)
+    fun = str(kw.get("fun_name", "?"))
+    if kind != "trace" and fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]     # tracing says f, the module is jit(f)
+    count, secs = _COLUMNS[kind]
+    with _lock:
+        row = _SETUP.functions.get(fun)
+        if row is None:
+            if len(_SETUP.functions) >= _FUNCTIONS_MAX:
+                fun = "(other)"
+            row = _SETUP.functions.setdefault(fun, _new_row())
+        late = _SETUP.job_end_ns is not None
+        for r in (row, _SETUP.total):
+            r[count] += 1
+            r[secs] += seconds
+            r["retrieval_s"] += retrieval
+            r["saved_s"] += saved
+            r["late"] += late
+        _SETUP.total["covered_s"] += own / 1e9
+    stack = getattr(_local, "stages", None)
+    if stack:
+        stack[-1]._child_ns += own
+    TRACER.fold("compile." + kind, own, own)
+
+
+def listen_to_compiles(monitoring) -> None:
+    """Register the compile ledger's listener pair with
+    ``jax.monitoring``, once a process, never unregistered:
+    ``demi_tpu.device`` calls it where it configures the compile cache."""
+    global _listening
+    with _lock:
+        if _listening:
+            return
+        _listening = True
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def compile_ledger() -> Dict[str, Any]:
+    """What JAX traced, lowered, compiled and loaded in this process, by
+    the function's name (``functions``) and summed (``total``). A row:
+    ``traces`` / ``trace_s``, ``lowerings`` / ``lower_s``, ``compiles`` /
+    ``compile_s`` (backend compiles the persistent cache did not
+    serve), ``cache_hits`` / ``cache_load_s`` (the compile request's
+    whole duration when the cache served it; ``retrieval_s`` the read
+    alone, ``saved_s`` what JAX says the hit saved), and ``late``:
+    events after the first job had ended, which a warm process should
+    not have. A row's seconds are each event's own, so a function traced
+    inside another's tracing is in both rows; the total's ``covered_s``
+    and the ``compile.*`` stages count such seconds once."""
+    with _lock:
+        return {
+            "total": dict(_SETUP.total),
+            "functions": {f: dict(r) for f, r in _SETUP.functions.items()},
+        }
+
+
+def setup_ledger() -> Dict[str, Any]:
+    """Where the time before the end of the process's first job went.
+
+    ``stages`` is the totals table's ``setup.*`` and ``compile.*`` rows
+    cut at the end of job 1 (as they stand now while it has not ended),
+    ``before_first_job`` the same rows at its start, ``compile`` the
+    compile total at its end. Times are seconds; ``start_s`` and ``end_s``
+    count from the process's start (``process_start_s`` on the span
+    clock, negative; from the span epoch where the platform does not
+    say), so ``first_job["end_s"]`` is the process's age at job 1's end.
+    ``pre_program_s`` is what came before the program's first stage: the
+    interpreter and whatever the caller imported first."""
+    started = _process_start_ns()
+    with _lock:
+        origin = _EPOCH_NS if started is None else started
+        first_job = None
+        if _SETUP.job_start_ns is not None:
+            first_job = {
+                "verb": _SETUP.verb,
+                "start_s": (_SETUP.job_start_ns - origin) / 1e9,
+                "end_s": None if _SETUP.job_end_ns is None
+                else (_SETUP.job_end_ns - origin) / 1e9,
+            }
+        ended = _SETUP.at_job_end is not None
+        return {
+            "process_start_s": None if started is None
+            else (started - _EPOCH_NS) / 1e9,
+            "pre_program_s": None
+            if started is None or _SETUP.first_stage_ns is None
+            else (_SETUP.first_stage_ns - started) / 1e9,
+            "first_job": first_job,
+            "stages": _as_table(
+                _SETUP.at_job_end if ended else _setup_totals()
+            ),
+            "before_first_job": None if _SETUP.before_job is None
+            else _as_table(_SETUP.before_job),
+            "compile": dict(
+                _SETUP.compile_at_job_end if ended else _SETUP.total
+            ),
+            "timeline": [
+                {"name": r["name"], "start_s": (r["ts_ns"] - origin) / 1e9,
+                 "seconds": r["dur_ns"] / 1e9, "args": dict(r["args"])}
+                for r in _SETUP.timeline
+            ],
+            "timeline_dropped": _SETUP.dropped,
+        }
+
+
+def _reset_setup() -> None:
+    """Testing hook: forget every stage, the compile table and job 1,
+    and hand out job numbers from 1 again. The listeners stay."""
+    global _SETUP, _jobs
+    with _lock:
+        _SETUP = _Setup()
+        _jobs = itertools.count(1)
+        for name in [n for n in TRACER.totals if n.startswith(_SETUP_PREFIXES)]:
+            del TRACER.totals[name]
+    _local.__dict__.pop("cover", None)

@@ -76,15 +76,18 @@ def build_workload(workload: Optional[dict], record: bool = False):
     """Build (app, DeviceConfig, fuzzer) from a CLI-args-shaped dict,
     reusing the CLI's own builders. ``record=True`` turns on trace +
     parent recording (the DPOR/fleet shape; sweeps keep it off)."""
-    from ..cli import build_app, build_fuzzer
-    from ..device.core import DeviceConfig
+    from ..obs import spans
 
-    args = workload_args(workload)
-    app = build_app(args)
-    cfg = DeviceConfig.for_workload(
-        app, args, record_trace=record, record_parents=record
-    )
-    fuzzer = build_fuzzer(app, args)
+    with spans.stage("setup.build", what="workload"):
+        from ..cli import build_app, build_fuzzer
+        from ..device.core import DeviceConfig
+
+        args = workload_args(workload)
+        app = build_app(args)
+        cfg = DeviceConfig.for_workload(
+            app, args, record_trace=record, record_parents=record
+        )
+        fuzzer = build_fuzzer(app, args)
     return app, cfg, fuzzer
 
 

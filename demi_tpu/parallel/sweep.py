@@ -328,6 +328,7 @@ class _RewardBucket:
 
 
 class SweepDriver:
+    @obs.spans.staged("setup.build", what="SweepDriver")
     def __init__(
         self,
         app: DSLApp,
@@ -794,31 +795,32 @@ class SweepDriver:
         if mode is None:
             mode = "continuous" if num_slices == 1 else "chunked"
         self._job = obs.new_job()
-        if mode == "continuous":
-            if num_slices != 1:
-                raise ValueError(
-                    "continuous sweeps are single-slice (slices partition "
-                    "the seed space; use mode='chunked')"
+        with obs.spans.first_job(self._job, "sweep"):
+            if mode == "continuous":
+                if num_slices != 1:
+                    raise ValueError(
+                        "continuous sweeps are single-slice (slices "
+                        "partition the seed space; use mode='chunked')"
+                    )
+                return self._sweep_continuous(
+                    total_lanes, chunk_size, stop_on_violation
                 )
-            return self._sweep_continuous(
-                total_lanes, chunk_size, stop_on_violation
-            )
-        result = SweepResult()
-        t0 = time.perf_counter()
-        seed = 0
-        chunk_idx = 0
-        while seed < total_lanes:
-            n = min(chunk_size, total_lanes - seed)
-            chunk = self.run_chunk(
-                range(seed, seed + n), slice_index=chunk_idx % num_slices
-            )
-            result.chunks.append(chunk)
-            seed += n
-            chunk_idx += 1
-            if stop_on_violation and chunk.violations:
-                break
-        result.wall_seconds = time.perf_counter() - t0
-        return result
+            result = SweepResult()
+            t0 = time.perf_counter()
+            seed = 0
+            chunk_idx = 0
+            while seed < total_lanes:
+                n = min(chunk_size, total_lanes - seed)
+                chunk = self.run_chunk(
+                    range(seed, seed + n), slice_index=chunk_idx % num_slices
+                )
+                result.chunks.append(chunk)
+                seed += n
+                chunk_idx += 1
+                if stop_on_violation and chunk.violations:
+                    break
+            result.wall_seconds = time.perf_counter() - t0
+            return result
 
     def _continuous_driver(
         self, batch: int, base_key: int = 0, program_gen=None
