@@ -641,6 +641,22 @@ class ContinuousSweepDriver:
                                 int(np.asarray(state.deliveries)[fin].sum())
                                 * self.cfg.max_outbox,
                             )
+                            if state.insert_full_steps is not None:
+                                # How often the insert's short pass is
+                                # taken: of the steps the retired lanes
+                                # were scanned, those in which some
+                                # resident lane sent more rows than it
+                                # holds (``core.insert_rows``).
+                                obs.stage_count(
+                                    "sweep.insert_full_steps",
+                                    int(np.asarray(
+                                        state.insert_full_steps
+                                    )[fin].sum()),
+                                )
+                                obs.stage_count(
+                                    "sweep.insert_steps",
+                                    int(steps_run[fin].sum()),
+                                )
                             obs.stage_count("sweep.retired", len(fin))
                             obs.stage_count(
                                 "sweep.quiesced",

@@ -282,6 +282,12 @@ class ScheduleState(NamedTuple):
     # Optional trace recording.
     trace: jnp.ndarray  # [T, rec_width] int32 (or [0,0] when disabled)
     trace_len: jnp.ndarray  # int32
+    # Inserts of this lane that took the full [K, P] pass (insert_rows:
+    # one count a step in the step kernels, the same in every lane that
+    # was resident). No leaf at all (None) where the short pass is not
+    # built (``_short_insert_built``): the smaller shapes' compiled
+    # programs are what they were.
+    insert_full_steps: Optional[jnp.ndarray] = None  # int32
 
 
 def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
@@ -321,6 +327,9 @@ def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
         rng=key,
         trace=jnp.zeros(trace_shape, jnp.int32),
         trace_len=jnp.int32(0),
+        insert_full_steps=(
+            jnp.int32(0) if _short_insert_built(cfg) else None
+        ),
     )
 
 
@@ -397,6 +406,95 @@ def alive_mask(state: ScheduleState) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Pool maintenance
 # ---------------------------------------------------------------------------
+
+# The one-hot insert's short pass (PR 37). A slot reads its row through
+# the first INSERT_SHORT_ROWS valid rows wherever no lane of the batch
+# inserts more in this step; it is built only where the insert carries
+# more than INSERT_SHORT_FACTOR times as many rows, so the raft shapes (K
+# under 10) keep the program they had. Measured on the v5e at the spark
+# cell's shape (K 402, P 1,024, 256 lanes) and the flood's (K 65, P 4,608,
+# 128 lanes), where a step sends one row or a whole outbox, so that 1 to
+# 16 rows spare the same steps: 4 read 1 to 3% faster than 8 there
+# (PERF.md, PR 37); 8 serves a protocol whose usual outbox is a handful
+# of rows.
+INSERT_SHORT_ROWS = 8
+INSERT_SHORT_FACTOR = 4
+
+
+def _short_insert_built(cfg: DeviceConfig) -> bool:
+    """Whether a lane of ``cfg`` carries ``insert_full_steps``: the step's
+    insert holds an outbox and at least one injected row."""
+    return cfg.use_onehot and (
+        cfg.max_outbox + 1 > INSERT_SHORT_FACTOR * INSERT_SHORT_ROWS
+    )
+
+
+def _sum_where(sel, col):
+    """[K, P] bool, [K] -> [P]: the value of the one row a slot selects."""
+    return jnp.sum(jnp.where(sel, col[:, None], 0), axis=0)
+
+
+def _landed_full(want, prefix, cols):
+    """``cols`` ([K] each) -> what each slot gets ([P] each): the row whose
+    rank among the valid rows (``want``; -1 on an invalid row, which no
+    slot matches, as a valid one past the last free slot matches none)
+    is the slot's among the free slots (``prefix``). One select-and-sum a
+    column over the shared compare: XLA fuses them all into one pass with
+    K outermost and writes nothing of [K, P] out (ranked on the v5e
+    against an einsum over a [K, C] table, which writes the compare out
+    first, and a variadic reduce: PERF.md, PR 32)."""
+    sel = want[:, None] == prefix[None, :]
+    return tuple(_sum_where(sel, col) for col in cols)
+
+
+def _landed_short(want, prefix, cols):
+    """``_landed_full``'s values wherever at most INSERT_SHORT_ROWS rows
+    are valid (anywhere in K): rank by rank, the row of rank r reduced
+    to a scalar a column (a [K] compare), then handed to the slot of rank
+    r (a [P] one): C x (K + P) compares where the full pass makes K x P.
+    Unrolled, so that a batch of lanes reduces [B, K] over its minor axis
+    and selects over [B, P] (ranked on the v5e against a [C, K] and a
+    [C, P] compare, each summed over an axis, whose operands the compiler
+    first transposes to lane-minor; the same with the columns stacked;
+    and a 0/1 table times the columns' bytes on the MXU: PERF.md, PR 37)."""
+    out = []
+    for col in cols:
+        landed = jnp.zeros_like(prefix)
+        for rank in range(1, INSERT_SHORT_ROWS + 1):
+            row = jnp.sum(jnp.where(want == rank, col, 0))
+            landed = jnp.where(prefix == rank, row, landed)
+        out.append(landed)
+    return tuple(out)
+
+
+@jax.custom_batching.custom_vmap
+def _landed_by_batch(want, prefix, n_rows, cols):
+    """``(_landed_full(...), 1)``: one lane alone takes the full pass. A
+    batch of lanes (the rule below) takes the short one in every step
+    where none of them inserts more than it holds, and says which it
+    took. Under ``vmap`` a ``lax.cond`` on a lane's own count would run
+    both branches; this predicate is one scalar for the whole batch, so
+    the compiled step holds a real ``case``: the one place a vmapped
+    kernel branches."""
+    return _landed_full(want, prefix, cols), jnp.int32(1)
+
+
+@_landed_by_batch.def_vmap
+def _landed_by_batch_rule(axis_size, in_batched, *args):
+    want, prefix, n_rows, cols = jax.tree_util.tree_map(
+        lambda x, batched: (
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+        ),
+        args, tuple(in_batched),
+    )
+    full = jnp.max(n_rows) > INSERT_SHORT_ROWS
+    landed = jax.lax.cond(
+        full, jax.vmap(_landed_full), jax.vmap(_landed_short),
+        want, prefix, cols,
+    )
+    took = jnp.broadcast_to(full.astype(jnp.int32), (axis_size,))
+    return (landed, took), ((True,) * len(cols), True)
+
 
 def insert_rows(
     state: ScheduleState,
@@ -477,17 +575,44 @@ def insert_rows(
             word = word | (flag.astype(jnp.int32) << (2 * bits + i))
         # An invalid row matches no slot (prefix is never negative), and
         # neither does a valid one past the last free slot.
-        sel = jnp.where(row_valid, want, -1)[:, None] == prefix[None, :]
+        want = jnp.where(row_valid, want, -1)
+        per_row_crec = crec is not None and jnp.ndim(crec) > 0
+        if (
+            state.insert_full_steps is not None
+            and k > INSERT_SHORT_FACTOR * INSERT_SHORT_ROWS
+        ):
+            # Every column through one call: one ``case`` a step.
+            cols = [word] + [
+                row_msg[:, j].astype(jnp.int32) for j in range(cfg.msg_width)
+            ]
+            if per_row_crec:
+                cols.append(jnp.asarray(crec, jnp.int32))
+            cols, took_full = _landed_by_batch(
+                want, prefix, n_rows, tuple(cols)
+            )
+            state = state._replace(
+                insert_full_steps=state.insert_full_steps + took_full
+            )
+            word = cols[0]
 
-        def landed(col):  # [K] int32 -> [P]: the value of the row a slot gets
-            # One select-and-sum a column over the shared ``sel``: XLA
-            # fuses them all into one pass with K outermost and writes
-            # nothing of [K, P] out (ranked on the v5e against an einsum
-            # over a [K, C] table, which writes ``sel`` out first, and a
-            # variadic reduce: PERF.md, PR 32).
-            return jnp.sum(jnp.where(sel, col[:, None], 0), axis=0)
+            def landed_msg(j):
+                return cols[1 + j]
 
-        word = landed(word)
+            def landed_crec():
+                return cols[-1]
+        else:
+            # The smaller shapes: each column where it is written, the
+            # program they had before the short pass came.
+            sel = want[:, None] == prefix[None, :]
+            word = _sum_where(sel, word)
+
+            def landed_msg(j):
+                # row_msg is already narrowed: the round trip is exact
+                return _sum_where(sel, row_msg[:, j].astype(jnp.int32))
+
+            def landed_crec():
+                return _sum_where(sel, crec)
+
         field = (1 << bits) - 1
 
         def bit(i):
@@ -502,11 +627,7 @@ def insert_rows(
             pool_msg=jnp.where(
                 hit[:, None],
                 jnp.stack(
-                    [  # row_msg is already narrowed: the round trip is exact
-                        landed(row_msg[:, j].astype(jnp.int32))
-                        for j in range(cfg.msg_width)
-                    ],
-                    axis=1,
+                    [landed_msg(j) for j in range(cfg.msg_width)], axis=1
                 ).astype(state.pool_msg.dtype),
                 state.pool_msg,
             ),
@@ -524,7 +645,8 @@ def insert_rows(
             # per-row creator links ([K]) come from round-delivery inserts
             new_state = new_state._replace(
                 pool_crec=jnp.where(
-                    hit, landed(crec) if crec.ndim else crec, state.pool_crec
+                    hit, landed_crec() if per_row_crec else crec,
+                    state.pool_crec,
                 )
             )
         return new_state

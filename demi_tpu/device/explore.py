@@ -217,12 +217,20 @@ def make_step_fn(app: DSLApp, cfg: DeviceConfig):
     computed with masks (inert op / invalid index for the inactive side) and
     their pool inserts merge into ONE insert_rows pass per step.
 
-    Under vmap a ``lax.cond``'s branches both execute anyway, so the old
-    two-branch form paid the insert machinery twice per step (two prefix
-    sums, then on a CPU a searchsorted and 8 scatters, on a TPU one
-    [K, pool] compare and a select-and-sum per packed column:
-    ``core.insert_rows``); profiling shows the insert dominates step cost.
-    Fusing removes a full insert pass and both cond selects."""
+    Under vmap a ``lax.cond`` on a lane's own predicate executes both
+    branches anyway, so the old two-branch form paid the insert machinery
+    twice per step (two prefix sums, then on a CPU a searchsorted and 8
+    scatters, on a TPU one [K, pool] compare and a select-and-sum per
+    packed column: ``core.insert_rows``); profiling shows the insert
+    dominates step cost. Fusing removes a full insert pass and both cond
+    selects.
+
+    The step's one real branch is inside that insert, and only where it
+    carries many rows (``core._short_insert_built``): its predicate, "does
+    any lane of the batch insert more than ``INSERT_SHORT_ROWS`` rows in
+    this step", is one scalar for the whole batch (a ``custom_vmap`` rule
+    reduces it), so the compiled step holds a ``case`` whose cheap branch
+    serves every step in which no resident lane sends a wide outbox."""
     init_states, initial_rows = _precomputed(app, cfg)
     oh = cfg.use_onehot
 

@@ -19,6 +19,12 @@ The pool insert is the one access with no helper here: ``core.insert_rows``
 scatters by slot number in scatter mode (``rank_slots`` below) and, in
 one-hot mode, never computes a slot number at all: each slot reads its
 row off its own rank among the free slots (tests/test_insert_parity.py).
+Where an insert carries many rows (an outbox of 32 or more) it is also the
+one place a vmapped kernel branches for real: a ``custom_vmap`` rule puts
+ONE ``lax.cond`` over the whole batch of lanes, which takes a short pass
+over the first 8 valid rows in every step where no lane sends more. A
+``cond`` on a lane's own count would run both branches under ``vmap``; a
+predicate reduced over the batch is a scalar, and stays a ``case``.
 """
 
 from __future__ import annotations

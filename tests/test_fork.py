@@ -247,6 +247,10 @@ def test_fork_lanes_matches_start_state_kernel(raft_level):
     for field in states._fields:
         if field == "rng":
             continue
+        if getattr(snap.state, field) is None:
+            # a leaf this shape does not carry (``insert_full_steps``)
+            assert getattr(states, field) is None
+            continue
         leaf = np.asarray(getattr(states, field))
         ref = np.asarray(getattr(snap.state, field))
         assert leaf.shape == (4,) + ref.shape, field

@@ -6,8 +6,16 @@ receives: one ``[K, P]`` compare, one contraction); under ``'scatter'`` it
 finds each row's slot number and scatters. Tier-1 runs on a CPU, where
 ``'auto'`` means scatter, so these cases are what guards the chip's path:
 every field of the resulting state equal, over random pools and proposals.
-The last test holds the passes down: the lowered one-hot insert compares
-over ``[K, P]`` once.
+
+Where the insert carries many rows (``core._short_insert_built``: the
+flood's and the spark DAG's shapes) a batch of lanes takes a short pass
+over the first ``INSERT_SHORT_ROWS`` valid rows in every step where no
+lane inserts more, picked by one ``lax.cond`` for the whole batch: the
+second half holds that pass to the scatter insert and to the full pass
+over where the rows sit and how many there are, and holds the compiled
+segment to one ``case`` there and none at raft's shape. The last tests
+hold the passes down: the lowered one-hot insert compares over ``[K, P]``
+once (a branch, where there are two).
 """
 
 import re
@@ -20,11 +28,17 @@ import pytest
 from demi_tpu.apps.broadcast import make_broadcast_app
 from demi_tpu.apps.common import dsl_start_events
 from demi_tpu.apps.raft import make_raft_app
+from demi_tpu.apps.spark_dag import make_spark_app
+from demi_tpu.device.continuous import make_init_kernel, make_segment_kernel
 from demi_tpu.device.core import (
-    ST_OVERFLOW, DeviceConfig, init_state, insert_rows,
+    INSERT_SHORT_FACTOR, INSERT_SHORT_ROWS, ST_OVERFLOW, DeviceConfig,
+    _short_insert_built, init_state, insert_rows,
 )
+from demi_tpu.device.encoding import empty_programs
 from demi_tpu.device.encoding import lower_program
-from demi_tpu.device.explore import broadcast_program, make_explore_kernel
+from demi_tpu.device.explore import (
+    ExtProgram, broadcast_program, make_explore_kernel,
+)
 from demi_tpu.external_events import MessageConstructor, Send, WaitQuiescence
 
 LANES = 4
@@ -36,7 +50,12 @@ SHAPES = {
     "raft5-p256": (make_raft_app, 5, 256, None),
     "bcast8-p96": (make_broadcast_app, 8, 96, None),
     "bcast64-p4608": (make_broadcast_app, 64, 4608, 65),
+    # a driver and 4 executors, 2 stages x 40 tasks: an 81-row outbox
+    "spark5-p256": (
+        lambda nodes: make_spark_app(nodes - 1, 2, 40), 5, 256, 82
+    ),
 }
+C = INSERT_SHORT_ROWS
 FILLS = (0.0, 0.3, 0.83, 1.0)
 
 
@@ -131,20 +150,37 @@ def _stack(cases):
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *cases)
 
 
+def _with_count(states, cfg):
+    """The lanes as ``init_state`` makes them under ``cfg``: with the
+    count of full passes where its insert has a short one (the cases are
+    built from the scatter config's state, which has none)."""
+    if not _short_insert_built(cfg):
+        return states
+    lanes = states.status.shape[0]
+    return states._replace(insert_full_steps=jnp.zeros(lanes, jnp.int32))
+
+
 def _insert_both(cfgs, cases):
+    """The cases as one batch through each mode's insert; where the
+    one-hot insert picks its pass by the batch, also ``onehot-full``: the
+    same lanes without the count, which take the full pass."""
     states, rows = _stack([c[0] for c in cases]), _stack([c[1] for c in cases])
     out = {}
     for mode, cfg in cfgs.items():
         fn = jax.jit(jax.vmap(
             lambda s, r, cfg=cfg: insert_rows(s, cfg, *r)
         ))
-        out[mode] = fn(states, rows)
+        out[mode] = fn(_with_count(states, cfg), rows)
+        if mode == "onehot" and _short_insert_built(cfg):
+            out["onehot-full"] = fn(states, rows)
     return out
 
 
-def _assert_same(out, what):
-    a, b = out["scatter"], out["onehot"]
+def _assert_same(out, what, modes=("scatter", "onehot")):
+    a, b = (out[m] for m in modes)
     for field in type(a)._fields:
+        if field == "insert_full_steps":  # the one-hot insert's alone
+            continue
         x, y = np.asarray(getattr(a, field)), np.asarray(getattr(b, field))
         assert x.dtype == y.dtype, f"{what}: {field} dtype"
         assert np.array_equal(x, y), (
@@ -266,26 +302,257 @@ def test_explore_kernel_on_a_flood_run_to_quiescence():
     assert (np.asarray(out["onehot"].status) == 2).all()
 
 
+# -- the short pass --------------------------------------------------------
+
+RANKED_SHAPES = ["spark5-p256", "bcast64-p4608"]
+# rows valid in the step, a lane: the last lane takes ``last``
+ROW_COUNTS = {
+    "none": dict(each=0, last=0),
+    "one": dict(each=1, last=1),
+    "c": dict(each=C, last=C),
+    "c-plus-one-in-one-lane": dict(each=1, last=C + 1),
+    "all-lanes-full": dict(each=None, last=None),
+}
+PLACES = ("prefix", "holes-from-lost", "injection-and-outbox")
+
+
+def _ranked_case(app, cfg, rng, k, n_rows, place, *, n_free=None, crec=None):
+    """One lane with exactly ``n_rows`` rows entering the pool (None:
+    every row of K), sitting where ``place`` says: the first rows of K;
+    anywhere in K with rows between them lost at the send (to a stopped
+    receiver, over a cut link); the injection's row 0 from the external
+    sender and the rest scattered over the outbox."""
+    n, p = cfg.num_actors, cfg.pool_capacity
+    fill = 0.5 if n_free is None else (p - n_free) / p
+    state, rows = _case(app, cfg, rng, k, fill, n_rows=0, crec=crec)
+    n_rows = k if n_rows is None else n_rows
+    valid = np.zeros(k, bool)
+    src = rng.integers(1, n, k)
+    dst = (src + 1 + rng.integers(0, n - 2, k)) % n  # never the sender
+    timer = np.zeros(k, bool)
+    cut = np.zeros((n, n), bool)
+    stopped = np.zeros(n, bool)
+    if place == "prefix":
+        valid[:n_rows] = True
+    elif place == "holes-from-lost":
+        # Twice the rows proposed (K allowing); the surplus is lost.
+        stopped[0] = True
+        cut[1, 2] = cut[2, 1] = True
+        dst = np.where(dst == 0, (src % (n - 1)) + 1, dst)
+        clash = ((src == 1) & (dst == 2)) | ((src == 2) & (dst == 1))
+        dst = np.where(clash, 3, dst)
+        at = rng.permutation(k)[: min(k, 2 * n_rows)]
+        valid[at] = True
+        for i, row in enumerate(at[n_rows:]):  # anywhere among the others
+            if i % 2:
+                src[row], dst[row] = 1, 2
+            else:
+                dst[row] = 0
+    else:
+        if n_rows:
+            valid[0] = True
+            src[0] = n  # the external sender: crosses no link
+            valid[1 + rng.permutation(k - 1)[: n_rows - 1]] = True
+    state = state._replace(
+        cut=jnp.asarray(cut), stopped=jnp.asarray(stopped)
+    )
+    rows = (
+        jnp.asarray(valid), jnp.asarray(src, jnp.int32),
+        jnp.asarray(dst, jnp.int32), jnp.asarray(timer),
+    ) + rows[4:]
+    return state, rows
+
+
+def _ranked_check(shape, counts, place, seed=41, lanes=LANES,
+                  cfg_overrides=None, **case_kw):
+    """A batch through the scatter insert, the one-hot insert that picks
+    its pass, and the one-hot insert's full pass: all three equal, every
+    row that was meant to enter entered, and the pass taken is the short
+    one unless a lane inserts more than it holds."""
+    app, cfgs = _cfgs(shape, **(cfg_overrides or {}))
+    k = _rows_k(shape, app)
+    assert k > INSERT_SHORT_FACTOR * C
+    rng = np.random.default_rng(seed)
+    per_lane = [counts["each"]] * (lanes - 1) + [counts["last"]]
+    cases = [
+        _ranked_case(app, cfgs["scatter"], rng, k, n_rows, place, **case_kw)
+        for n_rows in per_lane
+    ]
+    before = np.asarray(_stack([c[0] for c in cases]).pool_valid).sum(axis=1)
+    out = _insert_both(cfgs, cases)
+    what = f"{shape} {per_lane} {place}"
+    _assert_same(out, what)
+    _assert_same(out, what + " (full pass)", modes=("scatter", "onehot-full"))
+    most = max(k if n_rows is None else n_rows for n_rows in per_lane)
+    took_full = np.asarray(out["onehot"].insert_full_steps)
+    assert (took_full == int(most > C)).all(), what
+    assert out["onehot-full"].insert_full_steps is None
+    entered = np.asarray(out["onehot"].pool_valid).sum(axis=1) - before
+    return out, entered, per_lane
+
+
+@pytest.mark.parametrize("place", PLACES)
+@pytest.mark.parametrize("counts", list(ROW_COUNTS))
+@pytest.mark.parametrize("shape", RANKED_SHAPES)
+def test_short_insert_equals_scatter_and_full_insert(shape, counts, place):
+    out, entered, per_lane = _ranked_check(shape, ROW_COUNTS[counts], place)
+    for got, n_rows in zip(entered, per_lane):
+        if n_rows is not None:  # half the pool is free: they all fit
+            assert got == n_rows
+
+
+RANKED_FEATURES = {
+    "per-row-crec": dict(
+        cfg_overrides=dict(record_trace=True, record_parents=True),
+        crec="rows",
+    ),
+    "scalar-crec": dict(
+        cfg_overrides=dict(record_trace=True, record_parents=True),
+        crec="scalar",
+    ),
+    "srcdst-fifo-heads": dict(cfg_overrides=dict(srcdst_fifo=True)),
+    "int16-payloads": dict(cfg_overrides=dict(msg_dtype="int16")),
+    "fifo-heads-int16-per-row-crec": dict(
+        cfg_overrides=dict(
+            srcdst_fifo=True, msg_dtype="int16", record_trace=True,
+            record_parents=True,
+        ),
+        crec="rows",
+    ),
+}
+
+
+@pytest.mark.parametrize("feature", list(RANKED_FEATURES))
+@pytest.mark.parametrize("counts", ["c", "c-plus-one-in-one-lane"])
+def test_short_insert_equals_scatter_and_full_insert_with(counts, feature):
+    for place in PLACES[1:]:
+        _ranked_check(
+            "spark5-p256", ROW_COUNTS[counts], place, seed=43,
+            **RANKED_FEATURES[feature],
+        )
+
+
+@pytest.mark.parametrize("extra", [0, 1], ids=["exact-fit", "one-too-many"])
+@pytest.mark.parametrize("shape", RANKED_SHAPES)
+def test_the_overflow_edge_on_the_short_pass(shape, extra):
+    n_free = C - 3
+    out, entered, _ = _ranked_check(
+        shape, dict(each=n_free + extra, last=n_free + extra),
+        "holes-from-lost", n_free=n_free,
+    )
+    assert (entered == n_free).all()
+    assert (np.asarray(out["onehot"].status) == ST_OVERFLOW).all() == bool(extra)
+    assert np.asarray(out["onehot"].pool_valid).all()
+
+
+def test_one_lane_alone_equals_its_lane_of_a_batch():
+    """The unbatched call (the single-lane ``run_lane`` of the checks and
+    lifts) takes the full pass and gives what the batch gave its lane."""
+    app, cfgs = _cfgs("spark5-p256")
+    cfg = cfgs["onehot"]
+    rng = np.random.default_rng(47)
+    cases = [
+        _ranked_case(app, cfg, rng, 82, n_rows, "injection-and-outbox")
+        for n_rows in (0, 3, C, 2)
+    ]
+    batch = _insert_both(cfgs, cases)["onehot"]
+    assert (np.asarray(batch.insert_full_steps) == 0).all()
+    one = jax.jit(lambda s, r: insert_rows(s, cfg, *r))
+    for lane, (state, rows) in enumerate(cases):
+        alone = one(state._replace(insert_full_steps=jnp.int32(0)), rows)
+        assert int(alone.insert_full_steps) == 1
+        for field in type(alone)._fields:
+            if field == "insert_full_steps":
+                continue
+            assert np.array_equal(
+                np.asarray(getattr(alone, field)),
+                np.asarray(getattr(batch, field))[lane],
+            ), field
+
+
+def _segment_text(shape, lanes=4):
+    app, cfgs = _cfgs(shape, max_steps=64)
+    cfg = cfgs["onehot"]
+    state = make_init_kernel(app, cfg)(
+        jax.random.split(jax.random.PRNGKey(0), lanes)
+    )
+    progs = ExtProgram(*(jnp.asarray(x) for x in empty_programs(cfg, lanes)))
+    segment = make_segment_kernel(app, cfg, 8)
+    text = segment.lower(state, progs, jnp.zeros(lanes, jnp.int32)).as_text()
+    return text, state
+
+
+@pytest.mark.parametrize("shape,cases", [
+    ("spark5-p256", 1), ("bcast64-p4608", 1), ("raft5-p96", 0),
+    ("bcast8-p96", 0),
+])
+def test_the_segment_branches_once_where_the_short_pass_is_built(shape, cases):
+    """``jit(vmap(scan(step)))`` as the continuous driver compiles it: one
+    real ``case`` a step at the wide shapes, on a predicate that is one
+    scalar for the resident set; at raft's shape the program holds none
+    and the lanes carry no count."""
+    text, state = _segment_text(shape)
+    assert text.count("stablehlo.case") == cases
+    assert (state.insert_full_steps is not None) == bool(cases)
+    if cases:  # the branch index is a scalar, not a value a lane
+        assert re.search(r"\}\) : \(tensor<i32>\) -> ", text)
+
+
 # -- the passes do not come back -------------------------------------------
 
-def _kp_compares(cfg, app, k):
-    """How many compare ops of shape [K, P] the lowered insert holds."""
+def _kp_compares(cfg, app, k, lanes=2):
+    """How many compare ops over [K, P] the lowered insert of a batch
+    holds: outside any branch, and in each branch of its ``case`` (none
+    where the short pass is not built)."""
     rng = np.random.default_rng(0)
-    state, rows = _case(app, cfg, rng, k, 0.5)
-    text = jax.jit(
+    cases = [_case(app, cfg, rng, k, 0.5) for _ in range(lanes)]
+    states = _with_count(_stack([c[0] for c in cases]), cfg)
+    rows = _stack([c[1] for c in cases])
+    text = jax.jit(jax.vmap(
         lambda s, r: insert_rows(s, cfg, *r)
-    ).lower(state, rows).as_text()
+    )).lower(states, rows).as_text()
     p = cfg.pool_capacity
     pattern = re.compile(
-        rf"stablehlo\.compare.*->\s*tensor<{k}x{p}xi1>"
+        rf"stablehlo\.compare.*->\s*tensor<{lanes}x{k}x{p}xi1>"
     )
-    return sum(bool(pattern.search(line)) for line in text.splitlines())
+    counts = {"outside": 0}
+    branch = None  # the region of the case a line is in
+    for line in text.splitlines():
+        if "stablehlo.case" in line:
+            branch = 0
+        elif branch is not None and line.strip().startswith("}, {"):
+            branch += 1
+        elif branch is not None and line.strip().startswith("}) :"):
+            branch = None
+        if pattern.search(line):
+            where = "outside" if branch is None else f"branch{branch}"
+            counts[where] = counts.get(where, 0) + 1
+    return counts, text
 
 
 # Under ``track_fifo_heads`` a second question is asked over [K, P] (does
 # the pool hold the row's channel already: a src and a dst compare), which
-# is not the insert's and stays.
-@pytest.mark.parametrize("fifo,expected", [(False, 1), (True, 3)])
-def test_the_onehot_insert_compares_over_rows_and_slots_once(fifo, expected):
+# is not the insert's and stays, outside the branches.
+@pytest.mark.parametrize("fifo,outside", [(False, 0), (True, 2)])
+def test_the_onehot_insert_compares_over_rows_and_slots_once(fifo, outside):
     app, cfgs = _cfgs("bcast64-p4608", srcdst_fifo=fifo)
-    assert _kp_compares(cfgs["onehot"], app, 65) == expected
+    counts, text = _kp_compares(cfgs["onehot"], app, 65)
+    # lax.cond's false branch comes first: the short pass compares over
+    # [K] and [P], a rank at a time, and never over [K, P]; the full pass
+    # once.
+    assert counts == {"outside": outside, "branch1": 1}
+    assert text.count("stablehlo.case") == 1
+    short = text[text.index("stablehlo.case"):].split("}, {")[0]
+    for width in (65, 4608):
+        assert len(re.findall(
+            rf"stablehlo\.compare.*->\s*tensor<2x{width}xi1>", short
+        )) >= C
+
+
+@pytest.mark.parametrize("fifo,expected", [(False, 1), (True, 3)])
+def test_the_small_insert_compares_over_rows_and_slots_once(fifo, expected):
+    """Where no short pass is built there is no branch, and one compare."""
+    app, cfgs = _cfgs("raft5-p96", srcdst_fifo=fifo)
+    counts, text = _kp_compares(cfgs["onehot"], app, app.max_outbox + 2)
+    assert counts == {"outside": expected}
+    assert "stablehlo.case" not in text

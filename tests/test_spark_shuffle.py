@@ -500,3 +500,48 @@ def test_stale_task_is_still_found_and_is_the_reference_without_the_check():
         except dag_reference.Diverged:
             continue
         assert (right.code, right.step) != (1, int(res.deliveries[lane]))
+
+
+# -- the one-hot insert's short pass over a whole run -------------------------
+
+def test_a_whole_run_is_the_same_on_the_short_pass_as_on_the_scatter_insert():
+    """17 actors, 2 stages x 40 tasks (an 82-row insert: the short pass is
+    built) through the continuous driver: the path a TPU runs, where a step
+    of the resident set takes the insert's short pass unless some lane
+    launches a stage, gives every lane's status, code and sequence hash, and
+    every pool row entered (``seq_counter``, summed at the retire), as the
+    scatter insert does; and it counts the steps that took the full pass."""
+    from demi_tpu import obs
+    from demi_tpu.device.continuous import ContinuousSweepDriver
+
+    app, cfg, fuzzer = build_workload(dict(DAG5, nodes=17, stages=2))
+    assert (cfg.num_actors, cfg.max_outbox) == (17, 81)
+    lanes, resident = 48, 16
+    got = {}
+    for mode in ("scatter", "onehot"):
+        driver = ContinuousSweepDriver(
+            app, dataclasses.replace(cfg, index_mode=mode),
+            lambda s: fuzzer.generate_fuzz_test(seed=s),
+            batch=resident, seg_steps=32, seed_pure=True,
+        )
+        obs.disable()
+        obs.TRACER.clear()
+        obs.enable()
+        try:
+            rows = sorted(driver._run(lanes))
+            counts = obs.stage_counts()
+        finally:
+            obs.disable()
+            obs.TRACER.clear()
+        got[mode] = rows, counts
+    (rows, counts), (want_rows, want_counts) = got["onehot"], got["scatter"]
+    assert rows == want_rows
+    assert [r[0] for r in rows] == list(range(lanes))
+    assert any(r[2] == 0 and r[1] == 2 for r in rows)  # jobs ran to their end
+    assert counts["sweep.rows_inserted"] == want_counts["sweep.rows_inserted"]
+    assert counts["sweep.rows_inserted"] > lanes * 2 * 40
+    # Most steps send one row or none: the short pass; a launch anywhere in
+    # the resident set sends 80 and takes the full one.
+    assert 0 < counts["sweep.insert_full_steps"] < counts["sweep.insert_steps"]
+    assert counts["sweep.insert_steps"] >= counts["sweep.lane_steps"] // 2
+    assert "sweep.insert_steps" not in want_counts
