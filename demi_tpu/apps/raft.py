@@ -48,7 +48,8 @@ in for the akka-raft raft-NN branches):
                       two same-term leaders).
 
 One more case study needs NO bug flag: this fixture keeps voted_for/term
-in memory only (the DSL has no durable storage), so HardKill+restart wipes
+in memory only (it declares no ``DSLApp.durable`` word, and the
+``raft5-nemesis`` cell rests on that), so HardKill+restart wipes
 them and a restarted voter can grant a second vote in a term it already
 voted in — two same-term leaders (raft-66-class lost-durability bug;
 tests/test_raft_case_studies.py::test_lost_vote_durability_on_crash_recovery,

@@ -51,8 +51,13 @@ def build_app(args) -> DSLApp:
         from .apps.twopc import make_twopc_app
 
         return make_twopc_app(args.nodes, bug=args.bug)
+    if args.app == "vsr":
+        from .apps.vsr import make_vsr_app
+
+        return make_vsr_app(args.nodes, log_cap=args.log_cap, bug=args.bug)
     raise SystemExit(
-        f"unknown app {args.app!r} (choices: broadcast, raft, spark, twopc)"
+        f"unknown app {args.app!r} "
+        "(choices: broadcast, raft, spark, twopc, vsr)"
     )
 
 
@@ -65,6 +70,10 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         from .apps.twopc import twopc_send_generator
 
         gen = twopc_send_generator(app)
+    elif args.app == "vsr":
+        from .apps.vsr import vsr_send_generator
+
+        gen = vsr_send_generator(app)
     elif args.app == "broadcast":
         gen = broadcast_send_generator(app)
     else:

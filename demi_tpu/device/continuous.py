@@ -299,6 +299,17 @@ class ContinuousSweepDriver:
         self._occupancy = jax.jit(
             lambda valid: jnp.sum(valid, axis=1, dtype=jnp.int32)
         )
+        # The app's progress counts (``DSLApp.progress``) of every lane,
+        # ``[B, names]``: run at the retire, only while spans are live.
+        # None for an app that names none: no kernel, no pull.
+        self._progress = None
+        if app.progress:
+            fns = [fn for _name, fn in app.progress]
+            self._progress = jax.jit(jax.vmap(
+                lambda states: jnp.stack(
+                    [jnp.asarray(fn(states), jnp.int32) for fn in fns]
+                )
+            ))
 
     def _record_round_stats(self, state, finished, vio) -> None:
         """Fold one harvest round's finished lanes into the registry
@@ -657,6 +668,18 @@ class ContinuousSweepDriver:
                                     "sweep.insert_steps",
                                     int(steps_run[fin].sum()),
                                 )
+                            if self._progress is not None:
+                                # What the retired lanes' protocol got
+                                # done: one [B, names] pull.
+                                done = np.asarray(
+                                    self._progress(state.actor_state)
+                                )[fin].sum(axis=0)
+                                for (name, _fn), total in zip(
+                                    self.app.progress, done.tolist()
+                                ):
+                                    obs.stage_count(
+                                        f"sweep.app.{name}", total
+                                    )
                             obs.stage_count("sweep.retired", len(fin))
                             obs.stage_count(
                                 "sweep.quiesced",

@@ -909,10 +909,18 @@ def external_effects(
     stopped = ops.set_scalar(
         state.stopped, a_c, is_hardkill, is_start | is_hardkill, oh
     )
-    # Start after HardKill resets app state.
+    # Start after HardKill resets app state, but for the app's durable
+    # words (on a first start the old row is the init row). A Python
+    # gate: an app that declares none builds the program it always did.
+    fresh_row = ops.get_row(init_states, a_c, oh)
+    if app.durable:
+        kept = np.zeros(cfg.state_width, bool)
+        kept[list(app.durable)] = True
+        fresh_row = jnp.where(
+            kept, ops.get_row(state.actor_state, a_c, oh), fresh_row
+        )
     actor_state = ops.set_row(
-        state.actor_state, a_c, ops.get_row(init_states, a_c, oh),
-        fresh_start, oh,
+        state.actor_state, a_c, fresh_row, fresh_start, oh
     )
     if oh:
         oh_a = ops.onehot(a_c, n)

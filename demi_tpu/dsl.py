@@ -76,12 +76,31 @@ class DSLApp:
     # whatever budget it carries, and a run cut by its step budget is
     # unfinished, not judged.
     invariant_at: str = "delivery"
+    # Indices of ``state`` that a HardKill followed by a Start keeps: the
+    # words an actor has on disk (VSR's recovery counter, a raft that
+    # persists term and vote). Every other word is ``init_state``'s again,
+    # and the restarted actor gets its ``initial_msgs`` as a first start
+    # does; a soft Kill keeps all state, as ever. Both tiers keep them
+    # (``device/core.py: external_effects``; ``ControlledActorSystem``'s
+    # ``hard_kill`` and ``spawn``). Empty, a restart is a first start.
+    durable: Tuple[int, ...] = ()
+    # Named counts of a finished schedule, ``(name, states[N, S] -> int32)``:
+    # what the protocol got done (views changed, replicas recovered). The
+    # continuous sweep sums each over the lanes it retires and counts
+    # ``sweep.app.<name>``, only while spans are live; the step kernel
+    # carries nothing for them. An app with none costs and counts nothing.
+    progress: Tuple[Tuple[str, Callable], ...] = ()
 
     def __post_init__(self):
         if self.invariant_at not in ("delivery", "quiescence"):
             raise ValueError(
                 f"invariant_at must be 'delivery' or 'quiescence', "
                 f"got {self.invariant_at!r}"
+            )
+        if any(not 0 <= i < self.state_width for i in self.durable):
+            raise ValueError(
+                f"durable indices {self.durable!r} must lie in "
+                f"0..{self.state_width - 1}"
             )
 
     @property

@@ -415,6 +415,10 @@ def handler_fingerprint(app) -> str:
     for fn in (app.handler, app.invariant, app.init_state):
         if fn is not None:
             _code_digest(h, fn)
+    if getattr(app, "durable", ()):
+        # What a restart keeps is behaviour too; an app that names no
+        # durable word keeps the fingerprint it had.
+        h.update(repr(tuple(app.durable)).encode())
     return h.hexdigest()[:16]
 
 

@@ -94,6 +94,15 @@ class Actor:
         """State snapshot for invariant checking (CheckpointReply payload)."""
         return None
 
+    def durable_state(self) -> Any:
+        """What survives a HardKill (the actor's disk), or None. The
+        system hands it to ``restore_durable`` of the actor the next
+        Start creates, before its ``on_start``."""
+        return None
+
+    def restore_durable(self, kept: Any) -> None:  # noqa: B027
+        pass
+
 
 class DSLActorAdapter(Actor):
     """Runs one actor of a DSLApp on the host oracle, calling the *same*
@@ -126,6 +135,14 @@ class DSLActorAdapter(Actor):
 
     def checkpoint_state(self) -> np.ndarray:
         return self.state.copy()
+
+    def durable_state(self) -> Optional[np.ndarray]:
+        if not self.app.durable:
+            return None
+        return self.state[list(self.app.durable)].copy()
+
+    def restore_durable(self, kept: np.ndarray) -> None:
+        self.state[list(self.app.durable)] = kept
 
     # -- helpers -----------------------------------------------------------
     def _sender_id(self, snd: str) -> int:

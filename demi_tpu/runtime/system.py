@@ -130,6 +130,10 @@ class ControlledActorSystem:
         self.actors: Dict[str, Actor] = {}
         self.crashed: Set[str] = set()
         self.stopped: Set[str] = set()  # HardKilled names (may be re-Started)
+        # What a HardKilled actor had on disk (Actor.durable_state), handed
+        # to the actor its next Start creates. Device twin:
+        # device/core.py external_effects.
+        self.durable: Dict[str, Any] = {}
         # Blocked-ask semantics (bridge tier only; in-framework DSL apps are
         # CPS-style and never block — SURVEY §7.3). name -> reply predicate:
         # while present, only entries satisfying the predicate are
@@ -202,6 +206,11 @@ class ControlledActorSystem:
             self.network.unisolate(name)
             return []
         self.actors[name] = factory()
+        kept = self.durable.pop(name, None)
+        if kept is not None:
+            # A restart after HardKill: the new actor gets back what the
+            # old one had on disk (DSLApp.durable), before on_start runs.
+            self.actors[name].restore_durable(kept)
         self.stopped.discard(name)
         self.crashed.discard(name)
         self.network.unisolate(name)
@@ -219,6 +228,10 @@ class ControlledActorSystem:
             stop = getattr(actor, "on_stop", None)
             if stop is not None:
                 stop()
+            keep = getattr(actor, "durable_state", None)
+            kept = keep() if keep is not None else None
+            if kept is not None:
+                self.durable[name] = kept
         self.stopped.add(name)
         self.crashed.discard(name)
         self.blocked_asks.pop(name, None)
@@ -423,12 +436,14 @@ class ControlledActorSystem:
                 # ask would make deferred messages deliverable mid-probe.
                 self.blocked_asks,
                 self.pending_asks,
+                self.durable,
             )
         )
 
     def restore(self, snap) -> None:
         (actors, crashed, stopped, net, vcs, idstate,
-         blocked, asks) = copy.deepcopy(snap)
+         blocked, asks, durable) = copy.deepcopy(snap)
+        self.durable = durable
         self.actors = actors
         self.crashed = crashed
         self.stopped = stopped
