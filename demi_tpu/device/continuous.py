@@ -62,6 +62,7 @@ from .core import (
     ST_VIOLATION,
     DeviceConfig,
     ScheduleState,
+    insert_form,
 )
 from .encoding import count_op_arrays, empty_programs, lower_into
 from .explore import (
@@ -121,7 +122,10 @@ def _segment_lane_fn(app: DSLApp, cfg: DeviceConfig, seg_steps: int):
     return seg_lane
 
 
-@obs.spans.staged("setup.build", what="make_segment_kernel")
+@obs.spans.staged(
+    "setup.build", what="make_segment_kernel",
+    insert=lambda app, cfg, *a, **kw: insert_form(cfg),
+)
 def make_segment_kernel(
     app: DSLApp, cfg: DeviceConfig, seg_steps: int, mesh=None
 ):

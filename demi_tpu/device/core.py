@@ -429,9 +429,40 @@ def _short_insert_built(cfg: DeviceConfig) -> bool:
     )
 
 
+def insert_form(cfg: DeviceConfig) -> str:
+    """The form of pool insert a kernel of ``cfg`` is built with: what a
+    ``setup.build`` stage says of it."""
+    if not cfg.use_onehot:
+        return "scatter"
+    return "short" if _short_insert_built(cfg) else "rows"
+
+
 def _sum_where(sel, col):
     """[K, P] bool, [K] -> [P]: the value of the one row a slot selects."""
     return jnp.sum(jnp.where(sel, col[:, None], 0), axis=0)
+
+
+def _landed_rows(hit, want, prefix, row_msg, pool_msg):
+    """``pool_msg`` with each hit slot's row in it: row by row, the slots
+    of that row's rank take its W words whole ([P, W] selects over the
+    live pool, which fuse into one pass over it: no sum, no stack and no
+    merge after). An occupied slot shares its ``prefix`` with the free
+    slot before it, hence ``hit``; an invalid row's ``want`` is -1 and
+    matches nothing. Ranked on the v5e against a select-and-sum a payload
+    word through the shared [K, P] compare, stacked and merged (what PR 32
+    shaped at raft's W = 7: a batch carries ``pool_msg`` with W major, so
+    each ``row_msg[:, j]`` is a copy of its own out of the W-minor
+    proposal: at W = 37 over half of the kernel), the same stacked on
+    axis 0 and transposed, one [K, P, W] select-and-sum, and a 0/1 table
+    times the rows' bytes on the MXU: first at W = 37, and 7% under the
+    columns' kernel at W = 7 (PERF.md, PR 39). The short pass keeps its
+    columns (K there is an outbox of 65 or 402 rows, W 2 or 3)."""
+    for r in range(row_msg.shape[0]):
+        pool_msg = jnp.where(
+            (hit & (prefix == want[r]))[:, None], row_msg[r][None, :],
+            pool_msg,
+        )
+    return pool_msg
 
 
 def _landed_full(want, prefix, cols):
@@ -563,8 +594,10 @@ def insert_rows(
         # scalar creator link) is O(P); what it must read from its row is
         # ONE [K, P] compare, contracted against a few [K] columns: a word
         # packing src, dst and the row's bits (src is at most n, the
-        # external sender; dst is an actor id: every caller clips), the W
-        # payload words, and the creator links where they are per row.
+        # external sender; dst is an actor id: every caller clips) and the
+        # creator links where they are per row; the W payload words land
+        # as whole rows (``_landed_rows``; a [K] column each on the short
+        # pass).
         hit = free & (prefix <= n_rows)
         bits = n.bit_length()
         flags = [row_timer, row_parked]
@@ -595,20 +628,28 @@ def insert_rows(
             )
             word = cols[0]
 
-            def landed_msg(j):
-                return cols[1 + j]
+            def landed_msg():
+                # row_msg is already narrowed: the round trip is exact
+                return jnp.where(
+                    hit[:, None],
+                    jnp.stack(cols[1:1 + cfg.msg_width], axis=1).astype(
+                        state.pool_msg.dtype
+                    ),
+                    state.pool_msg,
+                )
 
             def landed_crec():
                 return cols[-1]
         else:
-            # The smaller shapes: each column where it is written, the
-            # program they had before the short pass came.
+            # The smaller outboxes: the packed word (and a per-row crec)
+            # through the one compare, the payload as whole rows.
             sel = want[:, None] == prefix[None, :]
             word = _sum_where(sel, word)
 
-            def landed_msg(j):
-                # row_msg is already narrowed: the round trip is exact
-                return _sum_where(sel, row_msg[:, j].astype(jnp.int32))
+            def landed_msg():
+                return _landed_rows(
+                    hit, want, prefix, row_msg, state.pool_msg
+                )
 
             def landed_crec():
                 return _sum_where(sel, crec)
@@ -624,13 +665,7 @@ def insert_rows(
             pool_dst=jnp.where(hit, (word >> bits) & field, state.pool_dst),
             pool_timer=jnp.where(hit, bit(0), state.pool_timer),
             pool_parked=jnp.where(hit, bit(1), state.pool_parked),
-            pool_msg=jnp.where(
-                hit[:, None],
-                jnp.stack(
-                    [landed_msg(j) for j in range(cfg.msg_width)], axis=1
-                ).astype(state.pool_msg.dtype),
-                state.pool_msg,
-            ),
+            pool_msg=landed_msg(),
             # arrival order follows row order: the row of rank r is r-th
             pool_seq=jnp.where(hit, state.seq_counter + prefix, state.pool_seq),
             seq_counter=state.seq_counter + n_rows,

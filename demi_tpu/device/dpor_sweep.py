@@ -51,6 +51,7 @@ from .core import (
     deliver_index,
     deliverable_mask,
     init_state,
+    insert_form,
 )
 from .encoding import lower_program
 from .explore import ExtProgram, LaneResult, _finalize, make_step_fn
@@ -462,7 +463,10 @@ def lane_keys(seeds):
     )(np.asarray(seeds, np.uint32))
 
 
-@obs.spans.staged("setup.build", what="build_dpor_kernel")
+@obs.spans.staged(
+    "setup.build", what="build_dpor_kernel",
+    insert=lambda app, cfg, *a, **kw: insert_form(cfg),
+)
 def build_dpor_kernel(
     app: DSLApp, cfg: DeviceConfig, mesh=None, start_state: bool = False,
     sleep_cap: int = 0, commute_matrix=None,

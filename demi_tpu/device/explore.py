@@ -220,10 +220,10 @@ def make_step_fn(app: DSLApp, cfg: DeviceConfig):
     Under vmap a ``lax.cond`` on a lane's own predicate executes both
     branches anyway, so the old two-branch form paid the insert machinery
     twice per step (two prefix sums, then on a CPU a searchsorted and 8
-    scatters, on a TPU one [K, pool] compare and a select-and-sum per
-    packed column: ``core.insert_rows``); profiling shows the insert
-    dominates step cost. Fusing removes a full insert pass and both cond
-    selects.
+    scatters, on a TPU one [K, pool] compare for the packed word and K
+    whole-row selects over the pool: ``core.insert_rows``); profiling
+    shows the insert dominates step cost. Fusing removes a full insert
+    pass and both cond selects.
 
     The step's one real branch is inside that insert, and only where it
     carries many rows (``core._short_insert_built``): its predicate, "does

@@ -25,6 +25,10 @@ ONE ``lax.cond`` over the whole batch of lanes, which takes a short pass
 over the first 8 valid rows in every step where no lane sends more. A
 ``cond`` on a lane's own count would run both branches under ``vmap``; a
 predicate reduced over the batch is a scalar, and stays a ``case``.
+Outside that short pass a hit slot takes its payload as a whole row: K
+selects over ``pool_msg``'s own ``[P, W]``, the merge into the live pool
+being the same pass (``core._landed_rows``); only the packed word and a
+per-row creator link go through the shared ``[K, P]`` compare.
 """
 
 from __future__ import annotations

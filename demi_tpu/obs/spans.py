@@ -607,12 +607,17 @@ class stage:
 
 
 def staged(name: str, **args):
-    """Decorator: the call is a ``stage(name, **args)``."""
+    """Decorator: the call is a ``stage(name, **args)``. An arg that is
+    callable is what it returns of the call's own arguments."""
 
     def wrap(fn):
         @functools.wraps(fn)
         def inner(*a, **kw):
-            with stage(name, **args):
+            said = {
+                key: value(*a, **kw) if callable(value) else value
+                for key, value in args.items()
+            }
+            with stage(name, **said):
                 return fn(*a, **kw)
 
         return inner

@@ -16,8 +16,16 @@ over where the rows sit and how many there are, and holds the compiled
 segment to one ``case`` there and none at raft's shape. The last tests
 hold the passes down: the lowered one-hot insert compares over ``[K, P]``
 once (a branch, where there are two).
+
+Outside the short pass a slot reads its payload as a whole row
+(``core._landed_rows``: K selects over ``[P, W]``, no column of the rows
+taken): ``vsr5-p256`` (the VSR cell's 37-word rows) and a 16-word shape
+run every parity case above beside raft's 7 words, and the last tests hold
+the insert's jaxpr to no per-column slice there, and to its columns on the
+short pass.
 """
 
+import dataclasses
 import re
 
 import jax
@@ -25,21 +33,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from demi_tpu import obs
 from demi_tpu.apps.broadcast import make_broadcast_app
 from demi_tpu.apps.common import dsl_start_events
 from demi_tpu.apps.raft import make_raft_app
 from demi_tpu.apps.spark_dag import make_spark_app
+from demi_tpu.apps.vsr import make_vsr_app
 from demi_tpu.device.continuous import make_init_kernel, make_segment_kernel
 from demi_tpu.device.core import (
     INSERT_SHORT_FACTOR, INSERT_SHORT_ROWS, ST_OVERFLOW, DeviceConfig,
-    _short_insert_built, init_state, insert_rows,
+    _short_insert_built, init_state, insert_form, insert_rows,
 )
+from demi_tpu.device.dpor_sweep import build_dpor_kernel
 from demi_tpu.device.encoding import empty_programs
 from demi_tpu.device.encoding import lower_program
 from demi_tpu.device.explore import (
     ExtProgram, broadcast_program, make_explore_kernel,
 )
 from demi_tpu.external_events import MessageConstructor, Send, WaitQuiescence
+from demi_tpu.obs import spans
 
 LANES = 4
 SHAPES = {
@@ -54,6 +66,11 @@ SHAPES = {
     "spark5-p256": (
         lambda nodes: make_spark_app(nodes - 1, 2, 40), 5, 256, 82
     ),
+    # the VSR cell's: 37-word rows (5 + log_cap 32); the fused step
+    # proposes a start's 3 rows, a send and a 5-row outbox
+    "vsr5-p256": (lambda nodes: make_vsr_app(nodes, log_cap=32), 5, 256, 9),
+    # a width between raft's 7 and VSR's 37
+    "vsr5-w16-p96": (lambda nodes: make_vsr_app(nodes, log_cap=11), 5, 96, 9),
 }
 C = INSERT_SHORT_ROWS
 FILLS = (0.0, 0.3, 0.83, 1.0)
@@ -213,7 +230,9 @@ def test_onehot_insert_equals_scatter_insert(shape, fill):
 
 
 @pytest.mark.parametrize("extra", [0, 1], ids=["exact-fit", "one-too-many"])
-@pytest.mark.parametrize("shape", ["raft5-p96", "bcast8-p96", "bcast64-p4608"])
+@pytest.mark.parametrize("shape", [
+    "raft5-p96", "bcast8-p96", "bcast64-p4608", "vsr5-p256", "vsr5-w16-p96",
+])
 def test_the_overflow_edge(shape, extra):
     app, cfgs = _cfgs(shape)
     k = _rows_k(shape, app)
@@ -259,7 +278,7 @@ FEATURES = {
 
 
 @pytest.mark.parametrize("feature", list(FEATURES))
-@pytest.mark.parametrize("shape", ["raft5-p96", "bcast8-p96"])
+@pytest.mark.parametrize("shape", ["raft5-p96", "bcast8-p96", "vsr5-p256"])
 def test_onehot_insert_equals_scatter_insert_with(shape, feature):
     kw = dict(FEATURES[feature])
     for fill in (0.3, 0.9):
@@ -500,17 +519,22 @@ def test_the_segment_branches_once_where_the_short_pass_is_built(shape, cases):
 
 # -- the passes do not come back -------------------------------------------
 
+def _batched_insert(cfg, app, k, lanes=2, crec=None):
+    """``(fn, states, rows)``: the one-hot insert of a batch of ``lanes``
+    half-full lanes, to lower or to trace."""
+    rng = np.random.default_rng(0)
+    cases = [_case(app, cfg, rng, k, 0.5, crec=crec) for _ in range(lanes)]
+    states = _with_count(_stack([c[0] for c in cases]), cfg)
+    rows = _stack([c[1] for c in cases])
+    return jax.vmap(lambda s, r: insert_rows(s, cfg, *r)), states, rows
+
+
 def _kp_compares(cfg, app, k, lanes=2):
     """How many compare ops over [K, P] the lowered insert of a batch
     holds: outside any branch, and in each branch of its ``case`` (none
     where the short pass is not built)."""
-    rng = np.random.default_rng(0)
-    cases = [_case(app, cfg, rng, k, 0.5) for _ in range(lanes)]
-    states = _with_count(_stack([c[0] for c in cases]), cfg)
-    rows = _stack([c[1] for c in cases])
-    text = jax.jit(jax.vmap(
-        lambda s, r: insert_rows(s, cfg, *r)
-    )).lower(states, rows).as_text()
+    fn, states, rows = _batched_insert(cfg, app, k, lanes)
+    text = jax.jit(fn).lower(states, rows).as_text()
     p = cfg.pool_capacity
     pattern = re.compile(
         rf"stablehlo\.compare.*->\s*tensor<{lanes}x{k}x{p}xi1>"
@@ -556,3 +580,103 @@ def test_the_small_insert_compares_over_rows_and_slots_once(fifo, expected):
     counts, text = _kp_compares(cfgs["onehot"], app, app.max_outbox + 2)
     assert counts == {"outside": expected}
     assert "stablehlo.case" not in text
+
+
+# -- the whole-row form -----------------------------------------------------
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _insert_ops(shape, lanes=2, crec=None, **overrides):
+    """``{(primitive, output shape): count}`` over the jaxpr of a batch's
+    one-hot insert, nested calls included."""
+    app, cfgs = _cfgs(shape, **overrides)
+    cfg = cfgs["onehot"]
+    k = _rows_k(shape, app)
+    fn, states, rows = _batched_insert(cfg, app, k, lanes, crec)
+    jaxpr = jax.make_jaxpr(fn)(states, rows)
+    counts = {}
+    for eqn in _eqns(jaxpr.jaxpr):
+        key = (eqn.primitive.name, tuple(eqn.outvars[0].aval.shape))
+        counts[key] = counts.get(key, 0) + 1
+    return counts, cfg, k
+
+
+@pytest.mark.parametrize("shape", [
+    "raft5-p96", "raft5-p256", "bcast8-p96", "vsr5-w16-p96", "vsr5-p256",
+])
+def test_the_insert_takes_no_column_of_its_rows(shape):
+    """One [K, P] compare and one select over it (the packed word); the
+    payload is K whole-row slices and K selects over [P, W], with no
+    stack: nothing repeats W-fold."""
+    counts, cfg, k = _insert_ops(shape)
+    assert insert_form(cfg) == "rows"
+    b, p, w = 2, cfg.pool_capacity, cfg.msg_width
+    assert counts[("eq", (b, k, p))] == 1
+    assert counts[("select_n", (b, k, p))] == 1
+    assert ("slice", (b, k, 1)) not in counts
+    assert not any(name == "concatenate" for name, _shape in counts)
+    assert counts[("slice", (b, 1, w))] == k
+    assert counts[("select_n", (b, p, w))] == k
+
+
+def test_a_per_row_crec_is_the_one_more_column():
+    counts, cfg, k = _insert_ops(
+        "vsr5-p256", crec="rows", record_trace=True, record_parents=True
+    )
+    assert counts[("select_n", (2, k, cfg.pool_capacity))] == 2
+    assert ("slice", (2, k, 1)) not in counts
+
+
+@pytest.mark.parametrize("shape", RANKED_SHAPES)
+def test_the_short_pass_keeps_its_columns(shape):
+    """K there is a whole outbox (65, 82 rows) and W 2 or 3: a [K] slice
+    a payload word into the one ``case``, one stack after it."""
+    counts, cfg, k = _insert_ops(shape)
+    assert insert_form(cfg) == "short"
+    assert insert_form(dataclasses.replace(cfg, index_mode="scatter")) == "scatter"
+    b, p, w = 2, cfg.pool_capacity, cfg.msg_width
+    assert counts[("slice", (b, k, 1))] == w
+    assert counts[("concatenate", (b, p, w))] == 1
+    assert ("slice", (b, 1, w)) not in counts
+
+
+@pytest.mark.parametrize("shape,form", [
+    ("vsr5-p256", "rows"), ("raft5-p96", "rows"),
+    ("bcast64-p4608", "short"), ("spark5-p256", "short"),
+])
+def test_the_build_stage_says_which_insert_a_kernel_has(shape, form):
+    app, cfgs = _cfgs(shape, max_steps=16)
+    spans._reset_setup()
+    try:
+        make_segment_kernel(app, cfgs["onehot"], 8)
+        make_segment_kernel(app, cfgs["scatter"], 8)
+        said = [
+            (e["args"]["what"], e["args"]["insert"])
+            for e in obs.setup_ledger()["timeline"]
+            if e["name"] == "setup.build" and "insert" in e["args"]
+        ]
+        assert said == [
+            ("make_segment_kernel", form), ("make_segment_kernel", "scatter"),
+        ]
+        if form != "short":
+            spans._reset_setup()
+            dpor_cfg = DeviceConfig.for_app(
+                app, pool_capacity=SHAPES[shape][2], max_steps=16,
+                record_trace=True, record_parents=True, index_mode="onehot",
+            )
+            build_dpor_kernel(app, dpor_cfg)
+            (entry,) = [
+                e for e in obs.setup_ledger()["timeline"]
+                if e["args"].get("what") == "build_dpor_kernel"
+            ]
+            assert entry["args"]["insert"] == form
+    finally:
+        spans._reset_setup()

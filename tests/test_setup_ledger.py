@@ -123,6 +123,18 @@ def test_staged_wraps_a_call_in_a_stage(fresh):
     assert entry["args"] == {"what": "make_thing"}
 
 
+def test_a_staged_arg_that_is_callable_reads_the_calls_arguments(fresh):
+    @spans.staged("setup.build", what="make_thing", size=lambda a, b=2: a * b)
+    def make_thing(a, b=2):
+        return a + b
+
+    assert make_thing(3) == 5 and make_thing(3, b=4) == 7
+    said = [e["args"] for e in obs.setup_ledger()["timeline"]]
+    assert said == [
+        {"what": "make_thing", "size": 6}, {"what": "make_thing", "size": 12},
+    ]
+
+
 def test_stages_are_handed_to_the_tracer_once_spans_are_live(fresh):
     with obs.stage("setup.import", module="early"):
         pass

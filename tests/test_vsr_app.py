@@ -10,6 +10,7 @@ JAX) agreeing lane for lane on fuzzed crash-recovery-and-partition
 schedules; the unmodified protocol clean under at most f kills a program;
 each seeded bug found by a small sweep and lifted with its code."""
 
+import dataclasses
 import importlib.util
 import os
 import sys
@@ -23,7 +24,7 @@ from demi_tpu.apps import vsr
 from demi_tpu.apps.common import make_host_invariant
 from demi_tpu.config import SchedulerConfig
 from demi_tpu.device.continuous import ContinuousSweepDriver
-from demi_tpu.device.core import ST_DONE, ST_VIOLATION
+from demi_tpu.device.core import ST_DONE, ST_VIOLATION, insert_form
 from demi_tpu.device.encoding import (
     device_trace_to_guide, lower_program, stack_programs,
 )
@@ -50,9 +51,10 @@ vsr_reference = _load("benchmarks/lib/vsr_reference.py", "vsr_reference")
 L = 4
 
 
-def workload(nodes=5, bug="recover_any"):
+def workload(nodes=5, bug="recover_any", log_cap=L):
     return {
-        "app": "vsr", "nodes": nodes, "bug": bug, "log_cap": L, "seed": 0,
+        "app": "vsr", "nodes": nodes, "bug": bug, "log_cap": log_cap,
+        "seed": 0,
         "num_events": 32, "max_messages": 384, "pool": 128,
         "timer_weight": 0.05, "send_weight": 0.15, "wait_weight": 0.35,
         "wait_budget": [1, 25], "hard_kill_weight": 0.15,
@@ -304,25 +306,49 @@ def test_no_branch_sends_more_rows_than_the_outbox_holds(nodes):
 SEEDS = list(range(24)) + [45, 60, 74, 132, 176, 226, 301, 309]
 
 
-@pytest.fixture(scope="module")
-def swept():
-    """The 32 seeds run to their end through the continuous driver's own
+# The same agreement with the batch on the chip's path: ``index_mode``
+# 'onehot' (a CPU's 'auto' is scatter, so (b) above never runs the one-hot
+# insert) at a ``log_cap`` that makes a row 17 words, since the one-hot
+# insert carries a payload as whole rows and its cost and layout follow W
+# (the cell's is 37): four of the first seeds and two on which the bug
+# fires.
+WIDE_L = 12
+WIDE_SEEDS = [0, 1, 2, 3, 45, 60]
+
+
+def _swept(seeds, log_cap=L, index_mode=None):
+    """The seeds run to their end through the continuous driver's own
     kernels (status, code, sequence hash, final actor rows), and what
-    the per-lane lifts need."""
-    app, cfg, fuzzer = build_workload(workload())
+    the per-lane lifts need. ``index_mode`` is the batch's; the lifts'
+    single-lane kernel keeps the workload's (scatter, on a CPU)."""
+    app, cfg, fuzzer = build_workload(workload(log_cap=log_cap))
     gen = lambda s: fuzzer.generate_fuzz_test(seed=s)  # noqa: E731
-    lanes = len(SEEDS)
-    progs = stack_programs([lower_program(app, cfg, gen(s)) for s in SEEDS])
-    keys = jax.vmap(lane_key)(np.asarray(SEEDS, np.uint32))
-    drv = ContinuousSweepDriver(app, cfg, gen, batch=lanes, seg_steps=64)
+    lanes = len(seeds)
+    progs = stack_programs([lower_program(app, cfg, gen(s)) for s in seeds])
+    keys = jax.vmap(lane_key)(np.asarray(seeds, np.uint32))
+    batch_cfg = cfg if index_mode is None else dataclasses.replace(
+        cfg, index_mode=index_mode
+    )
+    drv = ContinuousSweepDriver(app, batch_cfg, gen, batch=lanes, seg_steps=64)
     state = drv.init(keys)
     for steps in range(0, cfg.max_steps, 64):
         state = drv.segment(state, progs, jnp.full(lanes, steps, jnp.int32))
     state = jax.device_get(drv.finalize(state))
     return {
-        "app": app, "cfg": cfg, "progs": progs, "keys": keys, "state": state,
+        "app": app, "cfg": cfg, "batch_cfg": batch_cfg, "log_cap": log_cap,
+        "progs": progs, "keys": keys, "state": state,
         "kernel": make_single_lane_trace_kernel(app, cfg), "lifted": {},
     }
+
+
+@pytest.fixture(scope="module")
+def swept():
+    return _swept(SEEDS)
+
+
+@pytest.fixture(scope="module")
+def swept_wide():
+    return _swept(WIDE_SEEDS, log_cap=WIDE_L, index_mode="onehot")
 
 
 def lifted(swept, lane):
@@ -356,9 +382,7 @@ def test_the_seeds_hold_both_verdicts(swept):
     assert (np.asarray(state.violation)[:24] != 0).sum() <= 2
 
 
-@pytest.mark.parametrize("lane", range(len(SEEDS)))
-def test_device_and_host_agree_on_a_fuzzed_lane(swept, lane):
-    """Same code, same delivered sequence, same final rows."""
+def _device_and_host_agree(swept, lane):
     state = swept["state"]
     single, host, rows = lifted(swept, lane)
     code = int(state.violation[lane])
@@ -371,14 +395,11 @@ def test_device_and_host_agree_on_a_fuzzed_lane(swept, lane):
         np.testing.assert_array_equal(row, state.actor_state[lane][i], str(i))
 
 
-@pytest.mark.parametrize("lane", range(len(SEEDS)))
-def test_the_plain_reference_agrees_on_a_fuzzed_lane(swept, lane):
-    """Verdict, step and every replica's view, status, commit-number and
-    log, against the host oracle's rows."""
+def _the_plain_reference_agrees(swept, lane):
     single, host, rows = lifted(swept, lane)
     ref = vsr_reference.replay(
-        5, L, np.asarray(single.trace).tolist(), int(single.trace_len),
-        bug="recover_any",
+        5, swept["log_cap"], np.asarray(single.trace).tolist(),
+        int(single.trace_len), bug="recover_any",
     )
     host_code = host.violation.code if host.violation is not None else 0
     assert ref.code == host_code
@@ -392,6 +413,39 @@ def test_the_plain_reference_agrees_on_a_fuzzed_lane(swept, lane):
     assert ref.log_rows == int(
         swept["state"].actor_state[lane][:, vsr.LOG_ROWS_SENT].sum()
     )
+
+
+@pytest.mark.parametrize("lane", range(len(SEEDS)))
+def test_device_and_host_agree_on_a_fuzzed_lane(swept, lane):
+    """Same code, same delivered sequence, same final rows."""
+    _device_and_host_agree(swept, lane)
+
+
+@pytest.mark.parametrize("lane", range(len(SEEDS)))
+def test_the_plain_reference_agrees_on_a_fuzzed_lane(swept, lane):
+    """Verdict, step and every replica's view, status, commit-number and
+    log, against the host oracle's rows."""
+    _the_plain_reference_agrees(swept, lane)
+
+
+def test_the_wide_seeds_run_the_whole_row_insert(swept_wide):
+    assert swept_wide["app"].msg_width == 5 + WIDE_L
+    assert insert_form(swept_wide["batch_cfg"]) == "rows"
+    assert insert_form(swept_wide["cfg"]) == "scatter"  # the lifts' lane
+    codes = np.asarray(swept_wide["state"].violation)
+    assert (codes[4:] != 0).all() and (codes[:4] == 0).sum() >= 3
+
+
+@pytest.mark.parametrize("lane", range(len(WIDE_SEEDS)))
+def test_device_and_host_agree_on_a_wide_row_lane(swept_wide, lane):
+    """The one-hot batch over 17-word rows against its scatter lane and
+    the host oracle."""
+    _device_and_host_agree(swept_wide, lane)
+
+
+@pytest.mark.parametrize("lane", range(len(WIDE_SEEDS)))
+def test_the_plain_reference_agrees_on_a_wide_row_lane(swept_wide, lane):
+    _the_plain_reference_agrees(swept_wide, lane)
 
 
 def test_the_reference_without_the_bug_parts_from_the_program(swept):
