@@ -55,9 +55,13 @@ def build_app(args) -> DSLApp:
         from .apps.vsr import make_vsr_app
 
         return make_vsr_app(args.nodes, log_cap=args.log_cap, bug=args.bug)
+    if args.app == "chain":
+        from .apps.chain import make_chain_app
+
+        return make_chain_app(args.nodes, log_cap=args.log_cap, bug=args.bug)
     raise SystemExit(
         f"unknown app {args.app!r} "
-        "(choices: broadcast, raft, spark, twopc, vsr)"
+        "(choices: broadcast, chain, raft, spark, twopc, vsr)"
     )
 
 
@@ -74,6 +78,10 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         from .apps.vsr import vsr_send_generator
 
         gen = vsr_send_generator(app)
+    elif args.app == "chain":
+        from .apps.chain import chain_send_generator
+
+        gen = chain_send_generator(app)
     elif args.app == "broadcast":
         gen = broadcast_send_generator(app)
     else:
@@ -848,6 +856,7 @@ def _fuzz_checkpoint_run(args, app, config, fuzzer, controller) -> int:
             max_executions=args.max_executions,
             seed=args.seed, max_messages=args.max_messages,
             invariant_check_interval=app.invariant_interval,
+            strategy=app.random_strategy,
             timer_weight=args.timer_weight,
             validate_replay=True, controller=controller,
             start_execution=start, round_hook=hook,
@@ -1173,6 +1182,7 @@ def cmd_fuzz(args) -> int:
             seed=args.seed,
             max_messages=args.max_messages,
             invariant_check_interval=app.invariant_interval,
+            strategy=app.random_strategy,
             timer_weight=args.timer_weight,
             validate_replay=True,
             controller=controller,
@@ -1591,9 +1601,11 @@ def cmd_dpor(args) -> int:
         # tune.calibrate_dpor_inflight) and the test_window surface.
         os.environ["DEMI_ASYNC_MIN"] = "1"
     from .device import DeviceConfig
-    from .device.dpor_sweep import DeviceDPOROracle
+    from .device.dpor_sweep import FIFO_REFUSAL, DeviceDPOROracle
 
     app = build_app(args)
+    if app.channels == "fifo":
+        raise SystemExit(f"dpor: {FIFO_REFUSAL}")
     config = SchedulerConfig(invariant_check=make_host_invariant(app))
     cfg = DeviceConfig.for_workload(
         app, args, record_trace=True, record_parents=True
@@ -2045,6 +2057,7 @@ def cmd_stats(args) -> int:
             seed=args.seed,
             max_messages=args.max_messages,
             invariant_check_interval=app.invariant_interval,
+            strategy=app.random_strategy,
             timer_weight=args.timer_weight,
         )
         from .device import DeviceConfig

@@ -125,6 +125,7 @@ def _segment_lane_fn(app: DSLApp, cfg: DeviceConfig, seg_steps: int):
 @obs.spans.staged(
     "setup.build", what="make_segment_kernel",
     insert=lambda app, cfg, *a, **kw: insert_form(cfg),
+    fifo=lambda app, cfg, *a, **kw: cfg.track_fifo_heads,
 )
 def make_segment_kernel(
     app: DSLApp, cfg: DeviceConfig, seg_steps: int, mesh=None
@@ -303,6 +304,20 @@ class ContinuousSweepDriver:
         self._occupancy = jax.jit(
             lambda valid: jnp.sum(valid, axis=1, dtype=jnp.int32)
         )
+        # What the FIFO discipline holds back: the valid non-timer rows
+        # of the live lanes and those of them that head their channel,
+        # ``[2]``, beside the pool-peak sample and as rarely. None where
+        # the channels keep no order: no kernel, no count.
+        self._fifo_rows = None
+        if cfg.track_fifo_heads:
+            def fifo_rows(status, valid, timer, head):
+                rows = (status < ST_DONE)[:, None] & valid & ~timer
+                return jnp.stack([
+                    jnp.sum(rows, dtype=jnp.int32),
+                    jnp.sum(rows & head, dtype=jnp.int32),
+                ])
+
+            self._fifo_rows = jax.jit(fifo_rows)
         # The app's progress counts (``DSLApp.progress``) of every lane,
         # ``[B, names]``: run at the retire, only while spans are live.
         # None for an app that names none: no kernel, no pull.
@@ -571,6 +586,13 @@ class ContinuousSweepDriver:
                         self._occupancy(state.pool_valid)
                         if sample_pool else None
                     )
+                    fifo_rows = (
+                        self._fifo_rows(
+                            state.status, state.pool_valid,
+                            state.pool_timer, state.pool_head,
+                        )
+                        if sample_pool and self._fifo_rows else None
+                    )
                 t_gap = time.perf_counter()
                 if self.seed_pure:
                     self._make_ahead(
@@ -588,6 +610,10 @@ class ContinuousSweepDriver:
                             self.last_pool_peak,
                             int(np.asarray(occupancy).max()),
                         )
+                        if fifo_rows is not None:
+                            pending, heads = np.asarray(fifo_rows).tolist()
+                            obs.stage_count("sweep.fifo_pending_rows", pending)
+                            obs.stage_count("sweep.fifo_head_rows", heads)
                 t_harvest = time.perf_counter()
                 self.last_harvest_seconds += t_pull - t_gap
                 if self.last_lane_sharding is None:

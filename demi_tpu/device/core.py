@@ -105,8 +105,10 @@ class DeviceConfig:
     # SrcDstFIFO randomization (reference: RandomScheduler.scala:702-909,
     # host twin schedulers/random.py SrcDstFIFO): per-(src,dst) channels
     # are TCP-ordered — only each channel's FIFO head is a delivery
-    # candidate; timers stay individually choosable. Costs an O(P^2)
-    # same-channel compare per step, so opt-in.
+    # candidate; timers stay individually choosable. The head bits are
+    # kept as the pool changes (``track_fifo_heads``). An app whose
+    # channels are "fifo" (``DSLApp.channels``) gets it from
+    # ``for_workload``; no verb has a flag for it.
     srcdst_fifo: bool = False
     # Batched-replay peek (device twin of STSScheduler.allow_peek /
     # IntervalPeekScheduler): when an expected delivery has no pending
@@ -216,8 +218,10 @@ class DeviceConfig:
         capacities from the shared workload flags (``--pool``,
         ``--max-messages``, ``--num-events``, ``--timer-weight``; ``args``
         is the CLI's namespace or one from ``distributed.workload_args``),
-        and when the invariant is judged from the app
-        (``DSLApp.invariant_at``), which is no verb's to choose."""
+        and from the app what is no verb's to choose: when the invariant
+        is judged (``DSLApp.invariant_at``) and the order its channels
+        keep (``DSLApp.channels``; an app that says "any" builds exactly
+        what it always did)."""
         defaults = dict(
             pool_capacity=getattr(args, "pool", None) or 256,
             max_steps=args.max_messages,
@@ -225,6 +229,8 @@ class DeviceConfig:
             invariant_interval=app.invariant_interval,
             timer_weight=args.timer_weight,
         )
+        if app.channels == "fifo":
+            defaults["srcdst_fifo"] = True
         defaults.update(overrides)
         return DeviceConfig.for_app(app, **defaults)
 
@@ -948,11 +954,16 @@ def external_effects(
     # words (on a first start the old row is the init row). A Python
     # gate: an app that declares none builds the program it always did.
     fresh_row = ops.get_row(init_states, a_c, oh)
-    if app.durable:
+    if app.kept_words:
         kept = np.zeros(cfg.state_width, bool)
-        kept[list(app.durable)] = True
+        kept[list(app.kept_words)] = True
         fresh_row = jnp.where(
             kept, ops.get_row(state.actor_state, a_c, oh), fresh_row
+        )
+    if app.spawn_count is not None:
+        # The runtime's count of this actor's fresh starts.
+        fresh_row = fresh_row + (
+            np.arange(cfg.state_width) == app.spawn_count
         )
     actor_state = ops.set_row(
         state.actor_state, a_c, fresh_row, fresh_start, oh

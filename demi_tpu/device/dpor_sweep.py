@@ -1168,6 +1168,15 @@ def steering_prescription(
     return tuple(map(tuple, keep.tolist()))
 
 
+#: Why ``DeviceDPOR`` (and the ``dpor`` verb) turn a FIFO app away.
+FIFO_REFUSAL = (
+    "DPOR does not explore an app whose channels are FIFO "
+    "(DSLApp.channels): its racing analysis reverses two messages of one "
+    "(sender, receiver) pair, an order such a network cannot deliver; "
+    "sweep it instead"
+)
+
+
 class DeviceDPOR:
     """Frontier-batched DPOR driver: rounds of B prescriptions per kernel
     launch, deepest-first priority, explored-set dedup.
@@ -1211,6 +1220,8 @@ class DeviceDPOR:
         host_shards: Optional[int] = None,
     ):
         assert cfg.record_trace and cfg.record_parents
+        if app.channels == "fifo":
+            raise ValueError(FIFO_REFUSAL)
         self.app = app
         self.cfg = cfg
         # Static may-commute relation resolved FIRST: the sleep-set

@@ -90,17 +90,42 @@ class DSLApp:
     # ``sweep.app.<name>``, only while spans are live; the step kernel
     # carries nothing for them. An app with none costs and counts nothing.
     progress: Tuple[Tuple[str, Callable], ...] = ()
+    # Index of a state word in which the runtime counts the actor's fresh
+    # starts (its first Start, and every Start after a HardKill): 1 in a
+    # first life, 2 after the first restart. Kept like a durable word and
+    # raised before the actor handles anything, on both tiers, so a
+    # handler can tell a restart from a first start even where the
+    # earlier life handled no message and left no durable mark of its own
+    # (chain replication's servers: a restarted one is no member).
+    # ``init_state`` leaves the word 0. None: no such word, and the
+    # program an app lowers to is what it was.
+    spawn_count: Optional[int] = None
+    # The order the network keeps: "any" (a pending message may be
+    # delivered at any time: the reference's FullyRandom) or "fifo" (per
+    # (sender, receiver) pair, non-timer messages are delivered in the
+    # order they were sent: TCP links, Akka's guarantee, the reference's
+    # SrcDstFIFO; timers stay individually choosable, and the external
+    # sender is one source, so its sends to one actor are ordered too).
+    # A property of the deployment, so like ``invariant_at`` no verb
+    # chooses it: ``DeviceConfig.for_workload`` derives ``srcdst_fifo``
+    # from it, the host fuzz its ``RandomScheduler`` strategy, and the
+    # guided replay refuses a delivery that is not its channel's oldest.
+    channels: str = "any"
 
     def __post_init__(self):
+        if self.channels not in ("any", "fifo"):
+            raise ValueError(
+                f"channels must be 'any' or 'fifo', got {self.channels!r}"
+            )
         if self.invariant_at not in ("delivery", "quiescence"):
             raise ValueError(
                 f"invariant_at must be 'delivery' or 'quiescence', "
                 f"got {self.invariant_at!r}"
             )
-        if any(not 0 <= i < self.state_width for i in self.durable):
+        if any(not 0 <= i < self.state_width for i in self.kept_words):
             raise ValueError(
-                f"durable indices {self.durable!r} must lie in "
-                f"0..{self.state_width - 1}"
+                f"durable indices {self.durable!r} and spawn_count "
+                f"{self.spawn_count!r} must lie in 0..{self.state_width - 1}"
             )
 
     @property
@@ -108,6 +133,18 @@ class DSLApp:
         """The deliveries between invariant checks, in both tiers'
         numbering: 1, or 0 for "at the run's end only"."""
         return 1 if self.invariant_at == "delivery" else 0
+
+    @property
+    def kept_words(self) -> Tuple[int, ...]:
+        """The state words a HardKill followed by a Start keeps."""
+        if self.spawn_count is None:
+            return self.durable
+        return self.durable + (self.spawn_count,)
+
+    @property
+    def random_strategy(self) -> str:
+        """The host ``RandomScheduler``'s strategy for ``channels``."""
+        return "srcdst_fifo" if self.channels == "fifo" else "fully_random"
 
     # -- naming ------------------------------------------------------------
     def actor_name(self, actor_id: int) -> str:

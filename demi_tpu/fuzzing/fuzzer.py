@@ -59,6 +59,14 @@ class MessageGenerator:
         """Called at the start of each generated program; stateful
         generators (counters etc.) restart here."""
 
+    # ``note_fault(op, name)``, where a generator defines it, is told of
+    # every Kill, HardKill (``op`` is the op's code) and restart
+    # (``OP_START``) as the fuzzer draws it, with the actor's name: for a
+    # generator that stands for a part of the environment which sees the
+    # faults (chain replication's master, ``apps/chain.py``). The
+    # ``alive`` list of the next draw cannot show a kill and a restart of
+    # one actor that both fall between two sends. It takes no draw.
+
 
 @dataclass
 class FuzzerWeights:
@@ -124,6 +132,7 @@ class Fuzzer:
         self._send_row, self._row_sends = _send_rows(
             message_gen, self._frame.index
         )
+        self._note_fault = getattr(message_gen, "note_fault", None)
         self._choice_key: Optional[tuple] = None
         self._choices: tuple = (0, ())
         # How many named wait predicates the app declares
@@ -200,6 +209,7 @@ class Fuzzer:
         frame = self._frame
         index = frame.index
         send_row = self._send_row
+        note_fault = self._note_fault
         prog = FuzzProgram(frame, self._row_sends)
         kind, col_a, col_b = prog.kind, prog.a, prog.b
         payloads = prog.payloads
@@ -269,6 +279,8 @@ class Fuzzer:
                     col_a.append(index[victim])
                     col_b.append(0)
                     generated += 1
+                    if note_fault is not None:
+                        note_fault(op, victim)
             elif op == _RESTART:
                 if killed:
                     name = rng.choice(killed)
@@ -278,6 +290,8 @@ class Fuzzer:
                     col_a.append(index[name])
                     col_b.append(0)
                     generated += 1
+                    if note_fault is not None:
+                        note_fault(OP_START, name)
             elif op == _ATOMIC:
                 # Cap the batch at the remaining event budget so generated
                 # programs never overshoot num_events; with <2 remaining a

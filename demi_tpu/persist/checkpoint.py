@@ -415,10 +415,13 @@ def handler_fingerprint(app) -> str:
     for fn in (app.handler, app.invariant, app.init_state):
         if fn is not None:
             _code_digest(h, fn)
-    if getattr(app, "durable", ()):
+    if getattr(app, "kept_words", ()):
         # What a restart keeps is behaviour too; an app that names no
         # durable word keeps the fingerprint it had.
-        h.update(repr(tuple(app.durable)).encode())
+        h.update(repr(tuple(app.kept_words)).encode())
+    if getattr(app, "channels", "any") != "any":
+        # So is the order its network keeps.
+        h.update(app.channels.encode())
     return h.hexdigest()[:16]
 
 

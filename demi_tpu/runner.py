@@ -112,6 +112,7 @@ def fuzz(
     start_execution: int = 0,
     round_hook=None,
     on_violation=None,
+    strategy: str = "fully_random",
 ) -> Optional[FuzzResult]:
     """Generate fuzz tests and run them until a violation is found
     (reference: RunnerUtils.fuzz, RunnerUtils.scala:62-147). With
@@ -138,13 +139,17 @@ def fuzz(
     remaining executions — the host analog of the sweep drivers'
     violation handoff. Returning True from the hook stops the loop;
     with the hook set, ``fuzz`` always returns None (every violation
-    flowed through the hook)."""
+    flowed through the hook).
+
+    ``strategy`` is the scheduler's (``DSLApp.random_strategy``: an app
+    whose channels are FIFO is fuzzed under ``"srcdst_fifo"``)."""
     sched = RandomScheduler(
         config,
         seed=seed,
         max_messages=max_messages,
         invariant_check_interval=invariant_check_interval,
         timer_weight=timer_weight,
+        strategy=strategy,
     )
     for i in range(start_execution, max_executions):
         if controller is not None:

@@ -118,6 +118,10 @@ class DSLActorAdapter(Actor):
         )
 
     def on_start(self, ctx: Context) -> None:
+        if self.app.spawn_count is not None:
+            # The runtime's count of fresh starts (``restore_durable`` ran
+            # before this on a restart).
+            self.state[self.app.spawn_count] += 1
         if self.app.initial_msgs is None:
             return
         rows = np.asarray(self.app.initial_msgs(self.actor_id), dtype=np.int32)
@@ -137,12 +141,12 @@ class DSLActorAdapter(Actor):
         return self.state.copy()
 
     def durable_state(self) -> Optional[np.ndarray]:
-        if not self.app.durable:
+        if not self.app.kept_words:
             return None
-        return self.state[list(self.app.durable)].copy()
+        return self.state[list(self.app.kept_words)].copy()
 
     def restore_durable(self, kept: np.ndarray) -> None:
-        self.state[list(self.app.durable)] = kept
+        self.state[list(self.app.kept_words)] = kept
 
     # -- helpers -----------------------------------------------------------
     def _sender_id(self, snd: str) -> int:

@@ -34,7 +34,9 @@ from ..device.core import (
 
 
 class GuideDivergence(Exception):
-    """A guide step had no matching pending entry on the host oracle."""
+    """A guide step had no matching pending entry on the host oracle, or
+    (an app whose channels are FIFO) delivers a message that is not its
+    channel's oldest pending one."""
 
 
 class GuidedScheduler(BaseScheduler):
@@ -132,6 +134,7 @@ class GuidedScheduler(BaseScheduler):
         src_name = (
             app.actor_name(src) if src < app.num_actors else None
         )  # None = EXTERNAL
+        fifo = app.channels == "fifo" and not is_timer
         for entry in self._pending:  # FIFO: first match
             if entry.is_timer != is_timer:
                 continue
@@ -144,6 +147,14 @@ class GuidedScheduler(BaseScheduler):
                 elif entry.snd != src_name:
                     continue
             if self._msg_key(entry.msg) != tuple(msg):
+                if fifo:
+                    # ``_pending`` is in sending order: this is the
+                    # channel's oldest, and only it may be delivered.
+                    raise GuideDivergence(
+                        f"{(src, dst, tuple(msg))!r} is not the oldest "
+                        f"pending message of its FIFO channel: "
+                        f"{self._msg_key(entry.msg)!r} was sent first"
+                    )
                 continue
             return entry
         return None
