@@ -512,20 +512,41 @@ def _make_replication_app(
         catchup = jnp.stack([b_valid, b_dst, jnp.int32(U_CATCHUP), m, 0])
         return state, jnp.concatenate([lone[None], catchup[None], fwds])
 
+    words, ids, int32 = np.arange(S), np.arange(n), np.iinfo(np.int32)
+    # Word HIST + k is entry k of Hist; a scalar word is nobody's entry.
+    entry = np.where(words >= HIST, words - HIST, int32.max).astype(np.int32)
+
+    def column(states, word):
+        """``states[:, word]`` as a select-and-sum over the whole rows."""
+        return jnp.sum(jnp.where(words == word, states, 0), axis=1)
+
     def invariant(states, alive):
+        # Every read is of whole [N, S] rows under a constant word mask: no
+        # slice of Hist and no column ``states[:, word]`` either. A column
+        # read makes the v5e compiler carry the step kernel's batched rows
+        # with the N servers on its 128 lanes, and every pass of the step
+        # over the rows then moves 18 times their bytes (DESIGN.md sec. 3).
         member = (
-            alive & (states[:, AWAKE] == 1) & (states[:, STATUS] == MEMBER)
+            alive & (column(states, AWAKE) == 1)
+            & (column(states, STATUS) == MEMBER)
         )
-        opn, acked = states[:, OPN], states[:, ACKED]
-        hists = states[:, HIST:]
-        both = member[:, None] & member[None, :]
-        shared = (
-            jnp.arange(L)[None, None, :]
-            < jnp.minimum(opn[:, None], opn[None, :])[:, :, None]
+        opn, acked = column(states, OPN), column(states, ACKED)
+        # Update Propagation: the members agree pair by pair on the prefix
+        # both hold exactly when each agrees, on its own OPN entries, with
+        # the member that holds the most (the first of a tie), so its row
+        # is the one reference: a one-hot select-and-sum, no gather.
+        held = jnp.where(member, opn, -1)
+        first = jnp.min(jnp.where(held == jnp.max(held), ids, n))
+        longest = jnp.sum(
+            jnp.where((ids == first)[:, None], states, 0), axis=0
         )
-        differ = hists[:, None, :] != hists[None, :, :]
-        diverged = jnp.any(both[:, :, None] & shared & differ)
-        lost = jnp.any(both & (opn[:, None] < acked[None, :]))
+        own = member[:, None] & (entry[None, :] < opn[:, None])
+        diverged = jnp.any(own & (states != longest[None, :]))
+        # Some member lacks an update that some member has acknowledged.
+        lost = (
+            jnp.min(jnp.where(member, opn, int32.max))
+            < jnp.max(jnp.where(member, acked, int32.min))
+        )
         return jnp.where(
             diverged, jnp.int32(1), jnp.where(lost, jnp.int32(2), 0)
         )

@@ -435,7 +435,7 @@ def violating_seeds():
     )
     result = driver.sweep(512, 256, mode="continuous")
     assert result.overflow_lanes == 0
-    return app, cfg, fuzzer, sorted(found)
+    return app, cfg, fuzzer, sorted(found), result.lanes_digest
 
 
 @pytest.fixture(scope="module")
@@ -468,7 +468,7 @@ def lifted(swept, lane):
 
 
 def test_no_resend_is_found_and_lifts(violating_seeds):
-    app, cfg, fuzzer, found = violating_seeds
+    app, cfg, fuzzer, found, _digest = violating_seeds
     assert found and {code for _s, code in found} == {1}
     assert len(found) < 512 // 2
     seed = found[0][0]
@@ -484,6 +484,58 @@ def test_the_seeds_hold_both_verdicts(swept):
     state = swept["state"]
     assert set(np.asarray(state.status).tolist()) == {ST_DONE, ST_VIOLATION}
     assert (np.asarray(state.violation)[40:] == 1).all()
+
+
+# What the two runs above gave at PR 40's tree (commit 8c30193, the invariant
+# as every pair of members over a slice of Hist), recorded there: the 512
+# lanes' digest (seed, status, code, sched_hash) and the seeds that violate,
+# all code 1; the 48 lanes' codes, the delivery at which each stopped (a
+# violating lane stops at the delivery that broke the invariant) and their
+# sequence hashes.
+PINNED_DIGEST = 0x5ADA26D66BE2C110
+PINNED_VIOLATING = [
+    8, 11, 15, 36, 46, 57, 60, 64, 68, 84, 85, 91, 94, 95, 98, 105, 113, 114,
+    119, 129, 132, 146, 147, 148, 154, 156, 160, 163, 164, 167, 169, 170, 171,
+    181, 184, 196, 204, 211, 216, 223, 228, 240, 244, 249, 250, 254, 260, 269,
+    271, 272, 273, 287, 292, 294, 298, 302, 311, 312, 316, 317, 332, 345, 357,
+    358, 359, 366, 373, 378, 395, 396, 398, 419, 440, 447, 454, 461, 466, 473,
+    490, 498, 511,
+]
+PINNED_CODES = [
+    0, 0, 0, 0, 0, 0, 0, 0, 1, 0, 0, 1, 0, 0, 0, 1, 0, 0, 0, 0, 0, 0, 0, 0,
+    0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, 1, 0, 0, 0, 1, 1, 1, 1, 1, 1, 1, 1,
+]
+PINNED_DELIVERIES = [
+    98, 83, 87, 67, 56, 77, 84, 53, 16, 83, 77, 13, 53, 82, 93, 13, 79, 77,
+    85, 66, 75, 100, 93, 75, 77, 81, 74, 79, 94, 67, 75, 76, 92, 86, 55, 72,
+    35, 64, 80, 86, 54, 49, 17, 23, 35, 42, 29, 34,
+]
+PINNED_HASHES = [
+    3239852469, 3545814206, 1478089559, 2491871879, 356963990, 4078995865,
+    1886826398, 518230646, 729663784, 4235196847, 660415732, 3450893412,
+    3166059721, 3572828726, 1649350013, 4202567544, 804503596, 1352825361,
+    900937500, 1222409884, 4041693240, 2790122361, 3960803228, 2021721381,
+    1832616688, 3103302195, 1894064542, 504346449, 1600828952, 2112641134,
+    2401937295, 1193749253, 3770763725, 831647080, 2686620869, 1245903560,
+    439312453, 100698269, 2991427819, 289842114, 3017749830, 1375318182,
+    517026508, 3421372230, 2679053574, 2971015417, 1151801723, 3244869398,
+]
+
+
+def test_a_whole_run_is_what_it_was_with_the_pairwise_invariant(
+    violating_seeds, swept
+):
+    """The same lanes violate at the same delivery with the same code."""
+    _app, _cfg, _fuzzer, found, digest = violating_seeds
+    assert found == [(seed, 1) for seed in PINNED_VIOLATING]
+    assert digest == PINNED_DIGEST
+    state = swept["state"]
+    assert np.asarray(state.violation).tolist() == PINNED_CODES
+    assert np.asarray(state.deliveries).tolist() == PINNED_DELIVERIES
+    assert np.asarray(state.sched_hash).tolist() == PINNED_HASHES
+    assert (np.asarray(state.status) == ST_VIOLATION).tolist() == [
+        code != 0 for code in PINNED_CODES
+    ]
 
 
 @pytest.mark.parametrize("lane", range(48))
