@@ -42,12 +42,79 @@ between calls (the benchmark's closes over a per-job base), and a
 consumer that stops early just drops it. Only a generator the
 constructor is told is a function of the seed (``seed_pure``) is called
 ahead; any other is called at refill, in refill order.
+
+PRODUCER PROCESSES (PR 42). Where the making is what a call waits for,
+it is done by child processes beside the host thread, and the round is
+what is left: dispatch -> status pull -> harvest -> fill (one indexed
+copy per array out of the ring) -> refill. Whether it is, the call
+observes; nothing sets it. (1) The prime fill makes its first ``_PROBE``
+programs on the spot under the clock pair every program runs under:
+the programs the call still has to make, at that cost, must give each
+producer ``_WORTH_S`` seconds of making (``_producer_count``: none
+either where the generator is not ``seed_pure``, where there is a
+``program_key`` memo, on one core, or without ``fork``). (2) Making
+ahead hides the making while the device is the slower of the two, and
+then a fork only costs: so the call counts the nanoseconds its refills
+spend making programs the stock had run out of, the device idle
+meanwhile, and forks once they reach ``_WORTH_S`` (about what the forks
+will cost: it has then lost no more than twice what it could have),
+right after a dispatch, behind what the stock holds (the ring starts
+with it). The driver remembers the verdict (``_exposed``), and its next
+call forks at its prime fill, so that the first resident set comes out
+of the ring too. The children are forked inside the call because the
+generator is a closure that nothing can pickle and that may differ
+from call to call: a child inherits it as it stands. A fork copies the
+page tables of a process that holds a TPU client (14 GB resident:
+45-75 ms on the one-chip host, about 100 on four chips; PERF.md,
+PR 42), on the host thread, so HOW MANY is measured too: the first fork
+is timed (what a fork costs is the least the process has timed: one
+can read five times that), and the call forks ``sqrt(a resident set's
+making / one fork)`` children, the count behind which a fill that waits for a whole
+set waits least (``_fewest_wait``). The host thread forks them itself,
+one after the other: a child that forked its siblings would start its
+own chunks later and spare the host thread nothing at the prime fill,
+where it only waits meanwhile.
+
+Who writes which block when. The stock is then a ``_Ring``: one
+anonymous shared mapping made before the fork, ``_RING_SETS`` resident
+sets long whatever the call's length, cut into chunks of consecutive
+seed positions. Child ``i`` makes chunks ``i, i + P, ...`` (behind
+the chunks a mid-call fork found made) with the driver's own ``_make`` (the same code on the same seed: the bytes are
+the host thread's), each into the ring slot ``chunk % slots``, then
+writes the chunk's number into the slot's flag; before a chunk it waits
+until the fill has taken the chunk that held the slot a ring earlier
+(``head[0]``, the host thread's word). The host thread reads
+a slot only after its flag names the chunk it wants, copies, and only
+then advances ``taken``: no row is ever written and read at once, and
+the resident arrays are the host thread's alone. Where the chunk it
+needs is not flagged yet it waits inside a ``sweep.starve`` span, each
+wait under a deadline and a ``waitpid(WNOHANG)``; a child that died or
+outstayed the deadline is killed, reaped and written off, and the fill
+makes that child's positions itself, on the spot, from then on: a lost
+child costs time, never a result. The generator's ``finally`` kills and
+reaps every child and unmaps the ring, however the call ends.
+
+Why a child may touch nothing but its slots. It is a copy of a process
+that holds the chip, a profiler session and threads it did not inherit:
+it runs ``_make`` over its chunks and nothing else -- no ``jax`` call, no
+span, no ``TraceAnnotation``, no logging, no collector hook (the
+parent's would open a ``gc.pause`` span) -- and leaves through
+``os._exit``, so no ``atexit`` handler shuts the parent's TPU client
+down and no buffered output is written twice. Its clock pairs and the
+fuzzer's own counts reach the parent through the ring's ``stats`` rows
+(``sweep.producer_ns``; ``fuzz.programs_generated`` /
+``fuzz.events_generated`` under ``DEMI_OBS=1``).
 """
 
 from __future__ import annotations
 
+import gc
+import mmap
+import os
+import signal
 import time
-from typing import Callable, List, Optional, Sequence
+import warnings
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -206,16 +273,306 @@ class _Stock:
         self.count = 0
         self.made = np.zeros(room, bool)
         self.from_rows = np.zeros(room, bool)  # lowered from op rows
+        self.took_hosts = 0
 
     def next_slot(self) -> int:
         return (self.head + self.count) % self.room
 
-    def take(self, k: int) -> np.ndarray:
-        """The slots of the ``k`` oldest programs, given up."""
+    def take(self, k: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The slots of the oldest programs, ``k`` of them or all there
+        are, given up, and which of them were made (``took_hosts`` of
+        them: all that were; the name is ``_Producers``')."""
+        k = min(k, self.count)
         slots = (self.head + np.arange(k)) % self.room
         self.head = (self.head + k) % self.room
         self.count -= k
-        return slots
+        made = self.made[slots]
+        self.took_hosts = int(made.sum())
+        return slots, made
+
+    def free(self, k: int) -> None:
+        """Nothing to tell anybody: the host thread alone writes here."""
+
+
+# Producer processes (module doc).
+_PROBE = 32             # programs the prime fill makes on the spot to price one
+_CHUNK = 64             # programs between two flags, at most (a quarter set)
+# The ring's length, in resident sets. At least 2: a fill may take a
+# whole set from a position inside a chunk, and the chunk it ends in
+# must have a slot free of the one it starts in. 4 was no faster
+# (PERF.md, PR 42).
+_RING_SETS = 2
+# Seconds of making a producer must have ahead of it: several forks at
+# the chip host's 45-75 ms each (PERF.md, PR 42).
+_WORTH_S = 0.25
+_STARVE_DEADLINE_S = 2.0    # a wait for one chunk, before its child is given up
+_POLL_S = 1e-4
+# The shortest fork this process has timed: what a fork costs it. One
+# fork can read five times that (the first after a profiler session
+# ends: 239 ms against 60-80; PERF.md, PR 42), and a call that took it
+# at its word forked one child where three pay.
+_least_fork_ns = float("inf")
+
+
+def _cores() -> int:
+    """Cores this process may run on."""
+    affinity = getattr(os, "sched_getaffinity", None)
+    return len(affinity(0)) if affinity else (os.cpu_count() or 1)
+
+
+def _fewest_wait(prime_ns: float, fork_ns: float, most: int) -> int:
+    """The number of children, 1 to ``most``, behind which the prime
+    fill waits least: ``P`` forks one after the other, then ``prime_ns``
+    of making shared by ``P``."""
+    return min(most, max(1, round((prime_ns / max(fork_ns, 1)) ** 0.5)))
+
+
+class _Ring:
+    """The stock of a call whose programs producer processes make: the
+    ``ExtProgram`` block and the words the processes speak through, laid
+    over ONE anonymous shared mapping made before the fork. ``slots``
+    chunks of ``chunk`` rows; seed position ``q`` (counted from the
+    first a producer makes) lives in row ``q % room``."""
+
+    def __init__(self, cfg: DeviceConfig, resident: int, producers: int):
+        self.chunk = max(1, min(_CHUNK, resident // 4))
+        self.slots = -(-_RING_SETS * resident // self.chunk)
+        self.room = self.slots * self.chunk
+        fields = [
+            (np.dtype(np.int64), (2,)),             # head
+            (np.dtype(np.int64), (producers, 4)),   # stats
+            (np.dtype(np.int64), (self.slots,)),    # flags
+            (np.dtype(np.uint8), (self.room,)),     # from_rows
+        ] + [
+            (x.dtype, (self.room,) + x.shape[1:])
+            for x in empty_programs(cfg, 0)
+        ]
+        offsets, size = [], 0
+        for dtype, shape in fields:
+            offsets.append(size)
+            size += -(-int(np.prod(shape)) * dtype.itemsize // 64) * 64
+        self.block = mmap.mmap(-1, size)
+        views = [
+            np.frombuffer(
+                self.block, dtype, int(np.prod(shape)), offset
+            ).reshape(shape)
+            for (dtype, shape), offset in zip(fields, offsets)
+        ]
+        # The host thread's two words: [0] positions the fill has
+        # copied out; [1] how many children share the chunks, written
+        # once, after the first fork is timed (0: not decided yet).
+        # Everything below is the producers'.
+        self.head = views[0]
+        # Child i's row: fuzz ns, lower ns, and the fuzzer's own
+        # programs and events counts (0 unless telemetry is on).
+        self.stats = views[1]
+        # Slot s holds chunk ``flags[s] - 1`` whole; 0: none yet.
+        self.flags = views[2]
+        self.from_rows = views[3]
+        self.progs = ExtProgram(*views[4:])
+
+    def close(self) -> None:
+        """Unmap. The views go first: a mapping with a buffer exported
+        cannot be closed (one still held elsewhere keeps it until it
+        dies)."""
+        self.head = self.stats = self.flags = self.from_rows = None
+        self.progs = None
+        try:
+            self.block.close()
+        except BufferError:
+            pass
+
+
+class _Producers:
+    """Forked processes making the programs of ``seeds[first:]`` into a
+    ``_Ring``, in seed order, and the host thread's end of it (module
+    doc). ``make`` is the driver's ``_make``. At most ``most`` children:
+    as many as ``_fewest_wait`` says once the first fork is timed
+    (``prime_ns``: a resident set's making; a fork costs the least this
+    process has seen one take), or ``forced``, the driver's
+    private constructor argument, which is not measured. ``held`` is the
+    stock of a call that forks mid-way."""
+
+    def __init__(
+        self, cfg: DeviceConfig, make: Callable, seeds: Sequence[int],
+        first: int, resident: int, most: int, prime_ns: float,
+        forced: Optional[int] = None, held: Optional[_Stock] = None,
+    ):
+        self.ring = _Ring(cfg, resident, most)
+        self.progs, self.from_rows = self.ring.progs, self.ring.from_rows
+        self.make = make
+        self.seeds = seeds
+        self.first = first
+        self.todo = len(seeds) - first      # positions to make
+        self.cursor = 0                     # next position the fill takes
+        self.pids: List[int] = []           # 0 once reaped
+        self.children = forced or 0
+        # Where the call had a stock of its own (it forks mid-way), the
+        # ring starts with what the host thread had made ahead: whole
+        # chunks, flagged, that the children begin behind.
+        self.hosts = self.took_hosts = 0
+        if held is not None and held.count:
+            self.hosts = held.count
+            slots, _made = held.take(held.count)
+            for mine, ahead in zip(self.progs, held.progs):
+                mine[: self.hosts] = ahead[slots]
+            self.from_rows[: self.hosts] = held.from_rows[slots]
+            while self.hosts % self.ring.chunk and self.hosts < self.todo:
+                self.from_rows[self.hosts] = make(
+                    seeds[first + self.hosts], [0, 0], self.progs, self.hosts
+                )
+                self.hosts += 1
+        self.base = -(-self.hosts // self.ring.chunk)   # the children's first chunk
+        self.ring.flags[: self.base] = np.arange(1, self.base + 1)
+        parent = os.getpid()
+        with obs.span("sweep.fork") as sp, warnings.catch_warnings():
+            # JAX and CPython both warn that a fork in a threaded
+            # process may deadlock the child: this child runs no code
+            # but ``_make``, and every wait for it has a deadline.
+            warnings.simplefilter("ignore")
+            self.ring.head[1] = self.children
+            i = 0
+            while i < max(self.children, 1):
+                t0 = time.perf_counter_ns()
+                try:
+                    pid = os.fork()
+                except OSError:
+                    pid = 0     # no process to be had: the fill makes its share
+                else:
+                    if pid == 0:
+                        self._produce(i, parent)    # never returns
+                self.pids.append(pid)
+                if not self.children:
+                    global _least_fork_ns
+                    _least_fork_ns = min(
+                        _least_fork_ns, time.perf_counter_ns() - t0
+                    )
+                    self.children = _fewest_wait(
+                        prime_ns, _least_fork_ns, most
+                    )
+                    self.ring.head[1] = self.children
+                i += 1
+            sp.set(children=self.children)
+
+    # -- the child -----------------------------------------------------------
+    def _produce(self, i: int, parent: int) -> None:
+        """Child ``i``, whole: chunks ``base + i, base + i + children,
+        ...``, then out through ``os._exit`` whatever happened."""
+        code = 1
+        try:
+            gc.callbacks.clear()
+            gc.freeze()     # the inherited heap stays off copy-on-write
+            ring, make, seeds = self.ring, self.make, self.seeds
+            chunk, slots, first = ring.chunk, ring.slots, self.first
+            head, stats = ring.head, ring.stats[i]
+
+            def wait_while(behind) -> None:
+                while behind():
+                    if os.getppid() != parent:
+                        os._exit(0)
+                    time.sleep(_POLL_S)
+
+            counters = [
+                obs.counter("fuzz.programs_generated"),
+                obs.counter("fuzz.events_generated"),
+            ]
+            had = [c.value() for c in counters]
+            clock = [0, 0]
+            # (the first child is forked before their number is known)
+            wait_while(lambda: head[1] == 0)
+            for c in range(self.base + i, -(-self.todo // chunk), int(head[1])):
+                # The slot is free once the chunk a ring earlier is taken.
+                wait_while(lambda: head[0] < (c - slots + 1) * chunk)
+                lo = c * chunk
+                row = (c % slots) * chunk
+                for q in range(lo, min(lo + chunk, self.todo)):
+                    ring.from_rows[row] = make(
+                        seeds[first + q], clock, ring.progs, row
+                    )
+                    row += 1
+                stats[0], stats[1] = clock
+                stats[2] = counters[0].value() - had[0]
+                stats[3] = counters[1].value() - had[1]
+                ring.flags[c % slots] = c + 1
+            code = 0
+        finally:
+            os._exit(code)
+
+    # -- the host thread -----------------------------------------------------
+    def _reaped(self, i: int) -> bool:
+        """Whether child ``i`` is gone (reaping it if it just went)."""
+        pid = self.pids[i]
+        if pid:
+            try:
+                pid = 0 if os.waitpid(pid, os.WNOHANG)[0] else pid
+            except ChildProcessError:
+                pid = 0
+            self.pids[i] = pid
+        return not pid
+
+    def _kill(self, i: int) -> None:
+        pid = self.pids[i]
+        if pid:
+            try:
+                os.kill(pid, signal.SIGKILL)
+                os.waitpid(pid, 0)
+            except (ProcessLookupError, ChildProcessError):
+                pass
+            self.pids[i] = 0
+
+    def _flagged(self, c: int) -> bool:
+        """Whether chunk ``c`` is whole in its slot, waiting for it
+        while its child lives and the deadline lasts."""
+        ring = self.ring
+        slot = c % ring.slots
+        if ring.flags[slot] == c + 1:
+            return True
+        i = (c - self.base) % self.children
+        if not self.pids[i]:
+            return False
+        with obs.span("sweep.starve", chunk=c):
+            deadline = time.monotonic() + _STARVE_DEADLINE_S
+            while ring.flags[slot] != c + 1:
+                if self._reaped(i):
+                    break
+                if time.monotonic() > deadline:
+                    self._kill(i)
+                    break
+                time.sleep(_POLL_S)
+        # (a child may flag its last chunk and go between two looks)
+        return bool(ring.flags[slot] == c + 1)
+
+    def take(self, k: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The ring rows of the next ``k`` seed positions, and which of
+        them hold a program (the rest were a lost child's: the fill
+        makes them); ``took_hosts`` of them were the host thread's own.
+        The rows stay the fill's until ``free``."""
+        ring = self.ring
+        self.took_hosts = min(max(self.hosts - self.cursor, 0), k)
+        q = self.cursor + np.arange(k)
+        chunk_of = q // ring.chunk
+        c0 = int(chunk_of[0])
+        whole = np.array(
+            [self._flagged(c) for c in range(c0, int(chunk_of[-1]) + 1)]
+        )
+        return q % ring.room, whole[chunk_of - c0]
+
+    def free(self, k: int) -> None:
+        """The ``k`` positions last taken are copied out."""
+        self.cursor += k
+        self.ring.head[0] = self.cursor
+
+    def close(self) -> None:
+        """Kill and reap every child, fold what they counted, unmap."""
+        for i in range(len(self.pids)):
+            self._kill(i)
+        stats = self.ring.stats.sum(axis=0).tolist()
+        obs.stage_count("sweep.producer_ns", stats[0] + stats[1])
+        if obs.enabled():
+            obs.counter("fuzz.programs_generated").inc(stats[2])
+            obs.counter("fuzz.events_generated").inc(stats[3])
+        self.progs = self.from_rows = None
+        self.ring.close()
 
 
 class ContinuousSweepDriver:
@@ -237,6 +594,7 @@ class ContinuousSweepDriver:
         mesh=None,
         program_key: Optional[Callable] = None,
         seed_pure: bool = False,
+        producers: Optional[int] = None,
     ):
         self.app = app
         self.cfg = cfg
@@ -247,6 +605,15 @@ class ContinuousSweepDriver:
         # closes over the controller's weights and tags each seed with
         # the proposal it was drawn under) is called at refill only.
         self.seed_pure = seed_pure
+        # Private, for tests: how many producer processes a call forks
+        # where it may fork any (module doc). None, which is what every
+        # verb leaves it at, decides from what the call observes.
+        self._producers = producers
+        # The running call's producers, or None: a test's way in.
+        self._producing: Optional[_Producers] = None
+        # Whether this driver's last call found its making on the
+        # critical path (``_rounds``): the next forks at its prime fill.
+        self._exposed = False
         self.batch = batch
         self.seg_steps = seg_steps
         if mesh is not None and batch % mesh.size:
@@ -379,6 +746,39 @@ class ContinuousSweepDriver:
             )
         return from_rows
 
+    def _producer_count(self, left: int, ns_a_program: float) -> int:
+        """How many producer processes, at most, the ``left`` programs a
+        call has still to make are worth, one costing ``ns_a_program``
+        (module doc): as many as have ``_WORTH_S`` seconds of making
+        each, up to the cores beside the host thread's; 0 where the
+        call may fork none."""
+        if (
+            not self.seed_pure or self._program_key is not None
+            or not hasattr(os, "fork") or left <= 0
+        ):
+            return 0
+        if self._producers is not None:
+            return self._producers
+        worth = int(left * ns_a_program / 1e9 / _WORTH_S)
+        return max(0, min(worth, _cores() - 1))
+
+    def _start_producers(
+        self, seed_list: Sequence[int], first: int, resident: int,
+        ns_a_program: float, held: Optional[_Stock] = None,
+    ) -> Optional[_Producers]:
+        """Producer processes for ``seed_list[first:]`` behind what
+        ``held`` holds of it, or None where they are not worth it."""
+        left = len(seed_list) - first - (held.count if held else 0)
+        most = self._producer_count(left, ns_a_program)
+        if not most:
+            return None
+        self._producing = _Producers(
+            self.cfg, self._make, seed_list, first, resident, most,
+            resident * ns_a_program, self._producers, held,
+        )
+        obs.stage_count("sweep.producers", self._producing.children)
+        return self._producing
+
     def _make_ahead(
         self, pending, seed_list: Sequence[int], start: int, room: int,
         stock: _Stock,
@@ -414,40 +814,53 @@ class ContinuousSweepDriver:
 
     def _fill(
         self, seeds: Sequence[int], lanes: Sequence[int], progs: ExtProgram,
-        stock: Optional[_Stock] = None,
-    ) -> None:
+        stock=None,
+    ) -> int:
         """Write the program of each seed into its lane of the resident
         arrays ``progs``: from ``stock`` while it lasts (made ahead for
-        exactly these seeds, in this order: ``_make_ahead``), one
-        indexed copy per array; the rest ``program_gen`` then
-        ``lower_into`` on the spot, one seed at a time, each over its
-        lane's old program. A program's events, if it ever had any, die
-        before the next is generated, and a retired program's memory
-        serves the next: a fill's programs held together beside the
-        events cost the sweep more than the spans could (PERF.md,
-        PR 24). The loop is per lane, so fuzzing and lowering get no
-        span each: a clock pair per program sums them, and the
-        ``sweep.fill`` span hands the sums to the stages ``sweep.fuzz``
-        and ``sweep.lower`` (a no-op with spans off). ``sweep.stack`` is
-        the copy out of the stock, all that is left of stacking.
-        Counted beside them: ``sweep.programs`` put in a lane,
-        ``sweep.prefetched`` of those that were made ahead, and
-        ``sweep.row_lowered`` of those lowered from a fuzzed program's
-        op rows, here or ahead (a memo hit is lowered by nobody)."""
+        exactly these seeds, in this order: by ``_make_ahead`` into a
+        ``_Stock``, or by producer processes into a ``_Producers``'
+        ring, which lasts as long as its children do), one indexed copy
+        per array; the rest ``program_gen`` then ``lower_into`` on the
+        spot, one seed at a time, each over its lane's old program. A
+        program's events, if it ever had any, die before the next is
+        generated, and a retired program's memory serves the next: a
+        fill's programs held together beside the events cost the sweep
+        more than the spans could (PERF.md, PR 24). The loop is per
+        lane, so fuzzing and lowering get no span each: a clock pair per
+        program sums them, and the ``sweep.fill`` span hands the sums to
+        the stages ``sweep.fuzz`` and ``sweep.lower`` (a no-op with
+        spans off): the host thread's making only; the producers' is
+        ``sweep.producer_ns``. ``sweep.stack`` is the copy out of the
+        stock, all that is left of stacking; ``sweep.starve``
+        (``_Producers._flagged``) the wait for a chunk the producers
+        have not finished. Counted beside them: ``sweep.programs`` put
+        in a lane, ``sweep.prefetched`` of those that the host thread
+        made ahead, ``sweep.produced`` of those that a producer made,
+        and ``sweep.row_lowered`` of those lowered from a fuzzed
+        program's op rows, wherever (a memo hit is lowered by nobody).
+        Returns the nanoseconds the programs made here cost."""
         clock = [0, 0]
         lanes = np.asarray(lanes, np.intp)
-        k = min(stock.count, len(seeds)) if stock is not None else 0
         made = np.zeros(len(seeds), bool)
-        rows = 0
+        rows = hosts = k = 0
         with obs.span("sweep.fill", programs=len(seeds)) as sp:
+            if stock is not None:
+                # (a ``_Producers`` waits here for what is not made yet)
+                slots, held = stock.take(len(seeds))
+                k, hosts = len(slots), stock.took_hosts
+                made[:k] = held
             with obs.span("sweep.stack"):
                 if k:
-                    slots = stock.take(k)
-                    made[:k] = stock.made[slots]
-                    src, dst = slots[made[:k]], lanes[:k][made[:k]]
+                    src, dst = slots[held], lanes[:k][held]
+                    # Seed order is row order, but for the ring's wrap:
+                    # a run of rows is copied as the slice it is.
+                    if len(src) and src[-1] - src[0] == len(src) - 1:
+                        src = slice(int(src[0]), int(src[-1]) + 1)
                     for resident, ahead in zip(progs, stock.progs):
                         resident[dst] = ahead[src]
                     rows = int(stock.from_rows[src].sum())
+                    stock.free(k)
             lane_of = lanes.tolist()
             for j in np.flatnonzero(~made).tolist():
                 lane, seed = lane_of[j], seeds[j]
@@ -461,13 +874,15 @@ class ContinuousSweepDriver:
             sp.slice("sweep.lower", clock[1])
             if obs.spans.live():
                 obs.stage_count("sweep.programs", len(seeds))
-                obs.stage_count("sweep.prefetched", int(made.sum()))
+                obs.stage_count("sweep.prefetched", hosts)
+                obs.stage_count("sweep.produced", int(made.sum()) - hosts)
                 obs.stage_count("sweep.row_lowered", rows)
                 # What this fill lowered, by kind of external op: how
                 # much of the fault plane the traffic engages.
                 counts = count_op_arrays(progs.op[lanes], progs.a[lanes])
                 for kind, n in counts.items():
                     obs.stage_count(f"sweep.ops.{kind}", n)
+        return clock[0] + clock[1]
 
     def time_to_first_violation(self, max_lanes: int = 1_000_000):
         """Wall-clock seconds until the first violating lane finishes (the
@@ -519,7 +934,21 @@ class ContinuousSweepDriver:
         SweepDriver's harvest accumulation stay vectorized — per-lane
         Python tuples exist only for callers that ask (``_run``). The
         round's order, and the stock of programs made ahead while the
-        segment runs, are in the module doc."""
+        segment runs, are in the module doc. However the call ends (its
+        last seed, a consumer that stops early, an exception), the
+        producer processes it forked are killed and reaped and their
+        ring unmapped here."""
+        try:
+            yield from self._rounds(total_lanes, seeds)
+        finally:
+            producers, self._producing = self._producing, None
+            if producers is not None:
+                producers.close()
+
+    def _rounds(
+        self, total_lanes: int, seeds: Optional[Sequence[int]] = None
+    ):
+        """``_run_batches``' body."""
         seed_list = (
             list(range(total_lanes)) if seeds is None else list(seeds)
         )
@@ -550,15 +979,39 @@ class ContinuousSweepDriver:
             # The resident set's programs: what every segment takes,
             # written in place between a pull and the next dispatch.
             progs = empty_programs(self.cfg, b)
-            self._fill(lane_seed, range(b), progs)
+            # The first few are made on the spot: what one costs is
+            # half of whether the rest are worth producer processes.
+            probe = min(n_live, _PROBE)
+            ns_a_program = self._fill(
+                lane_seed[:probe], range(probe), progs
+            ) / probe
+            # Lowered programs of seed_list[probe:], made by producers
+            # from here on, or of seed_list[next_idx:], made by the host
+            # thread while a segment runs; this call's alone (module doc).
+            obs.stage_count("sweep.producers", 0)
+            stock = None
+            if self._exposed or self._producers is not None:
+                stock = self._start_producers(
+                    seed_list, probe, b, ns_a_program
+                )
+            if probe < n_live:
+                self._fill(
+                    lane_seed[probe:n_live], range(probe, n_live), progs,
+                    stock,
+                )
+            if stock is None and self.seed_pure:
+                stock = _Stock(self.cfg, b)
+            # Nanoseconds this call's refills spent making programs the
+            # stock had run out of, the device idle meanwhile: the other
+            # half (module doc).
+            exposed_ns = 0
+            if n_live < b:
+                self._fill(lane_seed[n_live:], range(n_live, b), progs)
             with obs.span("sweep.refill"):
                 state = self.init(keys_for(lane_seed))
             steps_run = np.zeros(b, np.int64)
             done_count = 0
             active = np.arange(b) < n_live
-            # Lowered programs of seed_list[next_idx:], made while a
-            # segment ran; this call's alone (module doc).
-            stock = _Stock(self.cfg, b) if self.seed_pure else None
 
             self.last_segment_seconds = 0.0
             self.last_harvest_seconds = 0.0
@@ -594,7 +1047,15 @@ class ContinuousSweepDriver:
                         if sample_pool and self._fifo_rows else None
                     )
                 t_gap = time.perf_counter()
-                if self.seed_pure:
+                if type(stock) is _Stock and exposed_ns >= _WORTH_S * 1e9:
+                    # The making has cost what the forks will: fork,
+                    # while the device runs the segment.
+                    self._exposed = True
+                    exposed_ns = float("-inf")      # asked once
+                    stock = self._start_producers(
+                        seed_list, next_idx, b, ns_a_program, stock
+                    ) or stock
+                if type(stock) is _Stock:
                     self._make_ahead(
                         state.status, seed_list, next_idx, n_active, stock
                     )
@@ -731,9 +1192,11 @@ class ContinuousSweepDriver:
                         ]
                         next_idx += len(refill_lanes)
                         # Ascending, as the loop below hands the seeds out.
-                        self._fill(
+                        spent = self._fill(
                             fresh_seeds, sorted(refill_lanes), progs, stock
                         )
+                        if type(stock) is _Stock:
+                            exposed_ns += spent
                         with obs.span("sweep.refill"):
                             mask = np.zeros(b, bool)
                             full_seeds = []

@@ -274,7 +274,9 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # sweep.live_step_share and sweep.fault_op_share (PR 27),
     # sweep.prefetch_share (PR 28), sweep.row_lowered_share (PR 30),
     # sweep.quiesced_share and sweep.pool_peak_share (PR 31),
-    # sweep.outbox_fill_share (PR 33).
+    # sweep.outbox_fill_share (PR 33), sweep.produced_share and
+    # sweep.starve_share (PR 42: ``sweep.producers`` says the program has
+    # producers at all; 24 programs are worth none).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -283,6 +285,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
         "dpor.candidates", "dpor.fresh", "dpor.materialized",
         "sweep.lane_steps", "sweep.live_lane_steps",
         "sweep.programs", "sweep.prefetched", "sweep.row_lowered",
+        "sweep.produced", "sweep.producers",
         "sweep.retired", "sweep.quiesced", "sweep.unfinished",
         "sweep.pool_peak_rows", "sweep.pool_rows",
         "sweep.rows_inserted", "sweep.outbox_rows",
@@ -294,6 +297,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # one program a schedule put in a lane; the prime fill's 8 never ahead
     assert counts["sweep.programs"] == 24
     assert 0 <= counts["sweep.prefetched"] <= 24 - 8
+    assert counts["sweep.produced"] == counts["sweep.producers"] == 0
     # the sweeper's generator is the fuzzer: every program from its rows
     assert counts["sweep.row_lowered"] == 24
     # 24 programs of the sweeper's: every actor started once in each
