@@ -32,9 +32,8 @@ Extra keys reported for the record:
     synchronous scratch loop on the config-2 raft fixture (frontier
     rounds/sec + speedup; explored_match / frontier_match /
     interleavings_match pin that the async pipeline explores the EXACT
-    same schedule space). Also measures the vectorized vs legacy-Python
-    HOST path with async off (host_path.speedup — the unhidden win) and
-    the host-vs-device wall split (host_share target < 25% async-on).
+    same schedule space). Also measures the host-vs-device wall split
+    (host_share target < 25% async-on).
   - config9: redundancy-ratio A/B — sleep-set + race-reversal DPOR
     (wakeup-sequence guides, device-encoded sleep rows, Mazurkiewicz
     class dedup) vs the observe-only baseline on the config-8 deep
@@ -1051,12 +1050,9 @@ def bench_config8(jax):
     fork_kernel = make_dpor_kernel(app, cfg, start_state=True)
 
     def run(variant):
-        # 'legacy'  — per-lane Python host path, async off (the unhidden
-        #             host-path baseline);
-        # 'sync'    — vectorized host path, async off (the win must
-        #             exist UNHIDDEN, not just under the overlap);
-        # 'async'   — vectorized + double-buffered rounds + prefix
-        #             forking with prescribed-resume trunks.
+        # 'sync'    — async off;
+        # 'async'   — double-buffered rounds + prefix forking with
+        #             prescribed-resume trunks.
         if variant == "async":
             # DEMI_BENCH_CONFIG8_MIN_GROUP overrides the platform fork
             # gate (CPU default: half a batch — which zeroes the fork
@@ -1074,7 +1070,6 @@ def bench_config8(jax):
             dpor = DeviceDPOR(
                 app, cfg, program, batch_size=batch,
                 prefix_fork=False, double_buffer=False, kernel=kernel,
-                host_path="legacy" if variant == "legacy" else "vectorized",
                 sleep_sets=False,
             )
         dpor.seed(presc)
@@ -1090,13 +1085,13 @@ def bench_config8(jax):
 
     run("sync")  # warm-up rep: compilation + trunk-cache steady state
     run("async")
-    times = {"legacy": [], "sync": [], "async": []}
+    times = {"sync": [], "async": []}
     dpors = {}
     measured = 0
     for _ in range(reps):
         # Interleaved reps + medians (the config-7 rule: machine drift
         # must land on every variant equally).
-        for variant in ("legacy", "sync", "async"):
+        for variant in ("sync", "async"):
             d, m, secs = run(variant)
             times[variant].append(secs)
             dpors[variant] = d
@@ -1107,7 +1102,7 @@ def bench_config8(jax):
     def median(xs):
         return sorted(xs)[len(xs) // 2]
 
-    s_dpor, a_dpor, l_dpor = dpors["sync"], dpors["async"], dpors["legacy"]
+    s_dpor, a_dpor = dpors["sync"], dpors["async"]
 
     def sibling_clustering(dpor, rounds_to_plan=3):
         # The dpor.prefix_group_size shift, measured directly: plan the
@@ -1140,10 +1135,7 @@ def bench_config8(jax):
 
     sync_secs = median(times["sync"])
     async_secs = median(times["async"])
-    legacy_secs = median(times["legacy"])
     fork = a_dpor._forker.stats_view()
-    s_share = s_dpor.host_share
-    l_share = l_dpor.host_share
     # Async-on host share: the double-buffered loop never blocks, so its
     # own wall-minus-blocked split degenerates on CPU (overlapped device
     # compute steals the same cores the host segment is timed on). The
@@ -1187,46 +1179,6 @@ def bench_config8(jax):
         "interleavings_match": s_dpor.interleavings == a_dpor.interleavings,
         "explored": len(s_dpor.explored),
         "frontier": len(s_dpor.frontier),
-        # Vectorized-vs-Python host path, async OFF on both sides: the
-        # win must exist unhidden (not just buried under the double
-        # buffer's overlap), and the explored space must be identical.
-        # Both variants launch bit-identical kernels on identical data
-        # (match pins it), so the device half of their wall time is the
-        # SAME computation; "speedup" therefore measures the half the
-        # variants actually differ in — host rounds/sec = rounds over
-        # measured host-seconds — next to the Amdahl-capped wall ratio.
-        "host_path": {
-            "legacy_seconds": round(legacy_secs, 3),
-            "vectorized_seconds": round(sync_secs, 3),
-            "wall_speedup": (
-                round(legacy_secs / sync_secs, 2) if sync_secs else None
-            ),
-            "legacy_host_seconds": round(l_dpor.host_seconds, 3),
-            "vectorized_host_seconds": round(s_dpor.host_seconds, 3),
-            "speedup": (
-                round(l_dpor.host_seconds / s_dpor.host_seconds, 2)
-                if s_dpor.host_seconds else None
-            ),
-            "legacy_host_rounds_per_sec": (
-                round(rounds / l_dpor.host_seconds, 2)
-                if l_dpor.host_seconds else None
-            ),
-            "vectorized_host_rounds_per_sec": (
-                round(rounds / s_dpor.host_seconds, 2)
-                if s_dpor.host_seconds else None
-            ),
-            "match": (
-                l_dpor.explored == s_dpor.explored
-                and l_dpor.frontier == s_dpor.frontier
-                and l_dpor.interleavings == s_dpor.interleavings
-            ),
-            "legacy_host_share": (
-                round(l_share, 3) if l_share is not None else None
-            ),
-            "vectorized_host_share": (
-                round(s_share, 3) if s_share is not None else None
-            ),
-        },
         # Host-vs-device wall split with the full async stack on — the
         # acceptance target is host share < 25% on this fixture.
         "host_share": round(a_share, 3) if a_share is not None else None,

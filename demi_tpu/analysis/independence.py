@@ -137,27 +137,7 @@ class StaticIndependence:
             self._matrix = np.ascontiguousarray(mat)
         return self._matrix
 
-    # -- per-pair predicates (legacy / host paths) ------------------------
-    def pair_pruned_kind(
-        self, row_i, row_j, rec_width: int
-    ) -> Optional[str]:
-        """'fungible' / 'commute' / None for one device-record racing
-        pair — the scalar twin of the vectorized masks in
-        native/analysis.py (fungible checked first; order is part of the
-        counter contract)."""
-        w = rec_width
-        if self.fungible and _rows_fungible(row_i, row_j, w):
-            return "fungible"
-        mat = self.device_matrix()
-        if mat is not None:
-            m = len(mat)
-            a, b = int(row_i[3]), int(row_j[3])
-            ia = a if 0 <= a < m - 1 else m - 1
-            ib = b if 0 <= b < m - 1 else m - 1
-            if mat[ia, ib]:
-                return "commute"
-        return None
-
+    # -- per-pair predicate (host tier) -----------------------------------
     def host_commutes_kind(self, ev_i, ev_j) -> Optional[str]:
         """'fungible' / 'commute' / None for a host-tier DporEvent pair
         (same receiver by construction of the racing scan)."""

@@ -152,14 +152,24 @@ def _device_fields() -> dict:
     return device_fields()
 
 
-def _sweep_driver(app, cfg, gen):
+def _sweep_driver(app, cfg, gen, prefix_fork=None):
     """The sweep verb's driver: lane-sharded over every local device
     when this process has more than one (parallel/mesh.local_lane_mesh)."""
     from .parallel.mesh import local_lane_mesh
     from .parallel.sweep import SweepDriver
 
     mesh = local_lane_mesh()
-    return SweepDriver(app, cfg, gen, mesh=mesh, use_mesh=mesh is not None)
+    return SweepDriver(
+        app, cfg, gen, mesh=mesh, use_mesh=mesh is not None,
+        prefix_fork=prefix_fork,
+    )
+
+
+def _flag_or_unset(args, name: str):
+    """A store_true flag as a constructor's switch: True when given,
+    else None, which leaves the switch to the constructor's own default
+    (a verb tells what it builds; it writes nothing into os.environ)."""
+    return True if getattr(args, name, False) else None
 
 
 def _autotune_requested(args) -> bool:
@@ -506,8 +516,7 @@ def _dpor_checkpoint_run(args, app, cfg) -> int:
     # On a FRESH run the flags resolve as usual (flag wins, else env);
     # a RESUMED run pins the RESOLVED booleans recorded at save time
     # (below) so the checkpoint restores regardless of the new
-    # environment's DEMI_SLEEP_SETS/DEMI_STATIC_PRUNE — same contract
-    # as host_path.
+    # environment's DEMI_SLEEP_SETS/DEMI_STATIC_PRUNE.
     from .parallel.mesh import local_lane_mesh
 
     dpor = DeviceDPOR(
@@ -528,11 +537,8 @@ def _dpor_checkpoint_run(args, app, cfg) -> int:
         # --async-min on non-CPU platforms) — same reason bench
         # config 10's loop pins it off.
         double_buffer=False,
-        # A resumed run pins the RESOLVED host path recorded at save
-        # time (below): the legacy path never maintains the digest
-        # dedup set, so crossing paths over a resume would re-admit
-        # explored work (the workload discriminator refuses it too).
-        host_path=getattr(args, "host_path", None),
+        prefix_fork=_flag_or_unset(args, "prefix_fork"),
+        host_shards=getattr(args, "host_shards", 0) or None,
     )
     autotune_on = (
         bool(getattr(args, "autotune", False))
@@ -578,7 +584,6 @@ def _dpor_checkpoint_run(args, app, cfg) -> int:
                     # RESOLVED values (flag-or-env at save time), so a
                     # resume in a fresh environment reconstructs the
                     # identical explorer shape.
-                    "host_path": dpor.host_path,
                     "sleep_sets": dpor.sleep is not None,
                     "static_prune": dpor.static_independence is not None,
                     "autotune": dpor.tuner is not None,
@@ -676,7 +681,9 @@ def _sweep_checkpoint_run(args, app, cfg, fuzzer) -> int:
         )
     store = CheckpointStore(args.checkpoint_dir)
     gen = lambda s: fuzzer.generate_fuzz_test(seed=args.seed + s)  # noqa: E731
-    driver = _sweep_driver(app, cfg, gen)
+    driver = _sweep_driver(
+        app, cfg, gen, prefix_fork=_flag_or_unset(args, "prefix_fork")
+    )
     chunk = min(args.batch, getattr(args, "chunk", None) or args.batch)
     state = {
         "seeds_done": 0, "chunks": 0, "violations": 0, "codes": {},
@@ -1468,8 +1475,7 @@ def cmd_sweep(args) -> int:
         return 0
 
     _strict_io_begin(args)
-    if getattr(args, "prefix_fork", False):
-        os.environ["DEMI_PREFIX_FORK"] = "1"
+    prefix_fork = _flag_or_unset(args, "prefix_fork")
     from .device import DeviceConfig
     from .parallel.sweep import SweepDriver
 
@@ -1517,7 +1523,8 @@ def cmd_sweep(args) -> int:
         )
         chunk = min(args.batch, int(decision.params.get("chunk", chunk)))
         driver = SweepDriver(
-            app, cfg, gen, variant=decision.params.get("variant")
+            app, cfg, gen, variant=decision.params.get("variant"),
+            prefix_fork=prefix_fork,
         )
         driver.violation_hook = note_violations
         controller = ExplorationController(fuzzer)
@@ -1538,7 +1545,7 @@ def cmd_sweep(args) -> int:
             },
         }
     else:
-        driver = _sweep_driver(app, cfg, gen)
+        driver = _sweep_driver(app, cfg, gen, prefix_fork=prefix_fork)
         driver.violation_hook = note_violations
         # Default: lane-compacted continuous sweep (finished lanes are
         # harvested and refilled at segment boundaries). --sweep-mode
@@ -1588,18 +1595,8 @@ def cmd_dpor(args) -> int:
     """Systematic batched DPOR search (BASELINE config 2 shape)."""
     _obs_begin(args)
     _strict_io_begin(args)
-    if getattr(args, "host_shards", 0):
-        # DeviceDPOROracle builds its DeviceDPOR internally; the env var
-        # is the documented channel (DEMI_HOST_SHARDS) and the flag just
-        # sets it for this process.
-        os.environ["DEMI_HOST_SHARDS"] = str(args.host_shards)
-    if getattr(args, "prefix_fork", False):
-        os.environ["DEMI_PREFIX_FORK"] = "1"
-    if getattr(args, "async_min", False):
-        # DeviceDPOROracle reads DEMI_ASYNC_MIN for the frontier's
-        # double-buffered in-flight rounds (platform-gated on CPU — see
-        # tune.calibrate_dpor_inflight) and the test_window surface.
-        os.environ["DEMI_ASYNC_MIN"] = "1"
+    prefix_fork = _flag_or_unset(args, "prefix_fork")
+    host_shards = getattr(args, "host_shards", 0) or None
     from .device import DeviceConfig
     from .device.dpor_sweep import FIFO_REFUSAL, DeviceDPOROracle
 
@@ -1630,7 +1627,8 @@ def cmd_dpor(args) -> int:
             app, cfg, batch=args.batch,
             measure=(
                 make_dpor_inflight_measure(
-                    app, cfg, program, batch=args.batch
+                    app, cfg, program, batch=args.batch,
+                    prefix_fork=prefix_fork,
                 )
                 if platform == "cpu"
                 else None
@@ -1638,38 +1636,36 @@ def cmd_dpor(args) -> int:
         )
         double_buffer = inflight_decision.enabled
     host_shard_decision = None
-    if (
-        autotune
-        and not getattr(args, "host_shards", 0)
-        and not os.environ.get("DEMI_HOST_SHARDS")
-    ):
+    if autotune and host_shards is None:
         # Measured host-shard axis: how many digest-range shards the
         # admission pipeline fans out over (bit-identical at any count,
         # so the only question is rounds/sec). A cache hit costs no
-        # measurements; the decision reaches DeviceDPOROracle through
-        # the same env channel as the explicit flag.
+        # measurements; the decision reaches DeviceDPOROracle as the
+        # explicit flag does.
         from .tune import calibrate_host_shards, make_host_shard_measure
 
         host_shard_decision = calibrate_host_shards(
             app, cfg, batch=args.batch,
             measure=make_host_shard_measure(
-                app, cfg, program, batch=args.batch
+                app, cfg, program, batch=args.batch,
+                prefix_fork=prefix_fork,
             ),
         )
-        if host_shard_decision.shards > 1:
-            os.environ["DEMI_HOST_SHARDS"] = str(host_shard_decision.shards)
+        host_shards = host_shard_decision.shards
     from .parallel.mesh import local_lane_mesh
 
     oracle = DeviceDPOROracle(
         app, cfg, config, batch_size=args.batch, max_rounds=args.rounds,
         autotune=autotune, double_buffer=double_buffer,
+        prefix_fork=prefix_fork,
+        # The oracle turns it into the frontier's double-buffered
+        # in-flight rounds (off on a CPU — see
+        # tune.calibrate_dpor_inflight) and the test_window surface.
+        async_min=_flag_or_unset(args, "async_min"),
+        host_shards=host_shards,
         mesh=local_lane_mesh(args.batch),
-        static_independence=(
-            True if getattr(args, "static_prune", False) else None
-        ),
-        sleep_sets=(
-            True if getattr(args, "sleep_sets", False) else None
-        ),
+        static_independence=_flag_or_unset(args, "static_prune"),
+        sleep_sets=_flag_or_unset(args, "sleep_sets"),
     )
     _profile_begin(args)
     with obs.span("cli.dpor", app=args.app):
@@ -2556,9 +2552,8 @@ def main(argv: Optional[list] = None) -> int:
         help="partition the host-half admission pipeline (scan, "
              "filters, digest dedup) into N digest-range shards run "
              "concurrently, with a canonical merge that keeps results "
-             "bit-identical to 1 shard; DEMI_HOST_SHARDS=N does the "
-             "same; under --autotune the measured host_shards axis "
-             "decides; default 1",
+             "bit-identical to 1 shard; under --autotune the measured "
+             "host_shards axis decides; default 1",
     )
     p.add_argument(
         "--profile-trace", default=None, dest="profile_trace",
@@ -2633,8 +2628,7 @@ def main(argv: Optional[list] = None) -> int:
         "--host-shards", type=int, default=0, dest="host_shards",
         metavar="N",
         help="digest-range shards for the coordinator's host-half "
-             "admission pipeline (bit-identical at any N; "
-             "DEMI_HOST_SHARDS=N does the same; default 1)",
+             "admission pipeline (bit-identical at any N; default 1)",
     )
     p.add_argument(
         "--lease-timeout", type=float, default=120.0, dest="lease_timeout",

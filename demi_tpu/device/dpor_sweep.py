@@ -11,15 +11,13 @@ happens-before forest and the next round's racing pairs with no
 re-execution. SURVEY §7.2 step 7: the racing-pair scan is data-parallel
 bit math; only the frontier priority queue stays host-side.
 
-Host path: the default ``host_path='vectorized'`` derives a whole
-round's prescriptions in ONE batch-native call
-(``native.racing_prescriptions_batch`` — C++ when a compiler exists,
-NumPy otherwise) and dedups on vectorized content digests, so the
-per-round host share stays small instead of merely hiding under the
-double-buffered overlap; ``'legacy'`` keeps the per-lane scan as the
-parity baseline. Both are bit-identical (tests/test_host_path.py), and
-every DeviceDPOR tracks its ``host_seconds``/``device_seconds`` split
-(the ``dpor.host_share`` gauge, bench configs 2/8).
+Host half: a whole round's prescriptions are derived in ONE
+batch-native call (``native.racing_prescriptions_batch`` — C++ when a
+compiler exists, its NumPy twin otherwise) and deduped on content
+digests; the tests hold both, and a whole search, to a per-lane
+assembly they keep (``_legacy_prescriptions``). Every DeviceDPOR tracks
+its ``host_seconds``/``device_seconds`` split (the ``dpor.host_share``
+gauge, bench configs 2/8).
 """
 
 from __future__ import annotations
@@ -491,88 +489,8 @@ def build_dpor_kernel(
 
 
 # ---------------------------------------------------------------------------
-# Host-side racing analysis over parent-tracked records
+# Host side: the search's switches, its oracle and its driver
 # ---------------------------------------------------------------------------
-
-def racing_prescriptions(
-    records: np.ndarray, trace_len: int, rec_width: int,
-    independence=None,
-) -> List[Tuple[Tuple[int, ...], ...]]:
-    """From one lane's parent-tracked trace, derive backtrack prescriptions:
-    for each racing pair (i, j) — same receiver, concurrent (no
-    happens-before path), j's message already created before i — the
-    prescription is the delivery records before i plus j's record.
-
-    This is the LEGACY per-lane surface (one scan call per lane, one
-    Python tuple loop per racing pair), kept for the ``host_path='legacy'``
-    parity baseline and the randomized parity suite
-    (tests/test_host_path.py). The frontier hot path uses
-    ``native.racing_prescriptions_batch`` — one call per ROUND — instead;
-    see ``DeviceDPOR._process_round``."""
-    out, _positions = racing_prescriptions_meta(
-        records, trace_len, rec_width, independence=independence
-    )
-    return [presc for presc, _branch, _flip_ord in out]
-
-
-def racing_prescriptions_meta(
-    records: np.ndarray, trace_len: int, rec_width: int,
-    independence=None,
-) -> Tuple[List[Tuple[Tuple[Tuple[int, ...], ...], int, int]], np.ndarray]:
-    """``racing_prescriptions`` plus the derivation metadata the sleep-
-    set admission needs: returns ``([(prescription, branch_ordinal,
-    flip_ordinal)], positions)`` where ``branch_ordinal`` is the count
-    of deliveries strictly before the race's first delivery
-    (== len(prescription) - 1), ``flip_ordinal`` the flipped delivery's
-    ordinal in the lane (the wakeup-sequence guide drops it from the
-    suffix), and ``positions`` the lane's delivery trace positions
-    (prescription prefix row t sits at ``positions[t]`` — the
-    own-position input of the canonical class key)."""
-    from ..native import racing_pair_scan
-
-    # Slice to rec_width: the scan derives the parent column from the last
-    # column, so trailing padding must never reach it.
-    recs = records[:trace_len, :rec_width]
-    pairs = racing_pair_scan(recs)
-    is_delivery = np.isin(recs[:, 0], (REC_DELIVERY, REC_TIMER))
-    positions = np.nonzero(is_delivery)[0]
-    if len(pairs) == 0:
-        return [], positions
-    # Record tuples materialized once; prefix for branch index i is the
-    # delivery tuples strictly before i.
-    tuples = {int(p): tuple(int(x) for x in recs[p]) for p in positions}
-    ordered = [int(p) for p in positions]
-    out: List[Tuple[Tuple[Tuple[int, ...], ...], int]] = []
-    pruned_fungible = pruned_commute = 0
-    for i, j in pairs:
-        if independence is not None:
-            # Same per-pair predicate + placement as the batch paths
-            # (analysis.StaticIndependence; fungible checked first), so
-            # legacy-vs-vectorized stays bit-identical with pruning on.
-            kind = independence.pair_pruned_kind(recs[i], recs[int(j)],
-                                                rec_width)
-            if kind is not None:
-                if kind == "fungible":
-                    pruned_fungible += 1
-                else:
-                    pruned_commute += 1
-                if independence.audit:
-                    k = np.searchsorted(positions, i)
-                    independence.note_pruned_prescription(
-                        tuple([tuples[p] for p in ordered[:k]]
-                              + [tuples[int(j)]])
-                    )
-                continue
-        k = int(np.searchsorted(positions, i))
-        jj = int(np.searchsorted(positions, int(j)))
-        prefix = [tuples[p] for p in ordered[:k]]
-        prefix.append(tuples[int(j)])
-        out.append((tuple(prefix), k, jj))
-    if independence is not None:
-        independence.note_pruned(pruned_fungible, pruned_commute,
-                                 tier="device")
-    return out, positions
-
 
 def _resolve_static_independence(app: DSLApp, explicit=None):
     """Resolve the static-pruning switch into a relation (or None).
@@ -618,35 +536,6 @@ def _resolve_sleep_sets(app: DSLApp, explicit=None, independence=None):
     return None
 
 
-def _resolve_host_path(explicit: Optional[str] = None) -> str:
-    """Resolve the frontier host-path switch: 'vectorized' (default —
-    batch-native racing analysis + digest-keyed dedup) or 'legacy' (the
-    per-lane scan + per-pair Python tuple loop, kept as the parity
-    baseline). An explicit constructor arg wins; ``DEMI_HOST_PATH``
-    otherwise (values ``legacy``/``python``/``py`` select the old path)."""
-    if explicit is None:
-        env = os.environ.get("DEMI_HOST_PATH", "").strip().lower()
-        explicit = "legacy" if env in ("legacy", "python", "py") else "vectorized"
-    if explicit not in ("vectorized", "legacy"):
-        raise ValueError(
-            f"host_path must be 'vectorized' or 'legacy', got {explicit!r}"
-        )
-    return explicit
-
-
-def _resolve_host_shards(explicit: Optional[int] = None) -> int:
-    """Resolve the admission shard count without importing the fleet
-    package on every construction: explicit arg wins, then
-    ``DEMI_HOST_SHARDS``, default 1 (the sequential pipeline — zero
-    sharded machinery is built at 1)."""
-    if explicit is not None:
-        return max(1, int(explicit))
-    try:
-        return max(1, int(os.environ.get("DEMI_HOST_SHARDS", "1") or 1))
-    except ValueError:
-        return 1
-
-
 class DeviceDPOROracle:
     """TestOracle over DeviceDPOR: systematic batched search for a target
     violation on a given external program; positives lift to full host
@@ -674,7 +563,10 @@ class DeviceDPOROracle:
     commits only when its resolver is consulted, so an unconsulted probe
     leaves its resumable frontier exactly as the sequential path would.
     ``double_buffer`` threads through to each instance's in-flight round
-    dispatch (see DeviceDPOR)."""
+    dispatch (see DeviceDPOR); left None it follows ``async_min`` as this
+    oracle resolved it (``_resolve_double_buffer``), so an oracle told
+    ``async_min=True`` needs no variable set for its instances to agree.
+    ``host_shards`` is each instance's admission shard count."""
 
     def __init__(
         self,
@@ -688,10 +580,10 @@ class DeviceDPOROracle:
         prefix_fork: Optional[bool] = None,
         async_min: Optional[bool] = None,
         double_buffer: Optional[bool] = None,
-        host_path: Optional[str] = None,
         static_independence=None,
         sleep_sets=None,
         mesh=None,
+        host_shards: Optional[int] = None,
     ):
         from ..minimization.pipeline import async_min_enabled
         from .fork import prefix_fork_enabled
@@ -705,7 +597,7 @@ class DeviceDPOROracle:
         self.last_interleavings = 0
         self.initial_trace = initial_trace
         self.prefix_fork = prefix_fork
-        self.host_path = host_path
+        self.host_shards = host_shards
         # One static may-commute relation shared by every resumable
         # instance (the relation is per-app; its prune ledger aggregates
         # across instances — what static_stats reports).
@@ -756,7 +648,9 @@ class DeviceDPOROracle:
         # per-subsequence), fed by the per-round redundant/pruned counts.
         self.autotune = autotune
         self._async = async_min_enabled(async_min)
-        self._double_buffer = double_buffer
+        self._double_buffer = _resolve_double_buffer(
+            double_buffer, self._async
+        )
         # Shared kernels: under a mesh every instance's rounds shard over
         # the same lane-sharded twin.
         self._kernel = build_dpor_kernel(
@@ -888,7 +782,7 @@ class DeviceDPOROracle:
                 double_buffer=self._double_buffer,
                 kernel=self._kernel,
                 fork_kernel=self._fork_kernel,
-                host_path=self.host_path,
+                host_shards=self.host_shards,
                 static_independence=(
                     self.static_independence
                     if self.static_independence is not None
@@ -1049,19 +943,23 @@ def max_distance_union(a: Optional[int], b: Optional[int]) -> Optional[int]:
     return max(a, b)
 
 
-def _resolve_double_buffer(explicit: Optional[bool] = None) -> bool:
+def _resolve_double_buffer(
+    explicit: Optional[bool] = None, async_min: Optional[bool] = None
+) -> bool:
     """Resolve the in-flight-round switch: an explicit constructor arg
     wins (bench and the calibrated tune axis pass one); otherwise the
-    feature rides the ``DEMI_ASYNC_MIN`` umbrella flag and defaults on
-    only where speculation is free — platforms where host and device are
-    disjoint. On CPU the device lanes run on the host's own cores, so a
-    mispredicted in-flight launch burns real compute; there the tuner
-    (``tune.calibrate_dpor_inflight``) must measure the trade."""
+    feature rides the async-minimization switch — ``async_min`` where the
+    caller was told it (the oracle), else the ``DEMI_ASYNC_MIN`` umbrella
+    flag — and defaults on only where speculation is free: platforms
+    where host and device are disjoint. On CPU the device lanes run on
+    the host's own cores, so a mispredicted in-flight launch burns real
+    compute; there the tuner (``tune.calibrate_dpor_inflight``) must
+    measure the trade."""
     if explicit is not None:
         return bool(explicit)
     from ..minimization.pipeline import async_min_enabled
 
-    if not async_min_enabled(None):
+    if not async_min_enabled(async_min):
         return False
     return jax.devices()[0].platform != "cpu"
 
@@ -1091,7 +989,7 @@ def _dpor_search_state(dpor: "DeviceDPOR") -> tuple:
         dpor.max_distance, dpor.interleavings, dpor.round_batch,
         dict(dpor.async_stats), tuner, set(dpor._explored_digests),
         dpor.host_seconds, dpor.device_seconds,
-        dict(dpor._sleep_rows), set(dpor._suppressed),
+        dict(dpor._sleep_rows),
         set(dpor._suppressed_digests), set(dpor.violation_codes),
         sleep_state, dict(dpor._guides),
     )
@@ -1103,8 +1001,6 @@ def _dpor_restore_state(dpor: "DeviceDPOR", state: tuple) -> None:
     # against it (prefix + last-entry check) and rebuilds when the
     # rollback invalidated it.
     dpor._explored_log.restore(state[0])
-    if dpor._legacy_explored is not None:
-        dpor._legacy_explored = set(dpor._explored_log)
     (
         dpor.frontier, dpor.original, dpor.max_distance,
         dpor.interleavings, dpor.round_batch, async_stats, tuner,
@@ -1115,9 +1011,8 @@ def _dpor_restore_state(dpor: "DeviceDPOR", state: tuple) -> None:
         state[9], state[10],
     )
     dpor._sleep_rows = dict(state[11])
-    dpor._suppressed = set(state[12])
-    dpor._suppressed_digests = set(state[13])
-    dpor.violation_codes = set(state[14])
+    dpor._suppressed_digests = set(state[12])
+    dpor.violation_codes = set(state[13])
     if getattr(dpor, "_sharder", None) is not None:
         # Snapshots hold the digest sets FLAT; a sharded instance
         # re-partitions them by digest range on restore (also how an
@@ -1130,13 +1025,13 @@ def _dpor_restore_state(dpor: "DeviceDPOR", state: tuple) -> None:
         dpor._suppressed_digests = DigestShards(
             dpor._host_shards, dpor._suppressed_digests
         )
-    dpor._guides = dict(state[16])
-    if state[15] is not None and dpor.sleep is not None:
-        dpor.sleep.classes = set(state[15][0])
+    dpor._guides = dict(state[15])
+    if state[14] is not None and dpor.sleep is not None:
+        dpor.sleep.classes = set(state[14][0])
         dpor.sleep._node_flips = {
-            k: list(v) for k, v in state[15][1].items()
+            k: list(v) for k, v in state[14][1].items()
         }
-        dpor.sleep.pruned_total = dict(state[15][2])
+        dpor.sleep.pruned_total = dict(state[14][2])
     if tuner is not None and dpor.tuner is not None:
         (
             dpor.tuner.rounds, dpor.tuner.round_batch,
@@ -1213,7 +1108,6 @@ class DeviceDPOR:
         double_buffer: Optional[bool] = None,
         kernel=None,
         fork_kernel=None,
-        host_path: Optional[str] = None,
         static_independence=None,
         sleep_sets=None,
         key_mode: Optional[str] = None,
@@ -1372,11 +1266,6 @@ class DeviceDPOR:
             "inflight_hits": 0,
             "inflight_waste": 0,
         }
-        # Frontier host path: 'vectorized' (batch-native racing analysis,
-        # digest-keyed dedup) or 'legacy' (per-lane scan + per-pair tuple
-        # loop). Both produce bit-identical explored/frontier/results —
-        # pinned by tests/test_host_path.py and bench config 8.
-        self.host_path = _resolve_host_path(host_path)
         # Host-share accounting (always on — two perf_counter reads per
         # round): wall time blocked harvesting device results vs
         # everything else in the frontier loop. The dpor.host_share gauge
@@ -1400,11 +1289,6 @@ class DeviceDPOR:
         # membership, for the round's candidates (digested in the scan)
         # and for a tuple from outside (``p in explored`` digests it).
         self._explored_digests: Set[bytes] = set()
-        # The legacy host path dedups on tuples by definition, so it
-        # alone keeps a tuple set beside the log.
-        self._legacy_explored: Optional[Set[Tuple]] = (
-            set() if self.host_path == "legacy" else None
-        )
         self.admit_tuples([tuple()])  # the root, log index 0
         self.frontier = [0]
         # Adaptive (n_presc, n_rows) buffer hint for the batch scan.
@@ -1417,7 +1301,7 @@ class DeviceDPOR:
 
         self._scan_buffers = ScanBuffers()
         # Digest-range-sharded admission (fleet/shard.py; host_shards >
-        # 1 via the constructor, --host-shards, or DEMI_HOST_SHARDS):
+        # 1 via the constructor, which --host-shards reaches):
         # the round's scan/filter/dedup pipeline runs as N concurrent
         # digest-range shards, then a serial canonical merge
         # (_admit_stream) applies fresh admissions in the sequential
@@ -1428,7 +1312,9 @@ class DeviceDPOR:
         # disjoint slice. Composes with sleep sets, static pruning,
         # prefix-fork, and double-buffering: sharding only touches how
         # one harvested round's candidates are scanned and deduped.
-        self._host_shards = _resolve_host_shards(host_shards)
+        from ..fleet.shard import resolve_host_shards
+
+        self._host_shards = resolve_host_shards(host_shards)
         self._sharder = None
         if self._host_shards > 1:
             from ..fleet.shard import DigestShards, ShardedAdmission
@@ -1451,10 +1337,9 @@ class DeviceDPOR:
         self.interleavings = 0
         # Sleep-set side state: per-prescription sleep rows (frontier
         # entries stay plain tuples — selection, dedup, and every parity
-        # surface are untouched), plus the class-suppressed sets kept in
-        # the same tuple/digest lockstep as explored/_explored_digests.
+        # surface are untouched), plus the class-suppressed digests, kept
+        # as the explored digests are.
         self._sleep_rows: Dict[Tuple, Tuple[Tuple[int, ...], ...]] = {}
-        self._suppressed: Set[Tuple] = set()
         self._suppressed_digests: Set[bytes] = set()
         if self._sharder is not None:
             from ..fleet.shard import DigestShards
@@ -1543,8 +1428,8 @@ class DeviceDPOR:
         self, prescriptions: Sequence[Tuple], keep: bool = True
     ) -> int:
         """Record prescriptions that arrive as tuples of row tuples — a
-        seed, a re-seeded class representative, a restored checkpoint,
-        the legacy host path's — as explored: the one way in for writers
+        seed, a re-seeded class representative, a restored checkpoint —
+        as explored: the one way in for writers
         outside the round's admission. The caller has checked that they
         are new. Returns the first one's log index (they follow on)."""
         from ..native import digest_keys
@@ -1552,8 +1437,6 @@ class DeviceDPOR:
         log = self._explored_log
         first = log.extend_tuples(prescriptions, keep=keep)
         self._explored_digests.update(digest_keys(log.digest[first: log.n]))
-        if self._legacy_explored is not None:
-            self._legacy_explored.update(prescriptions)
         return first
 
     def load_tuples(self, prescriptions: Sequence[Tuple]) -> None:
@@ -1561,8 +1444,6 @@ class DeviceDPOR:
         order (a restored checkpoint's log; the first is the root)."""
         self._explored_log = ExploredLog(self.cfg.rec_width)
         self._explored_digests = set()
-        if self._legacy_explored is not None:
-            self._legacy_explored = set()
         self.admit_tuples(prescriptions, keep=False)
 
     def seed(self, prescription: Tuple[Tuple[int, ...], ...]) -> None:
@@ -2012,14 +1893,11 @@ class DeviceDPOR:
         remainder — so the tuner sees the full frontier size). Returns a
         violating lane's (records, trace_len) or None.
 
-        The default ``host_path='vectorized'`` derives the whole round's
-        prescriptions in ONE batch-native call (packed int32 rows +
-        per-lane offsets — native/trace_analysis.cpp or the NumPy
-        fallback), dedups against the explored set on vectorized content
-        digests, and only materializes Python tuples for the FRESH
-        prescriptions that actually join the frontier. ``'legacy'`` keeps
-        the per-lane scan + per-pair tuple loop; outputs are bit-identical
-        (tests/test_host_path.py)."""
+        The whole round's prescriptions are derived in ONE batch-native
+        call (packed int32 rows + per-lane offsets —
+        native/trace_analysis.cpp or its NumPy twin) and deduped against
+        the explored set on content digests; Python tuples exist only
+        where a mode needs them (``_admit_stream``)."""
         batch = self._list(batch)
         self.interleavings += len(batch)
         with obs.span("dpor.pull"):
@@ -2049,11 +1927,7 @@ class DeviceDPOR:
         # Local fresh/redundant/pruned counts: the tuner's per-round
         # signal, needed whether or not telemetry is on (the obs
         # counters still carry the cross-round totals).
-        if self.host_path != "vectorized":
-            fresh_n, redundant_n, pruned_n = self._derive_legacy(
-                traces, lens, len(batch), frontier, batch=batch, res=res
-            )
-        elif self._sharder is not None:
+        if self._sharder is not None:
             fresh_n, redundant_n, pruned_n = self._derive_sharded(
                 traces, lens, len(batch), frontier, batch=batch, res=res
             )
@@ -2181,12 +2055,10 @@ class DeviceDPOR:
         return batch.lengths() == 0
 
     def _admit(
-        self, presc: Tuple, key: Optional[bytes], frontier: PrescList
+        self, presc: Tuple, key: bytes, frontier: PrescList
     ) -> bool:
         """Distance-gate one non-redundant prescription and mark it
-        explored (shared by the per-candidate paths of both host paths):
-        its digest ``key`` on the vectorized path, the tuple itself on
-        the legacy one (``key=None``). Returns True when it is to join
+        explored under its digest ``key``. Returns True when it is to join
         the frontier; the caller then records the round's admitted
         prescriptions in the log and in ``frontier`` together. The bulk
         path never calls this, so it runs only while this method is the
@@ -2199,10 +2071,7 @@ class DeviceDPOR:
             and arvind_distance(presc, self.original) > self.max_distance
         ):
             return False
-        if key is None:
-            self._legacy_explored.add(presc)
-        else:
-            self._explored_digests.add(key)
+        self._explored_digests.add(key)
         return True
 
     def _sleep_class_check(
@@ -2210,7 +2079,7 @@ class DeviceDPOR:
         lane_presc: Tuple, wake_row, ckey=None,
     ):
         """The class-dedup half of sleep-set admission for ONE fresh
-        candidate (shared by both host paths — parity by construction).
+        candidate.
         Returns ``(verdict, commit)``: verdict 'class' means the
         candidate's Mazurkiewicz class was already scheduled (suppress);
         verdict None means admit-eligible, and ``commit()`` — called
@@ -2295,9 +2164,7 @@ class DeviceDPOR:
         the branch — exact, not approximate: same-receiver deliveries
         always differ in the ``prev`` column (the per-receiver
         program-order chain is strictly increasing), so a full-row
-        match identifies the flipped delivery uniquely. Both host
-        paths use this one rule so their guides are bit-identical by
-        construction."""
+        match identifies the flipped delivery uniquely."""
         if flip_ord is None:
             flip_ord = next(
                 (
@@ -2667,126 +2534,6 @@ class DeviceDPOR:
         if tuples is not None:
             log.keep_tuples(first, tuples)
         frontier.extend(range(first, first + len(fresh)))
-
-    def _derive_legacy(
-        self, traces, lens, n_lanes: int, frontier: PrescList,
-        batch: Optional[PrescList] = None, res=None,
-    ) -> Tuple[int, int, int]:
-        """The pre-vectorization host path — per-lane scans, per-pair
-        tuple assembly, tuple-set membership — kept as the parity
-        baseline (bench config 8's host_path comparison and
-        tests/test_host_path.py pin bit-identical outputs). With sleep
-        sets on, applies the identical per-pair sleep filter (branch
-        beyond the redundant marker, flip asleep at the branch) and
-        class dedup in the same order as the batch path."""
-        from ..analysis.sleep import BIG_ORDINAL, rows_content_equal
-
-        recw = self.cfg.rec_width
-        sleep_ctx = (
-            self._sleep_ctx(batch, res)
-            if batch is not None and res is not None
-            else None
-        )
-        redundant_n = pruned_n = 0
-        sleep_pruned = 0
-        explored = self._legacy_explored
-        fresh: List[Tuple] = []
-        pad_lane = self._pad_lanes(batch)
-        lane_tuples = (
-            batch.tuples()
-            if self.sleep is not None and batch is not None
-            else None
-        )
-        for lane in range(n_lanes):
-            if pad_lane is not None and pad_lane[lane]:
-                # Closed seeded exploration (see pad_exploration): skip
-                # the padding lane's harvest wholesale.
-                continue
-            metas, positions = racing_prescriptions_meta(
-                traces[lane], int(lens[lane]), recw,
-                independence=self.static_independence,
-            )
-            lane_deliv: Optional[List[Tuple[int, ...]]] = None
-            for presc, branch, flip_ord in metas:
-                if (
-                    self.sleep is not None
-                    and self.sleep.prune
-                    and sleep_ctx is not None
-                ):
-                    # Per-pair sleep filter, identically placed to the
-                    # batch scan's (after static, before dedup).
-                    _srows, wake, slept, presc_deliv = sleep_ctx
-                    flip = presc[-1]
-                    asleep = branch > int(slept[lane])
-                    if not asleep and branch >= int(presc_deliv[lane]):
-                        lane_sleep = self._sleep_rows.get(
-                            lane_tuples[lane]
-                            if lane_tuples is not None else tuple(), ()
-                        )
-                        for s, srow in enumerate(lane_sleep):
-                            if int(wake[lane][s]) < branch:
-                                continue
-                            if rows_content_equal(flip, srow, recw):
-                                asleep = True
-                                break
-                    if asleep:
-                        sleep_pruned += 1
-                        if self.sleep.audit:
-                            self.sleep.note_pruned_prescription(presc)
-                        continue
-                if presc in explored:
-                    redundant_n += 1
-                    continue
-                if presc in self._suppressed:
-                    redundant_n += 1
-                    continue
-                commit = None
-                if self.sleep is not None:
-                    wake_row = (
-                        (sleep_ctx[1][lane], sleep_ctx[3][lane])
-                        if sleep_ctx is not None
-                        else None
-                    )
-                    m = len(presc)
-                    verdict, commit = self._sleep_class_check(
-                        presc, np.asarray(presc, np.int32),
-                        list(positions[: m - 1]) + [None], presc[-1],
-                        branch,
-                        lane_tuples[lane]
-                        if lane_tuples is not None else tuple(),
-                        wake_row,
-                    )
-                    if verdict == "class":
-                        self._suppressed.add(presc)
-                        redundant_n += 1
-                        continue
-                if self._admit(presc, None, frontier):
-                    fresh.append(presc)
-                    if self.sleep is not None:
-                        if lane_deliv is None:
-                            recs = traces[lane, : int(lens[lane]), :recw]
-                            lane_deliv = [
-                                tuple(r) for r in recs[positions].tolist()
-                            ]
-                        # flip_ord=None: the one guide rule both host
-                        # paths share (see _make_guide) — the meta's
-                        # exact ordinal resolves to the same row.
-                        guide = self._make_guide(
-                            lane_deliv, branch, presc[-1], None
-                        )
-                        self._guides[presc] = guide
-                        if commit is not None:
-                            commit(guide)
-                    elif commit is not None:
-                        commit()
-                else:
-                    pruned_n += 1
-        if sleep_pruned:
-            self.sleep.note_pruned(sleep=sleep_pruned, tier="device")
-        if fresh:
-            first = self.admit_tuples(fresh)
-            frontier.extend(range(first, first + len(fresh)))
-        return len(fresh), redundant_n, pruned_n
 
     def _note_inflight(self, outcome: str) -> None:
         self.async_stats[f"inflight_{outcome}"] += 1

@@ -479,7 +479,8 @@ class PrefixPlanner:
     recursion level is a lexsort + boundary scan over those hashes — no
     per-trial ``tobytes`` in the loop. ``plan_reference`` keeps the
     original per-chunk-bytes recursion as the parity baseline
-    (tests/test_host_path.py pins group-for-group equality)."""
+    (``test_prefix_planner_vectorized_matches_reference`` pins
+    group-for-group equality)."""
 
     def __init__(self, bucket: int = 8, min_group: int = 2):
         if bucket < 1:

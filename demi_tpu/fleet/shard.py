@@ -46,9 +46,9 @@ every key). The prune-note ledgers (``StaticIndependence`` /
 buffering each shard's notes (``_NoteBuffer``) and replaying them
 serially in slice order after the join.
 
-Knobs: ``DeviceDPOR(host_shards=N)`` / ``demi_tpu dpor --host-shards N``
-/ ``DEMI_HOST_SHARDS=N``; ``tune.calibrate_host_shards`` makes N a
-measured, TuningCache-persisted decision.
+Knobs: ``DeviceDPOR(host_shards=N)`` / ``demi_tpu dpor --host-shards N``;
+``tune.calibrate_host_shards`` makes N a measured, TuningCache-persisted
+decision.
 ``DEMI_HOST_SHARD_SERIALIZE=1`` runs the shard tasks sequentially on
 the calling thread — the bench's *uncontended* busy-seconds convention
 (each shard timed as if it owned its core, the config-13 analog of
@@ -76,14 +76,9 @@ __all__ = [
 
 
 def resolve_host_shards(explicit: Optional[int] = None) -> int:
-    """Admission shard count: explicit argument wins, then
-    ``DEMI_HOST_SHARDS``, default 1 (the plain sequential pipeline)."""
-    if explicit is not None:
-        return max(1, int(explicit))
-    try:
-        return max(1, int(os.environ.get("DEMI_HOST_SHARDS", "1") or 1))
-    except ValueError:
-        return 1
+    """Admission shard count: what the caller says, at least 1; None is
+    1 (the plain sequential pipeline)."""
+    return max(1, int(explicit or 1))
 
 
 def shard_of_key(key: bytes, n: int) -> int:

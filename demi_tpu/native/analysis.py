@@ -298,11 +298,10 @@ def racing_prescriptions_batch(
       pass on the fallback path).
 
     Prescription k is a backtrack point of its lane: the delivery records
-    strictly before the race's first delivery, plus the flipped record —
-    exactly what the per-lane ``racing_prescriptions`` tuple loop used to
-    assemble, lane-major and in identical pair order (pinned by
-    tests/test_host_path.py). One native call (or one NumPy pass) serves
-    the whole round. ``size_hint=(n_presc, n_rows)`` (e.g. the previous
+    strictly before the race's first delivery, plus the flipped record,
+    lane-major and in ``racing_pair_scan``'s pair order (the tests hold
+    it to a per-lane assembly they keep: ``_legacy_prescriptions``).
+    One native call (or one NumPy pass) serves the whole round. ``size_hint=(n_presc, n_rows)`` (e.g. the previous
     round's totals) sizes the output buffers; an overflow retries once
     with exact sizes.
 

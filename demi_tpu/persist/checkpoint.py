@@ -439,10 +439,6 @@ def device_dpor_workload(dpor) -> Dict[str, Any]:
         "key_mode": dpor.key_mode,
         "sleep": dpor.sleep is not None,
         "static": dpor.static_independence is not None,
-        # The legacy host path dedups on the tuple set alone and never
-        # maintains the digest set — restoring its checkpoint into a
-        # vectorized explorer would silently re-admit explored work.
-        "host_path": dpor.host_path,
     }
 
 
@@ -658,7 +654,6 @@ def device_dpor_payload(dpor) -> Dict[str, Any]:
         "sleep_rows_vals": _pack_rows(
             [dpor._sleep_rows[p] for p in sleep_keys]
         ),
-        "suppressed": _pack_rows(sorted(dpor._suppressed)),
         "suppressed_digests": _pack_digests(dpor._suppressed_digests),
         "violation_codes": sorted(dpor.violation_codes),
         "guides_keys": _pack_ints(log_index(p) for p in guide_keys),
@@ -687,7 +682,18 @@ def restore_device_dpor(dpor, payload: Dict[str, Any]) -> None:
     import numpy as np
 
     want = device_dpor_workload(dpor)
-    got = payload.get("workload", {})
+    got = dict(payload.get("workload", {}))
+    # Checkpoints written before PR 43 name the host path that wrote
+    # them. The per-lane 'legacy' one deduped on tuples and never kept
+    # the digest set the search now decides membership by, so its
+    # search cannot continue; 'vectorized' is the path there is.
+    host_path = got.pop("host_path", "vectorized")
+    if host_path != "vectorized":
+        raise CheckpointMismatch(
+            f"checkpoint workload key 'host_path' is {host_path!r}: that "
+            "host path is gone and its checkpoints carry no digest set "
+            "to continue from"
+        )
     if got != want:
         raise CheckpointMismatch(
             f"checkpoint workload {got!r} != this explorer's {want!r}"
@@ -725,7 +731,6 @@ def restore_device_dpor(dpor, payload: Dict[str, Any]) -> None:
             _unpack_rows(payload["sleep_rows_vals"]),
         )
     }
-    dpor._suppressed = set(_unpack_rows(payload["suppressed"]))
     dpor._suppressed_digests = _unpack_digests(
         payload["suppressed_digests"]
     )

@@ -504,6 +504,7 @@ class InflightDecision:
 def make_dpor_inflight_measure(
     app, device_cfg, program, *, batch: int = 16, rounds: int = 3,
     reps: int = 2, target_code: Optional[int] = None,
+    prefix_fork: Optional[bool] = None,
 ):
     """Real measurement for one in-flight candidate: a fresh DeviceDPOR
     per rep (exploration is stateful — reps must start from the same
@@ -511,17 +512,19 @@ def make_dpor_inflight_measure(
     frontier), then ``rounds`` timed frontier rounds; returns median
     interleavings/sec. Kernels are shared across points/reps so the walk
     compiles once. The winning run's in-flight economy lands in
-    ``measure.signals``."""
+    ``measure.signals``. ``prefix_fork`` is the search's own switch (the
+    ``dpor`` verb's flag), so the measured explorers are built as the
+    search's are."""
     from ..device.dpor_sweep import DeviceDPOR, make_dpor_kernel
     from ..device.fork import prefix_fork_enabled
 
     kernel = make_dpor_kernel(app, device_cfg)
-    # Under DEMI_PREFIX_FORK each fresh DeviceDPOR would otherwise jit
-    # its own identical start_state kernel — (reps+1) x 2 candidates of
+    # With prefix-fork on each fresh DeviceDPOR would otherwise jit its
+    # own identical start_state kernel — (reps+1) x 2 candidates of
     # redundant compiles polluting the timed rounds.
     fork_kernel = (
         make_dpor_kernel(app, device_cfg, start_state=True)
-        if prefix_fork_enabled(None)
+        if prefix_fork_enabled(prefix_fork)
         else None
     )
 
@@ -533,6 +536,7 @@ def make_dpor_inflight_measure(
             dpor = DeviceDPOR(
                 app, device_cfg, program, batch_size=batch,
                 double_buffer=on, kernel=kernel, fork_kernel=fork_kernel,
+                prefix_fork=prefix_fork,
                 # The shared kernels are plain ones; pin sleep mode off
                 # so an ambient DEMI_SLEEP_SETS cannot mismatch them.
                 sleep_sets=False,
@@ -674,6 +678,7 @@ class HostShardDecision:
 def make_host_shard_measure(
     app, device_cfg, program, *, batch: int = 16, rounds: int = 3,
     reps: int = 2, target_code: Optional[int] = None,
+    prefix_fork: Optional[bool] = None,
 ):
     """Real measurement for one host-shard candidate: a fresh DeviceDPOR
     per rep (exploration is stateful), one warm-up round, then
@@ -681,7 +686,8 @@ def make_host_shard_measure(
     median host-half rounds/sec. Device time is excluded — the axis only
     moves the admission pipeline, so ranking on host seconds keeps the
     decision stable across device-speed noise. Kernels are shared across
-    points/reps so the walk compiles once."""
+    points/reps so the walk compiles once. ``prefix_fork`` is the
+    search's own switch (the ``dpor`` verb's flag)."""
     from ..device.dpor_sweep import DeviceDPOR, make_dpor_kernel
     from ..fleet.shard import HostHalfTimer
 
@@ -694,6 +700,7 @@ def make_host_shard_measure(
             dpor = DeviceDPOR(
                 app, device_cfg, program, batch_size=batch,
                 kernel=kernel, sleep_sets=False, host_shards=n,
+                prefix_fork=prefix_fork,
             )
             dpor.explore(target_code=target_code, max_rounds=1)
             timer = HostHalfTimer(dpor)

@@ -120,6 +120,45 @@ def test_double_buffer_parity_with_prefix_fork(reversal):
     assert np.array_equal(fs[0], fr[0])
 
 
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_oracle_told_async_min_hands_its_instances_the_answer(
+    reversal, monkeypatch, platform
+):
+    """An oracle told ``async_min=True`` decides its instances'
+    in-flight rounds by the rule a bare DeviceDPOR applies to the
+    variable (on where host and device are disjoint, off on a CPU) and
+    hands the boolean down: no variable is set, and an explicit
+    ``double_buffer`` still wins."""
+    import types
+
+    from demi_tpu.device import dpor_sweep
+
+    app, cfg, program, _kernel, _ = reversal
+    config = SchedulerConfig(invariant_check=make_host_invariant(app))
+    monkeypatch.delenv("DEMI_ASYNC_MIN", raising=False)
+    monkeypatch.setattr(
+        dpor_sweep.jax, "devices",
+        lambda *a, **kw: [types.SimpleNamespace(platform=platform)],
+    )
+    want = platform != "cpu"
+    told = DeviceDPOROracle(app, cfg, config, batch_size=4, async_min=True)
+    assert told.supports_async
+    assert told._instance(program)._double_buffer is want
+    untold = DeviceDPOROracle(app, cfg, config, batch_size=4)
+    assert not untold.supports_async
+    assert untold._instance(program)._double_buffer is False
+    pinned = DeviceDPOROracle(
+        app, cfg, config, batch_size=4, async_min=True,
+        double_buffer=not want,
+    )
+    assert pinned._instance(program)._double_buffer is (not want)
+    # A bare DeviceDPOR (the benchmark cell, the tests) is told nothing
+    # and resolves as it did.
+    assert dpor_sweep._resolve_double_buffer(None) is False
+    monkeypatch.setenv("DEMI_ASYNC_MIN", "1")
+    assert dpor_sweep._resolve_double_buffer(None) is want
+
+
 def test_window_unconsulted_probe_keeps_state():
     """test_window commits a probe's resumable instance state only when
     its resolver is consulted: the unconsulted probe's instance looks

@@ -388,26 +388,21 @@ def test_bench_config8_smoke():
                 "sync_rounds_per_sec", "async_rounds_per_sec",
                 "explored_match", "frontier_match", "interleavings_match",
                 "explored", "frontier", "inflight", "fork",
-                "host_path", "host_share", "device_share"):
+                "host_share", "device_share"):
         assert key in section, key
     for key in ("inflight_rounds", "inflight_hits", "inflight_waste"):
         assert key in section["inflight"], key
     for key in ("prefix_hit_rate", "parent_trunks", "anchor_trunks",
                 "steps_saved", "mean_group_size"):
         assert key in section["fork"], key
-    for key in ("legacy_seconds", "vectorized_seconds", "speedup",
-                "wall_speedup", "legacy_host_seconds",
-                "vectorized_host_seconds", "match",
-                "legacy_host_share", "vectorized_host_share"):
-        assert key in section["host_path"], key
-    # The acceptance-grade >=1.2x (async) and >=1.3x (host path) need
-    # the DEEP saturated frontier (bench default); at smoke shapes only
-    # the equality contracts — the async loop AND the vectorized host
-    # path explore the EXACT same schedule space — are asserted.
+    assert "host_path" not in section
+    # The acceptance-grade >=1.2x (async) needs the DEEP saturated
+    # frontier (bench default); at smoke shapes only the equality
+    # contract — the async loop explores the EXACT same schedule space —
+    # is asserted.
     assert section["explored_match"] is True
     assert section["frontier_match"] is True
     assert section["interleavings_match"] is True
-    assert section["host_path"]["match"] is True
     assert section["interleavings"] > 0
     # Static-pruning A/B on the seeded deep fixture: no-op-only removal
     # with static_pruned > 0 (the deep raft frontier always carries

@@ -4,6 +4,7 @@ shrinks it with device-batched trials, replay reproduces, sweep counts
 violations, shiviz/dot export."""
 
 import json
+import os
 
 import pytest
 
@@ -325,3 +326,93 @@ def test_cli_dpor_profile_rounds(tmp_path, capsys, monkeypatch):
         for line in plane.lines for ev in line.events
     }
     assert {"demi.dpor.round", "demi.dpor.block"} <= names
+
+
+_SWITCHES = ("DEMI_PREFIX_FORK", "DEMI_ASYNC_MIN", "DEMI_HOST_SHARDS")
+
+
+def _recorded(monkeypatch, cls):
+    """Every ``cls`` built from here on, in order."""
+    built = []
+    init = cls.__init__
+
+    def recording(self, *args, **kw):
+        init(self, *args, **kw)
+        built.append(self)
+
+    monkeypatch.setattr(cls, "__init__", recording)
+    return built
+
+
+def _last_json(capsys):
+    return json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+
+
+def test_cli_dpor_tells_its_engine_and_leaves_no_switch_behind(
+    monkeypatch, capsys
+):
+    """`dpor --prefix-fork --async-min --host-shards 2` builds its
+    DeviceDPOR with a forker and two shards under an async oracle; a
+    second `dpor` in the same process with none of the flags builds one
+    with none of them, because the first wrote nothing into
+    os.environ."""
+    from demi_tpu.device.dpor_sweep import DeviceDPOR, DeviceDPOROracle
+
+    for name in _SWITCHES:
+        monkeypatch.delenv(name, raising=False)
+    dpors = _recorded(monkeypatch, DeviceDPOR)
+    oracles = _recorded(monkeypatch, DeviceDPOROracle)
+    argv = [
+        "dpor", "--app", "raft", "--nodes", "2", "--bug", "multivote",
+        "--batch", "8", "--rounds", "2", "--pool", "64",
+        "--max-messages", "48", "--num-events", "6",
+    ]
+    rc = main(argv + ["--prefix-fork", "--async-min", "--host-shards", "2"])
+    told = _last_json(capsys)
+    assert rc in (0, 1)
+    assert len(dpors) == len(oracles) == 1
+    assert dpors[0]._forker is not None and dpors[0]._host_shards == 2
+    assert oracles[0].supports_async
+    # On a CPU an in-flight launch burns the host's own cores: the
+    # oracle's rule keeps them off here and on elsewhere
+    # (tests/test_async_dpor.py holds the rule off a CPU).
+    assert dpors[0]._double_buffer is False
+    assert "prefix_fork" in told and told["async"]["inflight_rounds"] == 0
+    assert not [name for name in _SWITCHES if name in os.environ]
+
+    rc = main(argv)
+    plain = _last_json(capsys)
+    assert rc in (0, 1)
+    assert len(dpors) == len(oracles) == 2
+    assert dpors[1]._forker is None and dpors[1]._host_shards == 1
+    assert dpors[1]._sharder is None and dpors[1]._double_buffer is False
+    assert not oracles[1].supports_async
+    assert "prefix_fork" not in plain and "async" not in plain
+    assert plain["interleavings"] == told["interleavings"]
+    assert not [name for name in _SWITCHES if name in os.environ]
+
+
+def test_cli_sweep_tells_its_driver_and_leaves_no_switch_behind(
+    monkeypatch, capsys
+):
+    """The same pair for `sweep --prefix-fork`: the first call's
+    SweepDriver forks, the second's does not."""
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    monkeypatch.delenv("DEMI_PREFIX_FORK", raising=False)
+    drivers = _recorded(monkeypatch, SweepDriver)
+    argv = [
+        "sweep", "--app", "broadcast", "--nodes", "4", "--bug",
+        "unreliable", "--batch", "32", "--pool", "64",
+        "--max-messages", "96",
+    ]
+    assert main(argv + ["--prefix-fork"]) == 0
+    forked = _last_json(capsys)
+    assert drivers[-1].fork_stats is not None and "prefix_fork" in forked
+    assert "DEMI_PREFIX_FORK" not in os.environ
+
+    assert main(argv) == 0
+    plain = _last_json(capsys)
+    assert drivers[-1].fork_stats is None and "prefix_fork" not in plain
+    assert plain["lanes_digest"] == forked["lanes_digest"]
+    assert "DEMI_PREFIX_FORK" not in os.environ

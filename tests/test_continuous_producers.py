@@ -191,12 +191,32 @@ def test_a_job_is_the_in_thread_jobs(which, producers, in_thread, request, spans
         assert "sweep.producer_ns" not in counts
 
 
-def test_the_prime_fill_is_served_from_the_ring_too(raft, spans):
+def test_the_prime_fill_is_served_from_the_ring_too(raft, spans, monkeypatch):
     """A resident set larger than the probe: its other programs are the
-    producers'."""
+    producers', and the prime fill waits for a chunk that is not there
+    yet. Who is first at the ring after the fork is a race, so the test
+    decides it: a child makes nothing before the host thread is inside
+    a ``sweep.starve`` wait (whose loop alone asks ``_reaped``)."""
+    import multiprocessing
+
+    host = os.getpid()
+    waited_for = multiprocessing.get_context("fork").Event()
+
+    def gen(seed):
+        if os.getpid() != host:
+            assert waited_for.wait(60)
+        return raft.gen(seed)
+
+    reaped = continuous._Producers._reaped
+
+    def waiting(self, i):
+        waited_for.set()
+        return reaped(self, i)
+
+    monkeypatch.setattr(continuous._Producers, "_reaped", waiting)
     lanes, batch = 160, 2 * continuous._PROBE
     drv = continuous.ContinuousSweepDriver(
-        raft.app, raft.cfg, raft.gen, batch=batch, seg_steps=32,
+        raft.app, raft.cfg, gen, batch=batch, seg_steps=32,
         seed_pure=True, producers=0,
     )
     want = drv.sweep(lanes)
@@ -206,7 +226,6 @@ def test_the_prime_fill_is_served_from_the_ring_too(raft, spans):
     assert drv.sweep(lanes) == want
     assert raft.calls == list(range(continuous._PROBE))
     assert obs.stage_counts()["sweep.produced"] == lanes - continuous._PROBE
-    # nothing was ready at the fork: the prime fill waited for its chunks
     assert obs.stage_totals()["sweep.starve"]["count"] >= 1
 
 

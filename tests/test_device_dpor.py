@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from demi_tpu.apps.common import dsl_start_events
 from demi_tpu.device import DeviceConfig
 from demi_tpu.device.core import REC_DELIVERY
-from demi_tpu.device.dpor_sweep import DeviceDPOR, racing_prescriptions
+from demi_tpu.device.dpor_sweep import DeviceDPOR
 from demi_tpu.dsl import DSLApp, vset
 from demi_tpu.external_events import MessageConstructor, Send, WaitQuiescence
 
@@ -114,11 +114,14 @@ def test_racing_prescriptions_shape():
     # program-order predecessor at actor 0 is record 2
     recs[2] = [REC_DELIVERY, 2, 0, 1, 7, 0, -1]
     recs[3] = [REC_DELIVERY, 2, 0, 1, 8, 1, 2]
-    prescs = racing_prescriptions(recs, 4, recw)
-    assert len(prescs) == 1
-    (presc,) = prescs
+    from demi_tpu.native import racing_prescriptions_batch
+
+    rows, offsets, lanes, _digests = racing_prescriptions_batch(
+        recs[None], np.asarray([4]), recw
+    )
+    assert lanes.tolist() == [0] and offsets.tolist() == [0, 1]
     # Flip: deliver record 3's message first (no prior deliveries).
-    assert presc == (tuple(int(x) for x in recs[3]),)
+    assert rows.tolist() == [recs[3].tolist()]
 
 
 def test_device_dpor_steering_reproduces_in_first_batch():

@@ -70,14 +70,13 @@ def _answer(d, digest):
 
 
 @pytest.mark.parametrize("case", [
-    "sequential", "host_shards_2", "double_buffer", "legacy",
+    "sequential", "host_shards_2", "double_buffer",
     "split", "checkpoint_restore",
 ])
 def test_search_is_the_parents(raft3, case):
     kw = {
         "host_shards_2": {"host_shards": 2},
         "double_buffer": {"double_buffer": True},
-        "legacy": {"host_path": "legacy"},
     }.get(case, {})
     digest = hashlib.sha256()
     d = _driver(raft3, digest, **kw)

@@ -1,6 +1,7 @@
 """Vectorized host path: randomized parity native-vs-NumPy-vs-legacy for
-prescription assembly, LCP grouping, and record packing, plus the
-DeviceDPOR host-path switch and the collapsed continuous-autotuned sweep.
+prescription assembly, LCP grouping, and record packing, plus a whole
+DeviceDPOR search against the per-lane assembly kept here and the
+collapsed continuous-autotuned sweep.
 
 The contract under test: every vectorized host-side rewrite (batch racing
 analysis, digest dedup, array LCP planning, matrix packing, array harvest
@@ -239,46 +240,68 @@ def test_pack_records_vectorized_semantics():
     assert ragged[1, 3] == 3
 
 
-def test_device_dpor_host_paths_bit_identical():
-    """DeviceDPOR with host_path='vectorized' vs 'legacy': explored set,
-    frontier (order included), interleavings, and the found records all
-    equal — the acceptance contract for the frontier rewrite."""
-    from test_device_dpor import _setup
+def harvested_rounds(dpor):
+    """Make ``dpor`` note every round it harvests, before and after its
+    host half: ``(traces, lens, the batch's tuples, entries in the
+    explored log afterwards)`` a round."""
+    rounds = []
+    process = dpor._process_round
 
-    from demi_tpu.device.dpor_sweep import DeviceDPOR, make_dpor_kernel
+    def noting(res, batch, *args, **kw):
+        row = [
+            np.array(res.trace), np.array(res.trace_len),
+            dpor._list(batch).tuples(),
+        ]
+        rounds.append(row)
+        hit = process(res, batch, *args, **kw)
+        row.append(len(dpor._explored_log))
+        return hit
 
-    app, cfg, program = _setup(3)
-    kernel = make_dpor_kernel(app, cfg)
-    vec = DeviceDPOR(
-        app, cfg, program, batch_size=4, kernel=kernel,
-        host_path="vectorized",
-    )
-    leg = DeviceDPOR(
-        app, cfg, program, batch_size=4, kernel=kernel, host_path="legacy",
-    )
-    fv = vec.explore(target_code=1, max_rounds=20)
-    fl = leg.explore(target_code=1, max_rounds=20)
-    assert (fv is None) == (fl is None)
-    if fv is not None:
-        assert fv[1] == fl[1]
-        assert np.array_equal(fv[0], fl[0])
-    assert vec.explored == leg.explored
-    assert vec.frontier == leg.frontier
-    assert vec.interleavings == leg.interleavings
+    dpor._process_round = noting
+    return rounds
+
+
+def test_a_whole_search_is_the_per_lane_closure_of_its_own_traces():
+    """A DeviceDPOR search on 3-node raft admits, round by round and in
+    order, exactly what a loop kept here derives from the same harvested
+    traces with ``_legacy_prescriptions`` and a tuple set: the shipped
+    scan, digest dedup and columnar log against a reference that is no
+    shipped code."""
+    from demi_tpu.apps.common import dsl_start_events
+    from demi_tpu.device.dpor_sweep import DeviceDPOR
+    from demi_tpu.external_events import WaitQuiescence
+    from demi_tpu.parallel.distributed import build_workload
+
+    app, cfg, _fuzzer = build_workload({
+        "app": "raft", "nodes": 3, "bug": "multivote", "seed": 0,
+        "num_events": 12, "max_messages": 64, "pool": 48,
+        "timer_weight": 0.2, "kill_weight": 0.05, "partition_weight": 0.0,
+    }, record=True)
+    program = dsl_start_events(app) + [WaitQuiescence()]
+    dpor = DeviceDPOR(app, cfg, program, batch_size=16, double_buffer=False)
+    rounds = harvested_rounds(dpor)
+    dpor.explore(max_rounds=4, stop_on_violation=False)
+    assert len(rounds) == 4
+
+    admitted, seen, executed = [()], {()}, set()
+    for traces, lens, batch, n_after in rounds:
+        executed.update(batch)
+        for lane in range(len(batch)):
+            for presc in _legacy_prescriptions(
+                traces[lane], int(lens[lane]), cfg.rec_width
+            ):
+                if presc not in seen:
+                    seen.add(presc)
+                    admitted.append(presc)
+        # Round by round: the log, in admission order, is the closure.
+        assert n_after == len(admitted)
+    assert list(dpor._explored_log) == admitted
+    assert dpor.explored == seen and len(seen) > 100
+    assert dpor.interleavings == sum(len(r[2]) for r in rounds)
+    # Nothing admitted is lost: it was run, or it waits.
+    assert set(dpor.frontier) == seen - executed
     # Both ledgers ran: the host/device split is measured, not assumed.
-    assert vec.host_seconds > 0 and vec.device_seconds > 0
-
-
-def test_host_path_env_resolution(monkeypatch):
-    from demi_tpu.device.dpor_sweep import _resolve_host_path
-
-    monkeypatch.delenv("DEMI_HOST_PATH", raising=False)
-    assert _resolve_host_path() == "vectorized"
-    monkeypatch.setenv("DEMI_HOST_PATH", "legacy")
-    assert _resolve_host_path() == "legacy"
-    assert _resolve_host_path("vectorized") == "vectorized"  # arg wins
-    with pytest.raises(ValueError):
-        _resolve_host_path("turbo")
+    assert dpor.host_seconds > 0 and dpor.device_seconds > 0
 
 
 def test_continuous_autotuned_attribution_parity():

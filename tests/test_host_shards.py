@@ -87,16 +87,15 @@ def test_digest_shards_set_surface_and_reshard():
     assert d2 != d4
 
 
-def test_resolve_host_shards_env_and_explicit(monkeypatch):
-    monkeypatch.delenv("DEMI_HOST_SHARDS", raising=False)
+def test_resolve_host_shards_is_what_it_is_told(monkeypatch):
+    monkeypatch.setenv("DEMI_HOST_SHARDS", "4")  # no longer a channel
     assert resolve_host_shards() == 1
-    monkeypatch.setenv("DEMI_HOST_SHARDS", "4")
-    assert resolve_host_shards() == 4
-    assert resolve_host_shards(2) == 2  # explicit wins
-    monkeypatch.setenv("DEMI_HOST_SHARDS", "junk")
-    assert resolve_host_shards() == 1
-    monkeypatch.setenv("DEMI_HOST_SHARDS", "0")
-    assert resolve_host_shards() == 1
+    assert resolve_host_shards(None) == 1
+    assert resolve_host_shards(2) == 2
+    assert resolve_host_shards(0) == 1
+    assert resolve_host_shards(-3) == 1
+    with pytest.raises(ValueError):
+        resolve_host_shards("junk")
 
 
 def test_scan_buffers_grow_monotonically_and_are_reused():
@@ -315,13 +314,17 @@ def test_calibrate_host_shards_cache_and_default(tmp_path):
 
 
 def test_cli_dpor_host_shards_flag(monkeypatch, capsys):
-    """--host-shards reaches DeviceDPOROracle through DEMI_HOST_SHARDS
-    and the sharded search still finds the violation."""
+    """--host-shards reaches the DeviceDPOR the verb's oracle builds as
+    an argument, the process environment untouched, and the sharded
+    search still runs."""
     import json
 
     from demi_tpu.cli import main
+    from test_cli import _recorded
 
+    built = _recorded(monkeypatch, DeviceDPOR)
     monkeypatch.delenv("DEMI_HOST_SHARDS", raising=False)
+    environ = dict(os.environ)
     rc = main([
         "dpor", "--app", "raft", "--nodes", "2", "--bug", "multivote",
         "--batch", "8", "--rounds", "2", "--pool", "64",
@@ -329,7 +332,8 @@ def test_cli_dpor_host_shards_flag(monkeypatch, capsys):
         "--host-shards", "2",
     ])
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert os.environ.get("DEMI_HOST_SHARDS") == "2"
-    monkeypatch.delenv("DEMI_HOST_SHARDS", raising=False)
+    assert [d._host_shards for d in built] == [2]
+    assert built[0]._sharder is not None
+    assert dict(os.environ) == environ
     assert rc in (0, 1)
     assert "interleavings" in out

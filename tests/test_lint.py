@@ -361,13 +361,12 @@ def _raft_dpor_setup():
     return app, cfg, program, make_dpor_kernel(app, cfg)
 
 
-def _explore(app, cfg, program, kernel, rel, host_path="vectorized",
-             rounds=2, batch=8):
+def _explore(app, cfg, program, kernel, rel, rounds=2, batch=8):
     from demi_tpu.device.dpor_sweep import DeviceDPOR
 
     d = DeviceDPOR(
         app, cfg, program, batch_size=batch, prefix_fork=False,
-        double_buffer=False, kernel=kernel, host_path=host_path,
+        double_buffer=False, kernel=kernel,
         static_independence=rel if rel is not None else False,
     )
     d.explore(target_code=99, max_rounds=rounds)
@@ -391,14 +390,6 @@ def test_device_static_prune_noop_only_raft():
     assert (base.explored - pruned.explored) <= audit
     assert set(base.frontier) - set(pruned.frontier) <= audit
     assert not (set(pruned.frontier) - set(base.frontier))
-
-    # Legacy host path with the same relation: bit-identical pruning.
-    rel2 = StaticIndependence.for_app(app, audit=True)
-    legacy = _explore(app, cfg, program, kernel, rel2, host_path="legacy")
-    assert legacy.explored == pruned.explored
-    assert legacy.frontier == pruned.frontier
-    assert legacy.interleavings == pruned.interleavings
-    assert rel2.pruned_total == rel.pruned_total
 
 
 def test_device_static_prune_broadcast_bit_identical():

@@ -151,7 +151,7 @@ def _dpor_identity(d):
     return (
         d.explored, d._explored_log, d._explored_digests,
         d.frontier, d.original, d.max_distance, d.interleavings,
-        d.round_batch, d.violation_codes, d._suppressed,
+        d.round_batch, d.violation_codes,
         d._suppressed_digests, d._sleep_rows,
         {k: np.asarray(v).tolist() for k, v in d._guides.items()},
     )
@@ -210,6 +210,45 @@ def test_device_dpor_checkpoint_rejects_workload_mismatch(tmp_path):
         DeviceDPOR(clean, cfg_r, prog_r, batch_size=8).restore_state(
             payload_r
         )
+
+
+@pytest.mark.parametrize("stored", ["vectorized", "legacy"])
+def test_a_checkpoint_that_names_its_host_path(stored):
+    """Checkpoints written before the per-lane 'legacy' host path went
+    name the path that wrote them, in the workload a restore compares
+    whole, and carry a tuple set only that path wrote. One that says
+    'vectorized' restores and continues bit-identically; one that says
+    'legacy' never kept the digest set and is refused by the key's
+    name."""
+    import json
+
+    from demi_tpu.persist.checkpoint import _pack_rows
+
+    app, cfg, program, presc = _seeded_fixture("raft")
+
+    def new():
+        d = DeviceDPOR(app, cfg, program, batch_size=8,
+                       double_buffer=False, prefix_fork=False)
+        d.seed(presc)
+        return d
+
+    d = new()
+    d.explore(max_rounds=2)
+    old = json.loads(json.dumps(d.checkpoint_state()))
+    assert "host_path" not in old["workload"] and "suppressed" not in old
+    old["workload"]["host_path"] = stored
+    old["suppressed"] = _pack_rows([])
+    fresh = new()
+    if stored == "legacy":
+        with pytest.raises(CheckpointMismatch, match="'host_path'"):
+            fresh.restore_state(old)
+        return
+    fresh.restore_state(old)
+    assert _dpor_identity(fresh) == _dpor_identity(d)
+    d.explore(max_rounds=2)
+    fresh.explore(max_rounds=2)
+    assert _dpor_identity(fresh) == _dpor_identity(d)
+    assert len(d.explored) > 1
 
 
 @pytest.mark.parametrize("name", ["raft", "broadcast"])

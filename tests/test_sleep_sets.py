@@ -1,7 +1,7 @@
 """Sleep sets & race-reversal DPOR: canonical class keys, device wake
 tracking, the native/NumPy sleep filter, and the pruned-vs-unpruned
 parity contracts on raft, broadcast, and spark fixtures across the
-device-vectorized, device-legacy, and host DPORScheduler tiers."""
+device and host DPORScheduler tiers."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -414,26 +414,109 @@ def test_device_sleep_wake_parity_with_numpy_twin():
         assert min(slept, BIG_ORDINAL) == min(dev_slept, BIG_ORDINAL)
 
 
-def test_device_sleep_legacy_vectorized_parity():
-    """host_path='legacy' and 'vectorized' stay bit-identical with sleep
-    sets on and pruning actually firing (explored, frontier, prune
-    ledger, violations)."""
+def _asleep_per_pair(presc, lane_sleep, wake, slept, presc_deliv, recw):
+    """The sleep filter for ONE racing pair, spelt out: its branch lies
+    beyond the lane's redundant-suffix marker, or, at or after the
+    lane's node, its flip has the content of a row still asleep there
+    (kind, receiver, payload; the sender too unless a timer)."""
+    branch, flip = len(presc) - 1, presc[-1]
+    if branch > slept:
+        return True
+    if branch < presc_deliv:
+        return False
+    for s, srow in enumerate(lane_sleep):
+        if wake[s] < branch:
+            continue
+        if (
+            flip[0] == srow[0] and flip[2] == srow[2]
+            and flip[3: recw - 2] == srow[3: recw - 2]
+            and (flip[0] == REC_TIMER or flip[1] == srow[1])
+        ):
+            return True
+    return False
+
+
+def test_a_sleep_search_is_the_per_pair_filter_over_its_own_traces(
+    monkeypatch
+):
+    """A whole search with sleep sets and pruning on, held round by round
+    to a per-lane, per-pair loop kept in the tests: the candidates the
+    shipped scan lets through are the per-lane prescriptions
+    (test_host_path._legacy_prescriptions) that the per-pair filter above
+    leaves awake, in order; what is admitted is the not-yet-seen ones of
+    those, in order, less exactly the ones the class ledger counts; and
+    the two prune counters add up to what the loop dropped."""
+    import demi_tpu.native as native
+    from test_host_path import (
+        _legacy_prescriptions,
+        _unpack,
+        harvested_rounds,
+    )
+
     app, cfg, program, seed = _commute_setup()
-    kernel = make_dpor_kernel(
-        app, cfg, sleep_cap=4, commute_matrix=COMMUTE_MATRIX
+    recw = cfg.rec_width
+    sl = SleepSets(prune=True, cap=4)
+    sl.matrix = COMMUTE_MATRIX
+    d = DeviceDPOR(
+        app, cfg, program, batch_size=8, sleep_sets=sl,
+        kernel=make_dpor_kernel(
+            app, cfg, sleep_cap=4, commute_matrix=COMMUTE_MATRIX
+        ),
     )
-    vec, _ = _commute_sleep_run(
-        app, cfg, program, seed, kernel, prune=True, host_path="vectorized"
-    )
-    leg, _ = _commute_sleep_run(
-        app, cfg, program, seed, kernel, prune=True, host_path="legacy"
-    )
-    assert vec.sleep.pruned > 0  # parity under real pruning pressure
-    assert vec.explored == leg.explored
-    assert vec.frontier == leg.frontier
-    assert vec.violation_codes == leg.violation_codes
-    assert vec.sleep.pruned_total == leg.sleep.pruned_total
-    assert vec.sleep.classes == leg.sleep.classes
+    d.seed(seed)
+    rounds = harvested_rounds(d)
+    scans = []
+    scan = native.racing_prescriptions_batch
+
+    def noting(traces, lens, w, **kw):
+        out = scan(traces, lens, w, **kw)
+        ctx = [np.array(x) for x in kw["sleep_ctx"]]
+        lane_sleep = [
+            d._sleep_rows.get(p, ()) for p in rounds[-1][2]
+        ]
+        scans.append((
+            _unpack(*(np.array(x) for x in out[:3])), ctx, lane_sleep,
+        ))
+        return out
+
+    monkeypatch.setattr(native, "racing_prescriptions_batch", noting)
+    _drain(d, max_rounds=60)
+    assert len(scans) == len(rounds) > 3
+
+    # The seed went in before the first round, by the front door.
+    seen, suppressed, n_before = {(), seed}, set(), 2
+    slept_n = class_n = 0
+    for (traces, lens, batch, n_after), (got, ctx, lane_sleep) in zip(
+        rounds, scans
+    ):
+        _rows, wake, slept, presc_deliv = ctx
+        want = []
+        for lane in range(len(batch)):
+            for presc in _legacy_prescriptions(
+                traces[lane], int(lens[lane]), recw
+            ):
+                if _asleep_per_pair(
+                    presc, lane_sleep[lane], wake[lane].tolist(),
+                    int(slept[lane]), int(presc_deliv[lane]), recw,
+                ):
+                    slept_n += 1
+                else:
+                    want.append((lane, presc))
+        assert got == want
+        new = list(dict.fromkeys(
+            p for _lane, p in want if p not in seen and p not in suppressed
+        ))
+        admitted = [d._explored_log[k] for k in range(n_before, n_after)]
+        # Admitted in candidate order; the rest met a class seen before.
+        assert admitted == [p for p in new if p in set(admitted)]
+        seen.update(admitted)
+        suppressed.update(p for p in new if p not in seen)
+        class_n += len(new) - len(admitted)
+        n_before = n_after
+    assert d.explored == seen
+    assert sl.pruned_total == {"sleep": slept_n, "class": class_n}
+    assert slept_n > 0 and class_n > 0  # under real pruning pressure
+    assert not (seen & suppressed)
 
 
 def test_device_sleep_fork_parity():
