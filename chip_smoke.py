@@ -37,11 +37,15 @@ SIZES = {
         nodes=5, pool=96, steps=144, lanes=32768, chunk=8192, lifts=8,
         dpor_batch=256, dpor_rounds=8,
         min_nodes=3, min_events=12, min_steps=400, fuzz_executions=200,
+        paxos_lanes=512, paxos_log_cap=8, paxos_steps=2048, paxos_pool=256,
+        paxos_events=96, paxos_violating=1,
     ),
     "tiny": dict(
         nodes=3, pool=48, steps=64, lanes=64, chunk=32, lifts=2,
         dpor_batch=16, dpor_rounds=2,
         min_nodes=3, min_events=6, min_steps=96, fuzz_executions=200,
+        paxos_lanes=32, paxos_log_cap=4, paxos_steps=384, paxos_pool=128,
+        paxos_events=24, paxos_violating=0,
     ),
 }
 
@@ -317,6 +321,98 @@ def phase_lift(smoke: Smoke, sweep_summary: dict) -> None:
         rec.update(lanes_lifted=len(picked), host_agrees=True)
 
 
+def _paxos_workload(z: dict) -> dict:
+    """The datagram sweep's workload: Multi-Paxos with its seeded bug over
+    a network that re-delivers and loses messages."""
+    return {
+        "app": "paxos", "nodes": 11, "bug": "count_replies", "seed": 0,
+        "log_cap": z["paxos_log_cap"], "num_events": z["paxos_events"],
+        "max_messages": z["paxos_steps"], "pool": z["paxos_pool"],
+        "timer_weight": 0.2, "send_weight": 0.6, "wait_weight": 0.28,
+        "hard_kill_weight": 0.12, "kill_weight": 0.0,
+        "partition_weight": 0.0, "max_kills": 4, "wait_budget": [1, 40],
+        "dup_weight": 0.25, "drop_weight": 0.02, "max_dups": 256,
+        "max_drops": 16,
+    }
+
+
+def phase_datagram(smoke: Smoke) -> None:
+    """The datagram discipline outside the benchmark: one sweep of
+    Multi-Paxos (``--app paxos``, ``count_replies``) through the CLI's
+    normal path with ``--dup-weight`` and ``--drop-weight``; at the chip
+    size it holds both verdicts. Violating and clean lanes are re-run
+    traced and lifted: the host oracle follows the kept and discarded
+    deliveries to the same code and the same ``sched_hash``."""
+    import jax
+    import numpy as np
+
+    from demi_tpu.device.core import REC_DISCARDED, REC_KEPT
+    from demi_tpu.device.encoding import (
+        host_sched_hash, lower_program, stack_programs,
+    )
+    from demi_tpu.parallel.distributed import build_workload
+    from demi_tpu.runner import lift_lane_to_host
+
+    z = smoke.size
+    w = _paxos_workload(z)
+    with smoke.phase("datagram_sweep") as rec:
+        s = smoke.verb([
+            "sweep", "--app", "paxos", "--nodes", "11",
+            "--bug", "count_replies", "--log-cap", str(w["log_cap"]),
+            "--batch", str(z["paxos_lanes"]), "--pool", str(w["pool"]),
+            "--max-messages", str(w["max_messages"]),
+            "--num-events", str(w["num_events"]),
+            "--send-weight", "0.6", "--wait-weight", "0.28",
+            "--hard-kill-weight", "0.12", "--kill-weight", "0",
+            "--max-kills", "4", "--wait-budget", "1", "40",
+            "--dup-weight", "0.25", "--drop-weight", "0.02",
+            "--max-dups", "256", "--max-drops", "16", "--strict-io",
+        ])
+        smoke.check_device(s)
+        check(s["lanes"] == z["paxos_lanes"], f"datagram: {s['lanes']} lanes")
+        check(s["overflow_lanes"] == 0, "datagram: overflow lanes")
+        check(
+            z["paxos_violating"] <= s["violations"] < s["lanes"],
+            f"datagram: {s['violations']} violating lanes of {s['lanes']}: "
+            "the sweep should hold both verdicts",
+        )
+        violating = dict(s["violating_seeds"])
+        picked = sorted(violating)[:2]
+        picked += [x for x in range(z["paxos_lanes"]) if x not in violating][:2]
+        app, cfg, fuzzer = build_workload(w)
+        seeds = np.asarray(picked, np.uint32)
+        progs = stack_programs([
+            lower_program(app, cfg, fuzzer.generate_fuzz_test(seed=int(x)))
+            for x in seeds
+        ])
+        keys = jax.vmap(
+            lambda x: jax.random.fold_in(jax.random.PRNGKey(0), x)
+        )(seeds)
+        kept = dropped = 0
+        for lane, seed in enumerate(picked):
+            single, host = lift_lane_to_host(app, cfg, progs, keys, lane)
+            code = violating.get(seed, 0)
+            host_code = host.violation.code if host.violation else 0
+            check(
+                int(single.violation) == host_code == code,
+                f"datagram seed {seed}: sweep {code}, traced "
+                f"{int(single.violation)}, host {host_code}",
+            )
+            check(
+                host_sched_hash(app, host.trace) == int(single.sched_hash),
+                f"datagram seed {seed}: the host delivered another sequence",
+            )
+            kinds = np.asarray(single.trace)[: int(single.trace_len), 0]
+            kept += int((kinds == REC_KEPT).sum())
+            dropped += int((kinds == REC_DISCARDED).sum())
+        check(kept > 0, "datagram: no lifted lane kept a delivery")
+        rec.update(
+            lanes=s["lanes"], violations=s["violations"],
+            lanes_lifted=len(picked), kept=kept, discarded=dropped,
+            host_agrees=True, lanes_digest=s["lanes_digest"],
+        )
+
+
 def phase_dpor(smoke: Smoke) -> None:
     """BASELINE config 2 shape. First the full round budget on the
     correct protocol (a violating run stops at its first hit, so this is
@@ -435,6 +531,7 @@ def run(size: dict, device: dict) -> dict:
         sweep_summary = phase_sweep(smoke)
         check_warm_compile(smoke)
         phase_lift(smoke, sweep_summary)
+        phase_datagram(smoke)
         phase_dpor(smoke)
         phase_minimize(smoke, workdir)
         if device["count"] > 1:

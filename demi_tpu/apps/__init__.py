@@ -1,3 +1,9 @@
+"""The bundled apps, written once against the DSL and run on both tiers:
+``broadcast``, ``chain`` (chain replication with its failure repairs, over
+FIFO channels), ``paxos`` (Multi-Paxos as published, over datagram
+channels), ``raft``, ``spark_dag``, ``twopc``, ``vsr`` (Viewstamped
+Replication Revisited). ``cli.build_app`` names them for ``--app``."""
+
 from ..obs import spans as _spans
 
 with _spans.stage("setup.import", module=__name__):

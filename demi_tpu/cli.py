@@ -33,6 +33,18 @@ with obs.stage("setup.import", module=__name__):
 
 
 def build_app(args) -> DSLApp:
+    """The app a verb runs. ``--dup-weight`` and ``--drop-weight`` are a
+    datagram network's: given to an app with any other channels, every
+    verb ends here, with a sentence."""
+    from .device.core import datagram_flags_refusal, datagram_weight_given
+
+    app = _make_app(args)
+    if app.channels != "datagram" and datagram_weight_given(args):
+        raise SystemExit(datagram_flags_refusal(app))
+    return app
+
+
+def _make_app(args) -> DSLApp:
     if args.app == "broadcast":
         return make_broadcast_app(args.nodes, reliable=args.bug is None)
     if args.app == "raft":
@@ -59,9 +71,18 @@ def build_app(args) -> DSLApp:
         from .apps.chain import make_chain_app
 
         return make_chain_app(args.nodes, log_cap=args.log_cap, bug=args.bug)
+    if args.app == "paxos":
+        from .apps.paxos import make_paxos_app
+
+        try:
+            return make_paxos_app(
+                args.nodes, log_cap=args.log_cap, bug=args.bug
+            )
+        except ValueError as exc:
+            raise SystemExit(f"--app paxos: {exc}")
     raise SystemExit(
         f"unknown app {args.app!r} "
-        "(choices: broadcast, chain, raft, spark, twopc, vsr)"
+        "(choices: broadcast, chain, paxos, raft, spark, twopc, vsr)"
     )
 
 
@@ -82,6 +103,10 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         from .apps.chain import chain_send_generator
 
         gen = chain_send_generator(app)
+    elif args.app == "paxos":
+        from .apps.paxos import paxos_send_generator
+
+        gen = paxos_send_generator(app)
     elif args.app == "broadcast":
         gen = broadcast_send_generator(app)
     else:
@@ -106,6 +131,16 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
             None if args.wait_budget is None else tuple(args.wait_budget)
         ),
     )
+
+
+def _network_flags(app: DSLApp, args) -> dict:
+    """The datagram discipline's weights and budgets for the host
+    ``RandomScheduler`` (``--dup-weight``, ``--drop-weight``,
+    ``--max-dups``, ``--max-drops``); nothing for any other app
+    (``build_app`` has refused a non-zero weight)."""
+    from .device.core import datagram_knobs
+
+    return datagram_knobs(args) if app.channels == "datagram" else {}
 
 
 def _workload_dict(args) -> dict:
@@ -863,7 +898,7 @@ def _fuzz_checkpoint_run(args, app, config, fuzzer, controller) -> int:
             max_executions=args.max_executions,
             seed=args.seed, max_messages=args.max_messages,
             invariant_check_interval=app.invariant_interval,
-            strategy=app.random_strategy,
+            strategy=app.random_strategy, **_network_flags(app, args),
             timer_weight=args.timer_weight,
             validate_replay=True, controller=controller,
             start_execution=start, round_hook=hook,
@@ -1189,7 +1224,7 @@ def cmd_fuzz(args) -> int:
             seed=args.seed,
             max_messages=args.max_messages,
             invariant_check_interval=app.invariant_interval,
-            strategy=app.random_strategy,
+            strategy=app.random_strategy, **_network_flags(app, args),
             timer_weight=args.timer_weight,
             validate_replay=True,
             controller=controller,
@@ -1270,6 +1305,12 @@ def cmd_minimize(args) -> int:
     profiling = _profile_begin(args)
     sanitizing = _sanitize_begin(args)
     app = build_app(args)
+    if app.channels == "datagram":
+        from .device.core import datagram_refusal
+
+        raise SystemExit(
+            "minimize: " + datagram_refusal("the replay that judges a candidate")
+        )
     config = SchedulerConfig(invariant_check=make_host_invariant(app))
     de = ExperimentDeserializer(args.experiment, app)
     externals = de.get_externals()
@@ -1598,11 +1639,15 @@ def cmd_dpor(args) -> int:
     prefix_fork = _flag_or_unset(args, "prefix_fork")
     host_shards = getattr(args, "host_shards", 0) or None
     from .device import DeviceConfig
-    from .device.dpor_sweep import FIFO_REFUSAL, DeviceDPOROracle
+    from .device.dpor_sweep import (
+        DATAGRAM_REFUSAL, FIFO_REFUSAL, DeviceDPOROracle,
+    )
 
     app = build_app(args)
     if app.channels == "fifo":
         raise SystemExit(f"dpor: {FIFO_REFUSAL}")
+    if app.channels == "datagram":
+        raise SystemExit(f"dpor: {DATAGRAM_REFUSAL}")
     config = SchedulerConfig(invariant_check=make_host_invariant(app))
     cfg = DeviceConfig.for_workload(
         app, args, record_trace=True, record_parents=True
@@ -2053,7 +2098,7 @@ def cmd_stats(args) -> int:
             seed=args.seed,
             max_messages=args.max_messages,
             invariant_check_interval=app.invariant_interval,
-            strategy=app.random_strategy,
+            strategy=app.random_strategy, **_network_flags(app, args),
             timer_weight=args.timer_weight,
         )
         from .device import DeviceConfig
@@ -2254,6 +2299,22 @@ def main(argv: Optional[list] = None) -> int:
         p.add_argument("--log-cap", type=int, default=knobs["log_cap"],
                        dest="log_cap",
                        help="raft: log entries a node holds")
+        # Datagram channels (an app whose DSLApp.channels say so: paxos).
+        p.add_argument("--dup-weight", type=float,
+                       default=knobs["dup_weight"], dest="dup_weight",
+                       help="share of dispatch steps that deliver an "
+                            "actor's message and keep it pending, so that "
+                            "it may be delivered again")
+        p.add_argument("--drop-weight", type=float,
+                       default=knobs["drop_weight"], dest="drop_weight",
+                       help="share of dispatch steps that lose an actor's "
+                            "pending message undelivered")
+        p.add_argument("--max-dups", type=int, default=knobs["max_dups"],
+                       dest="max_dups",
+                       help="kept deliveries a schedule may hold")
+        p.add_argument("--max-drops", type=int, default=knobs["max_drops"],
+                       dest="max_drops",
+                       help="lost messages a schedule may hold")
         p.add_argument("--stages", type=int, default=knobs["stages"],
                        help="spark: stages a job has")
         p.add_argument("--tasks", type=int, default=knobs["tasks"],

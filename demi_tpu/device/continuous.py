@@ -193,6 +193,7 @@ def _segment_lane_fn(app: DSLApp, cfg: DeviceConfig, seg_steps: int):
     "setup.build", what="make_segment_kernel",
     insert=lambda app, cfg, *a, **kw: insert_form(cfg),
     fifo=lambda app, cfg, *a, **kw: cfg.track_fifo_heads,
+    channels=lambda app, cfg, *a, **kw: app.channels,
 )
 def make_segment_kernel(
     app: DSLApp, cfg: DeviceConfig, seg_steps: int, mesh=None
@@ -1159,6 +1160,20 @@ class ContinuousSweepDriver:
                                     "sweep.insert_steps",
                                     int(steps_run[fin].sum()),
                                 )
+                            if state.dups is not None:
+                                # Datagram channels: what the network
+                                # did to the retired lanes' messages, a
+                                # [B] pull each (``deliveries`` counts a
+                                # kept delivery, not a discarded one).
+                                for name, leaf in (
+                                    ("delivered", state.deliveries),
+                                    ("kept", state.dups),
+                                    ("discarded", state.drops),
+                                ):
+                                    obs.stage_count(
+                                        f"sweep.net.{name}",
+                                        int(np.asarray(leaf)[fin].sum()),
+                                    )
                             if self._progress is not None:
                                 # What the retired lanes' protocol got
                                 # done: one [B, names] pull.

@@ -19,6 +19,10 @@ schedules): int32 rows ``(kind, a, b, msg[W])`` with
   kind 0            = none / padding
   kind 1            = message delivery   (a=src, b=dst)
   kind 2            = timer delivery     (a=b=dst)
+  kind 5            = message delivered and kept pending (a=src, b=dst;
+                      datagram channels only)
+  kind 6            = message discarded undelivered      (a=src, b=dst;
+                      datagram channels only)
   kind 10+op        = external op applied (a, b = op args)
 Host-side lowering lives in demi_tpu/device/encoding.py.
 """
@@ -57,7 +61,15 @@ REC_TIMER = 2
 # Wildcard delivery (replay input only): a=dst, b=policy (0=first/FIFO,
 # 1=last), msg[0]=class tag. Lowered from WildCardMatch expected events.
 REC_WILDCARD = 4
+# Datagram channels (``DSLApp.channels``): the network delivered the
+# message and kept it pending, or lost it before any handler saw it.
+REC_KEPT = 5
+REC_DISCARDED = 6
 REC_EXT_BASE = 10  # REC_EXT_BASE + op
+
+# What a kept and a discarded delivery add to ``sched_hash``'s mix.
+HASH_KEPT = 0x27D4EB2F
+HASH_DISCARDED = 0x165667B1
 
 # Lane status.
 ST_DISPATCH = 0
@@ -147,6 +159,21 @@ class DeviceConfig:
     # sequential srcdst_fifo kernels (parity pin for the incremental
     # maintenance; tests/test_device_srcdst.py).
     head_recompute: bool = False
+    # Datagram channels (``DSLApp.channels``; ``for_workload`` derives it,
+    # no verb has a flag): every dispatch step draws an outcome beside
+    # the index. With probability ``dup_weight`` the delivered message
+    # stays pending (while the lane has kept fewer than ``max_dups``),
+    # with the next ``drop_weight`` it is consumed and reaches no handler
+    # (fewer than ``max_drops``): the shared workload flags
+    # ``--dup-weight``, ``--drop-weight``, ``--max-dups``, ``--max-drops``.
+    # Only an actor's message: timers and external sends are delivered
+    # exactly once. A lane then carries ``ScheduleState.dups`` / ``drops``;
+    # any other kernel is the program it always was.
+    datagram: bool = False
+    dup_weight: float = 0.0
+    drop_weight: float = 0.0
+    max_dups: int = 0
+    max_drops: int = 0
 
     def __post_init__(self):
         if self.index_mode not in ("auto", "onehot", "scatter"):
@@ -158,6 +185,16 @@ class DeviceConfig:
             raise ValueError(
                 f"msg_dtype must be 'int32' or 'int16', got {self.msg_dtype!r}"
             )
+        if not self.datagram and (self.dup_weight or self.drop_weight):
+            raise ValueError(
+                "dup_weight and drop_weight are for an app whose channels "
+                "are 'datagram' (DSLApp.channels): this network delivers a "
+                "message at most once and loses none on its own"
+            )
+        if self.datagram and (self.round_delivery or self.srcdst_fifo):
+            raise ValueError(datagram_refusal(
+                "round_delivery" if self.round_delivery else "srcdst_fifo"
+            ))
         if self.round_delivery and self.record_trace and not self.trace_capacity:
             # Round mode appends up to num_actors records per step; the
             # max_steps fallback that suits the sequential kernels would
@@ -221,7 +258,9 @@ class DeviceConfig:
         and from the app what is no verb's to choose: when the invariant
         is judged (``DSLApp.invariant_at``) and the order its channels
         keep (``DSLApp.channels``; an app that says "any" builds exactly
-        what it always did)."""
+        what it always did; one that says "datagram" takes
+        ``--dup-weight``, ``--drop-weight``, ``--max-dups`` and
+        ``--max-drops`` too, which any other app refuses)."""
         defaults = dict(
             pool_capacity=getattr(args, "pool", None) or 256,
             max_steps=args.max_messages,
@@ -231,8 +270,48 @@ class DeviceConfig:
         )
         if app.channels == "fifo":
             defaults["srcdst_fifo"] = True
+        if app.channels == "datagram":
+            defaults.update(datagram=True, **datagram_knobs(args))
+        elif datagram_weight_given(args):
+            raise ValueError(datagram_flags_refusal(app))
         defaults.update(overrides)
         return DeviceConfig.for_app(app, **defaults)
+
+
+#: The workload keys of the datagram discipline (``DeviceConfig`` fields
+#: and CLI flags of the same names).
+DATAGRAM_KNOBS = ("dup_weight", "drop_weight", "max_dups", "max_drops")
+
+
+def datagram_knobs(args) -> dict:
+    """The four from a workload namespace (0 where it has none): what
+    ``for_workload`` hands the kernel and the CLI the host scheduler."""
+    return {key: getattr(args, key, 0) or 0 for key in DATAGRAM_KNOBS}
+
+
+def datagram_weight_given(args) -> bool:
+    """Whether a workload asks for kept or lost messages at all."""
+    knobs = datagram_knobs(args)
+    return bool(knobs["dup_weight"] or knobs["drop_weight"])
+
+
+def datagram_refusal(who: str) -> str:
+    """Why ``who`` turns away an app whose channels are datagram."""
+    return (
+        f"{who} does not take an app whose channels are datagram "
+        "(DSLApp.channels): a delivery there may leave its message pending "
+        "and a pending message may vanish, and it knows a delivery only as "
+        "the one consumption of its message; sweep or fuzz it instead"
+    )
+
+
+def datagram_flags_refusal(app: DSLApp) -> str:
+    return (
+        f"--dup-weight and --drop-weight are for an app whose channels are "
+        f"'datagram'; this app's are {app.channels!r} (DSLApp.channels): "
+        "its network delivers a message at most once and loses none on its "
+        "own"
+    )
 
 
 class ScheduleState(NamedTuple):
@@ -294,6 +373,12 @@ class ScheduleState(NamedTuple):
     # built (``_short_insert_built``): the smaller shapes' compiled
     # programs are what they were.
     insert_full_steps: Optional[jnp.ndarray] = None  # int32
+    # Datagram channels: deliveries of this lane that left their message
+    # pending, and messages it lost undelivered (the budgets ``max_dups``
+    # and ``max_drops`` are held against them). No leaves (None) for any
+    # other kernel.
+    dups: Optional[jnp.ndarray] = None  # int32
+    drops: Optional[jnp.ndarray] = None  # int32
 
 
 def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
@@ -336,6 +421,8 @@ def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
         insert_full_steps=(
             jnp.int32(0) if _short_insert_built(cfg) else None
         ),
+        dups=jnp.int32(0) if cfg.datagram else None,
+        drops=jnp.int32(0) if cfg.datagram else None,
     )
 
 
@@ -745,17 +832,30 @@ class RowProposal(NamedTuple):
 
 
 def delivery_effects(
-    state: ScheduleState, cfg: DeviceConfig, app: DSLApp, idx: jnp.ndarray
+    state: ScheduleState, cfg: DeviceConfig, app: DSLApp, idx: jnp.ndarray,
+    keep=None, discard=None,
 ) -> Tuple[ScheduleState, RowProposal, jnp.ndarray]:
     """Deliver pool entry ``idx`` minus the pool insert: run the app handler
     for the receiver, consume the entry, update timer parking; return the
     outbox as a RowProposal plus the trace record for this delivery.
 
     ``idx`` must point at a deliverable entry; an invalid index
-    (== pool_capacity) makes the whole pass a no-op."""
+    (== pool_capacity) makes the whole pass a no-op.
+
+    A datagram kernel (``cfg.datagram``) passes the step's outcome as two
+    bools, at most one of them set and neither for a timer or an external
+    send: under ``keep`` the entry stays valid, with its ``pool_seq``;
+    under ``discard`` it is consumed and nothing else happens (no handler
+    effect, no outbox, no timer-memory change, ``deliveries`` as it
+    was). The record and ``sched_hash`` say which."""
     n = cfg.num_actors
     oh = cfg.use_onehot
     valid_idx = idx < cfg.pool_capacity
+    # What consumes the entry, and what runs the handler.
+    consumed = handled = valid_idx
+    if cfg.datagram:
+        consumed = valid_idx & ~keep
+        handled = valid_idx & ~discard
     safe_idx = jnp.minimum(idx, cfg.pool_capacity - 1)
     src = ops.get_scalar(state.pool_src, safe_idx, oh)
     dst = ops.get_scalar(state.pool_dst, safe_idx, oh)
@@ -769,7 +869,7 @@ def delivery_effects(
     new_row, outbox = app.handler(dst, handler_state, src, msg)
     # outbox: [K, 2+W] (valid, dst, msg...)
     k = outbox.shape[0]
-    ob_valid = (outbox[:, 0] != 0) & valid_idx
+    ob_valid = (outbox[:, 0] != 0) & handled
     ob_dst = jnp.clip(outbox[:, 1], 0, n - 1)
     ob_msg = outbox[:, 2:]
     ob_src = jnp.full((k,), 0, jnp.int32) + dst
@@ -788,7 +888,7 @@ def delivery_effects(
 
     # Apply handler effects only when the delivery really happened.
     new_actor_state = ops.set_row(
-        state.actor_state, dst, new_row, valid_idx, oh
+        state.actor_state, dst, new_row, handled, oh
     )
     # Fold this delivery into the lane's schedule fingerprint (uint32
     # FNV-style: multiply by an odd prime, mix in src/dst/timer/payload).
@@ -803,16 +903,28 @@ def delivery_effects(
         + dst.astype(jnp.uint32) * jnp.uint32(0x85EBCA77)
         + is_timer.astype(jnp.uint32) * jnp.uint32(0xC2B2AE35)
     )
+    if cfg.datagram:
+        # A kept and a discarded delivery fold with constants of their
+        # own: two lanes share a hash only if they made the same choices.
+        mix = (
+            mix + keep.astype(jnp.uint32) * jnp.uint32(HASH_KEPT)
+            + discard.astype(jnp.uint32) * jnp.uint32(HASH_DISCARDED)
+        )
     folded = state.sched_hash * jnp.uint32(0x01000193) + mix
     # Consume the entry.
     state = state._replace(
         actor_state=new_actor_state,
         pool_valid=ops.set_scalar(
-            state.pool_valid, safe_idx, False, valid_idx, oh
+            state.pool_valid, safe_idx, False, consumed, oh
         ),
-        deliveries=state.deliveries + valid_idx.astype(jnp.int32),
+        deliveries=state.deliveries + handled.astype(jnp.int32),
         sched_hash=jnp.where(valid_idx, folded, state.sched_hash),
     )
+    if cfg.datagram:
+        state = state._replace(
+            dups=state.dups + (valid_idx & keep).astype(jnp.int32),
+            drops=state.drops + (valid_idx & discard).astype(jnp.int32),
+        )
     if cfg.track_fifo_heads:
         # Promote the consumed channel's successor: recompute head bits
         # for THIS channel only (O(P); the consumed entry may not have
@@ -836,7 +948,7 @@ def delivery_effects(
     # justScheduledTimers cleared + timersToResend flushed on non-timer
     # delivery, RandomScheduler.scala:100-117).
     delivered_timer = is_timer & valid_idx
-    cleared = valid_idx & ~is_timer
+    cleared = handled & ~is_timer
     timer_mem = jnp.where(
         cleared,
         jnp.zeros_like(state.timer_mem),
@@ -851,7 +963,7 @@ def delivery_effects(
         ops.set_scalar(state.timer_mem_valid, dst, True, delivered_timer, oh),
     )
     pool_parked = jnp.where(
-        valid_idx & ~is_timer, jnp.zeros_like(state.pool_parked), state.pool_parked
+        handled & ~is_timer, jnp.zeros_like(state.pool_parked), state.pool_parked
     )
     state = state._replace(
         timer_mem=timer_mem, timer_mem_valid=timer_mem_valid, pool_parked=pool_parked
@@ -860,6 +972,10 @@ def delivery_effects(
     rows = RowProposal(ob_valid, ob_src, ob_dst, ob_timer, ob_parked, ob_msg)
     if cfg.record_trace:
         kind = jnp.where(is_timer, REC_TIMER, REC_DELIVERY)
+        if cfg.datagram:
+            kind = jnp.where(
+                keep, REC_KEPT, jnp.where(discard, REC_DISCARDED, kind)
+            )
         parts = [jnp.stack([kind, src, dst]), msg]
         if cfg.record_parents:
             # Two HB columns: creation link (pool_crec) + program-order
@@ -871,7 +987,7 @@ def delivery_effects(
             parts.append(prev_rec[None])
             state = state._replace(
                 last_rec=ops.set_scalar(
-                    state.last_rec, dst, state.trace_len, valid_idx, oh
+                    state.last_rec, dst, state.trace_len, handled, oh
                 )
             )
         rec = jnp.concatenate(parts)

@@ -46,6 +46,7 @@ from .core import (
     DeviceConfig,
     ScheduleState,
     check_invariant,
+    datagram_refusal,
     deliver_index,
     deliverable_mask,
     init_state,
@@ -1072,6 +1073,12 @@ FIFO_REFUSAL = (
 )
 
 
+#: Why they turn a datagram app away.
+DATAGRAM_REFUSAL = datagram_refusal(
+    "DPOR (its racing analysis reverses two deliveries of two messages)"
+)
+
+
 class DeviceDPOR:
     """Frontier-batched DPOR driver: rounds of B prescriptions per kernel
     launch, deepest-first priority, explored-set dedup.
@@ -1116,6 +1123,8 @@ class DeviceDPOR:
         assert cfg.record_trace and cfg.record_parents
         if app.channels == "fifo":
             raise ValueError(FIFO_REFUSAL)
+        if app.channels == "datagram":
+            raise ValueError(DATAGRAM_REFUSAL)
         self.app = app
         self.cfg = cfg
         # Static may-commute relation resolved FIRST: the sleep-set

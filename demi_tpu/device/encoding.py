@@ -23,7 +23,9 @@ from ..events import (
     BeginWaitQuiescence,
     HardKillEvent,
     KillEvent,
+    MsgDiscarded,
     MsgEvent,
+    MsgKept,
     MsgSend,
     PartitionEvent,
     Quiescence,
@@ -55,8 +57,12 @@ from .core import (
     OP_UNPARTITION,
     OP_WAIT,
     OP_WAITCOND,
+    HASH_DISCARDED,
+    HASH_KEPT,
     REC_DELIVERY,
+    REC_DISCARDED,
     REC_EXT_BASE,
+    REC_KEPT,
     REC_NONE,
     REC_TIMER,
     REC_WILDCARD,
@@ -576,11 +582,50 @@ class CandidateLowerer:
 # Lifting device explore traces back to host EventTraces
 # ---------------------------------------------------------------------------
 
+_NET_STEPS = {REC_KEPT: "keep", REC_DISCARDED: "discard"}
+
+
+def host_sched_hash(app: DSLApp, trace: EventTrace) -> int:
+    """The device's ``sched_hash`` (``core.delivery_effects``' fold) of a
+    host trace's delivered sequence, a kept delivery and a discarded
+    message with their constants: a lifted lane's host execution gives
+    the hash the lane carries exactly when it delivered, kept and
+    discarded the same messages in the same order."""
+    mod = 1 << 32
+    powers = [pow(31, j, mod) for j in range(app.msg_width)]
+    h, kept = 0x811C9DC5, False
+    for u in trace.events:
+        ev = u.event
+        if isinstance(ev, MsgKept):
+            kept = True  # says so of the MsgEvent that follows
+            continue
+        if isinstance(ev, TimerDelivery):
+            src = dst = app.actor_id(ev.rcv)
+            extra = 0xC2B2AE35
+        elif isinstance(ev, (MsgEvent, MsgDiscarded)):
+            src = _actor_or_external(app, ev.snd)
+            dst = app.actor_id(ev.rcv)
+            extra = HASH_DISCARDED if isinstance(ev, MsgDiscarded) else (
+                HASH_KEPT if kept else 0
+            )
+            kept = False
+        else:
+            continue
+        row = _msg_row(app, ev.msg, app.msg_width)
+        mix = sum((int(x) % mod) * p for x, p in zip(row, powers))
+        mix += src * 0x9E3779B1 + dst * 0x85EBCA77 + extra
+        h = (h * 0x01000193 + mix) % mod
+    return h
+
+
 def device_trace_to_guide(
     app: DSLApp, records: np.ndarray, trace_len: int
 ) -> List[Tuple]:
     """Decode a device-recorded trace into a host guide: a list of
-    ("ext", op, a, b, msg) / ("deliver", src, dst, msg, is_timer) steps.
+    ("ext", op, a, b, msg) / ("deliver", src, dst, msg, is_timer) steps,
+    and over datagram channels ("keep", src, dst, msg, False) /
+    ("discard", src, dst, msg, False) for a delivery that left its message
+    pending and for a message lost undelivered.
     Accepts parent-tracked records (extra trailing column) transparently."""
     guide: List[Tuple] = []
     for i in range(int(trace_len)):
@@ -591,6 +636,8 @@ def device_trace_to_guide(
             continue
         if kind in (REC_DELIVERY, REC_TIMER):
             guide.append(("deliver", int(rec[1]), int(rec[2]), msg, kind == REC_TIMER))
+        elif kind in _NET_STEPS:
+            guide.append((_NET_STEPS[kind], int(rec[1]), int(rec[2]), msg, False))
         elif kind >= REC_EXT_BASE:
             guide.append(("ext", kind - REC_EXT_BASE, int(rec[1]), int(rec[2]), msg))
     return guide

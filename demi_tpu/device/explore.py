@@ -212,6 +212,32 @@ def _segment_cond_met(state: ScheduleState, app: DSLApp, dispatching):
     )
 
 
+def _datagram_outcome(state: ScheduleState, cfg: DeviceConfig, idx, key):
+    """``(keep, discard)`` for the delivery of pool entry ``idx`` over
+    datagram channels: one uniform read against the two weights. The
+    first ``dup_weight`` of it keeps the message pending while the lane's
+    ``max_dups`` lasts, the next ``drop_weight`` loses it while
+    ``max_drops`` lasts, the rest (and a draw whose budget is spent)
+    delivers as ever. Only an actor's message has an outcome: a timer and
+    an external send (the fuzzer can send twice itself) are delivered
+    exactly once. Host twin: ``RandomScheduler.choose_outcome``."""
+    oh = cfg.use_onehot
+    safe_idx = jnp.minimum(idx, cfg.pool_capacity - 1)
+    from_actor = (
+        (idx < cfg.pool_capacity)
+        & ~ops.get_scalar(state.pool_timer, safe_idx, oh)
+        & (ops.get_scalar(state.pool_src, safe_idx, oh) < cfg.num_actors)
+    )
+    u = jax.random.uniform(key)
+    keep = from_actor & (u < cfg.dup_weight) & (state.dups < cfg.max_dups)
+    discard = (
+        from_actor & (u >= cfg.dup_weight)
+        & (u < cfg.dup_weight + cfg.drop_weight)
+        & (state.drops < cfg.max_drops)
+    )
+    return keep, discard
+
+
 def make_step_fn(app: DSLApp, cfg: DeviceConfig):
     """The fused, branchless step: injection and dispatch effects are both
     computed with masks (inert op / invalid index for the inactive side) and
@@ -230,7 +256,12 @@ def make_step_fn(app: DSLApp, cfg: DeviceConfig):
     any lane of the batch insert more than ``INSERT_SHORT_ROWS`` rows in
     this step", is one scalar for the whole batch (a ``custom_vmap`` rule
     reduces it), so the compiled step holds a ``case`` whose cheap branch
-    serves every step in which no resident lane sends a wide outbox."""
+    serves every step in which no resident lane sends a wide outbox.
+
+    Over datagram channels (``cfg.datagram``) the dispatch side draws an
+    outcome beside the index (``_datagram_outcome``): deliver and consume,
+    deliver and keep, or discard. A Python gate, as ``timer_weight != 1.0``
+    is: any other app lowers to the program it always did."""
     init_states, initial_rows = _precomputed(app, cfg)
     oh = cfg.use_onehot
 
@@ -276,18 +307,26 @@ def make_step_fn(app: DSLApp, cfg: DeviceConfig):
             pick_timer = jax.random.uniform(sub2) < p_timer
             mask = jnp.where(pick_timer, tmask, mmask)
             count = jnp.where(pick_timer, tcount, mcount)
+        if cfg.datagram:
+            # The outcome's draw, split off as the class draw is.
+            sub, sub3 = ops.rng_split(sub)
         u = jax.random.uniform(sub)
         k = jnp.minimum((u * count).astype(jnp.int32), jnp.maximum(count - 1, 0))
         idx = ops.first_true_index(mask, k, oh)
         idx = jnp.where(
             any_deliverable & dispatching, idx, jnp.int32(cfg.pool_capacity)
         )
+        keep = discard = None
+        if cfg.datagram:
+            keep, discard = _datagram_outcome(state, cfg, idx, sub3)
         # rng advances only on dispatch steps (keeps the schedule stream
         # identical to the unfused kernel).
         state = state._replace(
             rng=jnp.where(dispatching, key, state.rng)
         )
-        state, del_rows, del_rec = delivery_effects(state, cfg, app, idx)
+        state, del_rows, del_rec = delivery_effects(
+            state, cfg, app, idx, keep, discard
+        )
 
         # ----- the ONE pool insert for both sides -------------------------
         rows = RowProposal.concat(inj_rows, del_rows)

@@ -33,6 +33,7 @@ from .core import (
     _append_record,
     check_invariant,
     deliverable_mask,
+    datagram_refusal,
     delivery_effects,
     external_effects,
     init_state,
@@ -73,6 +74,8 @@ def make_replay_record_fn(app: DSLApp, cfg: DeviceConfig):
     (state', peek_hit)`` shared by ``make_replay_run_lane`` and the
     prefix-fork trunk runner. ``cfg`` must be pre-normalized by
     ``_replay_cfg``."""
+    if app.channels == "datagram" or cfg.datagram:
+        raise ValueError(datagram_refusal("the device replay checker"))
     init_states, initial_rows = _precomputed(app, cfg)
     big = jnp.int32(2**30)
 

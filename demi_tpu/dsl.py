@@ -40,6 +40,13 @@ OUT_DST = 1
 OUT_MSG = 2
 
 
+#: ``DSLApp.channels`` and the host ``RandomScheduler`` strategy of each.
+_RANDOM_STRATEGY = {
+    "any": "fully_random", "fifo": "srcdst_fifo", "datagram": "datagram",
+}
+CHANNELS = tuple(_RANDOM_STRATEGY)
+
+
 @dataclass(frozen=True)
 class DSLApp:
     """A complete application-under-test definition."""
@@ -110,12 +117,22 @@ class DSLApp:
     # chooses it: ``DeviceConfig.for_workload`` derives ``srcdst_fifo``
     # from it, the host fuzz its ``RandomScheduler`` strategy, and the
     # guided replay refuses a delivery that is not its channel's oldest.
+    # Or "datagram": no order, and the network may deliver a pending
+    # message and keep it (a later delivery repeats it) or lose it
+    # (Lamport's model, an RPC layer that retries, UDP). Which, and how
+    # often, is the scheduler's choice: at every dispatch step one more
+    # draw is read against ``--dup-weight`` and ``--drop-weight`` under
+    # the budgets ``--max-dups`` and ``--max-drops``
+    # (``DeviceConfig.for_workload`` derives ``datagram`` and takes the
+    # four from the workload; the host ``RandomScheduler`` draws the same
+    # three outcomes). Only an actor's message is kept or lost: a timer
+    # and an external send are delivered exactly once.
     channels: str = "any"
 
     def __post_init__(self):
-        if self.channels not in ("any", "fifo"):
+        if self.channels not in CHANNELS:
             raise ValueError(
-                f"channels must be 'any' or 'fifo', got {self.channels!r}"
+                f"channels must be one of {CHANNELS}, got {self.channels!r}"
             )
         if self.invariant_at not in ("delivery", "quiescence"):
             raise ValueError(
@@ -144,7 +161,7 @@ class DSLApp:
     @property
     def random_strategy(self) -> str:
         """The host ``RandomScheduler``'s strategy for ``channels``."""
-        return "srcdst_fifo" if self.channels == "fifo" else "fully_random"
+        return _RANDOM_STRATEGY[self.channels]
 
     # -- naming ------------------------------------------------------------
     def actor_name(self, actor_id: int) -> str:

@@ -110,6 +110,29 @@ class TimerDelivery(Event):
 
 
 @dataclass(frozen=True)
+class MsgKept(Event):
+    """Datagram channels (``DSLApp.channels``): the network is about to
+    deliver this message and keeps it pending as well, so a later
+    delivery may repeat it. Recorded right before the delivery's
+    ``MsgEvent``, under the id of the copy that stays pending: every
+    delivery of the trace then has an id of its own."""
+
+    snd: str
+    rcv: str
+    msg: Any
+
+
+@dataclass(frozen=True)
+class MsgDiscarded(Event):
+    """Datagram channels: the network lost this pending message; it
+    reached no handler. Recorded under the lost message's id."""
+
+    snd: str
+    rcv: str
+    msg: Any
+
+
+@dataclass(frozen=True)
 class SpawnEvent(Event):
     parent: str
     name: str

@@ -45,6 +45,14 @@ FAULT_PLANE_DEFAULTS = {
     # spark's stages a job and tasks a stage.
     "stages": 2,
     "tasks": 4,
+    # The datagram discipline (``DSLApp.channels == "datagram"``; any
+    # other app refuses a non-zero weight): the share of dispatch steps
+    # that deliver an actor's message and keep it pending, the share that
+    # lose it undelivered, and how many of each a schedule may hold.
+    "dup_weight": 0.0,
+    "drop_weight": 0.0,
+    "max_dups": 0,
+    "max_drops": 0,
 }
 
 DEFAULT_WORKLOAD = {

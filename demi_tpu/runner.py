@@ -113,6 +113,7 @@ def fuzz(
     round_hook=None,
     on_violation=None,
     strategy: str = "fully_random",
+    **network,
 ) -> Optional[FuzzResult]:
     """Generate fuzz tests and run them until a violation is found
     (reference: RunnerUtils.fuzz, RunnerUtils.scala:62-147). With
@@ -142,7 +143,9 @@ def fuzz(
     flowed through the hook).
 
     ``strategy`` is the scheduler's (``DSLApp.random_strategy``: an app
-    whose channels are FIFO is fuzzed under ``"srcdst_fifo"``)."""
+    whose channels are FIFO is fuzzed under ``"srcdst_fifo"``, one whose
+    channels are datagram under ``"datagram"``, with ``network`` its
+    ``dup_weight``, ``drop_weight``, ``max_dups`` and ``max_drops``)."""
     sched = RandomScheduler(
         config,
         seed=seed,
@@ -150,6 +153,7 @@ def fuzz(
         invariant_check_interval=invariant_check_interval,
         timer_weight=timer_weight,
         strategy=strategy,
+        **network,
     )
     for i in range(start_execution, max_executions):
         if controller is not None:

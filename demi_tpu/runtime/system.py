@@ -21,6 +21,7 @@ network, vector clocks, and crash state.
 from __future__ import annotations
 
 import copy
+import dataclasses
 import hashlib
 import itertools
 import random as _random
@@ -335,6 +336,23 @@ class ControlledActorSystem:
     def inject_from(self, snd: str, rcv: str, msg: Any) -> PendingEntry:
         """Synthetic-endpoint traffic (failure detector, etc.)."""
         return PendingEntry(self.id_gen.next(), snd, rcv, msg, vc={})
+
+    # -- datagram channels (DSLApp.channels) -------------------------------
+    def keep(self, entry: PendingEntry) -> PendingEntry:
+        """The network delivers ``entry`` and keeps it: the copy that
+        stays pending, the same message under an id of its own (the
+        scheduler delivers ``entry`` itself next). Only an actor's
+        message is ever repeated."""
+        assert not entry.is_timer and not entry.is_external, entry
+        obs.counter("runtime.net.kept").inc()
+        return dataclasses.replace(entry, uid=self.id_gen.next())
+
+    def discard(self, entry: PendingEntry) -> None:
+        """The network loses the pending ``entry``: no handler runs, no
+        vector clock moves. The scheduler has taken it off its pending
+        structure; nothing of it is left here."""
+        assert not entry.is_timer and not entry.is_external, entry
+        obs.counter("runtime.net.discarded").inc()
 
     def _with_capture(self, name: str, fn: Callable[[Context], None]) -> List[PendingEntry]:
         # Clear before anything can raise, so deliver()'s crash path can

@@ -34,7 +34,9 @@ from ..events import (
     EndExternalAtomicBlock,
     HardKillEvent,
     KillEvent,
+    MsgDiscarded,
     MsgEvent,
+    MsgKept,
     MsgSend,
     PartitionEvent,
     Quiescence,
@@ -132,6 +134,13 @@ class BaseScheduler:
         for entry in self.pending_entries():
             if not entry.is_timer and frozenset((entry.snd, entry.rcv)) == link:
                 self.remove_pending(entry)
+
+    def choose_outcome(self, entry: PendingEntry) -> str:
+        """What the network does with the entry ``choose_next`` picked:
+        "deliver" (and consume: all that a network other than a datagram
+        one ever does), "keep" (deliver, and leave it pending) or
+        "discard" (lose it undelivered)."""
+        return "deliver"
 
     # Optional hooks ----------------------------------------------------
     def on_delivery(self, unique: Unique, entry: PendingEntry) -> None:
@@ -367,7 +376,12 @@ class BaseScheduler:
                 return None
             if entry is None:
                 return None
-            self._deliver(entry)
+            outcome = self.choose_outcome(entry)
+            if outcome == "discard":
+                # No handler ran: nothing to judge, no delivery counted.
+                self._discard(entry)
+                continue
+            self._deliver(entry, keep=outcome == "keep")
             if (
                 self.invariant_check_interval
                 and self.deliveries % self.invariant_check_interval == 0
@@ -376,8 +390,24 @@ class BaseScheduler:
                 if violation is not None:
                     return violation
 
-    def _deliver(self, entry: PendingEntry) -> None:
+    def _discard(self, entry: PendingEntry) -> None:
+        """Datagram channels: the network loses ``entry``, which the
+        policy has already taken off its pending structure."""
+        self.system.discard(entry)
+        self.trace.append(
+            Unique(MsgDiscarded(entry.snd, entry.rcv, entry.msg), entry.uid)
+        )
+
+    def _deliver(self, entry: PendingEntry, keep: bool = False) -> None:
         system = self.system
+        if keep:
+            # Datagram channels: the message stays pending as well, as a
+            # copy under an id of its own (no MsgSend: nobody sent it).
+            copy = system.keep(entry)
+            self.trace.append(
+                Unique(MsgKept(copy.snd, copy.rcv, copy.msg), copy.uid)
+            )
+            self.add_pending(copy)
         if entry.is_timer:
             unique = Unique(TimerDelivery(entry.rcv, entry.msg,
                                           self.config.fingerprinter.fingerprint(entry.msg)),

@@ -34,9 +34,11 @@ from ..device.core import (
 
 
 class GuideDivergence(Exception):
-    """A guide step had no matching pending entry on the host oracle, or
-    (an app whose channels are FIFO) delivers a message that is not its
-    channel's oldest pending one."""
+    """A guide step had no matching pending entry on the host oracle
+    (over datagram channels: a second delivery of a message that was
+    consumed, with no "keep" before it), or (an app whose channels are
+    FIFO) delivers a message that is not its channel's oldest pending
+    one, or keeps or discards what this network never would."""
 
 
 class GuidedScheduler(BaseScheduler):
@@ -69,7 +71,9 @@ class GuidedScheduler(BaseScheduler):
     # -- guided execution --------------------------------------------------
     def execute_guide(self, guide: Sequence[Tuple]) -> ExecutionResult:
         """guide: list of ("ext", op, a, b, msg) / ("deliver", src, dst, msg,
-        is_timer) from device_trace_to_guide."""
+        is_timer) from device_trace_to_guide; over datagram channels
+        also ("keep", ...) (deliver, and leave the message pending) and
+        ("discard", ...) (take it off the pending set undelivered)."""
         self.prepare([])
         externals: List[ExternalEvent] = []
         for step in guide:
@@ -80,14 +84,26 @@ class GuidedScheduler(BaseScheduler):
                     externals.append(ext)
                     self._inject_one(ext)
             else:
-                _, src, dst, msg, is_timer = step
+                kind, src, dst, msg, is_timer = step
                 entry = self._match(src, dst, msg, is_timer)
                 if entry is None:
                     raise GuideDivergence(f"no pending match for {step!r}")
+                if kind != "deliver" and (
+                    self.app.channels != "datagram"
+                    or entry.is_timer or entry.is_external
+                ):
+                    raise GuideDivergence(
+                        f"{step!r}: only an actor's message over datagram "
+                        f"channels is kept or discarded (this app's are "
+                        f"{self.app.channels!r})"
+                    )
                 self._pending.remove(entry)
                 if not self.system.deliverable(entry):
                     raise GuideDivergence(f"guide entry undeliverable: {step!r}")
-                self._deliver(entry)
+                if kind == "discard":
+                    self._discard(entry)
+                else:
+                    self._deliver(entry, keep=kind == "keep")
         self.trace.append(self._unique(Quiescence()))
         self.trace.set_original_externals(externals)
         self._current_externals = externals

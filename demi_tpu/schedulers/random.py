@@ -13,6 +13,10 @@ over:
   - Pluggable RandomizationStrategy: FullyRandom (uniform over the pending
     set) or SrcDstFIFO (per-(src,dst) FIFO queues = TCP-like semantics,
     random across pairs; RandomScheduler.scala:624-909).
+  - Beyond the reference: the "datagram" strategy (``DSLApp.channels``) is
+    FullyRandom's choice plus an outcome for each chosen message: deliver
+    and consume, deliver and keep pending, or discard undelivered
+    (``choose_outcome``; device twin: ``explore._datagram_outcome``).
 
 Randomness is an explicit seeded PRNG — the reference seeds from wall clock
 (Util.scala:110), which SURVEY.md §7.3 flags as a reproducibility bug to fix.
@@ -178,18 +182,31 @@ class RandomScheduler(BaseScheduler):
         invariant_check_interval: int = 0,
         strategy: str = "fully_random",
         timer_weight: float = 1.0,
+        dup_weight: float = 0.0,
+        drop_weight: float = 0.0,
+        max_dups: int = 0,
+        max_drops: int = 0,
     ):
         super().__init__(config, max_messages, invariant_check_interval)
         self.seed = seed
         self.strategy_name = strategy
         self.timer_weight = timer_weight
+        if strategy != "datagram" and (dup_weight or drop_weight):
+            raise ValueError(
+                "dup_weight and drop_weight need strategy='datagram' (an "
+                "app whose DSLApp.channels are 'datagram')"
+            )
+        # The datagram strategy's weights and per-execution budgets.
+        self.dup_weight, self.drop_weight = dup_weight, drop_weight
+        self.max_dups, self.max_drops = max_dups, max_drops
+        self.dups = self.drops = 0
         self.rng = _random.Random(seed)
         self.pending = self._make_strategy()
         self._just_delivered_timers: set = set()
         self._parked_timers: List[PendingEntry] = []
 
     def _make_strategy(self) -> RandomizationStrategy:
-        if self.strategy_name == "fully_random":
+        if self.strategy_name in ("fully_random", "datagram"):
             return FullyRandom(self.rng, timer_weight=self.timer_weight)
         if self.strategy_name == "srcdst_fifo":
             return SrcDstFIFO(self.rng)
@@ -201,6 +218,29 @@ class RandomScheduler(BaseScheduler):
         self.pending = self._make_strategy()
         self._just_delivered_timers = set()
         self._parked_timers = []
+        self.dups = self.drops = 0
+
+    def choose_outcome(self, entry: PendingEntry) -> str:
+        """The datagram strategy's draw, one for every chosen entry as
+        the device step draws one (``explore._datagram_outcome``): the
+        first ``dup_weight`` of it keeps an actor's message pending while
+        ``max_dups`` lasts, the next ``drop_weight`` loses it while
+        ``max_drops`` lasts. A timer and an external send are delivered
+        exactly once."""
+        if self.strategy_name != "datagram":
+            return "deliver"
+        u = self.rng.random()
+        if entry.is_timer or entry.is_external:
+            return "deliver"
+        if u < self.dup_weight:
+            if self.dups < self.max_dups:
+                self.dups += 1
+                return "keep"
+        elif u < self.dup_weight + self.drop_weight:
+            if self.drops < self.max_drops:
+                self.drops += 1
+                return "discard"
+        return "deliver"
 
     def add_pending(self, entry: PendingEntry) -> None:
         if entry.is_timer:

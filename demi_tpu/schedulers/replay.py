@@ -35,7 +35,9 @@ from ..events import (
     Event,
     HardKillEvent,
     KillEvent,
+    MsgDiscarded,
     MsgEvent,
+    MsgKept,
     MsgSend,
     PartitionEvent,
     Quiescence,
@@ -187,6 +189,9 @@ class TraceFollowingScheduler(BaseScheduler):
         self.ignored_absent = []
         self._unignorable_depth = 0
         self._next_external_uid: Optional[int] = None
+        # Datagram channels: a recorded MsgKept says the next MsgEvent's
+        # message stays pending.
+        self._keep_next = False
 
     def add_pending(self, entry: PendingEntry) -> None:
         self.rpending.add(entry, external_uid=self._next_external_uid)
@@ -274,6 +279,15 @@ class TraceFollowingScheduler(BaseScheduler):
                 self._next_external_uid = exp.id
                 self._record_send(entry)
             # internal sends re-occur as delivery side effects; skip.
+        elif isinstance(event, MsgKept):
+            self._keep_next = True
+        elif isinstance(event, MsgDiscarded):
+            # Datagram channels: the recorded run lost this message.
+            entry = self.rpending.pop_internal(event.snd, event.rcv, event.msg)
+            if entry is None:
+                self._handle_absent(exp)
+            else:
+                self._discard(entry)
         elif isinstance(event, MsgEvent):
             self._replay_delivery(exp, event)
         elif isinstance(event, TimerDelivery):
@@ -320,11 +334,12 @@ class TraceFollowingScheduler(BaseScheduler):
             and not (event.is_external and not isinstance(event.msg, WildCardMatch))
         ):
             entry = self._peek(exp, event)
+        keep, self._keep_next = self._keep_next, False
         if entry is None:
             self._handle_absent(exp)
             return
         if self.system.deliverable(entry):
-            self._deliver(entry)
+            self._deliver(entry, keep=keep)
         # Undeliverable (partitioned/killed receiver): dropped, as recorded
         # kills/partitions dictate.
 

@@ -39,7 +39,9 @@ from .events import (
     Event,
     HardKillEvent,
     KillEvent,
+    MsgDiscarded,
     MsgEvent,
+    MsgKept,
     MsgSend,
     PartitionEvent,
     Quiescence,
@@ -69,6 +71,8 @@ from .trace import EventTrace
 _EVENT_TYPES = {
     "msg_send": MsgSend,
     "msg_event": MsgEvent,
+    "msg_kept": MsgKept,
+    "msg_discarded": MsgDiscarded,
     "timer_delivery": TimerDelivery,
     "spawn": SpawnEvent,
     "kill": KillEvent,
@@ -126,6 +130,11 @@ def _event_to_json(u: Unique) -> Dict[str, Any]:
         rec.update(type="msg_send", snd=e.snd, rcv=e.rcv, msg=_msg_to_json(e.msg))
     elif isinstance(e, MsgEvent):
         rec.update(type="msg_event", snd=e.snd, rcv=e.rcv, msg=_msg_to_json(e.msg))
+    elif isinstance(e, (MsgKept, MsgDiscarded)):
+        rec.update(
+            type="msg_kept" if isinstance(e, MsgKept) else "msg_discarded",
+            snd=e.snd, rcv=e.rcv, msg=_msg_to_json(e.msg),
+        )
     elif isinstance(e, TimerDelivery):
         rec.update(type="timer_delivery", rcv=e.rcv, msg=_msg_to_json(e.msg))
     elif isinstance(e, SpawnEvent):
@@ -163,8 +172,8 @@ def _event_from_json(rec: Dict[str, Any], app: Optional[DSLApp]) -> Unique:
     t = rec["type"]
     if t == "msg_send":
         e: Event = MsgSend(rec["snd"], rec["rcv"], _msg_from_json(rec["msg"]))
-    elif t == "msg_event":
-        e = MsgEvent(rec["snd"], rec["rcv"], _msg_from_json(rec["msg"]))
+    elif t in ("msg_event", "msg_kept", "msg_discarded"):
+        e = _EVENT_TYPES[t](rec["snd"], rec["rcv"], _msg_from_json(rec["msg"]))
     elif t == "timer_delivery":
         e = TimerDelivery(rec["rcv"], _msg_from_json(rec["msg"]))
     elif t == "spawn":

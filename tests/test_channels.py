@@ -196,7 +196,7 @@ def test_the_dpor_verb_refuses_it_in_one_sentence():
 
 
 def test_the_cli_knows_the_app():
-    with pytest.raises(SystemExit, match="chain, raft"):
+    with pytest.raises(SystemExit, match="chain, paxos, raft"):
         cli.main(["sweep", "--app", "nosuch"])
 
 

@@ -481,8 +481,8 @@ def test_one_lane_alone_equals_its_lane_of_a_batch():
         alone = one(state._replace(insert_full_steps=jnp.int32(0)), rows)
         assert int(alone.insert_full_steps) == 1
         for field in type(alone)._fields:
-            if field == "insert_full_steps":
-                continue
+            if field == "insert_full_steps" or getattr(alone, field) is None:
+                continue  # (a leaf only a datagram kernel carries)
             assert np.array_equal(
                 np.asarray(getattr(alone, field)),
                 np.asarray(getattr(batch, field))[lane],
