@@ -1149,13 +1149,19 @@ class ContinuousSweepDriver:
                                 # taken: of the steps the retired lanes
                                 # were scanned, those in which some
                                 # resident lane sent more rows than it
-                                # holds (``core.insert_rows``).
-                                obs.stage_count(
-                                    "sweep.insert_full_steps",
-                                    int(np.asarray(
-                                        state.insert_full_steps
-                                    )[fin].sum()),
-                                )
+                                # holds, and those in which the lane
+                                # itself went through the full pass
+                                # (``core.insert_rows``).
+                                for name, leaf in (
+                                    ("insert_full_steps",
+                                     state.insert_full_steps),
+                                    ("insert_full_lane_steps",
+                                     state.insert_full_lane_steps),
+                                ):
+                                    obs.stage_count(
+                                        f"sweep.{name}",
+                                        int(np.asarray(leaf)[fin].sum()),
+                                    )
                                 obs.stage_count(
                                     "sweep.insert_steps",
                                     int(steps_run[fin].sum()),

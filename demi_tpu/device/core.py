@@ -373,6 +373,12 @@ class ScheduleState(NamedTuple):
     # built (``_short_insert_built``): the smaller shapes' compiled
     # programs are what they were.
     insert_full_steps: Optional[jnp.ndarray] = None  # int32
+    # Those of them in which THIS lane's rows went through the full pass:
+    # it sent more than the short pass holds (alone behind the batch's
+    # short pass, or with the whole batch once more than
+    # ``INSERT_BURST_LANES`` lanes burst together). None where the count
+    # above is.
+    insert_full_lane_steps: Optional[jnp.ndarray] = None  # int32
     # Datagram channels: deliveries of this lane that left their message
     # pending, and messages it lost undelivered (the budgets ``max_dups``
     # and ``max_drops`` are held against them). No leaves (None) for any
@@ -387,6 +393,7 @@ def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
         [np.asarray(app.init_state(i), np.int32) for i in range(n)]
     )
     trace_shape = (cfg.trace_rows, cfg.rec_width) if cfg.record_trace else (0, 0)
+    insert_count = jnp.int32(0) if _short_insert_built(cfg) else None
     return ScheduleState(
         actor_state=jnp.asarray(init_states),
         started=jnp.zeros(n, bool),
@@ -418,9 +425,8 @@ def init_state(app: DSLApp, cfg: DeviceConfig, key) -> ScheduleState:
         rng=key,
         trace=jnp.zeros(trace_shape, jnp.int32),
         trace_len=jnp.int32(0),
-        insert_full_steps=(
-            jnp.int32(0) if _short_insert_built(cfg) else None
-        ),
+        insert_full_steps=insert_count,
+        insert_full_lane_steps=insert_count,
         dups=jnp.int32(0) if cfg.datagram else None,
         drops=jnp.int32(0) if cfg.datagram else None,
     )
@@ -510,13 +516,34 @@ def alive_mask(state: ScheduleState) -> jnp.ndarray:
 # 16 rows spare the same steps: 4 read 1 to 3% faster than 8 there
 # (PERF.md, PR 37); 8 serves a protocol whose usual outbox is a handful
 # of rows.
+#
+# A lane that inserts more is a burst (a paxos adoption's 160 P2A rows, a
+# spark stage launch, a chain repair): about one lane of the resident set
+# in such a step, and since PR 45 only the bursting lanes go through the
+# full [K, P] pass, one at a time behind the batch's short pass, while at
+# most INSERT_BURST_LANES of them burst in one step; past that the whole
+# batch takes the full pass as before (a refill wave, the flood's lanes
+# that start their floods together). Measured on the v5e, the insert's
+# passes alone, microseconds a step (PERF.md, PR 45): at the paxos
+# cell's [167, 1024] x 69 columns and 256 lanes the batched full pass
+# 2,054 and a trip of the loop 22.7 (the loop is the cheaper up to 68
+# bursting lanes); spark's [402, 1024] x 4, 256 lanes: 295 and 4.2 (55);
+# chain's [67, 256] x 4, 2,048 lanes: 295 and 2.7 (83); the flood's
+# [65, 4608] x 3, 128 lanes: 113 and 4.6 (13). No rule on the batch size
+# fits the four (13 of 128, 68 of 256, 83 of 2,048), so one constant
+# under the lowest: a step with 9 to 13 bursting lanes is rarer than one
+# in a million where a lane bursts in one step of 300, and a wave is
+# hundreds. In the paxos cell 8 and 256 read the same (77.17 and 76.43
+# schedules/s; the batched pass for all: 49.69).
 INSERT_SHORT_ROWS = 8
 INSERT_SHORT_FACTOR = 4
+INSERT_BURST_LANES = 8
 
 
 def _short_insert_built(cfg: DeviceConfig) -> bool:
-    """Whether a lane of ``cfg`` carries ``insert_full_steps``: the step's
-    insert holds an outbox and at least one injected row."""
+    """Whether a lane of ``cfg`` carries ``insert_full_steps`` and
+    ``insert_full_lane_steps``: the step's insert holds an outbox and at
+    least one injected row."""
     return cfg.use_onehot and (
         cfg.max_outbox + 1 > INSERT_SHORT_FACTOR * INSERT_SHORT_ROWS
     )
@@ -581,26 +608,83 @@ def _landed_short(want, prefix, cols):
     [C, P] compare, each summed over an axis, whose operands the compiler
     first transposes to lane-minor; the same with the columns stacked;
     and a 0/1 table times the columns' bytes on the MXU: PERF.md, PR 37)."""
+    # ``lax`` primitives, the compares hoisted out of the columns: the
+    # same selects and sums as ``jnp.where`` / ``jnp.sum`` give (XLA sees
+    # one program), traced at a fraction of the cost: 8 ranks x 69 columns
+    # x 3 ``jnp`` calls are each a jitted wrapper's Python path, and the
+    # batch rule traces this pass in two regions of its ``case``: the
+    # second copy cost the paxos cell 11 s of set-up on the chip's host
+    # (PERF.md, PR 45).
+    ranks = range(1, INSERT_SHORT_ROWS + 1)
+    at_row = [want == rank for rank in ranks]
+    at_slot = [prefix == rank for rank in ranks]
+    no_row, nothing = jnp.zeros_like(want), jnp.zeros_like(prefix)
     out = []
     for col in cols:
-        landed = jnp.zeros_like(prefix)
-        for rank in range(1, INSERT_SHORT_ROWS + 1):
-            row = jnp.sum(jnp.where(want == rank, col, 0))
-            landed = jnp.where(prefix == rank, row, landed)
+        landed = nothing
+        for row_is, slot_is in zip(at_row, at_slot):
+            row = jax.lax.reduce(
+                jax.lax.select(row_is, col, no_row), np.int32(0),
+                jax.lax.add, (0,),
+            )
+            landed = jax.lax.select(
+                slot_is, jax.lax.broadcast(row, prefix.shape), landed
+            )
         out.append(landed)
     return tuple(out)
 
 
+def _landed_burst_lanes(want, prefix, burst, cols):
+    """A batch whose lanes ``burst`` ([B] bool) insert more rows than the
+    short pass holds: the short pass for every lane, then the bursting
+    lanes one at a time through ``_landed_full`` (a loop of as many trips
+    as lanes burst: a lane's [K] and [P] rows taken by a dynamic slice on
+    the batch axis, a [K, P] compare with no batch beside it, its [P]
+    results written over the lane's rows of the landed columns, in place
+    in the loop's carry). Ranked on the v5e against a fixed sub-batch of
+    C lanes gathered the same way, one ``vmap(_landed_full)`` over
+    [C, K, P], written back, which pays C lanes' work where one bursts:
+    paxos cell 77.17 schedules/s against 69.13 (C = 2) and 72.29 (C = 4),
+    spark cell 401.4 against 384.6 (C = 2), the flood's 165.6 against
+    164.3 (PERF.md, PR 45)."""
+    landed = jax.vmap(_landed_short)(want, prefix, cols)
+    lanes = jnp.arange(burst.shape[0])
+
+    def trip(_, carry):
+        left, landed = carry
+        lane = jnp.argmax(left)
+
+        def take(x):
+            return jax.lax.dynamic_index_in_dim(x, lane, 0, keepdims=False)
+
+        one = _landed_full(
+            take(want), take(prefix), tuple(take(col) for col in cols)
+        )
+        landed = tuple(
+            jax.lax.dynamic_update_index_in_dim(whole, row, lane, 0)
+            for whole, row in zip(landed, one)
+        )
+        return left & (lanes != lane), landed
+
+    n_burst = jnp.sum(burst.astype(jnp.int32))
+    return jax.lax.fori_loop(0, n_burst, trip, (burst, landed))[1]
+
+
 @jax.custom_batching.custom_vmap
 def _landed_by_batch(want, prefix, n_rows, cols):
-    """``(_landed_full(...), 1)``: one lane alone takes the full pass. A
-    batch of lanes (the rule below) takes the short one in every step
-    where none of them inserts more than it holds, and says which it
-    took. Under ``vmap`` a ``lax.cond`` on a lane's own count would run
-    both branches; this predicate is one scalar for the whole batch, so
-    the compiled step holds a real ``case``: the one place a vmapped
-    kernel branches."""
-    return _landed_full(want, prefix, cols), jnp.int32(1)
+    """``(_landed_full(...), 1, 1)``: one lane alone takes the full pass.
+    A batch of lanes (the rule below) takes the short one in every step
+    where none of them inserts more than it holds; where up to
+    ``INSERT_BURST_LANES`` of them do, the short one and then the full
+    one for those lanes alone; past that the full one for all. It says
+    whether the batch left the short pass, and which lanes took the full
+    one. Under ``vmap`` a ``lax.cond`` on a lane's own count would run
+    both branches; this index is one scalar for the whole batch, so the
+    compiled step holds a real ``case``: the one place a vmapped kernel
+    branches. (What the chip said of the limit: the comment over
+    ``INSERT_SHORT_ROWS``; of the loop against a fixed sub-batch:
+    ``_landed_burst_lanes``.)"""
+    return _landed_full(want, prefix, cols), jnp.int32(1), jnp.int32(1)
 
 
 @_landed_by_batch.def_vmap
@@ -611,13 +695,27 @@ def _landed_by_batch_rule(axis_size, in_batched, *args):
         ),
         args, tuple(in_batched),
     )
-    full = jnp.max(n_rows) > INSERT_SHORT_ROWS
-    landed = jax.lax.cond(
-        full, jax.vmap(_landed_full), jax.vmap(_landed_short),
-        want, prefix, cols,
+    burst = n_rows > INSERT_SHORT_ROWS
+    n_burst = jnp.sum(burst.astype(jnp.int32))
+    some = n_burst > 0
+    many = n_burst > INSERT_BURST_LANES
+
+    def every_lane(landed):
+        return lambda want, prefix, burst, cols: jax.vmap(landed)(
+            want, prefix, cols
+        )
+
+    landed = jax.lax.switch(
+        some.astype(jnp.int32) + many.astype(jnp.int32),
+        (
+            every_lane(_landed_short), _landed_burst_lanes,
+            every_lane(_landed_full),
+        ),
+        want, prefix, burst, cols,
     )
-    took = jnp.broadcast_to(full.astype(jnp.int32), (axis_size,))
-    return (landed, took), ((True,) * len(cols), True)
+    took = jnp.broadcast_to(some.astype(jnp.int32), (axis_size,))
+    took_lane = (burst | many).astype(jnp.int32)
+    return (landed, took, took_lane), ((True,) * len(cols), True, True)
 
 
 def insert_rows(
@@ -713,11 +811,14 @@ def insert_rows(
             ]
             if per_row_crec:
                 cols.append(jnp.asarray(crec, jnp.int32))
-            cols, took_full = _landed_by_batch(
+            cols, took_full, took_full_lane = _landed_by_batch(
                 want, prefix, n_rows, tuple(cols)
             )
             state = state._replace(
-                insert_full_steps=state.insert_full_steps + took_full
+                insert_full_steps=state.insert_full_steps + took_full,
+                insert_full_lane_steps=(
+                    state.insert_full_lane_steps + took_full_lane
+                ),
             )
             word = cols[0]
 

@@ -252,11 +252,15 @@ def make_step_fn(app: DSLApp, cfg: DeviceConfig):
     pass and both cond selects.
 
     The step's one real branch is inside that insert, and only where it
-    carries many rows (``core._short_insert_built``): its predicate, "does
-    any lane of the batch insert more than ``INSERT_SHORT_ROWS`` rows in
-    this step", is one scalar for the whole batch (a ``custom_vmap`` rule
-    reduces it), so the compiled step holds a ``case`` whose cheap branch
-    serves every step in which no resident lane sends a wide outbox.
+    carries many rows (``core._short_insert_built``): its index, "how many
+    lanes of the batch insert more than ``INSERT_SHORT_ROWS`` rows in this
+    step: none, up to ``INSERT_BURST_LANES``, more", is one scalar for the
+    whole batch (a ``custom_vmap`` rule counts them), so the compiled step
+    holds a ``case`` of three regions: the short pass serves every step in
+    which no resident lane sends a wide outbox; where a few do, the short
+    pass serves the batch and a loop takes those lanes, one at a time,
+    through the full [K, pool] pass; only where many burst in one step
+    does the whole batch pay it.
 
     Over datagram channels (``cfg.datagram``) the dispatch side draws an
     outcome beside the index (``_datagram_outcome``): deliver and consume,

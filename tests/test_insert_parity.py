@@ -10,12 +10,16 @@ every field of the resulting state equal, over random pools and proposals.
 Where the insert carries many rows (``core._short_insert_built``: the
 flood's and the spark DAG's shapes) a batch of lanes takes a short pass
 over the first ``INSERT_SHORT_ROWS`` valid rows in every step where no
-lane inserts more, picked by one ``lax.cond`` for the whole batch: the
+lane inserts more, picked by one ``lax.switch`` for the whole batch: the
 second half holds that pass to the scatter insert and to the full pass
 over where the rows sit and how many there are, and holds the compiled
-segment to one ``case`` there and none at raft's shape. The last tests
-hold the passes down: the lowered one-hot insert compares over ``[K, P]``
-once (a branch, where there are two).
+segment to one ``case`` there and none at raft's shape. Where up to
+``INSERT_BURST_LANES`` lanes insert more, only they are then taken through
+the full pass, one at a time; past that the whole batch is: the third part
+holds the three branches to each other and the two counts a lane carries
+to what was taken. The last tests hold the passes down: the lowered
+one-hot insert compares over ``[K, P]`` once a branch (over one lane's in
+the loop, over the batch's past the limit, never on the short pass).
 
 Outside the short pass a slot reads its payload as a whole row
 (``core._landed_rows``: K selects over ``[P, W]``, no column of the rows
@@ -36,12 +40,14 @@ import pytest
 from demi_tpu import obs
 from demi_tpu.apps.broadcast import make_broadcast_app
 from demi_tpu.apps.common import dsl_start_events
+from demi_tpu.apps.paxos import make_paxos_app
 from demi_tpu.apps.raft import make_raft_app
 from demi_tpu.apps.spark_dag import make_spark_app
 from demi_tpu.apps.vsr import make_vsr_app
 from demi_tpu.device.continuous import make_init_kernel, make_segment_kernel
 from demi_tpu.device.core import (
-    INSERT_SHORT_FACTOR, INSERT_SHORT_ROWS, ST_OVERFLOW, DeviceConfig,
+    INSERT_BURST_LANES, INSERT_SHORT_FACTOR, INSERT_SHORT_ROWS, ST_DONE,
+    ST_OVERFLOW, DeviceConfig, _landed_by_batch, _landed_full,
     _short_insert_built, init_state, insert_form, insert_rows,
 )
 from demi_tpu.device.dpor_sweep import build_dpor_kernel
@@ -71,6 +77,9 @@ SHAPES = {
     "vsr5-p256": (lambda nodes: make_vsr_app(nodes, log_cap=32), 5, 256, 9),
     # a width between raft's 7 and VSR's 37
     "vsr5-w16-p96": (lambda nodes: make_vsr_app(nodes, log_cap=11), 5, 96, 9),
+    # the paxos cell's class: a 43-row insert of 20-word rows, so that
+    # the payload's columns are most of the short pass's work
+    "paxos11-p128": (lambda nodes: make_paxos_app(nodes, log_cap=8), 11, 128, None),
 }
 C = INSERT_SHORT_ROWS
 FILLS = (0.0, 0.3, 0.83, 1.0)
@@ -174,7 +183,10 @@ def _with_count(states, cfg):
     if not _short_insert_built(cfg):
         return states
     lanes = states.status.shape[0]
-    return states._replace(insert_full_steps=jnp.zeros(lanes, jnp.int32))
+    zeros = jnp.zeros(lanes, jnp.int32)
+    return states._replace(
+        insert_full_steps=zeros, insert_full_lane_steps=zeros
+    )
 
 
 def _insert_both(cfgs, cases):
@@ -196,7 +208,7 @@ def _insert_both(cfgs, cases):
 def _assert_same(out, what, modes=("scatter", "onehot")):
     a, b = (out[m] for m in modes)
     for field in type(a)._fields:
-        if field == "insert_full_steps":  # the one-hot insert's alone
+        if field.startswith("insert_full_"):  # the one-hot insert's alone
             continue
         x, y = np.asarray(getattr(a, field)), np.asarray(getattr(b, field))
         assert x.dtype == y.dtype, f"{what}: {field} dtype"
@@ -464,6 +476,125 @@ def test_the_overflow_edge_on_the_short_pass(shape, extra):
     assert np.asarray(out["onehot"].pool_valid).all()
 
 
+# -- the bursting lanes alone ----------------------------------------------
+
+BURST_SHAPES = RANKED_SHAPES + ["paxos11-p128"]
+BURST_LANES = INSERT_BURST_LANES + 3
+# how many lanes of the batch insert more than the short pass holds
+BURSTS = {
+    "no-lane-bursts": 0,
+    "one-lane-bursts": 1,
+    "as-many-as-the-loop-takes": INSERT_BURST_LANES,
+    "one-more-than-the-loop-takes": INSERT_BURST_LANES + 1,
+    "every-lane-bursts": BURST_LANES,
+}
+
+
+def _burst_check(shape, bursting, seed=53, *, overflowing=(), frozen=()):
+    """A batch of BURST_LANES lanes of which ``bursting`` (lane numbers)
+    insert more rows than the short pass holds and the others at most
+    that, through the scatter insert, the one-hot insert that picks its
+    pass, and the one-hot insert's full pass: all three equal field for
+    field, and the lanes' two counts say which pass the batch and each
+    lane took. ``overflowing`` lanes have fewer free slots than rows;
+    ``frozen`` ones are done and insert nothing."""
+    app, cfgs = _cfgs(shape)
+    k = _rows_k(shape, app)
+    assert k > INSERT_SHORT_FACTOR * C
+    rng = np.random.default_rng(seed)
+    cases = []
+    for lane in range(BURST_LANES):
+        if lane in frozen:
+            n_rows = 0
+        elif lane in bursting:
+            n_rows = int(rng.integers(C + 1, k + 1))
+        else:
+            n_rows = int(rng.integers(0, C + 1))
+        state, rows = _ranked_case(
+            app, cfgs["scatter"], rng, k, n_rows, PLACES[lane % len(PLACES)],
+            n_free=max(n_rows - 3, 0) if lane in overflowing else None,
+        )
+        if lane in frozen:
+            state = state._replace(status=jnp.int32(ST_DONE))
+        cases.append((state, rows))
+    out = _insert_both(cfgs, cases)
+    what = f"{shape} bursting {sorted(bursting)}"
+    _assert_same(out, what)
+    _assert_same(out, what + " (full pass)", modes=("scatter", "onehot-full"))
+    burst = np.isin(np.arange(BURST_LANES), sorted(bursting))
+    past = len(bursting) > INSERT_BURST_LANES
+    np.testing.assert_array_equal(
+        out["onehot"].insert_full_steps,
+        np.full(BURST_LANES, int(bool(bursting))), what,
+    )
+    np.testing.assert_array_equal(
+        out["onehot"].insert_full_lane_steps, (burst | past).astype(int), what
+    )
+    return out, cases
+
+
+@pytest.mark.parametrize("bursts", list(BURSTS))
+@pytest.mark.parametrize("shape", BURST_SHAPES)
+def test_the_bursting_lanes_alone_take_the_full_pass(shape, bursts):
+    rng = np.random.default_rng(59)
+    bursting = set(rng.permutation(BURST_LANES)[: BURSTS[bursts]].tolist())
+    out, _ = _burst_check(shape, bursting)
+    # Something landed in every lane that sent rows.
+    assert np.asarray(out["onehot"].pool_valid).any()
+
+
+@pytest.mark.parametrize("shape", BURST_SHAPES)
+def test_a_bursting_lane_that_overflows_its_pool(shape):
+    out, _ = _burst_check(shape, {2, 5}, overflowing={5})
+    status = np.asarray(out["onehot"].status)
+    assert status[5] == ST_OVERFLOW and (np.delete(status, 5) != ST_OVERFLOW).all()
+    assert np.asarray(out["onehot"].pool_valid)[5].all()
+
+
+@pytest.mark.parametrize("shape", BURST_SHAPES)
+def test_a_bursting_lane_next_to_a_frozen_one(shape):
+    out, cases = _burst_check(shape, {3}, frozen={2, 4})
+    for lane in (2, 4):
+        assert int(out["onehot"].status[lane]) == ST_DONE
+        for field in ("pool_valid", "pool_src", "pool_dst", "pool_msg",
+                      "pool_seq", "seq_counter"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(out["onehot"], field))[lane],
+                getattr(cases[lane][0], field), field,
+            )
+
+
+@pytest.mark.parametrize("n_burst", [0, 1, INSERT_BURST_LANES,
+                                     INSERT_BURST_LANES + 1])
+def test_the_batch_rule_gives_the_full_pass_values(n_burst):
+    """``_landed_by_batch`` under ``vmap`` against ``vmap(_landed_full)``
+    alone, column for column, on ranks and columns made by hand (K 40,
+    P 96, 5 columns): whichever branch the count of bursting lanes
+    picks."""
+    b, k, p, ncols = INSERT_BURST_LANES + 2, 40, 96, 5
+    rng = np.random.default_rng(61 + n_burst)
+    n_rows = rng.integers(0, C + 1, b)
+    n_rows[rng.permutation(b)[:n_burst]] = rng.integers(C + 1, k + 1, n_burst)
+    valid = np.zeros((b, k), bool)
+    for lane in range(b):
+        valid[lane, rng.permutation(k)[: n_rows[lane]]] = True
+    want = jnp.asarray(np.where(valid, np.cumsum(valid, axis=1), -1), jnp.int32)
+    prefix = jnp.asarray(np.cumsum(rng.random((b, p)) < 0.6, axis=1), jnp.int32)
+    cols = tuple(
+        jnp.asarray(rng.integers(-(2**31), 2**31 - 1, (b, k)), jnp.int32)
+        for _ in range(ncols)
+    )
+    landed, took, took_lane = jax.jit(jax.vmap(_landed_by_batch))(
+        want, prefix, jnp.asarray(n_rows, jnp.int32), cols
+    )
+    for got, full in zip(landed, jax.vmap(_landed_full)(want, prefix, cols)):
+        np.testing.assert_array_equal(got, full)
+    assert (np.asarray(took) == int(n_burst > 0)).all()
+    np.testing.assert_array_equal(
+        took_lane, (n_rows > C) | (n_burst > INSERT_BURST_LANES)
+    )
+
+
 def test_one_lane_alone_equals_its_lane_of_a_batch():
     """The unbatched call (the single-lane ``run_lane`` of the checks and
     lifts) takes the full pass and gives what the batch gave its lane."""
@@ -478,10 +609,17 @@ def test_one_lane_alone_equals_its_lane_of_a_batch():
     assert (np.asarray(batch.insert_full_steps) == 0).all()
     one = jax.jit(lambda s, r: insert_rows(s, cfg, *r))
     for lane, (state, rows) in enumerate(cases):
-        alone = one(state._replace(insert_full_steps=jnp.int32(0)), rows)
+        alone = one(
+            state._replace(
+                insert_full_steps=jnp.int32(0),
+                insert_full_lane_steps=jnp.int32(0),
+            ),
+            rows,
+        )
         assert int(alone.insert_full_steps) == 1
+        assert int(alone.insert_full_lane_steps) == 1
         for field in type(alone)._fields:
-            if field == "insert_full_steps" or getattr(alone, field) is None:
+            if field.startswith("insert_full_") or getattr(alone, field) is None:
                 continue  # (a leaf only a datagram kernel carries)
             assert np.array_equal(
                 np.asarray(getattr(alone, field)),
@@ -502,8 +640,8 @@ def _segment_text(shape, lanes=4):
 
 
 @pytest.mark.parametrize("shape,cases", [
-    ("spark5-p256", 1), ("bcast64-p4608", 1), ("raft5-p96", 0),
-    ("bcast8-p96", 0),
+    ("spark5-p256", 1), ("bcast64-p4608", 1), ("paxos11-p128", 1),
+    ("raft5-p96", 0), ("bcast8-p96", 0),
 ])
 def test_the_segment_branches_once_where_the_short_pass_is_built(shape, cases):
     """``jit(vmap(scan(step)))`` as the continuous driver compiles it: one
@@ -513,8 +651,14 @@ def test_the_segment_branches_once_where_the_short_pass_is_built(shape, cases):
     text, state = _segment_text(shape)
     assert text.count("stablehlo.case") == cases
     assert (state.insert_full_steps is not None) == bool(cases)
+    assert (state.insert_full_lane_steps is not None) == bool(cases)
     if cases:  # the branch index is a scalar, not a value a lane
         assert re.search(r"\}\) : \(tensor<i32>\) -> ", text)
+        # three regions: the short pass, the short pass and a loop over
+        # the bursting lanes, the full pass
+        regions = _case_regions(text)
+        assert len(regions) == 3
+        assert ["stablehlo.while" in r for r in regions] == [False, True, False]
 
 
 # -- the passes do not come back -------------------------------------------
@@ -529,28 +673,37 @@ def _batched_insert(cfg, app, k, lanes=2, crec=None):
     return jax.vmap(lambda s, r: insert_rows(s, cfg, *r)), states, rows
 
 
+def _case_regions(text):
+    """The regions of the lowered text's one ``case``, in branch order."""
+    start = text.index("stablehlo.case")
+    regions = [[]]
+    for line in text[start:].splitlines()[1:]:
+        if line.strip().startswith("}, {"):
+            regions.append([])
+        elif line.strip().startswith("}) :"):
+            break
+        else:
+            regions[-1].append(line)
+    return ["\n".join(r) for r in regions]
+
+
 def _kp_compares(cfg, app, k, lanes=2):
-    """How many compare ops over [K, P] the lowered insert of a batch
-    holds: outside any branch, and in each branch of its ``case`` (none
-    where the short pass is not built)."""
+    """How many compare ops over the batch's [B, K, P] the lowered insert
+    of a batch holds outside any branch and in each branch of its
+    ``case`` (none where the short pass is not built), and how many over
+    one lane's [K, P] (``lane-branch<i>``: the loop's)."""
     fn, states, rows = _batched_insert(cfg, app, k, lanes)
     text = jax.jit(fn).lower(states, rows).as_text()
     p = cfg.pool_capacity
-    pattern = re.compile(
-        rf"stablehlo\.compare.*->\s*tensor<{lanes}x{k}x{p}xi1>"
-    )
-    counts = {"outside": 0}
-    branch = None  # the region of the case a line is in
-    for line in text.splitlines():
-        if "stablehlo.case" in line:
-            branch = 0
-        elif branch is not None and line.strip().startswith("}, {"):
-            branch += 1
-        elif branch is not None and line.strip().startswith("}) :"):
-            branch = None
-        if pattern.search(line):
-            where = "outside" if branch is None else f"branch{branch}"
-            counts[where] = counts.get(where, 0) + 1
+    batch = re.compile(rf"stablehlo\.compare.*->\s*tensor<{lanes}x{k}x{p}xi1>")
+    lane = re.compile(rf"stablehlo\.compare.*->\s*tensor<{k}x{p}xi1>")
+    regions = _case_regions(text) if "stablehlo.case" in text else []
+    inside = sum(len(batch.findall(r)) for r in regions)
+    counts = {"outside": len(batch.findall(text)) - inside}
+    for i, region in enumerate(regions):
+        for name, pattern in ((f"branch{i}", batch), (f"lane-branch{i}", lane)):
+            if pattern.findall(region):
+                counts[name] = len(pattern.findall(region))
     return counts, text
 
 
@@ -561,16 +714,21 @@ def _kp_compares(cfg, app, k, lanes=2):
 def test_the_onehot_insert_compares_over_rows_and_slots_once(fifo, outside):
     app, cfgs = _cfgs("bcast64-p4608", srcdst_fifo=fifo)
     counts, text = _kp_compares(cfgs["onehot"], app, 65)
-    # lax.cond's false branch comes first: the short pass compares over
-    # [K] and [P], a rank at a time, and never over [K, P]; the full pass
-    # once.
-    assert counts == {"outside": outside, "branch1": 1}
+    # The short pass compares over [K] and [P], a rank at a time, and
+    # never over [K, P]; the loop over the bursting lanes once over one
+    # lane's [K, P], behind the short pass; the full pass once over the
+    # batch's.
+    assert counts == {"outside": outside, "lane-branch1": 1, "branch2": 1}
     assert text.count("stablehlo.case") == 1
-    short = text[text.index("stablehlo.case"):].split("}, {")[0]
-    for width in (65, 4608):
-        assert len(re.findall(
-            rf"stablehlo\.compare.*->\s*tensor<2x{width}xi1>", short
-        )) >= C
+    # Both regions that take the short pass compare rank by rank: once a
+    # rank over [K] and over [P], whatever the columns (the second of
+    # them before its loop).
+    regions = _case_regions(text)
+    for short in (regions[0], regions[1].split("stablehlo.while")[0]):
+        for width in (65, 4608):
+            assert len(re.findall(
+                rf"stablehlo\.compare.*->\s*tensor<2x{width}xi1>", short
+            )) == C
 
 
 @pytest.mark.parametrize("fifo,expected", [(False, 1), (True, 3)])

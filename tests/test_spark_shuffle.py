@@ -504,37 +504,49 @@ def test_stale_task_is_still_found_and_is_the_reference_without_the_check():
 
 # -- the one-hot insert's short pass over a whole run -------------------------
 
-def test_a_whole_run_is_the_same_on_the_short_pass_as_on_the_scatter_insert():
-    """17 actors, 2 stages x 40 tasks (an 82-row insert: the short pass is
-    built) through the continuous driver: the path a TPU runs, where a step
-    of the resident set takes the insert's short pass unless some lane
-    launches a stage, gives every lane's status, code and sequence hash, and
-    every pool row entered (``seq_counter``, summed at the retire), as the
-    scatter insert does; and it counts the steps that took the full pass."""
+@pytest.fixture(scope="module")
+def short_pass_runs():
+    """``{mode: (rows, counts)}``: 48 schedules of 17 actors, 2 stages x
+    40 tasks (an 82-row insert: the short pass is built) through the
+    continuous driver, 16 resident, in the scatter lowering a CPU takes
+    and the one-hot lowering a TPU takes."""
     from demi_tpu import obs
     from demi_tpu.device.continuous import ContinuousSweepDriver
 
     app, cfg, fuzzer = build_workload(dict(DAG5, nodes=17, stages=2))
     assert (cfg.num_actors, cfg.max_outbox) == (17, 81)
-    lanes, resident = 48, 16
     got = {}
     for mode in ("scatter", "onehot"):
         driver = ContinuousSweepDriver(
             app, dataclasses.replace(cfg, index_mode=mode),
             lambda s: fuzzer.generate_fuzz_test(seed=s),
-            batch=resident, seg_steps=32, seed_pure=True,
+            batch=16, seg_steps=32, seed_pure=True,
         )
         obs.disable()
         obs.TRACER.clear()
         obs.enable()
         try:
-            rows = sorted(driver._run(lanes))
+            rows = sorted(driver._run(48))
             counts = obs.stage_counts()
         finally:
             obs.disable()
             obs.TRACER.clear()
         got[mode] = rows, counts
-    (rows, counts), (want_rows, want_counts) = got["onehot"], got["scatter"]
+    return got
+
+
+def test_a_whole_run_is_the_same_on_the_short_pass_as_on_the_scatter_insert(
+    short_pass_runs,
+):
+    """The path a TPU runs, where a step of the resident set takes the
+    insert's short pass unless some lane launches a stage, gives every
+    lane's status, code and sequence hash, and every pool row entered
+    (``seq_counter``, summed at the retire), as the scatter insert does;
+    and it counts the steps that took the full pass."""
+    lanes = 48
+    (rows, counts), (want_rows, want_counts) = (
+        short_pass_runs["onehot"], short_pass_runs["scatter"]
+    )
     assert rows == want_rows
     assert [r[0] for r in rows] == list(range(lanes))
     assert any(r[2] == 0 and r[1] == 2 for r in rows)  # jobs ran to their end
@@ -545,3 +557,25 @@ def test_a_whole_run_is_the_same_on_the_short_pass_as_on_the_scatter_insert():
     assert 0 < counts["sweep.insert_full_steps"] < counts["sweep.insert_steps"]
     assert counts["sweep.insert_steps"] >= counts["sweep.lane_steps"] // 2
     assert "sweep.insert_steps" not in want_counts
+
+
+def test_only_the_launching_lanes_take_the_full_pass(short_pass_runs):
+    """Of the steps in which some resident lane launches a stage, each
+    lane is counted in those where it launched one itself (alone behind
+    the batch's short pass: never 9 lanes of 16 in one step here), and
+    the rows, violating lanes and sequence hashes are the scatter
+    lowering's all the same."""
+    (rows, counts), (want_rows, want_counts) = (
+        short_pass_runs["onehot"], short_pass_runs["scatter"]
+    )
+    assert [(r[0], r[2], r[3]) for r in rows] == [
+        (r[0], r[2], r[3]) for r in want_rows
+    ]
+    assert any(r[2] != 0 for r in rows)  # a violating lane among them
+    assert (
+        0 < counts["sweep.insert_full_lane_steps"]
+        < counts["sweep.insert_full_steps"]
+    )
+    # a lane launches each of its two stages once, or twice after a loss
+    assert counts["sweep.insert_full_lane_steps"] >= 48
+    assert "sweep.insert_full_lane_steps" not in want_counts
