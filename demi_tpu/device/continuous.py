@@ -14,29 +14,63 @@ step stream depends only on its own state/key, frozen lanes are no-ops,
 and refill replaces whole lanes atomically (tests/test_continuous.py).
 
 One round of the harvest loop (``_run_batches``), in order: dispatch the
-segment (asynchronous) -> MAKE AHEAD -> status pull (the sync point) ->
-harvest -> fill -> refill. Which lane gets which program is
+segment (asynchronous) and, right behind it, the finalize of the lanes
+that spend their last step in it -> MAKE AHEAD -> status pull (the sync
+point) -> harvest -> fill -> refill. Which lane gets which program is
 decided after the harvest, but what the coming programs are is a
 function of ``seed_list[next_idx:]`` alone, so between the dispatch and
 the pull the one host thread fuzzes and lowers them into a stock, in seed
 order, while the device runs the segment. It stops at the first of: the
-segment's result is ready (asked once a program); the stock holds as
-many programs as lanes are active (no round can refill more, so the
+awaited segment's result is ready (asked once a program); the stock holds
+as many programs as lanes are active (no round can refill more, so the
 host never holds more than one resident set); the call's seeds are used
 up. The fill hands out the stock first and makes the rest on the spot,
 so lane, seed, program and key pair up exactly as without it.
+
+THE LAG (PR 46). In that order nothing is in the device's queue while
+the host harvests: every round the chip sits through the pull's round
+trip, the harvest, the fill and the next dispatch. Where a schedule
+lives long enough (``_LAG_LIFE``; ``_lag``: a rule on ``cfg.max_steps``
+over ``seg_steps``, nothing sets it) the same statements run with the
+harvest ONE SEGMENT BEHIND the device: the round dispatches segment k+1
+on the state segment k will leave, and only then waits for segment k,
+harvests its finished lanes from that state's own leaves, fills, and
+queues ``refill`` behind segment k+1. Exact, because a lane with
+``status >= ST_DONE`` is a frozen no-op in every step
+(``explore.make_step_fn``) and ``finalize`` is the identity on it:
+merging the fresh lane after segment k+1 gives what merging before it
+would have, one segment later. A refilled lane's ``steps_run`` is
+zeroed when its refill is queued, so its first segment counts from 0;
+the next pull reads the merged state, so no lane is seen finished
+twice; the harvest always reads segment k, never "whichever is ready",
+so which lane a seed lands in and the order of the yielded batches are
+functions of the seeds and the sizes alone, as before. The price is one
+frozen segment a schedule (between the segment a lane finishes in and
+the one its refill lands behind), so ``live_lane_steps`` counts a
+finished lane out of the segment in flight at its retire; and one
+segment is in flight when the last lane retires or a consumer stops:
+its result is dropped, and the next call starts from its own state.
+With no lag the order is strict: the harvest reads the segment it just
+dispatched. The budget path pulls nothing under either order: which
+lanes exhaust ``cfg.max_steps`` in a segment follows from ``steps_run``,
+which the host holds.
 
 The resident programs are ONE set of host arrays (``op/a/b [b, E]``,
 ``msg [b, E, W]``: the ``ExtProgram`` every segment takes), allocated
 once a call and written in place: a fill lowers each program straight
 into its lane's rows (``encoding.lower_into``; a fuzzed program from its
 op rows, with no event object), so a retired program's memory serves
-the next and nothing is stacked. They are written only between a status
-pull and the next dispatch. The stock is a second such block, so what is
-made while the device may still read the resident set touches none of
-it (the CPU backend may alias NumPy memory); the refill copies the
-stock's rows onto the refilled lanes in one indexed assignment per
-array. The stock
+the next and nothing is stacked. A FILL WRITES ONLY ROWS OF LANES THAT
+EVERY DISPATCHED SEGMENT NOT YET PULLED HOLDS FROZEN: with no lag it
+writes between a status pull and the next dispatch; under the lag,
+while segment k+1, dispatched with these same arrays, may still read
+them, it writes the rows of lanes that finished in segment k, which
+segment k+1 steps as no-ops whatever their rows hold
+(``tests/test_continuous.py`` runs such a segment on garbage). The
+stock is a second such block, so what is made while the device may
+still read the resident set touches none of it (the CPU backend may
+alias NumPy memory); the refill copies the stock's rows onto the
+refilled lanes in one indexed assignment per array. The stock
 is a local of one ``_run_batches`` call: a caller's generator may change
 between calls (the benchmark's closes over a per-job base), and a
 consumer that stops early just drops it. Only a generator the
@@ -46,7 +80,8 @@ ahead; any other is called at refill, in refill order.
 PRODUCER PROCESSES (PR 42). Where the making is what a call waits for,
 it is done by child processes beside the host thread, and the round is
 what is left: dispatch -> status pull -> harvest -> fill (one indexed
-copy per array out of the ring) -> refill. Whether it is, the call
+copy per array out of the ring) -> refill (the lag moves the pull and
+what follows it one segment back, as above). Whether it is, the call
 observes; nothing sets it. (1) The prime fill makes its first ``_PROBE``
 programs on the spot under the clock pair every program runs under:
 the programs the call still has to make, at that cost, must give each
@@ -306,6 +341,19 @@ _RING_SETS = 2
 # Seconds of making a producer must have ahead of it: several forks at
 # the chip host's 45-75 ms each (PERF.md, PR 42).
 _WORTH_S = 0.25
+# Segments a schedule's step budget must hold (``cfg.max_steps`` over
+# ``seg_steps``) for the harvest to run one segment behind the device
+# (module doc, THE LAG). It trades one frozen segment a schedule, between
+# the one a lane finishes in and the one its refill lands behind, for a
+# device that never waits for the host's round: a sixteenth of the chip's
+# work at 16, a quarter at 4. Engaged: the flood (72 segments a life),
+# spark (52), chain (32), paxos (64), deep raft and VSR (16) cells.
+# Bypassed: ``raft5-sweep`` and its ``-x4`` (4), whose every fifth
+# segment would be idle. (On the chip the deep raft and VSR cells, which
+# sit on it, gain 21% and 9%; forced on in ``raft5-sweep`` it gained
+# 16%, the host bounding that job too: 4 is the next PR's to claim with
+# the x4 cell measured. PERF.md, PR 46.)
+_LAG_LIFE = 16
 _STARVE_DEADLINE_S = 2.0    # a wait for one chunk, before its child is given up
 _POLL_S = 1e-4
 # The shortest fork this process has timed: what a fork costs it. One
@@ -720,6 +768,29 @@ class ContinuousSweepDriver:
         if self.last_occupancy is not None:
             obs.gauge("device.continuous.occupancy").set(self.last_occupancy)
 
+    def _lag(self) -> int:
+        """How many segments the harvest runs behind the device: 1 where
+        a schedule's budget is ``_LAG_LIFE`` segments or more, else 0
+        (the constant's comment has the trade)."""
+        return int(self.cfg.max_steps >= _LAG_LIFE * self.seg_steps)
+
+    def _samples(self, state):
+        """A traced job's reduces over the state a segment left,
+        dispatched behind it and pulled at its harvest: each lane's
+        valid pool rows ``[B]``; the FIFO discipline's two counts
+        ``[2]``; the app's progress counts ``[B, names]`` (a finished
+        lane's rows stay as the segment left them, whatever is merged
+        into the batch later); None for what the kernel or the app has
+        not."""
+        return (
+            self._occupancy(state.pool_valid),
+            self._fifo_rows(
+                state.status, state.pool_valid, state.pool_timer,
+                state.pool_head,
+            ) if self._fifo_rows else None,
+            self._progress(state.actor_state) if self._progress else None,
+        )
+
     def _memo_hit(self, seed: int):
         """The lowered program the ``program_key`` memo holds for
         ``seed``, or None (no memo, or not lowered yet)."""
@@ -965,6 +1036,16 @@ class ContinuousSweepDriver:
         live_lane_steps = 0
         total_lane_steps = 0
 
+        def count_steps(scanned: int, live: int) -> None:
+            nonlocal total_lane_steps, live_lane_steps
+            total_lane_steps += scanned
+            live_lane_steps += live
+            self.last_occupancy = live_lane_steps / total_lane_steps
+            self.last_total_lane_steps = total_lane_steps
+            self.last_live_lane_steps = live_lane_steps
+            obs.stage_count("sweep.lane_steps", scanned)
+            obs.stage_count("sweep.live_lane_steps", live)
+
         def keys_for(seeds):
             return self._vkeys(jnp.asarray(seeds, jnp.uint32))
 
@@ -1020,34 +1101,59 @@ class ContinuousSweepDriver:
             self.last_unfinished_lanes = 0
             sample_pool = obs.spans.live()
             self.last_pool_peak = 0 if sample_pool else None
+        seg_steps, max_steps = self.seg_steps, self.cfg.max_steps
+        lag = self._lag()
+        # The status the segment before left, for ``sweep.segments_queued``,
+        # and what was sampled behind it (a traced job's: below).
+        landed = samples = None
         while done_count < total_lanes:
             with obs.span("sweep.round"):
+                # Lanes the host has not seen finish count as live; the
+                # retire takes back those the lag's segment held frozen.
                 n_active = int(active.sum())
-                round_live = n_active * self.seg_steps
-                total_lane_steps += b * self.seg_steps
-                live_lane_steps += round_live
-                self.last_occupancy = live_lane_steps / total_lane_steps
-                self.last_total_lane_steps = total_lane_steps
-                self.last_live_lane_steps = live_lane_steps
-                obs.stage_count("sweep.lane_steps", b * self.seg_steps)
-                obs.stage_count("sweep.live_lane_steps", round_live)
+                count_steps(b * seg_steps, n_active * seg_steps)
+                if obs.spans.live():
+                    # (no probe while nothing counts: a dispatch's cost)
+                    obs.stage_count("sweep.segments")
+                    obs.stage_count(
+                        "sweep.segments_queued",
+                        landed is not None and not _ready(landed),
+                    )
+                # What the round harvests under the lag: the state as
+                # the round before left it (segment k, its finalize and
+                # the refill behind it), segment k's samples, the steps
+                # its lanes had run.
+                ended = steps_run
+                held = (state, samples, ended) if lag else None
                 t_seg = time.perf_counter()
                 with obs.span("sweep.block"):
                     state = self.segment(
                         state, progs, jnp.asarray(steps_run, jnp.int32)
                     )
-                    occupancy = (
-                        self._occupancy(state.pool_valid)
-                        if sample_pool else None
-                    )
-                    fifo_rows = (
-                        self._fifo_rows(
-                            state.status, state.pool_valid,
-                            state.pool_timer, state.pool_head,
-                        )
-                        if sample_pool and self._fifo_rows else None
-                    )
+                    landed = state.status
+                    # A traced job's samples, dispatched behind the
+                    # segment and pulled at its harvest: each lane's
+                    # valid rows, the FIFO discipline's two counts, the
+                    # app's progress counts.
+                    samples = self._samples(state) if sample_pool else None
                 t_gap = time.perf_counter()
+                steps_run = np.minimum(ended + seg_steps, max_steps)
+                # Budget exhaustion (the plain kernel's run-out-of-steps
+                # semantics), queued behind the segment with no pull:
+                # which lanes spend their last step in it follows from
+                # their counts, and ``finalize`` is the identity on a
+                # lane that finished on its own.
+                spent = active & (ended < max_steps) & (
+                    steps_run >= max_steps
+                )
+                if spent.any():
+                    with obs.span("sweep.finalize"):
+                        state = self.refill(
+                            state, jnp.asarray(spent), self.finalize(state)
+                        )
+                harvested, sampled, steps_ended = held or (
+                    state, samples, steps_run
+                )
                 if type(stock) is _Stock and exposed_ns >= _WORTH_S * 1e9:
                     # The making has cost what the forks will: fork,
                     # while the device runs the segment.
@@ -1058,7 +1164,8 @@ class ContinuousSweepDriver:
                     ) or stock
                 if type(stock) is _Stock:
                     self._make_ahead(
-                        state.status, seed_list, next_idx, n_active, stock
+                        harvested.status, seed_list, next_idx, n_active,
+                        stock,
                     )
                 t_pull = time.perf_counter()
                 with obs.span("sweep.block"):
@@ -1066,8 +1173,9 @@ class ContinuousSweepDriver:
                     # and the wait here are device-segment time; what
                     # was made in the gap, and the rest of the
                     # iteration, is harvest.
-                    _status_sync = np.asarray(state.status)
-                    if sample_pool:
+                    status = np.asarray(harvested.status)
+                    if sampled:
+                        occupancy, fifo_rows, progress = sampled
                         self.last_pool_peak = max(
                             self.last_pool_peak,
                             int(np.asarray(occupancy).max()),
@@ -1082,40 +1190,23 @@ class ContinuousSweepDriver:
                     from ..parallel.mesh import lane_sharding_summary
 
                     self.last_lane_sharding = lane_sharding_summary(
-                        state.status
+                        harvested.status
                     )
                 self.last_segment_seconds += (t_gap - t_seg) + (
                     t_harvest - t_pull
                 )
-                steps_run = np.minimum(
-                    steps_run + self.seg_steps, self.cfg.max_steps
-                )
-                # Budget exhaustion: force-finalize overdue live lanes
-                # (the plain kernel's run-out-of-steps semantics).
-                status = _status_sync
-                overdue = (
-                    active & (status < ST_DONE)
-                    & (steps_run >= self.cfg.max_steps)
-                )
-                if overdue.any():
-                    with obs.span("sweep.finalize"):
-                        finalized = self.finalize(state)
-                        state = self.refill(
-                            state, jnp.asarray(overdue), finalized
-                        )
-                        status = np.asarray(state.status)
                 finished = active & (status >= ST_DONE)
-                out = None
+                out, refill_lanes = None, ()
                 if finished.any():
                     with obs.span("sweep.pull"):
-                        vio = np.asarray(state.violation)
-                        sh = np.asarray(state.sched_hash)
+                        vio = np.asarray(harvested.violation)
+                        sh = np.asarray(harvested.sched_hash)
                         if obs.enabled():
                             # Round-granularity lane telemetry: the
                             # status pull above is the round's one sync
                             # point; deliveries ride the same harvest
                             # (never per segment step).
-                            self._record_round_stats(state, finished, vio)
+                            self._record_round_stats(harvested, finished, vio)
                     with obs.span("sweep.retire"):
                         fin = np.flatnonzero(finished)
                         # Seeds gathered BEFORE refill rewrites lane_seed.
@@ -1125,26 +1216,29 @@ class ContinuousSweepDriver:
                             sh[fin].copy(),
                         )
                         done_count += len(fin)
+                        # (frozen through the segment in flight)
+                        count_steps(0, -lag * len(fin) * seg_steps)
                         unfinished = int(
                             (out[1] == ST_UNFINISHED).sum()
                         )
                         self.last_unfinished_lanes += unfinished
-                        if sample_pool:
+                        if sampled:
                             # What the retired lanes put in their pools
                             # (every insert advances ``seq_counter`` by
                             # its rows) against the outbox rows their
                             # deliveries carried through the insert: a
                             # [B] pull each, beside the status pull.
-                            obs.stage_count(
-                                "sweep.rows_inserted",
-                                int(np.asarray(state.seq_counter)[fin].sum()),
-                            )
+                            # The batch-level counts advance in frozen
+                            # lanes too: all are read from the state
+                            # that finished the lane.
+                            rows = np.asarray(harvested.seq_counter)[fin]
+                            obs.stage_count("sweep.rows_inserted", rows.sum())
+                            rows = np.asarray(harvested.deliveries)[fin]
                             obs.stage_count(
                                 "sweep.outbox_rows",
-                                int(np.asarray(state.deliveries)[fin].sum())
-                                * self.cfg.max_outbox,
+                                int(rows.sum()) * self.cfg.max_outbox,
                             )
-                            if state.insert_full_steps is not None:
+                            if harvested.insert_full_steps is not None:
                                 # How often the insert's short pass is
                                 # taken: of the steps the retired lanes
                                 # were scanned, those in which some
@@ -1154,9 +1248,9 @@ class ContinuousSweepDriver:
                                 # (``core.insert_rows``).
                                 for name, leaf in (
                                     ("insert_full_steps",
-                                     state.insert_full_steps),
+                                     harvested.insert_full_steps),
                                     ("insert_full_lane_steps",
-                                     state.insert_full_lane_steps),
+                                     harvested.insert_full_lane_steps),
                                 ):
                                     obs.stage_count(
                                         f"sweep.{name}",
@@ -1164,28 +1258,26 @@ class ContinuousSweepDriver:
                                     )
                                 obs.stage_count(
                                     "sweep.insert_steps",
-                                    int(steps_run[fin].sum()),
+                                    int(steps_ended[fin].sum()),
                                 )
-                            if state.dups is not None:
+                            if harvested.dups is not None:
                                 # Datagram channels: what the network
                                 # did to the retired lanes' messages, a
                                 # [B] pull each (``deliveries`` counts a
                                 # kept delivery, not a discarded one).
                                 for name, leaf in (
-                                    ("delivered", state.deliveries),
-                                    ("kept", state.dups),
-                                    ("discarded", state.drops),
+                                    ("delivered", harvested.deliveries),
+                                    ("kept", harvested.dups),
+                                    ("discarded", harvested.drops),
                                 ):
                                     obs.stage_count(
                                         f"sweep.net.{name}",
                                         int(np.asarray(leaf)[fin].sum()),
                                     )
-                            if self._progress is not None:
+                            if progress is not None:
                                 # What the retired lanes' protocol got
                                 # done: one [B, names] pull.
-                                done = np.asarray(
-                                    self._progress(state.actor_state)
-                                )[fin].sum(axis=0)
+                                done = np.asarray(progress)[fin].sum(axis=0)
                                 for (name, _fn), total in zip(
                                     self.app.progress, done.tolist()
                                 ):
@@ -1207,37 +1299,43 @@ class ContinuousSweepDriver:
                         )
                         for lane in np.flatnonzero(finished):
                             active[lane] = False
-                    if refill_lanes:
-                        fresh_seeds = seed_list[
-                            next_idx : next_idx + len(refill_lanes)
-                        ]
-                        next_idx += len(refill_lanes)
-                        # Ascending, as the loop below hands the seeds out.
-                        spent = self._fill(
-                            fresh_seeds, sorted(refill_lanes), progs, stock
+                # The harvest is over: what it read goes before the
+                # refill builds a fresh state beside the one in flight.
+                held = harvested = sampled = None
+                if refill_lanes:
+                    fresh_seeds = seed_list[
+                        next_idx : next_idx + len(refill_lanes)
+                    ]
+                    next_idx += len(refill_lanes)
+                    # Ascending, as the loop below hands the seeds
+                    # out. Under the lag the segment in flight took
+                    # these same arrays: it holds every one of
+                    # these lanes frozen (module doc).
+                    spent_ns = self._fill(
+                        fresh_seeds, sorted(refill_lanes), progs, stock
+                    )
+                    if type(stock) is _Stock:
+                        exposed_ns += spent_ns
+                    with obs.span("sweep.refill"):
+                        mask = np.zeros(b, bool)
+                        full_seeds = []
+                        k = 0
+                        for lane in range(b):
+                            if lane in refill_lanes and k < len(
+                                fresh_seeds
+                            ):
+                                mask[lane] = True
+                                lane_seed[lane] = fresh_seeds[k]
+                                full_seeds.append(fresh_seeds[k])
+                                active[lane] = True
+                                steps_run[lane] = 0
+                                k += 1
+                            else:
+                                full_seeds.append(lane_seed[lane])
+                        state = self.refill(
+                            state, jnp.asarray(mask),
+                            self.init(keys_for(full_seeds)),
                         )
-                        if type(stock) is _Stock:
-                            exposed_ns += spent
-                        with obs.span("sweep.refill"):
-                            mask = np.zeros(b, bool)
-                            full_seeds = []
-                            k = 0
-                            for lane in range(b):
-                                if lane in refill_lanes and k < len(
-                                    fresh_seeds
-                                ):
-                                    mask[lane] = True
-                                    lane_seed[lane] = fresh_seeds[k]
-                                    full_seeds.append(fresh_seeds[k])
-                                    active[lane] = True
-                                    steps_run[lane] = 0
-                                    k += 1
-                                else:
-                                    full_seeds.append(lane_seed[lane])
-                            fresh = self.init(keys_for(full_seeds))
-                            state = self.refill(
-                                state, jnp.asarray(mask), fresh
-                            )
                 self.last_harvest_seconds += time.perf_counter() - t_harvest
             # Yield outside every span, and after the timing stop: caller
             # time (a generator consumer may do arbitrary work per item)

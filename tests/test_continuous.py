@@ -31,6 +31,24 @@ def _parity(app, cfg, gen, n, batch, seg_steps):
     return violations
 
 
+def _raft_fixture(max_steps):
+    app = make_raft_app(3, bug="multivote")
+    cfg = DeviceConfig.for_app(
+        app, pool_capacity=96, max_steps=max_steps, max_external_ops=24,
+        invariant_interval=1, timer_weight=0.1,
+    )
+    fz = Fuzzer(
+        num_events=10,
+        weights=FuzzerWeights(
+            send=0.3, kill=0.1, wait_quiescence=0.3, hard_kill=0.15,
+            restart=0.15,
+        ),
+        message_gen=raft_send_generator(app),
+        prefix=dsl_start_events(app), max_kills=2, wait_budget=(5, 30),
+    )
+    return app, cfg, lambda s: fz.generate_fuzz_test(seed=s)
+
+
 def test_continuous_matches_plain_kernel_broadcast():
     app = make_broadcast_app(4, reliable=False)
     cfg = DeviceConfig.for_app(
@@ -51,21 +69,7 @@ def test_continuous_matches_plain_kernel_broadcast():
 def test_continuous_matches_plain_kernel_raft_faults():
     """Mixed-length lanes (full drains vs quick crashes) + the forced
     finalization path for budget-exhausted lanes."""
-    app = make_raft_app(3, bug="multivote")
-    cfg = DeviceConfig.for_app(
-        app, pool_capacity=96, max_steps=160, max_external_ops=24,
-        invariant_interval=1, timer_weight=0.1,
-    )
-    fz = Fuzzer(
-        num_events=10,
-        weights=FuzzerWeights(
-            send=0.3, kill=0.1, wait_quiescence=0.3, hard_kill=0.15,
-            restart=0.15,
-        ),
-        message_gen=raft_send_generator(app),
-        prefix=dsl_start_events(app), max_kills=2, wait_budget=(5, 30),
-    )
-    _parity(app, cfg, lambda s: fz.generate_fuzz_test(seed=s), 24, 8, 32)
+    _parity(*_raft_fixture(160), 24, 8, 32)
 
 
 def test_continuous_nondivisible_seg_steps():
@@ -73,21 +77,7 @@ def test_continuous_nondivisible_seg_steps():
     clamp each lane exactly at the step budget (advisor repro: raft
     multivote, max_steps=40, seg_steps=28 — seed 59 diverged before the
     per-lane budget mask)."""
-    app = make_raft_app(3, bug="multivote")
-    cfg = DeviceConfig.for_app(
-        app, pool_capacity=96, max_steps=40, max_external_ops=24,
-        invariant_interval=1, timer_weight=0.1,
-    )
-    fz = Fuzzer(
-        num_events=10,
-        weights=FuzzerWeights(
-            send=0.3, kill=0.1, wait_quiescence=0.3, hard_kill=0.15,
-            restart=0.15,
-        ),
-        message_gen=raft_send_generator(app),
-        prefix=dsl_start_events(app), max_kills=2, wait_budget=(5, 30),
-    )
-    _parity(app, cfg, lambda s: fz.generate_fuzz_test(seed=s), 64, 8, 28)
+    _parity(*_raft_fixture(40), 64, 8, 28)
 
 
 def test_sweep_driver_continuous_parity_and_occupancy():
@@ -97,21 +87,8 @@ def test_sweep_driver_continuous_parity_and_occupancy():
     occupancy must stay high (the whole point of the refill)."""
     from demi_tpu.parallel.sweep import SweepDriver
 
-    app = make_raft_app(3, bug="multivote")
-    cfg = DeviceConfig.for_app(
-        app, pool_capacity=96, max_steps=160, max_external_ops=24,
-        invariant_interval=1, timer_weight=0.1,
-    )
-    fz = Fuzzer(
-        num_events=10,
-        weights=FuzzerWeights(
-            send=0.3, kill=0.1, wait_quiescence=0.3, hard_kill=0.15,
-            restart=0.15,
-        ),
-        message_gen=raft_send_generator(app),
-        prefix=dsl_start_events(app), max_kills=2, wait_budget=(5, 30),
-    )
-    driver = SweepDriver(app, cfg, lambda s: fz.generate_fuzz_test(seed=s))
+    app, cfg, gen = _raft_fixture(160)
+    driver = SweepDriver(app, cfg, gen)
     cont = driver.sweep(48, 8)  # default mode: continuous
     chunked = driver.sweep(48, 8, mode="chunked")
     assert cont.occupancy is not None and cont.occupancy > 0.5
@@ -644,3 +621,321 @@ def test_a_made_ahead_program_never_touches_the_resident_arrays(
         want = lower_program(drv.app, drv.cfg, in_place.gen(seed))
         for arr, ref in zip(resident, want):
             assert np.array_equal(arr[lane], ref)
+
+
+# -- PR 46: the harvest runs one segment behind the device -------------------
+
+import contextlib
+import dataclasses
+
+from demi_tpu import obs
+from demi_tpu.device.core import ST_DONE, ST_UNFINISHED
+from demi_tpu.parallel.sweep import lanes_digest
+
+_NEVER = 1 << 30     # a ``_LAG_LIFE`` no budget reaches: the strict order
+
+
+@contextlib.contextmanager
+def _spans_live():
+    """The stage tables, empty and recording, for the block's sweeps."""
+    obs.disable()
+    obs.TRACER.clear()
+    obs.enable()
+    try:
+        yield
+    finally:
+        obs.disable()
+        obs.TRACER.clear()
+
+
+def _short_broadcast_fixture():
+    """The broadcast app judges at quiescence: on 20 steps two floods in
+    three are cut by the budget and end with no verdict."""
+    app, cfg, gen = _broadcast_fixture()
+    assert app.invariant_at == "quiescence" and cfg.invariant_interval == 0
+    return app, dataclasses.replace(cfg, max_steps=20), gen
+
+
+# case -> (fixture, seeds, resident lanes, seg_steps, devices of the mesh)
+_LAG_CASES = {
+    "broadcast": (_broadcast_fixture, 40, 8, 16, 0),
+    "raft_faults": (lambda: _raft_fixture(160), 24, 8, 32, 0),
+    "seg_steps_not_dividing": (lambda: _raft_fixture(40), 32, 8, 28, 0),
+    "ends_at_the_budget": (lambda: _raft_fixture(48), 24, 8, 16, 0),
+    "unfinished_at_the_budget": (_short_broadcast_fixture, 24, 8, 8, 0),
+    "mesh": (_broadcast_fixture, 20, 8, 16, 2),
+    "shorter_than_a_resident_set": (_broadcast_fixture, 5, 8, 16, 0),
+}
+
+
+class _Lagged:
+    """One driver of a case and what the plain explore kernel says of
+    the same seeds; ``run`` is one whole sweep under a forced lag."""
+
+    def __init__(self, case):
+        from demi_tpu.parallel.mesh import make_mesh
+
+        fixture, self.n, batch, seg_steps, devices = _LAG_CASES[case]
+        self.app, self.cfg, gen = fixture()
+        mesh = make_mesh(jax.devices()[:devices]) if devices else None
+        self.drv = ContinuousSweepDriver(
+            self.app, self.cfg, gen, batch=batch, seg_steps=seg_steps,
+            mesh=mesh,
+        )
+        self.finalized = 0
+        finalize = self.drv.finalize
+
+        def counted(state):
+            self.finalized += 1
+            return finalize(state)
+
+        self.drv.finalize = counted
+        progs = stack_programs(
+            [lower_program(self.app, self.cfg, gen(s)) for s in range(self.n)]
+        )
+        keys = np.stack(
+            [np.asarray(jax.random.PRNGKey(s)) for s in range(self.n)]
+        )
+        ref = make_explore_kernel(self.app, self.cfg)(progs, keys)
+        self.want = {
+            s: (st, code, h) for s, st, code, h in zip(
+                range(self.n), np.asarray(ref.status).tolist(),
+                np.asarray(ref.violation).tolist(),
+                np.asarray(ref.sched_hash).tolist(),
+            )
+        }
+
+    def run(self, monkeypatch, lag):
+        """The yielded batches, as lists."""
+        monkeypatch.setattr(continuous, "_LAG_LIFE", 0 if lag else _NEVER)
+        assert self.drv._lag() == lag
+        return [
+            tuple(a.tolist() for a in batch)
+            for batch in self.drv._run_batches(self.n)
+        ]
+
+
+def _per_seed(batches):
+    return {
+        s: (st, code, h)
+        for seeds, statuses, codes, hashes in batches
+        for s, st, code, h in zip(seeds, statuses, codes, hashes)
+    }
+
+
+def _digest(per_seed):
+    seeds = sorted(per_seed)
+    cols = np.array([per_seed[s] for s in seeds], np.int64)
+    return lanes_digest(np.array(seeds), cols[:, 0], cols[:, 1], cols[:, 2])
+
+
+@pytest.mark.parametrize("case", list(_LAG_CASES))
+def test_the_lagged_harvest_changes_no_verdict(case, monkeypatch):
+    """(status, code, sched_hash) per seed and ``lanes_digest`` under
+    the lag equal the strict order and the plain explore kernel; the
+    yielded batches are the same in two runs; a frozen segment a
+    schedule is what it costs."""
+    fx = _Lagged(case)
+    plain = fx.run(monkeypatch, lag=0)
+    plain_steps = fx.drv.last_total_lane_steps
+    fx.finalized = 0
+    lagged = fx.run(monkeypatch, lag=1)
+    assert _per_seed(plain) == fx.want
+    assert _per_seed(lagged) == fx.want
+    assert _digest(_per_seed(lagged)) == _digest(fx.want)
+    assert sorted(_per_seed(lagged)) == list(range(fx.n))
+    assert fx.run(monkeypatch, lag=1) == lagged
+    assert fx.drv.last_total_lane_steps >= plain_steps
+    assert 0 < fx.drv.last_live_lane_steps <= fx.drv.last_total_lane_steps
+    statuses = [st for st, _code, _h in fx.want.values()]
+    if case == "ends_at_the_budget":
+        # the eager finalize ran, and no lane was left without a verdict
+        assert fx.finalized > 0 and ST_UNFINISHED not in statuses
+    if case == "unfinished_at_the_budget":
+        assert fx.finalized > 0 and 0 < statuses.count(ST_UNFINISHED) < fx.n
+        assert fx.drv.last_unfinished_lanes == statuses.count(ST_UNFINISHED)
+    if case == "mesh":
+        assert fx.drv.last_lane_sharding["devices"] == 2
+
+
+def test_the_budget_path_is_queued_behind_its_segment(monkeypatch):
+    """Lanes that spend their budget in a segment are finalized right
+    behind it, from the host's own step counts: before the round's first
+    look at the device, under either order."""
+    fx = _Lagged("ends_at_the_budget")
+    fx.drv.seed_pure = True
+    log = []
+    segment, finalize = fx.drv.segment, fx.drv.finalize
+    fx.drv.segment = lambda *a: log.append("segment") or segment(*a)
+    fx.drv.finalize = lambda state: log.append("finalize") or finalize(state)
+    monkeypatch.setattr(
+        continuous, "_ready", lambda _array: log.append("probe") or False
+    )
+    for lag in (0, 1):
+        del log[:]
+        assert _per_seed(fx.run(monkeypatch, lag)) == fx.want
+        assert "finalize" in log and "probe" in log
+        assert all(
+            prev == "segment"
+            for prev, cur in zip(log, log[1:]) if cur == "finalize"
+        )
+
+
+@pytest.mark.parametrize("how", ["break", "stop_on_violation"])
+def test_an_outstanding_segment_leaves_the_driver_reusable(
+    ahead, stub_ready, monkeypatch, how
+):
+    """One segment is in flight when a consumer stops: the next call
+    starts from its own state."""
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    want = ahead.run(seed_pure=False)
+    monkeypatch.setattr(continuous, "_LAG_LIFE", 0)
+    ahead.reset(seed_pure=True)
+    if how == "break":
+        for _ in range(2):
+            for _batch in ahead.drv._run_batches(ahead.N):
+                break
+    else:
+        app, cfg, gen = _broadcast_fixture()
+        driver = SweepDriver(app, cfg, gen)
+        assert driver._continuous_driver(8)._lag() == 1
+        stopped = driver.sweep(64, 8, stop_on_violation=True)
+        assert 0 < stopped.lanes < 64 and stopped.violations
+        whole = driver.sweep(64, 8)
+        chunked = driver.sweep(64, 8, mode="chunked")
+        assert whole.lanes_digest == chunked.lanes_digest
+        assert whole.codes == chunked.codes
+    assert ahead.run(seed_pure=True) == want
+
+
+def test_a_fill_writes_only_rows_the_segment_in_flight_holds_frozen(
+    monkeypatch,
+):
+    """Under the lag a refill's programs are written while the segment
+    dispatched with the same arrays may still read them. Every lane a
+    fill writes is finished in the state that segment took, and a
+    segment that reads garbage in those rows gives the same verdicts."""
+    fx = _Lagged("broadcast")
+    segment, fill = fx.drv.segment, fx.drv._fill
+    frozen = []     # of the state the last dispatched segment took
+    written = []
+
+    def poisoned(state, progs, steps_run):
+        del frozen[:]
+        frozen.extend((np.asarray(state.status) >= ST_DONE).tolist())
+        rows = np.flatnonzero(frozen)
+        torn = type(progs)(*(x.copy() for x in progs))
+        for x in torn:
+            x[rows] = 1 << 30
+        return segment(state, torn, steps_run)
+
+    def checked(seeds, lanes, progs, stock=None):
+        if frozen:      # (the prime fill comes before any segment)
+            written.extend(lanes)
+            assert all(frozen[lane] for lane in lanes)
+        return fill(seeds, lanes, progs, stock)
+
+    fx.drv.segment, fx.drv._fill = poisoned, checked
+    assert _per_seed(fx.run(monkeypatch, lag=1)) == fx.want
+    assert len(written) == fx.n - fx.drv.batch
+
+
+def test_the_lag_is_a_rule_on_the_budget_in_segments():
+    """1 where a schedule's budget holds ``_LAG_LIFE`` segments, else
+    0: by ``SweepDriver``'s ``seg_steps`` the cells of 1,024 steps and
+    more, never ``raft5-sweep``'s 144 (4 segments a life)."""
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    assert continuous._LAG_LIFE == 16
+    app, cfg, gen = _broadcast_fixture()
+    for max_steps, seg_steps, lag in (
+        (16 * 28, 28, 1), (16 * 28 - 1, 28, 0), (96, 28, 0), (4608, 64, 1),
+    ):
+        drv = ContinuousSweepDriver(
+            app, dataclasses.replace(cfg, max_steps=max_steps), gen,
+            seg_steps=seg_steps,
+        )
+        assert drv._lag() == lag, (max_steps, seg_steps)
+    for max_steps, seg_steps, lag in (
+        (144, 36, 0), (1023, 64, 0), (1024, 64, 1), (3328, 64, 1),
+    ):
+        drv = SweepDriver(
+            app, dataclasses.replace(cfg, max_steps=max_steps), gen
+        )._continuous_driver(8)
+        assert (drv.seg_steps, drv._lag()) == (seg_steps, lag), max_steps
+
+
+@pytest.mark.parametrize(
+    "lag, busy, queued",
+    [(1, True, "all_but_the_first"), (1, False, "none"), (0, None, "none")],
+)
+def test_the_dispatches_count_how_often_the_device_had_work_queued(
+    in_place, monkeypatch, lag, busy, queued
+):
+    """``sweep.segments`` per dispatch, the dropped one included, and
+    ``sweep.segments_queued`` where the segment before had not landed:
+    under a stub that never or always reads ready, and with the real
+    probe under the strict order, whose every dispatch follows a pull."""
+    drv = in_place.driver(in_place.gen, seed_pure=True)
+    monkeypatch.setattr(continuous, "_LAG_LIFE", 0 if lag else _NEVER)
+    if busy is not None:
+        monkeypatch.setattr(continuous, "_ready", lambda _array: not busy)
+    with _spans_live():
+        lanes = sum(len(b[0]) for b in drv._run_batches(in_place.N))
+        counts = obs.stage_counts()
+    assert lanes == in_place.N
+    segments = drv.last_total_lane_steps // (in_place.BATCH * drv.seg_steps)
+    assert counts["sweep.segments"] == segments > 0
+    assert counts["sweep.lane_steps"] == drv.last_total_lane_steps
+    assert counts["sweep.live_lane_steps"] == drv.last_live_lane_steps
+    assert counts["sweep.segments_queued"] == {
+        "all_but_the_first": segments - 1, "none": 0,
+    }[queued]
+
+
+def test_the_benchmarks_reader_of_the_two_counts(monkeypatch):
+    """``benchmarks/layer_metrics/sweep.queued_segment_share.py`` over a
+    traced job's table, and None where a program keeps no such counts
+    (the parent's); its entry lists the sweep cells."""
+    import importlib.util
+    import json
+    import os
+    import sys
+
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = "sweep.queued_segment_share"
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "drivers (host)",
+        "moves": "schedules_per_s",
+        "workloads": bench["end_to_end"][0]["workloads"],
+    }
+    monkeypatch.syspath_prepend(os.path.join(root, "benchmarks"))
+    spec = importlib.util.spec_from_file_location(
+        "queued_segment_share",
+        os.path.join(root, "benchmarks", "layer_metrics", name + ".py"),
+    )
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    # (the reader holds what it imported; no other test finds a ``lib``)
+    for module in [m for m in sys.modules if m.split(".")[0] == "lib"]:
+        del sys.modules[module]
+
+    app, cfg, gen = _broadcast_fixture()
+    driver = SweepDriver(app, cfg, gen)
+    monkeypatch.setattr(continuous, "_LAG_LIFE", 0)
+    monkeypatch.setattr(continuous, "_ready", lambda _array: False)
+    with _spans_live():
+        assert reader.read(None) is None
+        driver.sweep(24, 8)
+        segments = obs.stage_counts()["sweep.segments"]
+        share = reader.read(None)
+        del obs.TRACER.counts["sweep.segments"]
+        assert reader.read(None) is None
+    assert share == pytest.approx(100.0 * (segments - 1) / segments)

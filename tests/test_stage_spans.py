@@ -276,7 +276,8 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # sweep.quiesced_share and sweep.pool_peak_share (PR 31),
     # sweep.outbox_fill_share (PR 33), sweep.produced_share and
     # sweep.starve_share (PR 42: ``sweep.producers`` says the program has
-    # producers at all; 24 programs are worth none).
+    # producers at all; 24 programs are worth none),
+    # sweep.queued_segment_share (PR 46).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -284,6 +285,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     assert set(counts) == {
         "dpor.candidates", "dpor.fresh", "dpor.materialized",
         "sweep.lane_steps", "sweep.live_lane_steps",
+        "sweep.segments", "sweep.segments_queued",
         "sweep.programs", "sweep.prefetched", "sweep.row_lowered",
         "sweep.produced", "sweep.producers",
         "sweep.retired", "sweep.quiesced", "sweep.unfinished",
@@ -294,6 +296,12 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
     assert result.lanes == 24
     assert 0 < counts["sweep.live_lane_steps"] <= counts["sweep.lane_steps"]
+    # a dispatch a segment; 4 segments a life, so the harvest lags none
+    drv = sweeper._continuous_driver(8)
+    assert counts["sweep.lane_steps"] == (
+        counts["sweep.segments"] * 8 * drv.seg_steps
+    )
+    assert drv._lag() == 0 and counts["sweep.segments_queued"] == 0
     # one program a schedule put in a lane; the prime fill's 8 never ahead
     assert counts["sweep.programs"] == 24
     assert 0 <= counts["sweep.prefetched"] <= 24 - 8
