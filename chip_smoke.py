@@ -39,6 +39,8 @@ SIZES = {
         min_nodes=3, min_events=12, min_steps=400, fuzz_executions=200,
         paxos_lanes=512, paxos_log_cap=8, paxos_steps=2048, paxos_pool=256,
         paxos_events=96, paxos_violating=1,
+        reconfig_lanes=1024, reconfig_log_cap=32, reconfig_steps=2048,
+        reconfig_pool=512, reconfig_events=96, reconfig_commits=30,
     ),
     "tiny": dict(
         nodes=3, pool=48, steps=64, lanes=64, chunk=32, lifts=2,
@@ -46,6 +48,8 @@ SIZES = {
         min_nodes=3, min_events=6, min_steps=96, fuzz_executions=200,
         paxos_lanes=32, paxos_log_cap=4, paxos_steps=384, paxos_pool=128,
         paxos_events=24, paxos_violating=0,
+        reconfig_lanes=32, reconfig_log_cap=8, reconfig_steps=256,
+        reconfig_pool=128, reconfig_events=48, reconfig_commits=3,
     ),
 }
 
@@ -413,6 +417,115 @@ def phase_datagram(smoke: Smoke) -> None:
         )
 
 
+def phase_reconfig(smoke: Smoke) -> None:
+    """Row-scale ``DSLApp.durable`` outside the benchmark: one sweep of the
+    reconfiguring raft (``--app raft_reconfig``, ``snapshot_keeps_config``)
+    through the CLI's normal path under crash-recovery and partitions. A
+    violating lane where there is one, and clean ones, are re-run traced and
+    lifted: the host oracle restarts servers from their durable rows to the
+    same code, the same ``sched_hash`` and the same final rows, which hold
+    compactions, installed snapshots and restarts."""
+    import jax
+    import numpy as np
+
+    from demi_tpu.apps import raft_reconfig as rr
+    from demi_tpu.apps.common import make_host_invariant
+    from demi_tpu.config import SchedulerConfig
+    from demi_tpu.device.encoding import (
+        device_trace_to_guide, lower_program, stack_programs,
+    )
+    from demi_tpu.device.explore import make_single_lane_trace_kernel
+    from demi_tpu.parallel.distributed import build_workload
+    from demi_tpu.schedulers.guided import GuidedScheduler
+
+    z = smoke.size
+    w = {
+        "app": "raft_reconfig", "nodes": 7, "bug": "snapshot_keeps_config",
+        "seed": 0, "log_cap": z["reconfig_log_cap"],
+        "snapshot_every": z["reconfig_log_cap"] // 2,
+        "num_events": z["reconfig_events"],
+        "max_messages": z["reconfig_steps"], "pool": z["reconfig_pool"],
+        "timer_weight": 0.1, "send_weight": 0.5, "wait_weight": 0.28,
+        "hard_kill_weight": 0.08, "restart_weight": 0.1,
+        "partition_weight": 0.04, "kill_weight": 0.0, "max_kills": 4,
+        "wait_budget": [1, 40],
+    }
+    with smoke.phase("reconfig_sweep") as rec:
+        s = smoke.verb([
+            "sweep", "--app", "raft_reconfig", "--nodes", "7",
+            "--bug", "snapshot_keeps_config",
+            "--log-cap", str(w["log_cap"]),
+            "--snapshot-every", str(w["snapshot_every"]),
+            "--batch", str(z["reconfig_lanes"]), "--pool", str(w["pool"]),
+            "--max-messages", str(w["max_messages"]),
+            "--num-events", str(w["num_events"]), "--timer-weight", "0.1",
+            "--send-weight", "0.5", "--wait-weight", "0.28",
+            "--hard-kill-weight", "0.08", "--restart-weight", "0.1",
+            "--partition-weight", "0.04", "--kill-weight", "0",
+            "--max-kills", "4", "--wait-budget", "1", "40", "--strict-io",
+        ])
+        smoke.check_device(s)
+        check(s["lanes"] == z["reconfig_lanes"], f"reconfig: {s['lanes']} lanes")
+        check(s["overflow_lanes"] == 0, "reconfig: overflow lanes")
+        check(s["violations"] < s["lanes"] // 8,
+              f"reconfig: {s['violations']} violating lanes of {s['lanes']}")
+        violating = dict(s["violating_seeds"])
+        picked = sorted(violating)[:1]
+        picked += [x for x in range(z["reconfig_lanes"]) if x not in violating][:3]
+        app, cfg, fuzzer = build_workload(w)
+        seeds = np.asarray(picked, np.uint32)
+        progs = stack_programs([
+            lower_program(app, cfg, fuzzer.generate_fuzz_test(seed=int(x)))
+            for x in seeds
+        ])
+        keys = jax.vmap(
+            lambda x: jax.random.fold_in(jax.random.PRNGKey(0), x)
+        )(seeds)
+        done = dict.fromkeys(
+            ("COMMIT", "COMPACTIONS", "SNAP_INSTALLED", "RESTORES"), 0
+        )
+        kernel = make_single_lane_trace_kernel(app, cfg)
+        config = SchedulerConfig(invariant_check=make_host_invariant(app))
+        for lane, seed in enumerate(picked):
+            # lift_lane_to_host's ritual, keeping the host's actors
+            single = kernel(
+                jax.tree_util.tree_map(lambda x: x[lane], progs), keys[lane]
+            )
+            sched = GuidedScheduler(config, app)
+            host = sched.execute_guide(device_trace_to_guide(
+                app, np.asarray(single.trace), int(single.trace_len)
+            ))
+            code = violating.get(seed, 0)
+            host_code = host.violation.code if host.violation else 0
+            check(
+                int(single.violation) == host_code == code,
+                f"reconfig seed {seed}: sweep {code}, traced "
+                f"{int(single.violation)}, host {host_code}",
+            )
+            check(
+                int(single.deliveries) == host.deliveries,
+                f"reconfig seed {seed}: the host delivered another sequence",
+            )
+            rows = np.stack([
+                np.asarray(actor.state)
+                for actor in sched.system.actors.values()
+            ])
+            done["COMMIT"] = max(done["COMMIT"], int(rows[:, rr.COMMIT].max()))
+            for name in ("COMPACTIONS", "SNAP_INSTALLED"):
+                done[name] += int(rows[:, getattr(rr, name)].sum())
+            done["RESTORES"] += int((rows[:, rr.RESTORES] - 1).clip(min=0).sum())
+        check(done["COMMIT"] >= z["reconfig_commits"],
+              f"reconfig: the lifted lanes commit {done['COMMIT']} at most")
+        check(done["COMPACTIONS"] > 0 and done["RESTORES"] > 0,
+              f"reconfig: the lifted lanes did {done}")
+        rec.update(
+            lanes=s["lanes"], violations=s["violations"],
+            lanes_lifted=len(picked), host_agrees=True,
+            lanes_digest=s["lanes_digest"],
+            **{k.lower(): v for k, v in done.items()},
+        )
+
+
 def phase_dpor(smoke: Smoke) -> None:
     """BASELINE config 2 shape. First the full round budget on the
     correct protocol (a violating run stops at its first hit, so this is
@@ -532,6 +645,7 @@ def run(size: dict, device: dict) -> dict:
         check_warm_compile(smoke)
         phase_lift(smoke, sweep_summary)
         phase_datagram(smoke)
+        phase_reconfig(smoke)
         phase_dpor(smoke)
         phase_minimize(smoke, workdir)
         if device["count"] > 1:

@@ -80,9 +80,19 @@ def _make_app(args) -> DSLApp:
             )
         except ValueError as exc:
             raise SystemExit(f"--app paxos: {exc}")
+    if args.app == "raft_reconfig":
+        from .apps.raft_reconfig import make_raft_reconfig_app
+
+        try:
+            return make_raft_reconfig_app(
+                args.nodes, log_cap=args.log_cap,
+                snapshot_every=args.snapshot_every, bug=args.bug,
+            )
+        except ValueError as exc:
+            raise SystemExit(f"--app raft_reconfig: {exc}")
     raise SystemExit(
-        f"unknown app {args.app!r} "
-        "(choices: broadcast, chain, paxos, raft, spark, twopc, vsr)"
+        f"unknown app {args.app!r} (choices: broadcast, chain, paxos, raft, "
+        "raft_reconfig, spark, twopc, vsr)"
     )
 
 
@@ -107,6 +117,10 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         from .apps.paxos import paxos_send_generator
 
         gen = paxos_send_generator(app)
+    elif args.app == "raft_reconfig":
+        from .apps.raft_reconfig import reconfig_send_generator
+
+        gen = reconfig_send_generator(app)
     elif args.app == "broadcast":
         gen = broadcast_send_generator(app)
     else:
@@ -2299,6 +2313,11 @@ def main(argv: Optional[list] = None) -> int:
         p.add_argument("--log-cap", type=int, default=knobs["log_cap"],
                        dest="log_cap",
                        help="raft: log entries a node holds")
+        p.add_argument("--snapshot-every", type=int,
+                       default=knobs["snapshot_every"], dest="snapshot_every",
+                       help="raft_reconfig: applied entries above its "
+                            "snapshot at which a server compacts "
+                            "(default: half of --log-cap)")
         # Datagram channels (an app whose DSLApp.channels say so: paxos).
         p.add_argument("--dup-weight", type=float,
                        default=knobs["dup_weight"], dest="dup_weight",

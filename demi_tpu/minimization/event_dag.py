@@ -128,8 +128,16 @@ class UnmodifiedEventDag(EventDag):
                     raise ValueError(f"Kill({e.name}) without preceding Start")
                 place(start, e)
             elif isinstance(e, Start):
+                # A restart after a HardKill, or a second Start of one
+                # that is up: the open one stands alone.
+                again = open_dual.pop(("start", e.name), None)
+                if again is not None:
+                    place(again)
                 open_dual[("start", e.name)] = e
             elif isinstance(e, Partition):
+                again = open_dual.pop(("part", e.a, e.b), None)
+                if again is not None:
+                    place(again)
                 open_dual[("part", e.a, e.b)] = e
             elif isinstance(e, UnPartition):
                 part = open_dual.pop(("part", e.a, e.b), None)

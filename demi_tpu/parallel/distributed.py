@@ -41,6 +41,9 @@ FAULT_PLANE_DEFAULTS = {
     "max_sends": None,  # client sends a program may hold; None = unlimited
     "wait_budget": None,  # (lo, hi) deliveries of a generated wait; None = drain
     "log_cap": 8,
+    # raft_reconfig: applied entries above its snapshot at which a server
+    # compacts (etcd's --snapshot-count); None = half of ``log_cap``.
+    "snapshot_every": None,
     # App-shape keys the other apps ignore, as they ignore ``log_cap``:
     # spark's stages a job and tasks a stage.
     "stages": 2,

@@ -77,6 +77,17 @@ def test_smoke_lift_agrees_with_host_oracle(tiny_run):
     assert phases["lift"]["host_agrees"] is True
 
 
+def test_smoke_reconfig_sweep_restarts_from_disk_and_lifts(tiny_run):
+    """PR 47's phase: ``--app raft_reconfig`` under crash-recovery and
+    partitions; the lifted lanes' host rows hold compactions, installed
+    snapshots and restarts from the durable row."""
+    _lines, phases = tiny_run
+    r = phases["reconfig_sweep"]
+    assert r["lanes"] == 32 and r["lanes_lifted"] == 3
+    assert r["host_agrees"] is True and r["violations"] == 0
+    assert r["commit"] >= 3 and r["compactions"] > 0 and r["restores"] > 0
+
+
 def test_smoke_dpor_runs_its_budget_then_finds_and_verifies(tiny_run):
     _lines, phases = tiny_run
     assert phases["dpor_rounds"]["interleavings"] == 16 * 2

@@ -99,6 +99,24 @@ UNMODELED = {
     # paxos (PR 44): 8 tags, Request .. Backoff; ``propose()`` is a loop
     # unrolled over the replica's window.
     "paxos": (lambda: _paxos(), 8, "loops are not modeled"),
+    # raft_reconfig (PR 47): 10 tags, ElectionTimeout .. Admin, over a row
+    # of 216 words (wider than any log) that the handler takes apart into
+    # a dict of scalars and arrays and packs again.
+    "raft_reconfig": (
+        lambda: _raft_reconfig(), 10, "unsupported expression DictComp"
+    ),
+}
+
+TAG_NAMES = {
+    "paxos": (
+        "Request", "Propose", "Decision", "P1a", "P1b", "P2a", "P2b",
+        "Backoff",
+    ),
+    "raft_reconfig": (
+        "ElectionTimeout", "HeartbeatTimer", "RequestVote", "VoteReply",
+        "AppendEntries", "AppendReply", "InstallSnapshot", "SnapshotReply",
+        "ClientCmd", "Admin",
+    ),
 }
 
 
@@ -108,15 +126,20 @@ def _paxos():
     return make_paxos_app(11, log_cap=4, bug="count_replies")
 
 
+def _raft_reconfig():
+    from demi_tpu.apps.raft_reconfig import make_raft_reconfig_app
+
+    app = make_raft_reconfig_app(7, log_cap=32, bug="snapshot_keeps_config")
+    assert app.state_width == 216
+    return app
+
+
 @pytest.mark.parametrize("name", sorted(UNMODELED))
 def test_an_unmodeled_app_says_so_and_fabricates_nothing(name):
     make_app, n_tags, sentence = UNMODELED[name]
     app = make_app()
     assert len(app.tag_names) - 1 == n_tags
-    assert app.tag_names[1:] == (
-        "Request", "Propose", "Decision", "P1a", "P1b", "P2a", "P2b",
-        "Backoff",
-    )
+    assert app.tag_names[1:] == TAG_NAMES[name]
     eff = analyze_dsl_app(app)
     assert eff.n_tags == n_tags
     assert eff.failure is not None and sentence in str(eff.failure)
