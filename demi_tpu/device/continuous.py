@@ -15,64 +15,90 @@ and refill replaces whole lanes atomically (tests/test_continuous.py).
 
 One round of the harvest loop (``_run_batches``), in order: dispatch the
 segment (asynchronous) and, right behind it, the finalize of the lanes
-that spend their last step in it -> MAKE AHEAD -> status pull (the sync
-point) -> harvest -> fill -> refill. Which lane gets which program is
-decided after the harvest, but what the coming programs are is a
-function of ``seed_list[next_idx:]`` alone, so between the dispatch and
-the pull the one host thread fuzzes and lowers them into a stock, in seed
-order, while the device runs the segment. It stops at the first of: the
-awaited segment's result is ready (asked once a program); the stock holds
-as many programs as lanes are active (no round can refill more, so the
-host never holds more than one resident set); the call's seeds are used
-up. The fill hands out the stock first and makes the rest on the spot,
+that spend their last step in it (which those are follows from
+``steps_run``, which the host holds: no pull) -> MAKE AHEAD -> status
+pull (the sync point) -> harvest -> fill -> refill. Which lane gets
+which program is decided after the harvest, but what the coming programs
+are is a function of ``seed_list[next_idx:]`` alone, so between the
+dispatch and the pull the one host thread fuzzes and lowers them into a
+stock, in seed order, while the device runs the segment. It stops at
+the first of: the awaited segment's result is ready (asked once a
+program); the stock holds as many programs as lanes are active (no round
+can refill more, so the host never holds more than one resident set of
+them); the call's seeds are used up. The fill hands out the stock first and makes the rest on the spot,
 so lane, seed, program and key pair up exactly as without it.
 
-THE LAG (PR 46). In that order nothing is in the device's queue while
-the host harvests: every round the chip sits through the pull's round
-trip, the harvest, the fill and the next dispatch. Where a schedule
-lives long enough (``_LAG_LIFE``; ``_lag``: a rule on ``cfg.max_steps``
-over ``seg_steps``, nothing sets it) the same statements run with the
-harvest ONE SEGMENT BEHIND the device: the round dispatches segment k+1
-on the state segment k will leave, and only then waits for segment k,
-harvests its finished lanes from that state's own leaves, fills, and
-queues ``refill`` behind segment k+1. Exact, because a lane with
-``status >= ST_DONE`` is a frozen no-op in every step
-(``explore.make_step_fn``) and ``finalize`` is the identity on it:
-merging the fresh lane after segment k+1 gives what merging before it
-would have, one segment later. A refilled lane's ``steps_run`` is
-zeroed when its refill is queued, so its first segment counts from 0;
-the next pull reads the merged state, so no lane is seen finished
-twice; the harvest always reads segment k, never "whichever is ready",
-so which lane a seed lands in and the order of the yielded batches are
-functions of the seeds and the sizes alone, as before. The price is one
-frozen segment a schedule (between the segment a lane finishes in and
-the one its refill lands behind), so ``live_lane_steps`` counts a
-finished lane out of the segment in flight at its retire; and one
-segment is in flight when the last lane retires or a consumer stops:
-its result is dropped, and the next call starts from its own state.
-With no lag the order is strict: the harvest reads the segment it just
-dispatched. The budget path pulls nothing under either order: which
-lanes exhaust ``cfg.max_steps`` in a segment follows from ``steps_run``,
-which the host holds.
+THE LAG (PR 46; the budget path behind its segment: PR 48). In that
+order nothing is in the device's queue while the host harvests: every
+round the chip sits through the pull's round trip, the harvest, the fill
+and the next dispatch. Where a schedule lives ``_LAG_LIFE`` segments or
+more (``_lag``: a rule on ``cfg.max_steps`` over ``seg_steps``, nothing
+sets it) the same statements run with the harvest ONE SEGMENT BEHIND the
+device. A round of it, in order:
 
-The resident programs are ONE set of host arrays (``op/a/b [b, E]``,
-``msg [b, E, W]``: the ``ExtProgram`` every segment takes), allocated
-once a call and written in place: a fill lowers each program straight
-into its lane's rows (``encoding.lower_into``; a fuzzed program from its
-op rows, with no event object), so a retired program's memory serves
-the next and nothing is stacked. A FILL WRITES ONLY ROWS OF LANES THAT
-EVERY DISPATCHED SEGMENT NOT YET PULLED HOLDS FROZEN: with no lag it
-writes between a status pull and the next dispatch; under the lag,
-while segment k+1, dispatched with these same arrays, may still read
-them, it writes the rows of lanes that finished in segment k, which
-segment k+1 steps as no-ops whatever their rows hold
-(``tests/test_continuous.py`` runs such a segment on garbage). The
-stock is a second such block, so what is made while the device may
-still read the resident set touches none of it (the CPU backend may
-alias NumPy memory); the refill copies the stock's rows onto the
-refilled lanes in one indexed assignment per array. The stock
-is a local of one ``_run_batches`` call: a caller's generator may change
-between calls (the benchmark's closes over a per-job base), and a
+1. dispatch segment k on the state the round before left and, from
+   ``steps_run``, the ``finalize`` of the lanes that spend their last
+   step in it (``spent``): no pull. That state, F(k), is ``held``, with
+   the samples, the steps and the seeds of segment k's lanes;
+2. make ahead, then wait for F(k-1)'s status: the one sync point;
+3. harvest from F(k-1)'s own leaves: the lanes ``seen`` finished in it
+   for the first time (they stopped on their own in segment k-1 and sit
+   frozen through segment k: a lane with ``status >= ST_DONE`` is a
+   no-op in every step, ``explore.make_step_fn``, and ``finalize`` is
+   the identity on it), and the lanes ``owed`` from the round before:
+   those segment k-1 was known to spend. ``renewed`` masks the lanes
+   whose refill was queued behind F(k-1): it shows their predecessors;
+4. fill and queue ONE ``refill`` behind F(k) for the lanes ``seen`` and
+   the lanes ``spent``, in lane order, with the next seeds in seed
+   order, ``steps_run`` zeroed: segment k+1 runs them live. The
+   ``spent`` lanes' verdicts are read at the next round's pull, from
+   F(k), which the refill does not touch: they become its ``owed``.
+
+So a lane that stops on its own pays one frozen segment (between the
+one it finishes in and the one its refill lands behind:
+``live_lane_steps`` counts it out of the segment in flight at its
+retire), and a lane that runs to its budget pays none: the host knew,
+when it dispatched the segment, that the lane would be free behind it.
+A lane is retired once: one that stopped on its own in segment k-1 and
+is spent by count in segment k is ``seen`` at round k's pull and taken
+out of ``spent`` there; one that stops in the very segment that spends
+it is ``spent``, and ``renewed`` keeps the next pull from seeing its
+old status. Merging a fresh lane after F(k) gives what merging it
+before segment k+1 would have; the harvest always reads segment k-1,
+never "whichever is ready", so which lane a seed lands in and the order
+of the yielded batches are functions of the seeds and the sizes alone.
+Where segment k spends every lane still active and no seed is left,
+the next round dispatches nothing and only harvests; where the last
+lanes stop on their own, or a consumer stops, one segment is in
+flight: its result is dropped, and the next call starts from its own
+state. ``held`` keeps F(k) alive through round k+1's harvest, beside
+the merged state segment k+1 takes: one state more than the strict
+order holds. With no lag the order is strict: the harvest reads the
+segment it just dispatched, ``owed`` and ``renewed`` stay empty, and
+the lanes a segment spends are ``seen`` in the same round.
+
+The resident programs are host arrays (``op/a/b [b, E]``, ``msg [b, E,
+W]``: the ``ExtProgram`` every segment takes), allocated once a call
+and written in place: a fill lowers each program straight into its
+lane's rows (``encoding.lower_into``; a fuzzed program from its op
+rows, with no event object), so a retired program's memory serves the
+next and nothing is stacked. A SET IS WRITTEN ONLY AFTER EVERY SEGMENT
+DISPATCHED WITH IT HAS BEEN PULLED (the CPU backend may alias NumPy
+memory, and a transfer may still read it). With no lag there is one
+set, written between a status pull and the next dispatch. Under the lag
+segment k, in flight, reads the ``spent`` lanes' rows live while the
+fill writes their successors', so there are TWO sets: a refill round
+takes the set segment k was not dispatched with (every segment that
+took it was pulled by round k's sync point), copies onto it the rows it
+lacks (``stale``: the lanes the refill before wrote in the other set;
+the two differ in nothing else), writes the refilled lanes' rows there,
+and segment k+1 takes it (``tests/test_continuous.py`` holds every
+dispatched set to its bytes at dispatch until its segment is pulled).
+The stock is one more such block, so what is made while the device may
+still read a resident set touches none of it; the refill copies the
+stock's rows onto the refilled lanes in one indexed assignment per
+array. The stock is a local of one ``_run_batches`` call: a caller's
+generator may change between calls (the benchmark's closes over a per-job base), and a
 consumer that stops early just drops it. Only a generator the
 constructor is told is a function of the seed (``seed_pure``) is called
 ahead; any other is called at refill, in refill order.
@@ -343,17 +369,19 @@ _RING_SETS = 2
 _WORTH_S = 0.25
 # Segments a schedule's step budget must hold (``cfg.max_steps`` over
 # ``seg_steps``) for the harvest to run one segment behind the device
-# (module doc, THE LAG). It trades one frozen segment a schedule, between
-# the one a lane finishes in and the one its refill lands behind, for a
-# device that never waits for the host's round: a sixteenth of the chip's
-# work at 16, a quarter at 4. Engaged: the flood (72 segments a life),
-# spark (52), chain (32), paxos (64), deep raft and VSR (16) cells.
-# Bypassed: ``raft5-sweep`` and its ``-x4`` (4), whose every fifth
-# segment would be idle. (On the chip the deep raft and VSR cells, which
-# sit on it, gain 21% and 9%; forced on in ``raft5-sweep`` it gained
-# 16%, the host bounding that job too: 4 is the next PR's to claim with
-# the x4 cell measured. PERF.md, PR 46.)
-_LAG_LIFE = 16
+# (module doc, THE LAG). The lag trades one frozen segment of every
+# schedule that stops on its own, between the one it finishes in and the
+# one its refill lands behind, for a device that never waits for the
+# host's round; a schedule that runs to its budget pays nothing (PR 48).
+# 4 is the life ``SweepDriver`` builds for every ``max_steps >= 32``, so
+# all nine sweep cells run this order: ``raft5-sweep`` and its ``-x4``
+# (4 segments a life; 78% of their lanes end at the budget), deep raft
+# and VSR (16), ``raft7-reconfig`` and chain (32), spark (52), paxos
+# (64), the flood (72). Under it the order is strict: callers that cut
+# a budget into fewer segments (``tools/soak.py``'s 40 steps in
+# segments of 28), where a frozen segment is a third of a life or more.
+# (On the chip: PERF.md, PRs 46 and 48.)
+_LAG_LIFE = 4
 _STARVE_DEADLINE_S = 2.0    # a wait for one chunk, before its child is given up
 _POLL_S = 1e-4
 # The shortest fork this process has timed: what a fork costs it. One
@@ -1047,25 +1075,32 @@ class ContinuousSweepDriver:
             obs.stage_count("sweep.live_lane_steps", live)
 
         def keys_for(seeds):
-            return self._vkeys(jnp.asarray(seeds, jnp.uint32))
+            return self._vkeys(jnp.asarray(seeds.astype(np.uint32)))
 
         n_live = min(b, total_lanes)
+        seed_arr = np.asarray(seed_list, np.int64)
+        if seed_arr.min() < 0 or seed_arr.max() >= 1 << 32:
+            # (what the conversion of a list of them raised, at a refill)
+            raise OverflowError("a lane's key is made of its seed as uint32")
+        lag = self._lag()
         with obs.span("sweep.prime", lanes=b):
             # Lane i runs seed_list[i]; surplus (mesh-alignment) lanes run
             # the first seed inertly — never yielded, never refilled.
-            lane_seed = [
-                seed_list[i] if i < n_live else seed_list[0]
-                for i in range(b)
-            ]
+            lane_seed = np.full(b, seed_arr[0])
+            lane_seed[:n_live] = seed_arr[:n_live]
             next_idx = n_live  # next position in seed_list to hand out
-            # The resident set's programs: what every segment takes,
-            # written in place between a pull and the next dispatch.
+            # The resident set's programs: what every segment takes.
+            # Under the lag there are two such sets, and a refill
+            # writes the one no segment in flight was dispatched with
+            # (module doc); ``stale`` are the rows that one lacks.
             progs = empty_programs(self.cfg, b)
+            spare = empty_programs(self.cfg, b) if lag else None
+            stale = slice(None)
             # The first few are made on the spot: what one costs is
             # half of whether the rest are worth producer processes.
             probe = min(n_live, _PROBE)
             ns_a_program = self._fill(
-                lane_seed[:probe], range(probe), progs
+                seed_list[:probe], range(probe), progs
             ) / probe
             # Lowered programs of seed_list[probe:], made by producers
             # from here on, or of seed_list[next_idx:], made by the host
@@ -1078,7 +1113,7 @@ class ContinuousSweepDriver:
                 )
             if probe < n_live:
                 self._fill(
-                    lane_seed[probe:n_live], range(probe, n_live), progs,
+                    seed_list[probe:n_live], range(probe, n_live), progs,
                     stock,
                 )
             if stock is None and self.seed_pure:
@@ -1088,7 +1123,9 @@ class ContinuousSweepDriver:
             # half (module doc).
             exposed_ns = 0
             if n_live < b:
-                self._fill(lane_seed[n_live:], range(n_live, b), progs)
+                self._fill(
+                    seed_list[:1] * (b - n_live), range(n_live, b), progs
+                )
             with obs.span("sweep.refill"):
                 state = self.init(keys_for(lane_seed))
             steps_run = np.zeros(b, np.int64)
@@ -1102,58 +1139,70 @@ class ContinuousSweepDriver:
             sample_pool = obs.spans.live()
             self.last_pool_peak = 0 if sample_pool else None
         seg_steps, max_steps = self.seg_steps, self.cfg.max_steps
-        lag = self._lag()
-        # The status the segment before left, for ``sweep.segments_queued``,
-        # and what was sampled behind it (a traced job's: below).
-        landed = samples = None
+        no_lane = np.zeros(b, bool)
+        # Of the segment ``held``: the lanes the host knew spent when it
+        # dispatched it, to be yielded at its harvest, and the lanes a
+        # refill was queued behind (``held`` shows their predecessors).
+        owed = renewed = no_lane
+        # What a lagged round harvests: a segment's state (its finalize
+        # behind it, no refill yet), its samples, and the steps and the
+        # seeds its lanes had run. The first round's is the fresh state.
+        held = (state, None, steps_run, lane_seed) if lag else None
+        # The status the segment before left, for ``sweep.segments_queued``.
+        landed = None
         while done_count < total_lanes:
             with obs.span("sweep.round"):
                 # Lanes the host has not seen finish count as live; the
                 # retire takes back those the lag's segment held frozen.
+                # None is active where the lag's last segment spent
+                # every lane left and no seed is: the round only harvests.
                 n_active = int(active.sum())
-                count_steps(b * seg_steps, n_active * seg_steps)
-                if obs.spans.live():
-                    # (no probe while nothing counts: a dispatch's cost)
-                    obs.stage_count("sweep.segments")
-                    obs.stage_count(
-                        "sweep.segments_queued",
-                        landed is not None and not _ready(landed),
-                    )
-                # What the round harvests under the lag: the state as
-                # the round before left it (segment k, its finalize and
-                # the refill behind it), segment k's samples, the steps
-                # its lanes had run.
-                ended = steps_run
-                held = (state, samples, ended) if lag else None
+                latest, spent = None, no_lane
                 t_seg = time.perf_counter()
-                with obs.span("sweep.block"):
-                    state = self.segment(
-                        state, progs, jnp.asarray(steps_run, jnp.int32)
-                    )
-                    landed = state.status
-                    # A traced job's samples, dispatched behind the
-                    # segment and pulled at its harvest: each lane's
-                    # valid rows, the FIFO discipline's two counts, the
-                    # app's progress counts.
-                    samples = self._samples(state) if sample_pool else None
-                t_gap = time.perf_counter()
-                steps_run = np.minimum(ended + seg_steps, max_steps)
-                # Budget exhaustion (the plain kernel's run-out-of-steps
-                # semantics), queued behind the segment with no pull:
-                # which lanes spend their last step in it follows from
-                # their counts, and ``finalize`` is the identity on a
-                # lane that finished on its own.
-                spent = active & (ended < max_steps) & (
-                    steps_run >= max_steps
-                )
-                if spent.any():
-                    with obs.span("sweep.finalize"):
-                        state = self.refill(
-                            state, jnp.asarray(spent), self.finalize(state)
+                if n_active:
+                    count_steps(b * seg_steps, n_active * seg_steps)
+                    if obs.spans.live():
+                        # (no probe while nothing counts: a dispatch's cost)
+                        obs.stage_count("sweep.segments")
+                        obs.stage_count(
+                            "sweep.segments_queued",
+                            landed is not None and not _ready(landed),
                         )
-                harvested, sampled, steps_ended = held or (
-                    state, samples, steps_run
+                    ended = steps_run
+                    with obs.span("sweep.block"):
+                        state = self.segment(
+                            state, progs, jnp.asarray(steps_run, jnp.int32)
+                        )
+                        landed = state.status
+                        # A traced job's samples, dispatched behind the
+                        # segment and pulled at its harvest: each lane's
+                        # valid rows, the FIFO discipline's two counts,
+                        # the app's progress counts.
+                        samples = (
+                            self._samples(state) if sample_pool else None
+                        )
+                    steps_run = np.minimum(ended + seg_steps, max_steps)
+                    # Budget exhaustion (the plain kernel's
+                    # run-out-of-steps semantics), queued behind the
+                    # segment with no pull: which lanes spend their last
+                    # step in it follows from their counts, and
+                    # ``finalize`` is the identity on a lane that
+                    # finished on its own.
+                    spent = active & (ended < max_steps) & (
+                        steps_run >= max_steps
+                    )
+                    if spent.any():
+                        with obs.span("sweep.finalize"):
+                            state = self.refill(
+                                state, jnp.asarray(spent),
+                                self.finalize(state),
+                            )
+                    latest = (state, samples, steps_run, lane_seed)
+                t_gap = time.perf_counter()
+                harvested, sampled, steps_ended, seeds_then = (
+                    held if lag else latest
                 )
+                held = latest if lag else None
                 if type(stock) is _Stock and exposed_ns >= _WORTH_S * 1e9:
                     # The making has cost what the forks will: fork,
                     # while the device runs the segment.
@@ -1195,9 +1244,17 @@ class ContinuousSweepDriver:
                 self.last_segment_seconds += (t_gap - t_seg) + (
                     t_harvest - t_pull
                 )
-                finished = active & (status >= ST_DONE)
-                out, refill_lanes = None, ()
-                if finished.any():
+                # Lanes first seen finished in this pull (under the lag:
+                # they stopped on their own a segment ago and sat frozen
+                # through the one in flight), and with them the lanes
+                # the harvested segment was known to spend. A lane the
+                # segment in flight spends by count that is seen
+                # finished here is retired here, once.
+                seen = active & ~renewed & (status >= ST_DONE)
+                retiring = seen | owed
+                spent = spent & ~seen
+                out = None
+                if retiring.any():
                     with obs.span("sweep.pull"):
                         vio = np.asarray(harvested.violation)
                         sh = np.asarray(harvested.sched_hash)
@@ -1206,18 +1263,15 @@ class ContinuousSweepDriver:
                             # status pull above is the round's one sync
                             # point; deliveries ride the same harvest
                             # (never per segment step).
-                            self._record_round_stats(harvested, finished, vio)
+                            self._record_round_stats(harvested, retiring, vio)
                     with obs.span("sweep.retire"):
-                        fin = np.flatnonzero(finished)
-                        # Seeds gathered BEFORE refill rewrites lane_seed.
-                        out = (
-                            np.asarray(lane_seed, np.int64)[fin],
-                            status[fin].copy(), vio[fin].copy(),
-                            sh[fin].copy(),
-                        )
+                        fin = np.flatnonzero(retiring)
+                        # The seeds the harvested segment ran: a lane's
+                        # refill may have been queued behind it since.
+                        out = (seeds_then[fin], status[fin], vio[fin], sh[fin])
                         done_count += len(fin)
                         # (frozen through the segment in flight)
-                        count_steps(0, -lag * len(fin) * seg_steps)
+                        count_steps(0, -lag * int(seen.sum()) * seg_steps)
                         unfinished = int(
                             (out[1] == ST_UNFINISHED).sum()
                         )
@@ -1285,57 +1339,59 @@ class ContinuousSweepDriver:
                                         f"sweep.app.{name}", total
                                     )
                             obs.stage_count("sweep.retired", len(fin))
+                            # (of them, those the host knew spent: their
+                            # lanes were free behind their last segment)
+                            obs.stage_count(
+                                "sweep.budget_retired", int(owed.sum())
+                            )
                             obs.stage_count(
                                 "sweep.quiesced",
                                 int((out[1] <= ST_VIOLATION).sum()),
                             )
                             obs.stage_count("sweep.unfinished", unfinished)
-                        # Refill finished lanes with fresh seeds (or park
-                        # them).
-                        refill_lanes = set(
-                            int(x) for x in np.flatnonzero(finished)[
-                                : max(0, total_lanes - next_idx)
-                            ]
-                        )
-                        for lane in np.flatnonzero(finished):
-                            active[lane] = False
                 # The harvest is over: what it read goes before the
                 # refill builds a fresh state beside the one in flight.
-                held = harvested = sampled = None
-                if refill_lanes:
-                    fresh_seeds = seed_list[
-                        next_idx : next_idx + len(refill_lanes)
-                    ]
-                    next_idx += len(refill_lanes)
-                    # Ascending, as the loop below hands the seeds
-                    # out. Under the lag the segment in flight took
-                    # these same arrays: it holds every one of
-                    # these lanes frozen (module doc).
+                harvested = sampled = None
+                # Lanes free for the next seeds, in seed order (the rest
+                # are parked): those seen finished, and under the lag
+                # those the segment in flight spends, whose verdicts the
+                # next round reads from ``held``.
+                free = seen | spent
+                take = np.flatnonzero(free)[: max(0, total_lanes - next_idx)]
+                active = active & ~free
+                owed, renewed = spent, no_lane
+                if len(take):
+                    upto = next_idx + len(take)
+                    if lag:
+                        # The segment in flight took ``progs`` and reads
+                        # the rows of the lanes it spends: the fill
+                        # writes the other set, brought up to date by
+                        # the rows the last refill gave this one, and
+                        # the next segment takes that (module doc).
+                        progs, spare = spare, progs
+                        with obs.span("sweep.stack"):
+                            for mine, theirs in zip(progs, spare):
+                                mine[stale] = theirs[stale]
+                        stale = take
                     spent_ns = self._fill(
-                        fresh_seeds, sorted(refill_lanes), progs, stock
+                        seed_list[next_idx:upto], take, progs, stock
                     )
                     if type(stock) is _Stock:
                         exposed_ns += spent_ns
                     with obs.span("sweep.refill"):
                         mask = np.zeros(b, bool)
-                        full_seeds = []
-                        k = 0
-                        for lane in range(b):
-                            if lane in refill_lanes and k < len(
-                                fresh_seeds
-                            ):
-                                mask[lane] = True
-                                lane_seed[lane] = fresh_seeds[k]
-                                full_seeds.append(fresh_seeds[k])
-                                active[lane] = True
-                                steps_run[lane] = 0
-                                k += 1
-                            else:
-                                full_seeds.append(lane_seed[lane])
+                        mask[take] = True
+                        # (fresh arrays: ``held`` keeps the old ones)
+                        lane_seed = lane_seed.copy()
+                        lane_seed[take] = seed_arr[next_idx:upto]
+                        steps_run = np.where(mask, 0, steps_run)
+                        active[take] = True
                         state = self.refill(
                             state, jnp.asarray(mask),
-                            self.init(keys_for(full_seeds)),
+                            self.init(keys_for(lane_seed)),
                         )
+                    next_idx = upto
+                    renewed = mask if lag else no_lane
                 self.last_harvest_seconds += time.perf_counter() - t_harvest
             # Yield outside every span, and after the timing stop: caller
             # time (a generator consumer may do arbitrary work per item)

@@ -80,11 +80,12 @@ def test_continuous_nondivisible_seg_steps():
     _parity(*_raft_fixture(40), 64, 8, 28)
 
 
-def test_sweep_driver_continuous_parity_and_occupancy():
+def test_sweep_driver_continuous_parity_and_occupancy(monkeypatch):
     """SweepDriver.sweep defaults to the lane-compacted continuous path:
     per-seed verdicts must match chunked mode exactly (same fold_in key
     scheme), and on a heavy-tailed corpus the compacted sweep's lane-step
     occupancy must stay high (the whole point of the refill)."""
+    from demi_tpu.device import continuous
     from demi_tpu.parallel.sweep import SweepDriver
 
     app, cfg, gen = _raft_fixture(160)
@@ -99,9 +100,21 @@ def test_sweep_driver_continuous_parity_and_occupancy():
     assert cont.unique_schedules == chunked.unique_schedules
     # Heavy-tailed corpus: quick-crash lanes end far below max_steps, so
     # the compacted sweep must scan meaningfully fewer lane-steps than
-    # the fixed sweep's lanes * max_steps.
+    # the fixed sweep's lanes * max_steps. That is the strict order's
+    # pin; at 4 segments a life the harvest lags (PR 48), every lane
+    # that stops on its own sits frozen through one more segment, and
+    # the job scans whole rounds more: never more than the fixed sweep,
+    # and the same live lane-steps.
     drv = driver._continuous_driver(8)
+    assert drv._lag() == 1
+    lagged = (drv.last_total_lane_steps, drv.last_live_lane_steps)
+    monkeypatch.setattr(continuous, "_LAG_LIFE", 1 << 30)
+    strict = driver.sweep(48, 8)
+    assert drv._lag() == 0 and strict.lanes_digest == cont.lanes_digest
     assert 0 < drv.last_total_lane_steps < 48 * cfg.max_steps
+    assert drv.last_total_lane_steps <= lagged[0] <= 48 * cfg.max_steps
+    assert (lagged[0] - drv.last_total_lane_steps) % (8 * drv.seg_steps) == 0
+    assert lagged[1] == drv.last_live_lane_steps
     # first_violating_seed is a real, replayable seed in BOTH modes.
     assert chunked.first_violating_seed in range(48)
     assert cont.first_violating_seed in range(48)
@@ -665,7 +678,29 @@ _LAG_CASES = {
     "unfinished_at_the_budget": (_short_broadcast_fixture, 24, 8, 8, 0),
     "mesh": (_broadcast_fixture, 20, 8, 16, 2),
     "shorter_than_a_resident_set": (_broadcast_fixture, 5, 8, 16, 0),
+    # PR 48, 4 segments a life: every lane runs to its budget; some
+    # stop on their own, one of them a segment before its budget
+    "budget": (lambda: _budget_fixture(), 24, 8, 16, 0),
+    "mixed": (lambda: _raft_fixture(64), 64, 8, 16, 0),
+    "budget_mesh": (lambda: _budget_fixture(), 20, 8, 16, 2),
 }
+
+
+def _budget_fixture():
+    """A correct raft nobody kills: its timers keep every schedule going
+    to its last step."""
+    app = make_raft_app(3)
+    cfg = DeviceConfig.for_app(
+        app, pool_capacity=96, max_steps=64, max_external_ops=24,
+        invariant_interval=1, timer_weight=0.1,
+    )
+    fz = Fuzzer(
+        num_events=10,
+        weights=FuzzerWeights(send=0.6, wait_quiescence=0.4),
+        message_gen=raft_send_generator(app),
+        prefix=dsl_start_events(app), wait_budget=(5, 30),
+    )
+    return app, cfg, lambda s: fz.generate_fuzz_test(seed=s)
 
 
 class _Lagged:
@@ -677,6 +712,7 @@ class _Lagged:
 
         fixture, self.n, batch, seg_steps, devices = _LAG_CASES[case]
         self.app, self.cfg, gen = fixture()
+        self.gen = gen
         mesh = make_mesh(jax.devices()[:devices]) if devices else None
         self.drv = ContinuousSweepDriver(
             self.app, self.cfg, gen, batch=batch, seg_steps=seg_steps,
@@ -743,7 +779,8 @@ def test_the_lagged_harvest_changes_no_verdict(case, monkeypatch):
     assert _per_seed(plain) == fx.want
     assert _per_seed(lagged) == fx.want
     assert _digest(_per_seed(lagged)) == _digest(fx.want)
-    assert sorted(_per_seed(lagged)) == list(range(fx.n))
+    # every seed once: no lane is retired by two paths
+    assert sorted(s for batch in lagged for s in batch[0]) == list(range(fx.n))
     assert fx.run(monkeypatch, lag=1) == lagged
     assert fx.drv.last_total_lane_steps >= plain_steps
     assert 0 < fx.drv.last_live_lane_steps <= fx.drv.last_total_lane_steps
@@ -809,48 +846,62 @@ def test_an_outstanding_segment_leaves_the_driver_reusable(
     assert ahead.run(seed_pure=True) == want
 
 
-def test_a_fill_writes_only_rows_the_segment_in_flight_holds_frozen(
-    monkeypatch,
+@pytest.mark.parametrize("case", ["broadcast", "budget", "mixed"])
+def test_a_fill_never_writes_a_set_a_segment_in_flight_took(
+    case, monkeypatch
 ):
-    """Under the lag a refill's programs are written while the segment
-    dispatched with the same arrays may still read them. Every lane a
-    fill writes is finished in the state that segment took, and a
-    segment that reads garbage in those rows gives the same verdicts."""
-    fx = _Lagged("broadcast")
+    """Under the lag the segment in flight reads the rows of the lanes
+    it spends live while the fill writes their successors' (PR 48): a
+    fill writes the other resident set. Every dispatched set still holds
+    its bytes of the dispatch when the next segment is dispatched (the
+    one pull between the two is of the segment before it), whatever is
+    written meanwhile, and no fill's target shares memory with it."""
+    fx = _Lagged(case)
     segment, fill = fx.drv.segment, fx.drv._fill
-    frozen = []     # of the state the last dispatched segment took
-    written = []
+    flown = []      # (the set a dispatch took, its bytes then), newest last
+    filled = []
 
-    def poisoned(state, progs, steps_run):
-        del frozen[:]
-        frozen.extend((np.asarray(state.status) >= ST_DONE).tolist())
-        rows = np.flatnonzero(frozen)
-        torn = type(progs)(*(x.copy() for x in progs))
-        for x in torn:
-            x[rows] = 1 << 30
-        return segment(state, torn, steps_run)
+    def held_to_its_bytes(state, progs, steps_run):
+        if flown:
+            took, then = flown[-1]
+            assert all(
+                np.array_equal(x, y) for x, y in zip(took, then)
+            ), "a set was written while its segment was in flight"
+        flown.append((progs, type(progs)(*(x.copy() for x in progs))))
+        return segment(state, progs, steps_run)
 
-    def checked(seeds, lanes, progs, stock=None):
-        if frozen:      # (the prime fill comes before any segment)
-            written.extend(lanes)
-            assert all(frozen[lane] for lane in lanes)
+    def not_the_set_in_flight(seeds, lanes, progs, stock=None):
+        if flown:       # (the prime fill comes before any segment)
+            filled.extend(lanes)
+            took, _then = flown[-1]
+            assert not any(
+                np.shares_memory(x, y) for x, y in zip(progs, took)
+            )
+            # whatever the free set held in these rows goes
+            for x in progs:
+                x[np.asarray(lanes)] = 1 << 30
         return fill(seeds, lanes, progs, stock)
 
-    fx.drv.segment, fx.drv._fill = poisoned, checked
+    fx.drv.segment, fx.drv._fill = held_to_its_bytes, not_the_set_in_flight
     assert _per_seed(fx.run(monkeypatch, lag=1)) == fx.want
-    assert len(written) == fx.n - fx.drv.batch
+    assert len(filled) == fx.n - fx.drv.batch
+    # two sets, taken in turn at every refill round
+    assert len({id(took.op) for took, _then in flown}) == 2
 
 
 def test_the_lag_is_a_rule_on_the_budget_in_segments():
     """1 where a schedule's budget holds ``_LAG_LIFE`` segments, else
-    0: by ``SweepDriver``'s ``seg_steps`` the cells of 1,024 steps and
-    more, never ``raft5-sweep``'s 144 (4 segments a life)."""
+    0: by ``SweepDriver``'s ``seg_steps`` every budget of 32 steps and
+    more (all nine sweep cells; ``raft5-sweep``'s 144 is 4 segments a
+    life: PR 48), never a life cut into fewer (``tools/soak.py``'s 40
+    steps in segments of 28)."""
     from demi_tpu.parallel.sweep import SweepDriver
 
-    assert continuous._LAG_LIFE == 16
+    assert continuous._LAG_LIFE == 4
     app, cfg, gen = _broadcast_fixture()
     for max_steps, seg_steps, lag in (
-        (16 * 28, 28, 1), (16 * 28 - 1, 28, 0), (96, 28, 0), (4608, 64, 1),
+        (4 * 28, 28, 1), (4 * 28 - 1, 28, 0), (96, 28, 0), (40, 28, 0),
+        (96, 32, 0), (96, 16, 1), (4608, 64, 1),
     ):
         drv = ContinuousSweepDriver(
             app, dataclasses.replace(cfg, max_steps=max_steps), gen,
@@ -858,7 +909,8 @@ def test_the_lag_is_a_rule_on_the_budget_in_segments():
         )
         assert drv._lag() == lag, (max_steps, seg_steps)
     for max_steps, seg_steps, lag in (
-        (144, 36, 0), (1023, 64, 0), (1024, 64, 1), (3328, 64, 1),
+        (144, 36, 1), (31, 8, 0), (32, 8, 1), (1023, 64, 1), (1024, 64, 1),
+        (3328, 64, 1),
     ):
         drv = SweepDriver(
             app, dataclasses.replace(cfg, max_steps=max_steps), gen
@@ -894,19 +946,16 @@ def test_the_dispatches_count_how_often_the_device_had_work_queued(
     }[queued]
 
 
-def test_the_benchmarks_reader_of_the_two_counts(monkeypatch):
-    """``benchmarks/layer_metrics/sweep.queued_segment_share.py`` over a
-    traced job's table, and None where a program keeps no such counts
-    (the parent's); its entry lists the sweep cells."""
+def _benchmark_reader(name, monkeypatch):
+    """``benchmarks/layer_metrics/<name>.py`` as the harness loads it,
+    held to its entry in ``BENCHMARK.json``: a count of the drivers'
+    layer, listed for the sweep cells."""
     import importlib.util
     import json
     import os
     import sys
 
-    from demi_tpu.parallel.sweep import SweepDriver
-
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    name = "sweep.queued_segment_share"
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
@@ -918,7 +967,7 @@ def test_the_benchmarks_reader_of_the_two_counts(monkeypatch):
     }
     monkeypatch.syspath_prepend(os.path.join(root, "benchmarks"))
     spec = importlib.util.spec_from_file_location(
-        "queued_segment_share",
+        name.replace(".", "_"),
         os.path.join(root, "benchmarks", "layer_metrics", name + ".py"),
     )
     reader = importlib.util.module_from_spec(spec)
@@ -926,7 +975,16 @@ def test_the_benchmarks_reader_of_the_two_counts(monkeypatch):
     # (the reader holds what it imported; no other test finds a ``lib``)
     for module in [m for m in sys.modules if m.split(".")[0] == "lib"]:
         del sys.modules[module]
+    return reader
 
+
+def test_the_benchmarks_reader_of_the_two_counts(monkeypatch):
+    """``benchmarks/layer_metrics/sweep.queued_segment_share.py`` over a
+    traced job's table, and None where a program keeps no such counts
+    (the parent's); its entry lists the sweep cells."""
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    reader = _benchmark_reader("sweep.queued_segment_share", monkeypatch)
     app, cfg, gen = _broadcast_fixture()
     driver = SweepDriver(app, cfg, gen)
     monkeypatch.setattr(continuous, "_LAG_LIFE", 0)
@@ -939,3 +997,125 @@ def test_the_benchmarks_reader_of_the_two_counts(monkeypatch):
         del obs.TRACER.counts["sweep.segments"]
         assert reader.read(None) is None
     assert share == pytest.approx(100.0 * (segments - 1) / segments)
+
+
+def test_the_benchmarks_reader_of_the_budget_count(monkeypatch):
+    """``benchmarks/layer_metrics/sweep.budget_refill_share.py`` (PR 48)
+    over a traced job's table: the lanes that ran to their budget over
+    the lanes retired, 0 under the strict order, and None where a
+    program keeps no such count (the parent's)."""
+    from demi_tpu.parallel.sweep import SweepDriver
+
+    reader = _benchmark_reader("sweep.budget_refill_share", monkeypatch)
+    app, cfg, gen = _raft_fixture(64)
+    driver = SweepDriver(app, cfg, gen)
+    assert driver._continuous_driver(8)._lag() == 1
+    with _spans_live():
+        assert reader.read(None) is None
+        result = driver.sweep(32, 8)
+        counts = obs.stage_counts()
+        share = reader.read(None)
+        del obs.TRACER.counts["sweep.budget_retired"]
+        assert reader.read(None) is None
+    assert counts["sweep.retired"] == result.lanes == 32
+    # a lane that violates stops on its own; the others run on
+    assert 32 - result.violations >= counts["sweep.budget_retired"] > 0
+    assert share == pytest.approx(100.0 * counts["sweep.budget_retired"] / 32)
+    monkeypatch.setattr(continuous, "_LAG_LIFE", _NEVER)
+    with _spans_live():
+        assert driver.sweep(32, 8).lanes_digest == result.lanes_digest
+        assert reader.read(None) == 0.0
+
+
+# -- PR 48: a lane the host knows spent is refilled behind its last segment --
+
+def _first_finished_in(fx, segments):
+    """Per seed of ``fx``, the segment (1-based) in which its lane first
+    reads finished on its own, over ``segments`` segments of the
+    driver's own kernels run side by side with no refill and no
+    finalize; 0 where it is still running after them."""
+    import jax.numpy as jnp
+
+    progs = stack_programs(
+        [lower_program(fx.app, fx.cfg, fx.gen(s)) for s in range(fx.n)]
+    )
+    keys = np.stack([np.asarray(jax.random.PRNGKey(s)) for s in range(fx.n)])
+    segment = fx.drv.segment
+    state = fx.drv.init(jnp.asarray(keys))
+    first = np.zeros(fx.n, int)
+    for k in range(segments):
+        steps = jnp.full(fx.n, k * fx.drv.seg_steps, jnp.int32)
+        state = segment(state, progs, steps)
+        done = np.asarray(state.status) >= ST_DONE
+        first[done & (first == 0)] = k + 1
+    return first
+
+
+def test_a_lane_that_runs_to_its_budget_pays_no_frozen_segment(monkeypatch):
+    """Every lane of the fixture ends at its budget of 4 segments: under
+    the lag the job dispatches the strict order's segments and no more
+    (none after the last wave: its lanes are known spent and no seed is
+    left), every lane-step is live, and every lane is retired by the
+    budget path."""
+    fx = _Lagged("budget")
+    dispatched = []
+    segment = fx.drv.segment
+    fx.drv.segment = lambda *a: dispatched.append(1) or segment(*a)
+    life = fx.cfg.max_steps // fx.drv.seg_steps
+    assert life == continuous._LAG_LIFE == 4
+    waves = fx.n // fx.drv.batch
+    want = waves * life * fx.drv.batch * fx.drv.seg_steps
+    for lag in (0, 1):
+        del dispatched[:]
+        with _spans_live():
+            assert _per_seed(fx.run(monkeypatch, lag)) == fx.want
+            counts = obs.stage_counts()
+        assert len(dispatched) == counts["sweep.segments"] == waves * life
+        assert fx.drv.last_total_lane_steps == want
+        assert fx.drv.last_live_lane_steps == want == fx.n * fx.cfg.max_steps
+        assert fx.drv.last_occupancy == 1.0
+        assert counts["sweep.retired"] == fx.n
+        assert counts["sweep.budget_retired"] == (fx.n if lag else 0)
+
+
+def test_a_lane_that_stops_a_segment_before_its_budget_is_retired_once(
+    monkeypatch,
+):
+    """Such a lane is seen finished at the pull of the very round that
+    counts it spent: it goes the late way (frozen through one segment),
+    not both ways. The budget path takes the lanes that run on, the
+    late path those that stop, and a frozen segment is counted out of
+    the live lane-steps for the latter alone."""
+    fx = _Lagged("mixed")
+    life = fx.cfg.max_steps // fx.drv.seg_steps
+    first = _first_finished_in(fx, life)
+    stopped = first > 0     # (in its last segment: the budget path's)
+    late = stopped & (first < life)
+    assert (first == life - 1).any() and (first == 0).any()
+    with _spans_live():
+        batches = fx.run(monkeypatch, lag=1)
+        counts = obs.stage_counts()
+    assert sorted(s for batch in batches for s in batch[0]) == list(range(fx.n))
+    assert _per_seed(batches) == fx.want
+    assert counts["sweep.retired"] == fx.n
+    assert counts["sweep.budget_retired"] == fx.n - int(late.sum())
+    # live: a lane's segments up to the one it is seen finished in
+    lived = np.where(late, first, life) * fx.drv.seg_steps
+    assert fx.drv.last_live_lane_steps == int(lived.sum())
+    lagged_steps = fx.drv.last_total_lane_steps
+    assert _per_seed(fx.run(monkeypatch, lag=0)) == fx.want
+    assert fx.drv.last_live_lane_steps == int(lived.sum())
+    assert fx.drv.last_total_lane_steps <= lagged_steps
+
+
+def test_lanes_that_stop_on_their_own_take_no_budget_path(monkeypatch):
+    """The broadcast fixture's floods quiesce inside their budget: the
+    lag costs each its frozen segment, as before PR 48, and the count
+    reads 0."""
+    fx = _Lagged("broadcast")
+    assert (_first_finished_in(fx, 5) > 0).all()
+    with _spans_live():
+        assert _per_seed(fx.run(monkeypatch, lag=1)) == fx.want
+        counts = obs.stage_counts()
+    assert counts["sweep.retired"] == fx.n
+    assert counts["sweep.budget_retired"] == 0

@@ -277,7 +277,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     # sweep.outbox_fill_share (PR 33), sweep.produced_share and
     # sweep.starve_share (PR 42: ``sweep.producers`` says the program has
     # producers at all; 24 programs are worth none),
-    # sweep.queued_segment_share (PR 46).
+    # sweep.queued_segment_share (PR 46), sweep.budget_refill_share (PR 48).
     op_kinds = {
         "start", "send", "wait", "kill", "hard_kill", "restart",
         "partition", "unpartition",
@@ -289,6 +289,7 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
         "sweep.programs", "sweep.prefetched", "sweep.row_lowered",
         "sweep.produced", "sweep.producers",
         "sweep.retired", "sweep.quiesced", "sweep.unfinished",
+        "sweep.budget_retired",
         "sweep.pool_peak_rows", "sweep.pool_rows",
         "sweep.rows_inserted", "sweep.outbox_rows",
     } | {f"sweep.ops.{kind}" for kind in op_kinds}
@@ -296,12 +297,16 @@ def test_counts_at_the_stage_boundaries(clean, reversal, sweeper):
     assert counts["dpor.fresh"] == len(d.explored) - 1    # the root was seeded
     assert result.lanes == 24
     assert 0 < counts["sweep.live_lane_steps"] <= counts["sweep.lane_steps"]
-    # a dispatch a segment; 4 segments a life, so the harvest lags none
+    # a dispatch a segment; 4 segments a life, so the harvest lags one
+    # (since PR 48), and a job's first dispatch finds nothing queued
     drv = sweeper._continuous_driver(8)
     assert counts["sweep.lane_steps"] == (
         counts["sweep.segments"] * 8 * drv.seg_steps
     )
-    assert drv._lag() == 0 and counts["sweep.segments_queued"] == 0
+    assert drv._lag() == 1
+    assert 0 <= counts["sweep.segments_queued"] < counts["sweep.segments"]
+    # the lanes the host knew spent when it dispatched their last segment
+    assert 0 <= counts["sweep.budget_retired"] <= counts["sweep.retired"]
     # one program a schedule put in a lane; the prime fill's 8 never ahead
     assert counts["sweep.programs"] == 24
     assert 0 <= counts["sweep.prefetched"] <= 24 - 8
