@@ -302,9 +302,11 @@ def _obs_end(args, experiment_dir: Optional[str] = None) -> None:
     snap = obs.REGISTRY.snapshot()
     if getattr(args, "stats_out", None):
         # With the registry: where the seconds before the first job went
-        # (the setup.* stages) and the per-function compile table.
+        # (the setup.* stages), the per-function compile table, and a
+        # row of its own stages and counts for each of the run's jobs.
         doc = dict(
-            snap, setup=obs.setup_ledger(), compile=obs.compile_ledger()
+            snap, setup=obs.setup_ledger(), compile=obs.compile_ledger(),
+            jobs=obs.job_ledger(),
         )
         with open(args.stats_out, "w") as f:
             json.dump(doc, f, indent=2, sort_keys=True)

@@ -972,10 +972,11 @@ class ContinuousSweepDriver:
                         resident[lane] = held
             sp.slice("sweep.fuzz", clock[0])
             sp.slice("sweep.lower", clock[1])
-            if obs.spans.live():
+            if obs.spans.folding():
                 obs.stage_count("sweep.programs", len(seeds))
                 obs.stage_count("sweep.prefetched", hosts)
                 obs.stage_count("sweep.produced", int(made.sum()) - hosts)
+            if obs.spans.live():
                 obs.stage_count("sweep.row_lowered", rows)
                 # What this fill lowered, by kind of external op: how
                 # much of the fault plane the traffic engages.
@@ -1158,10 +1159,13 @@ class ContinuousSweepDriver:
                 # every lane left and no seed is: the round only harvests.
                 n_active = int(active.sum())
                 latest, spent = None, no_lane
+                # What the host already knows is counted while spans
+                # fold; what buys device work, only while they are live.
+                counting = obs.spans.folding()
                 t_seg = time.perf_counter()
                 if n_active:
                     count_steps(b * seg_steps, n_active * seg_steps)
-                    if obs.spans.live():
+                    if counting:
                         # (no probe while nothing counts: a dispatch's cost)
                         obs.stage_count("sweep.segments")
                         obs.stage_count(
@@ -1234,6 +1238,17 @@ class ContinuousSweepDriver:
                             obs.stage_count("sweep.fifo_pending_rows", pending)
                             obs.stage_count("sweep.fifo_head_rows", heads)
                 t_harvest = time.perf_counter()
+                if counting:
+                    # The host thread inside ``segment(...)``, the
+                    # samples' dispatch and the ``finalize`` behind it;
+                    # and blocked at the status pull. The job row's
+                    # alone: ``stage_counts()`` keeps no nanoseconds.
+                    obs.spans.job_count(
+                        "sweep.dispatch_ns", int((t_gap - t_seg) * 1e9)
+                    )
+                    obs.spans.job_count(
+                        "sweep.wait_ns", int((t_harvest - t_pull) * 1e9)
+                    )
                 self.last_harvest_seconds += t_pull - t_gap
                 if self.last_lane_sharding is None:
                     from ..parallel.mesh import lane_sharding_summary
@@ -1276,6 +1291,13 @@ class ContinuousSweepDriver:
                             (out[1] == ST_UNFINISHED).sum()
                         )
                         self.last_unfinished_lanes += unfinished
+                        if counting:
+                            obs.stage_count("sweep.retired", len(fin))
+                            # (of them, those the host knew spent: their
+                            # lanes were free behind their last segment)
+                            obs.stage_count(
+                                "sweep.budget_retired", int(owed.sum())
+                            )
                         if sampled:
                             # What the retired lanes put in their pools
                             # (every insert advances ``seq_counter`` by
@@ -1338,12 +1360,6 @@ class ContinuousSweepDriver:
                                     obs.stage_count(
                                         f"sweep.app.{name}", total
                                     )
-                            obs.stage_count("sweep.retired", len(fin))
-                            # (of them, those the host knew spent: their
-                            # lanes were free behind their last segment)
-                            obs.stage_count(
-                                "sweep.budget_retired", int(owed.sum())
-                            )
                             obs.stage_count(
                                 "sweep.quiesced",
                                 int((out[1] <= ST_VIOLATION).sum()),

@@ -8,9 +8,11 @@ The snapshot half (one switch, off by default):
     timing histograms with JSON snapshot + cross-process merge;
   - ``spans``: nested ``span("stage.name", ...)`` tracing with JSONL and
     Chrome/Perfetto ``trace_event`` export, a per-name totals table
-    (``stage_totals()`` / ``stage_counts()``), and, while a
-    ``jax.profiler`` session records, the same spans on its timeline as
-    ``demi.<name>`` (live then with no switch); and, with no switch at
+    (``stage_totals()`` / ``stage_counts()``), a row of the same stages
+    for each job (``job_ledger()``), and, while a ``jax.profiler``
+    session records, the same spans on its timeline as ``demi.<name>``
+    (live then with no switch; the rows keep coming after it with
+    nothing recorded); and, with no switch at
     all, the set-up stages and the compile ledger (``setup_ledger()`` /
     ``compile_ledger()``): where the seconds before a command's first
     job went, and which jitted functions they were spent on;
@@ -62,6 +64,7 @@ from .spans import (  # noqa: F401
     TRACER,
     Tracer,
     compile_ledger,
+    job_ledger,
     new_job,
     record_span,
     setup_ledger,
@@ -86,6 +89,7 @@ __all__ = [
     "enabled",
     "gauge",
     "histogram",
+    "job_ledger",
     "journal",
     "merge_snapshots",
     "new_job",

@@ -25,6 +25,19 @@ whose numbers are span durations keeps spans live while it is on
 DEMI_PROFILE=1 / ``--profile-rounds`` also hold finished spans in TRACER
 (up to ``max_spans``) and record the collector's passes.
 
+Every job keeps a row of its own stages (``job_ledger()``): a span that
+carries ``job=`` while no row is open on its thread opens one, every
+span, slice and count that closes under it folds into the row as it
+folds into the tables above, and the row is kept (the last
+``_ROWS_MAX``) when that span exits. A process in which a recording
+session has been seen keeps *folding* once the session has ended
+(``folding()``): a span then stamps its clock pair and adds to the open
+row, and nothing else; it records nothing, annotates nothing and leaves
+the totals and counts tables alone, so those hold what was live (the
+benchmark's traced job) and the rows hold every job after it too (the
+window's), ``recorded: false``. A process that never saw a session, with
+telemetry off, folds nothing: a ``span(...)`` reads one more bool.
+
 Every span carries the span that caused it (``parent``: the enclosing
 span's ``op_b``) and the request it belongs to (``job``: a number from
 ``new_job()`` given to a root span and inherited below it). At exit a
@@ -45,6 +58,7 @@ loaded from the persistent cache in them.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import gc
@@ -76,6 +90,10 @@ _jobs = itertools.count(1)
 _annotation = None      # jax.profiler.TraceAnnotation, once jax is imported
 _gc_hooked = False
 _switches: List[Callable[[], bool]] = []    # see live_while
+_session_seen = False   # a jax.profiler session has recorded in this process
+_ROWS_MAX = 256
+# Finished job rows, oldest first (``job_ledger``), under ``_lock``.
+_rows: "collections.deque[Dict[str, Any]]" = collections.deque(maxlen=_ROWS_MAX)
 
 
 def _now_us() -> int:
@@ -110,12 +128,24 @@ def live_while(switch: Callable[[], bool]) -> None:
 
 def live() -> bool:
     """Whether a span entered now records."""
-    if _metrics.enabled() or _profiling():
+    global _session_seen
+    if _metrics.enabled():
+        return True
+    if _profiling():
+        _session_seen = True
         return True
     for switch in _switches:
         if switch():
             return True
     return False
+
+
+def folding() -> bool:
+    """Whether a span entered now folds into its job's row: it is live,
+    or a recording session has been seen in this process. What costs
+    the device nothing is counted under this; what buys device work (a
+    sample, a pull) stays under ``live()``."""
+    return _session_seen or live()
 
 
 def now_us() -> int:
@@ -146,6 +176,16 @@ def _export_args(s: Dict[str, Any]) -> Dict[str, Any]:
     args = s["args"]
     extra = {k: s[k] for k in ("parent", "job") if s.get(k) is not None}
     return {**args, **extra} if extra else args
+
+
+def _fold(totals: Dict[str, List[int]], name: str, dur_ns: int,
+          self_ns: int) -> None:
+    t = totals.get(name)
+    if t is None:
+        t = totals[name] = [0, 0, 0]
+    t[0] += 1
+    t[1] += dur_ns
+    t[2] += self_ns
 
 
 class Tracer:
@@ -189,12 +229,7 @@ class Tracer:
 
     def fold(self, name: str, dur_ns: int, self_ns: int) -> None:
         with _lock:
-            t = self.totals.get(name)
-            if t is None:
-                t = self.totals[name] = [0, 0, 0]
-            t[0] += 1
-            t[1] += dur_ns
-            t[2] += self_ns
+            _fold(self.totals, name, dur_ns, self_ns)
 
     def clear(self) -> None:
         with _lock:
@@ -202,6 +237,7 @@ class Tracer:
             self.dropped = 0
             self.totals.clear()
             self.counts.clear()
+            _rows.clear()
 
     # -- exports ------------------------------------------------------------
     def write_jsonl(self, path: str) -> None:
@@ -297,11 +333,71 @@ def stage_counts() -> Dict[str, int]:
 
 def stage_count(name: str, n: int = 1) -> None:
     """Add ``n`` to the count ``name``, at the boundary where the work
-    happens; kept only while spans are live."""
-    if not live():
+    happens; kept only while spans are live, and in the open job row
+    while they fold."""
+    if live():
+        with _lock:
+            TRACER.counts[name] = TRACER.counts.get(name, 0) + int(n)
+    elif not _session_seen:
         return
+    job_count(name, n)
+
+
+def job_count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the count ``name`` of the job row open on this
+    thread, and to nothing else (``stage_counts()`` never sees it);
+    nothing without an open row."""
+    row = getattr(_local, "row", None)
+    if row is not None:
+        row.counts[name] = row.counts.get(name, 0) + int(n)
+
+
+class _JobRow:
+    """The row a job's root span keeps open on its thread."""
+
+    __slots__ = ("root", "recorded", "profiled", "totals", "counts")
+
+    def __init__(self, root: "span", recorded: bool, profiled: bool):
+        self.root = root
+        self.recorded = recorded
+        self.profiled = profiled
+        self.totals: Dict[str, List[int]] = {}
+        self.counts: Dict[str, int] = {}
+
+    def finish(self, dur_ns: int) -> None:
+        root = self.root
+        row = {
+            "job": root._job,
+            "root": root.name,
+            "args": dict(root.args),
+            "start_s": (root._ts - _EPOCH_NS) / 1e9,
+            "seconds": dur_ns / 1e9,
+            "recorded": self.recorded,
+            "profiled": self.profiled,
+            "stages": _as_table(self.totals),
+            "counts": dict(self.counts),
+        }
+        with _lock:
+            _rows.append(row)
+
+
+def job_ledger() -> List[Dict[str, Any]]:
+    """A row for each of the last ``_ROWS_MAX`` jobs that ran while
+    spans folded, oldest first: ``job``, ``root`` (the span that carried
+    ``job=``: ``sweep.job``, ``dpor.search``) with its ``args``,
+    ``start_s`` on the span clock and ``seconds``; ``recorded`` (spans
+    were live at its entry: TRACER and the totals table hold it too) and
+    ``profiled`` (a ``jax.profiler`` session was recording then);
+    ``stages``, shaped as ``stage_totals()`` and holding what closed
+    under the root on its thread, the root itself and ``gc.pause``
+    among them, so the self seconds sum to ``seconds``; ``counts``, as
+    ``stage_counts()`` and what ``job_count`` added."""
     with _lock:
-        TRACER.counts[name] = TRACER.counts.get(name, 0) + int(n)
+        return [
+            dict(row, args=dict(row["args"]), counts=dict(row["counts"]),
+                 stages={k: dict(v) for k, v in row["stages"].items()})
+            for row in _rows
+        ]
 
 
 def _gc_hook(phase: str, info: Dict[str, Any]) -> None:
@@ -309,9 +405,10 @@ def _gc_hook(phase: str, info: Dict[str, Any]) -> None:
     is open on this thread becomes a ``gc.pause`` span under it. With no
     span open (spans off) it is one branch."""
     if phase == "start":
-        if getattr(_local, "stack", None):
+        stack = getattr(_local, "stack", None)
+        if stack:
             pause = span("gc.pause", generation=info.get("generation"))
-            pause._enter()
+            pause._enter(stack[-1]._rec)
             _local.gc_pause = pause
     else:
         pause = getattr(_local, "gc_pause", None)
@@ -327,10 +424,12 @@ class span:
     keeping the per-thread stack discipline intact. ``job=n`` names the
     request a root span belongs to; below a span it is inherited.
     ``seconds`` is the duration once a live span has exited (0.0 for one
-    that never was live)."""
+    that never was live). A span entered while spans only fold (see
+    ``folding``) keeps the stack, its child time and ``seconds`` the
+    same way and adds to its job's row at exit; it records nothing."""
 
-    __slots__ = ("name", "args", "seconds", "_ts", "_op", "_live", "_job",
-                 "_parent", "_child_ns", "_ann")
+    __slots__ = ("name", "args", "seconds", "_ts", "_op", "_live", "_rec",
+                 "_job", "_parent", "_child_ns", "_ann")
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -340,10 +439,14 @@ class span:
 
     def __enter__(self) -> "span":
         if live():
-            self._enter()
+            self._enter(True)
+        elif _session_seen:
+            self._enter(False)
         return self
 
-    def _enter(self) -> None:
+    def _enter(self, recording: bool) -> None:
+        """Open the span on this thread's stack; ``recording`` says
+        whether it is live or only folds."""
         global _gc_hooked
         if not _gc_hooked:
             with _lock:
@@ -351,6 +454,7 @@ class span:
                     _gc_hooked = True
                     gc.callbacks.append(_gc_hook)
         self._live = True
+        self._rec = recording
         self._op = next(_ops)
         self._child_ns = 0
         stack = getattr(_local, "stack", None)
@@ -363,8 +467,12 @@ class span:
         else:
             self._parent = None
             self._job = job
+        # (a span that only folds was entered with no session recording)
+        profiling = recording and _profiling()
+        if job is not None and getattr(_local, "row", None) is None:
+            _local.row = _JobRow(self, recording, profiling)
         self._ann = None
-        if _profiling():
+        if profiling:
             self._ann = _annotation("demi." + self.name)
             self._ann.__enter__()
         # Pushed and stamped last: a collector pass that starts during
@@ -372,20 +480,30 @@ class span:
         stack.append(self)
         self._ts = time.perf_counter_ns()
 
-    def _close(self, end: int, op_e: int, tid: int, stack) -> None:
+    def _close(self, end: int, stack) -> None:
         """Finish one span whose stack entry is already popped: end its
-        annotation, hand its duration to its parent, fold and record."""
+        annotation, hand its duration to its parent, fold (into the open
+        job row, which ends with its root) and record."""
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         dur = max(0, end - self._ts)
         self.seconds = dur / 1e9
         if stack:
             stack[-1]._child_ns += dur
+        row = getattr(_local, "row", None)
+        if row is not None:
+            _fold(row.totals, self.name, dur, dur - self._child_ns)
+            if row.root is self:
+                _local.row = None
+                row.finish(dur)
+        if not self._rec:
+            return
         TRACER.fold(self.name, dur, dur - self._child_ns)
         ts = (self._ts - _EPOCH_NS) // 1000
         TRACER.record(
-            self.name, ts, (end - _EPOCH_NS) // 1000 - ts, tid, self._op,
-            op_e, self.args, self._parent, self._job,
+            self.name, ts, (end - _EPOCH_NS) // 1000 - ts,
+            threading.get_ident() & 0xFFFF, self._op, next(_ops), self.args,
+            self._parent, self._job,
         )
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -395,7 +513,6 @@ class span:
         self._live = False
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
-        tid = threading.get_ident() & 0xFFFF
         stack = getattr(_local, "stack", None)
         try:
             # Stack repair instead of an assert: a stage that raised
@@ -411,13 +528,13 @@ class span:
                         break
                     top._live = False
                     top.args.setdefault("error", "orphaned")
-                    top._close(end, next(_ops), tid, stack)
+                    top._close(end, stack)
         finally:
             # The end event is emitted from a finally so a raising
             # handler/stage can never orphan this span's B/E pair —
             # Perfetto trace validity under exceptions is pinned by
             # tests/test_obs.py.
-            self._close(end, next(_ops), tid, stack)
+            self._close(end, stack)
 
     def set(self, **args) -> None:
         """Attach result attributes discovered mid-span."""
@@ -428,11 +545,15 @@ class span:
         stage ``name``, as a child would take them: for work interleaved
         per item inside the span (summed by the caller from a clock pair
         per item), where a span per item would be a span in a per-item
-        loop. It folds into the totals table and has no interval of its
-        own, so it is in no export."""
+        loop. It folds into the totals table (and the open job row) and
+        has no interval of its own, so it is in no export."""
         if self._live:
             self._child_ns += ns
-            TRACER.fold(name, ns, ns)
+            row = getattr(_local, "row", None)
+            if row is not None:
+                _fold(row.totals, name, ns, ns)
+            if self._rec:
+                TRACER.fold(name, ns, ns)
 
 
 def current_depth() -> int:
@@ -812,12 +933,14 @@ def setup_ledger() -> Dict[str, Any]:
 
 
 def _reset_setup() -> None:
-    """Testing hook: forget every stage, the compile table and job 1,
-    and hand out job numbers from 1 again. The listeners stay."""
+    """Testing hook: forget every stage, the compile table, job 1 and
+    the job rows, and hand out job numbers from 1 again. The listeners
+    stay."""
     global _SETUP, _jobs
     with _lock:
         _SETUP = _Setup()
         _jobs = itertools.count(1)
+        _rows.clear()
         for name in [n for n in TRACER.totals if n.startswith(_SETUP_PREFIXES)]:
             del TRACER.totals[name]
     _local.__dict__.pop("cover", None)
