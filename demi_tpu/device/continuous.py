@@ -191,6 +191,7 @@ from .core import (
     DeviceConfig,
     ScheduleState,
     insert_form,
+    table_reads,
 )
 from .encoding import count_op_arrays, empty_programs, lower_into
 from .explore import (
@@ -255,6 +256,7 @@ def _segment_lane_fn(app: DSLApp, cfg: DeviceConfig, seg_steps: int):
     insert=lambda app, cfg, *a, **kw: insert_form(cfg),
     fifo=lambda app, cfg, *a, **kw: cfg.track_fifo_heads,
     channels=lambda app, cfg, *a, **kw: app.channels,
+    table_reads=lambda app, cfg, *a, **kw: table_reads(app, cfg),
 )
 def make_segment_kernel(
     app: DSLApp, cfg: DeviceConfig, seg_steps: int, mesh=None

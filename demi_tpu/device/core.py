@@ -557,6 +557,24 @@ def insert_form(cfg: DeviceConfig) -> str:
     return "short" if _short_insert_built(cfg) else "rows"
 
 
+def table_reads(app: DSLApp, cfg: DeviceConfig) -> dict:
+    """The form each of the fused step's two one-hot table reads takes in
+    a kernel of ``cfg`` (``ops.table_read_form`` at the shapes the step
+    reads at: timer parking's ``timer_mem`` rows for an outbox, the
+    insert's ``cut`` bits for the outbox and the injected rows): what a
+    ``setup.build`` stage says of them."""
+    if not cfg.use_onehot:
+        return {"gather_rows": "scatter", "gather_mat": "scatter"}
+    n, k = cfg.num_actors, cfg.max_outbox
+    injected = 1
+    if app.initial_msgs is not None:
+        injected += max(np.shape(app.initial_msgs(i))[0] for i in range(n))
+    return {
+        "gather_rows": ops.table_read_form(k, n, cfg.msg_width),
+        "gather_mat": ops.table_read_form(k + injected, n, n),
+    }
+
+
 def _sum_where(sel, col):
     """[K, P] bool, [K] -> [P]: the value of the one row a slot selects."""
     return jnp.sum(jnp.where(sel, col[:, None], 0), axis=0)

@@ -15,6 +15,10 @@ exactly when the default JAX backend is a TPU).
 Both modes are bit-identical by construction (tests/test_device.py parity
 case runs the explore kernel in both and compares all outputs).
 
+The one-hot reads of a table by a vector of indices (``gather_rows``,
+``gather_mat``) have two one-hot forms of their own, picked by the size
+of the one-hot product (``table_read_form``; tests/test_table_reads.py).
+
 The pool insert is the one access with no helper here: ``core.insert_rows``
 scatters by slot number in scatter mode (``rank_slots`` below) and, in
 one-hot mode, never computes a slot number at all: each slot reads its
@@ -117,22 +121,64 @@ def gather_vec(vec: jnp.ndarray, idx: jnp.ndarray, oh: bool):
     return vec[idx]
 
 
+# The one-hot read of a table (``gather_rows``, ``gather_mat``) has two
+# forms, chosen by the elements of the one-hot product it would
+# materialise for a lane (k indices x the table's n x w), which is static
+# at trace time. A contraction (``einsum``: a ``dot_general``) is real work
+# where an outbox of 65 to 402 rows fills the tile; a batched
+# ``dot_general`` wants its batch axis major, though, so under ``vmap`` the
+# v5e compiler carries its operands, and every fusion that shares one with
+# them, row-major: at raft's 5 x 5 x 7 that put 5 actors on the 128 lanes
+# and cost 69% of the step (PERF.md, PR 50). A select-and-reduce leaves the
+# compiler free to carry them batch-minor like the rest of the step. The
+# bound sits in the gap between the largest read that gains (chain
+# replication's 67 x 7 x 7 = 3,283) and the smallest read of a deployment
+# that loses (the flood's 64 x 64 x 2 = 8,192), so every read of the three
+# wide-outbox deployments stays the contraction it was.
+SELECT_READ_MAX = 4096
+
+
+def table_read_form(k: int, n: int, w: int) -> str:
+    """``"select"`` or ``"dot"``: the one-hot form of a read of ``k``
+    indices into an ``[n, w]`` table."""
+    return "select" if k * n * w <= SELECT_READ_MAX else "dot"
+
+
+def _select_rows(hit: jnp.ndarray, mat: jnp.ndarray) -> jnp.ndarray:
+    """hit[k, n] one-hot (or all-False) rows, mat[n, w] -> [k, w]: the row
+    each picks, zeros where it picks none. One term of each sum is
+    nonzero: the same bits as the integer contraction."""
+    if mat.dtype == jnp.bool_:
+        return jnp.any(hit[:, :, None] & mat[None], axis=1)
+    picked = jnp.sum(jnp.where(hit[:, :, None], mat[None], 0), axis=1)
+    return picked.astype(mat.dtype)
+
+
 def gather_rows(mat: jnp.ndarray, idx: jnp.ndarray, oh: bool):
-    """mat[idx] for idx[k] into mat[n, w] -> [k, w]."""
+    """mat[idx] for idx[k] into mat[n, w] -> [k, w]; out-of-range reads
+    zeros in one-hot mode."""
     if oh:
-        m = (idx[:, None] == jnp.arange(mat.shape[0])[None, :]).astype(mat.dtype)
-        return jnp.einsum("kn,nw->kw", m, mat)
+        n, w = mat.shape
+        hit = idx[:, None] == jnp.arange(n)[None, :]
+        if table_read_form(idx.shape[0], n, w) == "select":
+            return _select_rows(hit, mat)
+        return jnp.einsum("kn,nw->kw", hit.astype(mat.dtype), mat)
     return mat[idx]
 
 
 def gather_mat(mat: jnp.ndarray, ri: jnp.ndarray, ci: jnp.ndarray, oh: bool):
-    """mat[ri, ci] for paired index vectors ri[k], ci[k] into mat[n, m]."""
+    """mat[ri, ci] for paired index vectors ri[k], ci[k] into mat[n, m];
+    out-of-range reads 0/False in one-hot mode."""
     if oh:
-        roh = ri[:, None] == jnp.arange(mat.shape[0])[None, :]
-        coh = ci[:, None] == jnp.arange(mat.shape[1])[None, :]
-        rows = jnp.einsum(
-            "kn,nm->km", roh.astype(jnp.int32), mat.astype(jnp.int32)
-        )
+        n, m = mat.shape
+        roh = ri[:, None] == jnp.arange(n)[None, :]
+        coh = ci[:, None] == jnp.arange(m)[None, :]
+        if table_read_form(ri.shape[0], n, m) == "select":
+            rows = _select_rows(roh, mat)
+        else:
+            rows = jnp.einsum(
+                "kn,nm->km", roh.astype(jnp.int32), mat.astype(jnp.int32)
+            )
         picked = jnp.sum(jnp.where(coh, rows, 0), axis=1)
         if mat.dtype == jnp.bool_:
             return picked.astype(bool)

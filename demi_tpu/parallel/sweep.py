@@ -327,6 +327,12 @@ class _RewardBucket:
             self._fire()
 
 
+def segment_steps(max_steps: int) -> int:
+    """The steps of one segment of a continuous sweep whose schedules run
+    ``max_steps``: a quarter of a schedule, within 8 and 64."""
+    return max(8, min(64, max_steps // 4))
+
+
 class SweepDriver:
     @obs.spans.staged("setup.build", what="SweepDriver")
     def __init__(
@@ -848,7 +854,7 @@ class SweepDriver:
         drv = ContinuousSweepDriver(
             self.app, self.cfg, program_gen or self.program_gen,
             batch=batch,
-            seg_steps=max(8, min(64, self.cfg.max_steps // 4)),
+            seg_steps=segment_steps(self.cfg.max_steps),
             mesh=self.mesh,
             # Same per-seed key scheme as run_chunk => identical verdicts.
             # No np.uint32() wrapper: the seed must stay traceable so the

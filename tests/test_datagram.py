@@ -454,16 +454,18 @@ def test_no_other_network_counts_it():
 
 @pytest.mark.parametrize("config,record,index_mode,sha", [
     # (the one segment here with the insert's short pass: its ``case`` has
-    # had a third region since PR 45, and this is what that tree lowered;
-    # at cccab40 it was a5fae2621ef1c20d...)
+    # had a third region since PR 45; at cccab40 it was a5fae2621ef1c20d...
+    # The three one-hot segments are what PR 50's tree lowers: their table
+    # reads are selects since, not ``dot_general``s; before, 4cf328d82ca3...,
+    # 483476c6ff21..., 6750f2f0840b...)
     ("chain7-fifo", False, "onehot",
-     "4cf328d82ca32ce6372daa38e2c7003e3e13ffb4ef461ee4a1f624dcf92f24ad"),
+     "089e080bfb600a595ff6de7542a1acc76b09cf8fe12553115dd140d60ebf58b6"),
     ("chain7-fifo", False, "scatter",
      "aa8cbeabca70a00b8c808370dfa2229e2a37e51cc19fb1544392360e5f94a450"),
     ("raft5-nemesis", False, "onehot",
-     "483476c6ff21e87689a87fc90f0cd9d94c8d98f1ceff22c3abe956ecb2a8a529"),
+     "953542ec686b291ae2550f15142d2ee48c6770305009003871d0dcba51d7dce7"),
     ("raft5-multivote", True, "onehot",
-     "6750f2f0840b745a22aaec0b93d5b81060e2eae504b8d7e63cdf371440f57007"),
+     "f23f5827583bc65c7118e8204e971cda4a644e1a0dc4a3083d847a336cb6d020"),
 ])
 def test_a_fifo_and_an_any_app_lower_to_the_parents_segment(
     config, record, index_mode, sha
