@@ -41,6 +41,8 @@ SIZES = {
         paxos_events=96, paxos_violating=1,
         reconfig_lanes=1024, reconfig_log_cap=32, reconfig_steps=2048,
         reconfig_pool=512, reconfig_events=96, reconfig_commits=30,
+        kafka_lanes=1024, kafka_nodes=6, kafka_log_cap=24,
+        kafka_steps=2048, kafka_pool=256, kafka_events=96, kafka_elections=40,
     ),
     "tiny": dict(
         nodes=3, pool=48, steps=64, lanes=64, chunk=32, lifts=2,
@@ -50,6 +52,8 @@ SIZES = {
         paxos_events=24, paxos_violating=0,
         reconfig_lanes=32, reconfig_log_cap=8, reconfig_steps=256,
         reconfig_pool=128, reconfig_events=48, reconfig_commits=3,
+        kafka_lanes=32, kafka_nodes=5, kafka_log_cap=8,
+        kafka_steps=256, kafka_pool=128, kafka_events=48, kafka_elections=6,
     ),
 }
 
@@ -417,18 +421,14 @@ def phase_datagram(smoke: Smoke) -> None:
         )
 
 
-def phase_reconfig(smoke: Smoke) -> None:
-    """Row-scale ``DSLApp.durable`` outside the benchmark: one sweep of the
-    reconfiguring raft (``--app raft_reconfig``, ``snapshot_keeps_config``)
-    through the CLI's normal path under crash-recovery and partitions. A
-    violating lane where there is one, and clean ones, are re-run traced and
-    lifted: the host oracle restarts servers from their durable rows to the
-    same code, the same ``sched_hash`` and the same final rows, which hold
-    compactions, installed snapshots and restarts."""
+def _lifted_rows(w: dict, picked, violating: dict, label: str):
+    """``lift_lane_to_host``'s ritual on the seeds ``picked`` of workload
+    ``w``, keeping the host's actors: each lane is re-run traced and
+    replayed on the host oracle, which must give the sweep's code and the
+    traced lane's deliveries; yields the host's final rows, ``[actors, S]``."""
     import jax
     import numpy as np
 
-    from demi_tpu.apps import raft_reconfig as rr
     from demi_tpu.apps.common import make_host_invariant
     from demi_tpu.config import SchedulerConfig
     from demi_tpu.device.encoding import (
@@ -437,6 +437,69 @@ def phase_reconfig(smoke: Smoke) -> None:
     from demi_tpu.device.explore import make_single_lane_trace_kernel
     from demi_tpu.parallel.distributed import build_workload
     from demi_tpu.schedulers.guided import GuidedScheduler
+
+    app, cfg, fuzzer = build_workload(w)
+    seeds = np.asarray(picked, np.uint32)
+    progs = stack_programs([
+        lower_program(app, cfg, fuzzer.generate_fuzz_test(seed=int(x)))
+        for x in seeds
+    ])
+    keys = jax.vmap(
+        lambda x: jax.random.fold_in(jax.random.PRNGKey(0), x)
+    )(seeds)
+    kernel = make_single_lane_trace_kernel(app, cfg)
+    config = SchedulerConfig(invariant_check=make_host_invariant(app))
+    for lane, seed in enumerate(picked):
+        single = kernel(
+            jax.tree_util.tree_map(lambda x: x[lane], progs), keys[lane]
+        )
+        sched = GuidedScheduler(config, app)
+        host = sched.execute_guide(device_trace_to_guide(
+            app, np.asarray(single.trace), int(single.trace_len)
+        ))
+        code = violating.get(seed, 0)
+        host_code = host.violation.code if host.violation else 0
+        check(
+            int(single.violation) == host_code == code,
+            f"{label} seed {seed}: sweep {code}, traced "
+            f"{int(single.violation)}, host {host_code}",
+        )
+        check(
+            int(single.deliveries) == host.deliveries,
+            f"{label} seed {seed}: the host delivered another sequence",
+        )
+        yield np.stack([
+            np.asarray(sched.system.actors[app.actor_name(i)].state)
+            for i in range(app.num_actors)
+            if app.actor_name(i) in sched.system.actors
+        ])
+
+
+def _fault_plane_flags(w: dict) -> list:
+    """A workload's mix as the CLI's flags."""
+    return [
+        "--pool", str(w["pool"]), "--max-messages", str(w["max_messages"]),
+        "--num-events", str(w["num_events"]),
+        "--timer-weight", str(w["timer_weight"]),
+        "--send-weight", str(w["send_weight"]),
+        "--wait-weight", str(w["wait_weight"]),
+        "--hard-kill-weight", str(w["hard_kill_weight"]),
+        "--restart-weight", str(w["restart_weight"]),
+        "--partition-weight", str(w["partition_weight"]),
+        "--kill-weight", "0", "--max-kills", str(w["max_kills"]),
+        "--wait-budget", *map(str, w["wait_budget"]), "--strict-io",
+    ]
+
+
+def phase_reconfig(smoke: Smoke) -> None:
+    """Row-scale ``DSLApp.durable`` outside the benchmark: one sweep of the
+    reconfiguring raft (``--app raft_reconfig``, ``snapshot_keeps_config``)
+    through the CLI's normal path under crash-recovery and partitions. A
+    violating lane where there is one, and clean ones, are re-run traced and
+    lifted: the host oracle restarts servers from their durable rows to the
+    same code, the same ``sched_hash`` and the same final rows, which hold
+    compactions, installed snapshots and restarts."""
+    from demi_tpu.apps import raft_reconfig as rr
 
     z = smoke.size
     w = {
@@ -456,14 +519,8 @@ def phase_reconfig(smoke: Smoke) -> None:
             "--bug", "snapshot_keeps_config",
             "--log-cap", str(w["log_cap"]),
             "--snapshot-every", str(w["snapshot_every"]),
-            "--batch", str(z["reconfig_lanes"]), "--pool", str(w["pool"]),
-            "--max-messages", str(w["max_messages"]),
-            "--num-events", str(w["num_events"]), "--timer-weight", "0.1",
-            "--send-weight", "0.5", "--wait-weight", "0.28",
-            "--hard-kill-weight", "0.08", "--restart-weight", "0.1",
-            "--partition-weight", "0.04", "--kill-weight", "0",
-            "--max-kills", "4", "--wait-budget", "1", "40", "--strict-io",
-        ])
+            "--batch", str(z["reconfig_lanes"]),
+        ] + _fault_plane_flags(w))
         smoke.check_device(s)
         check(s["lanes"] == z["reconfig_lanes"], f"reconfig: {s['lanes']} lanes")
         check(s["overflow_lanes"] == 0, "reconfig: overflow lanes")
@@ -472,44 +529,10 @@ def phase_reconfig(smoke: Smoke) -> None:
         violating = dict(s["violating_seeds"])
         picked = sorted(violating)[:1]
         picked += [x for x in range(z["reconfig_lanes"]) if x not in violating][:3]
-        app, cfg, fuzzer = build_workload(w)
-        seeds = np.asarray(picked, np.uint32)
-        progs = stack_programs([
-            lower_program(app, cfg, fuzzer.generate_fuzz_test(seed=int(x)))
-            for x in seeds
-        ])
-        keys = jax.vmap(
-            lambda x: jax.random.fold_in(jax.random.PRNGKey(0), x)
-        )(seeds)
         done = dict.fromkeys(
             ("COMMIT", "COMPACTIONS", "SNAP_INSTALLED", "RESTORES"), 0
         )
-        kernel = make_single_lane_trace_kernel(app, cfg)
-        config = SchedulerConfig(invariant_check=make_host_invariant(app))
-        for lane, seed in enumerate(picked):
-            # lift_lane_to_host's ritual, keeping the host's actors
-            single = kernel(
-                jax.tree_util.tree_map(lambda x: x[lane], progs), keys[lane]
-            )
-            sched = GuidedScheduler(config, app)
-            host = sched.execute_guide(device_trace_to_guide(
-                app, np.asarray(single.trace), int(single.trace_len)
-            ))
-            code = violating.get(seed, 0)
-            host_code = host.violation.code if host.violation else 0
-            check(
-                int(single.violation) == host_code == code,
-                f"reconfig seed {seed}: sweep {code}, traced "
-                f"{int(single.violation)}, host {host_code}",
-            )
-            check(
-                int(single.deliveries) == host.deliveries,
-                f"reconfig seed {seed}: the host delivered another sequence",
-            )
-            rows = np.stack([
-                np.asarray(actor.state)
-                for actor in sched.system.actors.values()
-            ])
+        for rows in _lifted_rows(w, picked, violating, "reconfig"):
             done["COMMIT"] = max(done["COMMIT"], int(rows[:, rr.COMMIT].max()))
             for name in ("COMPACTIONS", "SNAP_INSTALLED"):
                 done[name] += int(rows[:, getattr(rr, name)].sum())
@@ -522,6 +545,62 @@ def phase_reconfig(smoke: Smoke) -> None:
             lanes=s["lanes"], violations=s["violations"],
             lanes_lifted=len(picked), host_agrees=True,
             lanes_digest=s["lanes_digest"],
+            **{k.lower(): v for k, v in done.items()},
+        )
+
+
+def phase_kafka(smoke: Smoke) -> None:
+    """Per-pair FIFO links under hard kills, restarts and cuts, and a row
+    with a partition axis, outside the benchmark: one sweep of Kafka's
+    partition replication (``--app kafka``, ``truncate_to_hw``) through the
+    CLI's normal path. A violating lane where there is one, and clean ones,
+    are re-run traced and lifted: the host oracle, which refuses a delivery
+    that is not its channel's oldest, gives the same code, the same
+    deliveries and final rows that hold elections, ISR changes, fenced
+    fetches and restarts from the durable rows."""
+    from demi_tpu.apps import kafka as kf
+
+    z = smoke.size
+    nodes = z["kafka_nodes"]
+    w = {
+        "app": "kafka", "nodes": nodes, "bug": "truncate_to_hw", "seed": 0, "log_cap": z["kafka_log_cap"],
+        "num_events": z["kafka_events"], "max_messages": z["kafka_steps"],
+        "pool": z["kafka_pool"], "timer_weight": 0.3, "send_weight": 0.4,
+        "wait_weight": 0.28, "hard_kill_weight": 0.08,
+        "restart_weight": 0.12, "partition_weight": 0.04, "kill_weight": 0.0,
+        "max_kills": 4, "wait_budget": [1, 40],
+    }
+    lay = kf.state_layout(nodes, w["log_cap"])
+    with smoke.phase("kafka_sweep") as rec:
+        s = smoke.verb([
+            "sweep", "--app", "kafka", "--nodes", str(nodes),
+            "--bug", "truncate_to_hw",
+            "--log-cap", str(w["log_cap"]), "--batch", str(z["kafka_lanes"]),
+        ] + _fault_plane_flags(w))
+        smoke.check_device(s)
+        check(s["lanes"] == z["kafka_lanes"], f"kafka: {s['lanes']} lanes")
+        check(s["overflow_lanes"] == 0, "kafka: overflow lanes")
+        check(s["violations"] < s["lanes"] // 8,
+              f"kafka: {s['violations']} violating lanes of {s['lanes']}")
+        violating = dict(s["violating_seeds"])
+        picked = sorted(violating)[:1]
+        picked += [x for x in range(z["kafka_lanes"]) if x not in violating][:3]
+        done = dict.fromkeys(("ELECTED", "ISR_SHRUNK", "FENCED", "ACKED"), 0)
+        restores = 0
+        for rows in _lifted_rows(w, picked, violating, "kafka"):
+            brokers = rows[: nodes - 1]
+            for name in done:
+                start, length = lay[name]
+                done[name] += int(brokers[:, start : start + length].sum())
+            restores += int((brokers[:, kf.RESTORES] - 1).clip(min=0).sum())
+        check(done["ELECTED"] >= z["kafka_elections"],
+              f"kafka: the lifted lanes elect {done['ELECTED']} times")
+        check(done["FENCED"] > 0 and restores > 0,
+              f"kafka: the lifted lanes did {done}, {restores} restarts")
+        rec.update(
+            lanes=s["lanes"], violations=s["violations"],
+            lanes_lifted=len(picked), host_agrees=True,
+            lanes_digest=s["lanes_digest"], restores=restores,
             **{k.lower(): v for k, v in done.items()},
         )
 
@@ -646,6 +725,7 @@ def run(size: dict, device: dict) -> dict:
         phase_lift(smoke, sweep_summary)
         phase_datagram(smoke)
         phase_reconfig(smoke)
+        phase_kafka(smoke)
         phase_dpor(smoke)
         phase_minimize(smoke, workdir)
         if device["count"] > 1:

@@ -1,8 +1,10 @@
 """The bundled apps, written once against the DSL and run on both tiers:
 ``broadcast``, ``chain`` (chain replication with its failure repairs, over
-FIFO channels), ``paxos`` (Multi-Paxos as published, over datagram
-channels), ``raft``, ``raft_reconfig`` (the dissertation's Raft: persistent
-state, single-server membership changes, InstallSnapshot), ``spark_dag``,
+FIFO channels), ``kafka`` (partition replication as KIP-101 found it:
+many groups over one set of brokers, a controller, FIFO links), ``paxos``
+(Multi-Paxos as published, over datagram channels), ``raft``,
+``raft_reconfig`` (the dissertation's Raft: persistent state,
+single-server membership changes, InstallSnapshot), ``spark_dag``,
 ``twopc``, ``vsr`` (Viewstamped Replication Revisited). ``cli.build_app``
 names them for ``--app``."""
 

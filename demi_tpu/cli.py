@@ -90,9 +90,18 @@ def _make_app(args) -> DSLApp:
             )
         except ValueError as exc:
             raise SystemExit(f"--app raft_reconfig: {exc}")
+    if args.app == "kafka":
+        from .apps.kafka import make_kafka_app
+
+        try:
+            return make_kafka_app(
+                args.nodes, log_cap=args.log_cap, bug=args.bug
+            )
+        except ValueError as exc:
+            raise SystemExit(f"--app kafka: {exc}")
     raise SystemExit(
-        f"unknown app {args.app!r} (choices: broadcast, chain, paxos, raft, "
-        "raft_reconfig, spark, twopc, vsr)"
+        f"unknown app {args.app!r} (choices: broadcast, chain, kafka, paxos, "
+        "raft, raft_reconfig, spark, twopc, vsr)"
     )
 
 
@@ -121,6 +130,10 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         from .apps.raft_reconfig import reconfig_send_generator
 
         gen = reconfig_send_generator(app)
+    elif args.app == "kafka":
+        from .apps.kafka import kafka_send_generator
+
+        gen = kafka_send_generator(app)
     elif args.app == "broadcast":
         gen = broadcast_send_generator(app)
     else:
@@ -144,6 +157,7 @@ def build_fuzzer(app: DSLApp, args) -> Fuzzer:
         wait_budget=(
             None if args.wait_budget is None else tuple(args.wait_budget)
         ),
+        unkillable=[app.actor_name(i) for i in app.unkillable],
     )
 
 

@@ -128,6 +128,14 @@ class DSLApp:
     # three outcomes). Only an actor's message is kept or lost: a timer
     # and an external send are delivered exactly once.
     channels: str = "any"
+    # Actors the fault program may cut off and may not kill: a part of the
+    # deployment whose survival the source takes as given (Kafka's
+    # ZooKeeper quorum, ``apps/kafka.py``'s controller). A property of the
+    # deployment, so like ``channels`` no verb has a flag: the fuzzer draws
+    # no Kill or HardKill of such an actor (``cli.build_fuzzer`` names them
+    # to it) and draws its partitions as any other's. Empty, every started
+    # actor is a candidate, and the draws are what they were.
+    unkillable: Tuple[int, ...] = ()
 
     def __post_init__(self):
         if self.channels not in CHANNELS:
@@ -138,6 +146,11 @@ class DSLApp:
             raise ValueError(
                 f"invariant_at must be 'delivery' or 'quiescence', "
                 f"got {self.invariant_at!r}"
+            )
+        if any(not 0 <= i < self.num_actors for i in self.unkillable):
+            raise ValueError(
+                f"unkillable actors {self.unkillable!r} must lie in "
+                f"0..{self.num_actors - 1}"
             )
         if any(not 0 <= i < self.state_width for i in self.kept_words):
             raise ValueError(

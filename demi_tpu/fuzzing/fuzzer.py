@@ -119,6 +119,7 @@ class Fuzzer:
         wait_budget: Optional[tuple] = None,
         num_conditions: int = 0,
         max_sends: Optional[int] = None,
+        unkillable: Sequence[str] = (),
     ):
         self.num_events = num_events
         self.weights = weights
@@ -141,6 +142,9 @@ class Fuzzer:
         # Keeping a quorum alive is the app's concern; cap kills so fuzz runs
         # don't trivially kill everyone (the reference relies on weights).
         self.max_kills = max_kills
+        # Names no Kill or HardKill is drawn for (``DSLApp.unkillable``):
+        # they stay in ``alive``, so sends and partitions reach them.
+        self.unkillable = frozenset(unkillable)
         # Cap on client sends, atomic blocks' members included: where one
         # send fans out to thousands of deliveries (a 64-node broadcast
         # floods 4,033) the pool's bound is a bound on the floods in
@@ -226,6 +230,7 @@ class Fuzzer:
         random = rng.random
         num_events = self.num_events
         max_kills = self.max_kills
+        unkillable = self.unkillable
         # The sends drawn so far are ``len(payloads)``: the cap keeps no
         # count of its own, and costs an uncapped program one test a send.
         max_sends = self.max_sends
@@ -270,8 +275,14 @@ class Fuzzer:
                     col_b.append(0)
                     generated += 1
             elif op == OP_KILL or op == OP_HARDKILL:
-                if alive and (max_kills is None or kills < max_kills):
-                    victim = rng.choice(alive)
+                # Without unkillable names the candidates are ``alive``
+                # itself: the same draw as ever.
+                mortal = (
+                    [name for name in alive if name not in unkillable]
+                    if unkillable else alive
+                )
+                if mortal and (max_kills is None or kills < max_kills):
+                    victim = rng.choice(mortal)
                     alive.remove(victim)
                     killed.append(victim)
                     kills += 1

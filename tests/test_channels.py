@@ -199,7 +199,7 @@ def test_the_dpor_verb_refuses_it_in_one_sentence():
 
 
 def test_the_cli_knows_the_app():
-    with pytest.raises(SystemExit, match="chain, paxos, raft"):
+    with pytest.raises(SystemExit, match="chain, kafka, paxos, raft"):
         cli.main(["sweep", "--app", "nosuch"])
 
 

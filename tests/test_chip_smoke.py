@@ -88,6 +88,17 @@ def test_smoke_reconfig_sweep_restarts_from_disk_and_lifts(tiny_run):
     assert r["commit"] >= 3 and r["compactions"] > 0 and r["restores"] > 0
 
 
+def test_smoke_kafka_sweep_lifts_over_fifo_links(tiny_run):
+    """PR 52's phase: ``--app kafka`` under crash-recovery and cuts over
+    per-pair FIFO links; the lifted lanes' host rows hold elections, fenced
+    fetches and restarts from the durable rows."""
+    _lines, phases = tiny_run
+    r = phases["kafka_sweep"]
+    assert r["lanes"] == 32 and r["lanes_lifted"] in (3, 4)
+    assert r["host_agrees"] is True and r["violations"] <= 1
+    assert r["elected"] >= 6 and r["fenced"] > 0 and r["restores"] > 0
+
+
 def test_smoke_dpor_runs_its_budget_then_finds_and_verifies(tiny_run):
     _lines, phases = tiny_run
     assert phases["dpor_rounds"]["interleavings"] == 16 * 2

@@ -105,6 +105,11 @@ UNMODELED = {
     "raft_reconfig": (
         lambda: _raft_reconfig(), 10, "unsupported expression DictComp"
     ),
+    # kafka (PR 52): 15 tags over two roles (a broker's four timers and
+    # nine messages, the controller's session timer and its three; ISSUE 52
+    # counted 13), one handler that takes a 421-word row apart into a dict
+    # of arrays over the partition slots.
+    "kafka": (lambda: _kafka(), 15, "unsupported expression Dict"),
 }
 
 TAG_NAMES = {
@@ -117,6 +122,12 @@ TAG_NAMES = {
         "AppendEntries", "AppendReply", "InstallSnapshot", "SnapshotReply",
         "ClientCmd", "Admin",
     ),
+    "kafka": (
+        "FetchTimer", "IsrTimer", "CheckpointTimer", "HeartbeatTimer",
+        "SessionTimer", "Register", "Heartbeat", "LeaderAndIsr", "AlterIsr",
+        "AlterIsrResp", "Fetch", "FetchResp", "OffsetsForEpoch",
+        "OffsetsForEpochResp", "Produce",
+    ),
 }
 
 
@@ -124,6 +135,14 @@ def _paxos():
     from demi_tpu.apps.paxos import make_paxos_app
 
     return make_paxos_app(11, log_cap=4, bug="count_replies")
+
+
+def _kafka():
+    from demi_tpu.apps.kafka import make_kafka_app
+
+    app = make_kafka_app(6, log_cap=24, bug="truncate_to_hw")
+    assert app.state_width == 421 and app.unkillable == (5,)
+    return app
 
 
 def _raft_reconfig():
